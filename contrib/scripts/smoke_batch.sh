@@ -9,7 +9,7 @@
 #     solo execution across the whole distinct-task pool,
 #   * batch occupancy > 1 at concurrency 32 (batches actually formed),
 #   * batching-on c=32 device-path QPS beats batching-off on the
-#     emulated-relay-sync sweep (the regime PERF.md measures),
+#     emulated-device-sync sweep (a seeded delay, not a device number),
 # then replays distinct queries against a batching Node vs a --no_batch
 # Node end-to-end (flags surface) and checks the dgraph_batch_* series on
 # /debug/metrics. Runs entirely on the XLA host platform — no TPU needed.

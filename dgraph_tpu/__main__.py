@@ -11,9 +11,26 @@ from __future__ import annotations
 import argparse
 import sys
 
-from dgraph_tpu.utils import log
+from dgraph_tpu.utils import log, runtime
 
 VERSION = "dgraph-tpu 0.2.0"
+
+# subcommands that must leave the device to serve/worker (one process per
+# chip): main() fails them if they ever initialise a JAX backend
+HOST_ONLY = frozenset(
+    {"bulk", "zero", "live", "export", "convert", "ldbc_gen", "version"})
+
+
+def _init_backend(lg) -> dict:
+    """Bring the JAX backend up before the banner: a platform that cannot
+    initialise fails the start, and one that came up without an
+    accelerator is announced instead of discovered by the first query."""
+    info = runtime.init_backend()
+    if info["platform"] == "cpu":
+        lg.warn("no accelerator: device programs run on XLA:CPU and the "
+                "Pallas kernel tiers stay off (interpret mode)",
+                default_backend=info["default_backend"])
+    return runtime.banner_fields(info)
 
 
 def cmd_serve(args) -> int:
@@ -23,6 +40,7 @@ def cmd_serve(args) -> int:
     from dgraph_tpu.api.server import Node
 
     lg = log.get_logger("serve")
+    where = _init_backend(lg)
     node = Node(dirpath=args.postings, trace_fraction=args.trace,
                 memory_mb=args.memory_mb or None,
                 plan_cache_size=args.plan_cache,
@@ -60,6 +78,7 @@ def cmd_serve(args) -> int:
                 delta_journal_max_keys=args.delta_journal_max_keys or None,
                 qos=not args.no_qos,
                 tenants=args.tenants or None)
+    runtime.publish(where, node.metrics)
     if args.faults or args.faults_seed is not None:
         from dgraph_tpu.utils import faults as faults_mod
 
@@ -88,7 +107,7 @@ def cmd_serve(args) -> int:
                       tls_cert=args.tls_cert, tls_key=args.tls_key)
     lg.info(f"serving HTTP{'S' if args.tls_cert else ''} on "
             f"{args.host}:{srv.server_address[1]}",
-            postings=args.postings or "<memory>")
+            postings=args.postings or "<memory>", **where)
     try:
         srv.serve_forever()
     except KeyboardInterrupt:
@@ -183,6 +202,7 @@ def cmd_worker(args) -> int:
     from dgraph_tpu.utils.schema import parse_schema
 
     lg = log.get_logger("worker")
+    where = _init_backend(lg)
     store = Store(args.postings,
                   max_delta_keys=args.delta_journal_max_keys or None)
     if args.schema:
@@ -197,6 +217,7 @@ def cmd_worker(args) -> int:
                                 batch_max=args.batch_max,
                                 cost_ledger=not args.no_cost_ledger,
                                 lazy_folds=not args.no_lazy_folds)
+    runtime.publish(where, server.dgt_svc.metrics)
     if args.zero:
         import threading
 
@@ -252,7 +273,7 @@ def cmd_worker(args) -> int:
             # dgraph: allow(ctxvar-copy) detached membership bg loop
             threading.Thread(target=membership_loop, daemon=True).start()
     lg.info(f"worker serving {len(store.predicates())} tablets on "
-            f"{args.host}:{port}")
+            f"{args.host}:{port}", **where)
     try:
         while True:
             time.sleep(3600)
@@ -276,6 +297,7 @@ def cmd_zero(args) -> int:
                                                serve_zero_http)
 
     lg = log.get_logger("zero")
+    had_backend = runtime.backend_initialized()     # embedded callers
     zero = Zero(n_groups=args.groups, dirpath=args.wal)
     from dgraph_tpu.coord.zero_service import ZeroReplica, ZeroService
 
@@ -340,6 +362,8 @@ def cmd_zero(args) -> int:
                     lg.error("rebalance error", error=str(e))
         # dgraph: allow(ctxvar-copy) detached console-stats bg loop
         threading.Thread(target=loop, daemon=True).start()
+    if not had_backend:
+        runtime.assert_host_only("zero")
     lg.info(f"zero serving {args.groups} groups on {args.host}:{port}")
     try:
         while True:
@@ -708,7 +732,13 @@ def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
     if getattr(args, "log_json", False):
         log.configure(json_mode=True)
-    return args.fn(args)
+    # a caller that already holds a backend (tests, an embedding process)
+    # is not the subcommand's doing
+    had_backend = runtime.backend_initialized()
+    rc = args.fn(args)
+    if args.cmd in HOST_ONLY and not had_backend:
+        runtime.assert_host_only(args.cmd)
+    return rc
 
 
 if __name__ == "__main__":

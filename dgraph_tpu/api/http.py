@@ -42,7 +42,7 @@ from urllib.parse import parse_qs, urlparse
 from dgraph_tpu import tenancy as tnc
 from dgraph_tpu.api.server import Node
 from dgraph_tpu.coord.zero import TxnConflict
-from dgraph_tpu.utils import faults
+from dgraph_tpu.utils import faults, runtime
 from dgraph_tpu.utils.deadline import DeadlineExceeded, ResourceExhausted
 
 
@@ -491,7 +491,9 @@ class _Handler(BaseHTTPRequestHandler):
         "/debug/compiles": "XLA compile observatory: per-program-family "
                            "build/compile counts, cumulative compile ms, "
                            "live jit-cache sizes, last-trigger shapes, "
-                           "retrace-storm flags",
+                           "retrace-storm flags; `runtime` names the "
+                           "platform, device kind/count, compile-cache "
+                           "dir, native codec and per-device memory",
         "/debug/timeline": "device dispatch timeline ring as Chrome "
                            "trace-event JSON (load in Perfetto; ?view=raw "
                            "for the record list, ?n=256 bounds it)",
@@ -564,6 +566,10 @@ class _Handler(BaseHTTPRequestHandler):
             prof = self.node.devprof
             body = (prof.compiles_snapshot() if prof is not None
                     else {"enabled": False})
+            # where this process runs (platform, device kind/count, cache
+            # dir, native codec, per-device memory) — served with or
+            # without the observatory armed
+            body["runtime"] = runtime.describe()
             self._send(200, json.dumps(body, default=str).encode())
         elif path == "/debug/timeline":
             prof = self.node.devprof

@@ -17,10 +17,7 @@ import threading
 import time
 from concurrent import futures
 
-try:
-    import grpc
-except ImportError:              # pragma: no cover
-    grpc = None
+import grpc
 
 from ..obs import otrace
 from ..protos import internal_pb2 as ipb

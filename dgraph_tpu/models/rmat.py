@@ -29,19 +29,22 @@ def rmat_edges(scale: int, edge_factor: int = 16,
         src = (src << 1) | src_bit
         dst = (dst << 1) | dst_bit
     keep = src != dst
-    edges = np.stack([src[keep], dst[keep]], axis=1)
+    src, dst = src[keep], dst[keep]
     if dedup:
-        edges = np.unique(edges, axis=0)
-    return edges
+        # (src, dst) packed into one int64 key: a 1-D unique sorts and
+        # dedups in the same (src, dst) lexicographic order as a row-wise
+        # unique, several times faster at 16M edges
+        key = np.unique((src << 32) | dst)
+        src, dst = key >> 32, key & 0xFFFFFFFF
+    return np.stack([src, dst], axis=1)
 
 
 def rmat_csr(scale: int, edge_factor: int = 16, seed: int = 1,
              base_uid: int = 1):
     """R-MAT graph as a CSR (subjects, indptr, indices) with uids starting at
     base_uid (uid 0 is reserved, storage/postings.py VALUE_UID)."""
+    # deduplicated edges come back sorted by (src, dst) already
     edges = rmat_edges(scale, edge_factor, seed=seed) + base_uid
-    order = np.lexsort((edges[:, 1], edges[:, 0]))
-    edges = edges[order]
     subjects, counts = np.unique(edges[:, 0], return_counts=True)
     indptr = np.zeros(len(subjects) + 1, dtype=np.int64)
     np.cumsum(counts, out=indptr[1:])

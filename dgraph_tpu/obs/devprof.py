@@ -122,6 +122,10 @@ def unregister(p: "DevProfiler") -> None:
 # check is the armed tuple, so a later disarm costs one load per compile.
 
 _COMPILE_EVENT = "backend_compile_duration"
+# persistent compilation cache outcomes (one event per compile request
+# that consulted the cache): a warm restart should show hits and no misses
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hits",
+                 "/jax/compilation_cache/cache_misses": "misses"}
 
 
 def _on_duration_event(event: str, duration: float, **kw) -> None:
@@ -141,6 +145,13 @@ def _on_duration_event(event: str, duration: float, **kw) -> None:
         lg.add_compile(ms)
 
 
+def _on_event(event: str, **kw) -> None:
+    outcome = _CACHE_EVENTS.get(event)
+    if outcome is not None:
+        for p in _PROFILERS:
+            p.on_cache_event(outcome)
+
+
 def _install_listener_once() -> None:
     global _listener_installed
     if _listener_installed:
@@ -152,6 +163,7 @@ def _install_listener_once() -> None:
         return
     try:
         monitoring.register_event_duration_secs_listener(_on_duration_event)
+        monitoring.register_event_listener(_on_event)
     except Exception:
         pass
 
@@ -203,6 +215,7 @@ class DevProfiler:
         self._busy_ms = 0.0              # cumulative fenced run ms
         self._born = time.monotonic()
         self._cache_probes: list[tuple[str, object]] = []
+        self._pcache = {"hits": 0, "misses": 0}   # persistent compile cache
         self._hbm_capable: bool | None = None
         self._high_water: dict[str, int] = {}
         self._pressure_latched = False
@@ -247,6 +260,12 @@ class DevProfiler:
         self._c_compiles.inc()
         self._h_compile.observe(ms)
         self._note_event(family, None, compile_ms=ms)
+
+    def on_cache_event(self, outcome: str) -> None:
+        """One compile request hit or missed JAX's persistent compilation
+        cache (jax.monitoring event listener)."""
+        with self._lock:
+            self._pcache[outcome] += 1
 
     def _note_event(self, family: str, sig: str | None,
                     compile_ms: float | None) -> None:
@@ -303,6 +322,7 @@ class DevProfiler:
                        "recent_shapes": [s for _t, s in f["shapes"]][-8:]}
                 for name, f in sorted(self._fams.items())}
             probes = list(self._cache_probes)
+            pcache = dict(self._pcache)
         caches = {}
         for name, fn in probes:
             try:
@@ -321,6 +341,7 @@ class DevProfiler:
             "enabled": True,
             "families": fams,
             "cache_sizes": caches,
+            "persistent_cache": pcache,
             "compiles": self._c_compiles.value,
             "compile_ms_total": round(sum(
                 f["compile_ms"] for f in fams.values()), 3),

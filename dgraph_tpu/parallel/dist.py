@@ -26,10 +26,9 @@ from typing import NamedTuple
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dgraph_tpu.parallel.mesh import shard_map
 from dgraph_tpu.obs import devprof
 from dgraph_tpu.ops.uidset import sentinel, _dedup_sorted
 from dgraph_tpu.ops.csr import expand
@@ -105,8 +104,7 @@ def _local_rows(subjects: jax.Array, frontier: jax.Array) -> jax.Array:
 def _expand_program(mesh: Mesh, fcap: int, edge_cap: int):
     """ONE compiled sharded-expand per (mesh, frontier cap, edge cap) —
     rebuilding the shard_map closure per call would retrace + recompile
-    every dispatch (the host-round-trip tax PERF.md measured at
-    ~100-150 ms). Each shard resolves the replicated frontier against its
+    every dispatch. Each shard resolves the replicated frontier against its
     local subject rows and gathers its adjacency slices — this is
     ProcessTaskOverNetwork's scatter (worker/task.go:137) with the gRPC
     fan-out replaced by SPMD over the mesh; the host reassembles the
@@ -129,7 +127,7 @@ def _expand_program(mesh: Mesh, fcap: int, edge_cap: int):
         shard_map, mesh=mesh,
         in_specs=(P("shard"), P("shard"), P("shard"), P()),
         out_specs=(P("shard"), P("shard"), P()),
-        check_rep=False,
+        check_vma=False,
     )
     def run(sub, ptr, idx, fr):
         rows = _local_rows(sub[0], fr)
@@ -265,8 +263,8 @@ def _k_hop_program(mesh: Mesh, hops: int, frontier_cap: int, num_nodes: int,
                    edge_cap: int):
     """Cached jitted k-hop program — building the shard_map closure inside
     dist_k_hop made EVERY call a fresh function identity, so jax retraced
-    the whole hop loop per query (the dominant fixed cost of the
-    MULTICHIP_r0* dryruns)."""
+    the whole hop loop per query (the dominant fixed cost of the early
+    multi-device dry runs)."""
     devprof.note_build("dist.k_hop",
                        (hops, frontier_cap, num_nodes, edge_cap))
 
@@ -290,7 +288,7 @@ def _k_hop_program(mesh: Mesh, hops: int, frontier_cap: int, num_nodes: int,
         shard_map, mesh=mesh,
         in_specs=(P("shard"), P("shard"), P("shard"), P(), P()),
         out_specs=(P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     def run(sub, ptr, idx, seeds_in, visited0):
         def body(_i, carry):

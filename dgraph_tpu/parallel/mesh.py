@@ -19,25 +19,9 @@ DCN-for-control split.
 
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
-
-# shard_map compat shim — the ONE definition every mesh module imports
-# (parallel/dist.py, parallel/mesh_exec.py). Newer jax exposes
-# jax.shard_map with check_vma replacing check_rep; older jax keeps the
-# experimental module with check_rep. Callers write check_rep=... and the
-# shim translates.
-try:
-    from jax import shard_map as _shard_map
-
-    def shard_map(f=None, **kw):          # new API: check_vma replaces check_rep
-        kw["check_vma"] = kw.pop("check_rep", kw.pop("check_vma", True))
-        return _shard_map(f, **kw) if f is not None else partial(_shard_map, **kw)
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map  # noqa: F401
 
 
 def make_mesh(n_shards: int | None = None, devices=None) -> Mesh:

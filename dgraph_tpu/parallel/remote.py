@@ -18,12 +18,8 @@ import threading
 import time
 from concurrent import futures
 
+import grpc
 import numpy as np
-
-try:
-    import grpc
-except ImportError:              # pragma: no cover
-    grpc = None
 
 from .. import tenancy as tnc
 from ..obs import costs, otrace
@@ -219,9 +215,9 @@ class WorkerService:
         # per predicate — the assembler replaces (never mutates) a
         # PredData on any visible commit/overlay-stamp/replay/drop.
         self.task_cache = TaskResultCache(32 << 20, self.metrics)
-        # device-dispatch batcher (ISSUE 9): the wire path is where the
-        # fixed per-dispatch relay sync dominates (PERF.md configs 4-5),
-        # so concurrent fanned-in ServeTask calls that classify as the
+        # device-dispatch batcher (ISSUE 9): the wire path fans many small
+        # device steps into one worker, each paying the fixed dispatch +
+        # sync, so concurrent fanned-in ServeTask calls that classify as the
         # same device-class kernel pack into ONE launch exactly like the
         # embedded node's. No DispatchGate on the worker: the batcher runs
         # the kernel directly and idle-fires off its own in-flight count.

@@ -6,7 +6,7 @@ posting-list LRU (posting/lists.go:123, caching decoded lists across
 queries) and per-goroutine task reuse; repeated traffic mostly re-reads
 memory. This port re-parsed every DQL string and re-executed every
 process_task per query. The three tiers here convert the single-query
-kernel wins (PERF.md rounds 1-5) into QPS:
+kernel wins into QPS:
 
   * PlanCache — parsed ASTs keyed on (DQL text, variables signature). The
     parsed tree is read-only during execution (the executor only ever
@@ -563,8 +563,8 @@ class DispatchGate:
         t0 = time.perf_counter()
         try:
             # device.step fires while HOLDING the slot: a slow device
-            # program (or the distributed configs' fixed relay sync),
-            # serialized by the gate exactly like real device occupancy —
+            # program (or an emulated fixed device sync), serialized by
+            # the gate exactly like real device occupancy —
             # device.dispatch above models pre-gate submission latency
             faults.fire("device.step", m=self.metrics)
             ds = time.perf_counter() - t0

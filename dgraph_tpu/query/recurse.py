@@ -15,7 +15,7 @@ TPU shape — one hot path, benched and served alike (worker/task.go:605):
     node-sized bounds-diff for the next frontier. The reach-set of
     recurse.go:129 is a device-resident bool vector over the edge stream.
     The common single-child no-filter shape runs ALL levels in one
-    dispatch (recurse_fused lax.scan) — no relay sync between levels.
+    dispatch (recurse_fused lax.scan) — no host sync between levels.
     Per-source target lists (uidMatrix) stay CSR-shaped and deferred
     (LazyRecurseMatrix): output encoders materialize on demand.
   * Small CSRs keep the vectorized host-mirror gather (the size-adaptive
@@ -31,7 +31,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from dgraph_tpu.obs import costs
+from dgraph_tpu.obs import costs, otrace
 from dgraph_tpu.query import dql
 from dgraph_tpu.query.engine import QueryError, SubGraph
 from dgraph_tpu.query.task import TaskQuery, process_task
@@ -421,7 +421,7 @@ def _mesh_recurse_path(ex, sg: SubGraph, cgq, csr, depth: int,
             child.dest_uids = ex._apply_filter(cgq.filter,
                                                child.dest_uids)
             cur.append(child)
-            # cross-check the device program's frontier relay against
+            # cross-check the device program's level frontiers against
             # the host replay (the host — which evaluates the REAL
             # filter tree — stays authoritative, so a divergence means
             # an allow-set resolver gap or a program bug: surfaced as a
@@ -451,18 +451,31 @@ def _recurse_fused_path(ex, sg: SubGraph, cgq, csr, depth: int,
     # traversals stack their seed masks into one multi-source dispatch;
     # without a batcher this is exactly the old gated solo call
     def _solo_fused():
-        with costs.kernel("pb.recurse_fused", attr=cgq.attr):
-            return pb.recurse_fused(
+        with otrace.span("device_kernel", kernel="pb.recurse_fused",
+                         depth=depth, edges=g.num_edges) as sp, \
+                costs.kernel("pb.recurse_fused", attr=cgq.attr) as ck:
+            masks_p, trav, fresh = pb.recurse_fused(
                 g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank, g.subjects,
                 g.in_subjects, seeds_mask,
                 depth=depth, chunks=g.chunks, chunks_d=g.chunks_d,
                 allow_loop=allow_loop)
+            # the fetch is the fence: dispatch is asynchronous, so the
+            # timer (and the gate slot) must cover it to book device time
+            masks_h, trav_h = jax.device_get((masks_p, trav))
+            d2h = int(masks_h.nbytes + trav_h.nbytes)
+            ck.set(h2d=int(seeds_mask.nbytes), d2h=d2h)
+            if sp:
+                sp.set(transfer_h2d_bytes=int(seeds_mask.nbytes),
+                       transfer_d2h_bytes=d2h)
+            return masks_h, trav_h, fresh
 
     masks_p, trav, fresh = ex.batched_recurse(
         g, seeds_mask, depth, allow_loop, _solo_fused)
-    # ONE relay round-trip for the whole traversal, bit-packed in DST-RANK
+    # ONE host round-trip for the whole traversal, bit-packed in DST-RANK
     # space (fresh flags stay on device until a lazy uidMatrix
-    # materialization needs them); host maps ranks -> uids
+    # materialization needs them); host maps ranks -> uids. A no-op after
+    # the solo closure, which fetched under its timer; the batched runner
+    # hands back device slices.
     masks_h, trav_h = jax.device_get((masks_p, trav))
     nd = len(g.host_in_subjects)
     shared_fresh = FreshFlags(fresh)

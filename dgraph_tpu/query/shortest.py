@@ -28,7 +28,7 @@ import heapq
 
 import numpy as np
 
-from dgraph_tpu.obs import costs
+from dgraph_tpu.obs import costs, otrace
 from dgraph_tpu.query import dql
 from dgraph_tpu.query.engine import QueryError, SubGraph
 from dgraph_tpu.query.task import TaskQuery
@@ -104,9 +104,9 @@ SSSP_KERNEL_MIN: int | None = None
 
 
 _SSSP_KERNEL_MIN_TPU = 1 << 17   # == the device tier's default floor —
-# the kernel's bit-packed distance fetch (~Nd/8 bytes) beats Bellman-
-# Ford's dist+parent fetch (8 B/node) through the relay at every size the
-# device path serves. A SEPARATE constant: tests monkeypatch
+# the kernel's bit-packed distance fetch (~Nd/8 bytes) moves 64x fewer
+# bytes to the host than Bellman-Ford's dist+parent fetch (8 B/node) at
+# every size the device path serves. A SEPARATE constant: tests monkeypatch
 # DEVICE_SSSP_MIN_EDGES to force the sssp tier on tiny graphs, and the
 # kernel floor must not follow it down.
 
@@ -147,14 +147,16 @@ def _device_csr(ex, sg: SubGraph):
     return cgq.attr, csr
 
 
-def _device_shortest(attr: str, csr, src: int, dst: int, max_depth: int):
+def _device_shortest(ex, attr: str, csr, src: int, dst: int,
+                     max_depth: int):
     """Unweighted single-source shortest path on device, parent chain
-    walked on host. On TPU the Pallas BFS kernel serves the whole device
-    range (bfs_dist — one dispatch for the whole hop loop, bit-packed
-    distance fetch); the Bellman-Ford relaxation (ops/traversal.sssp)
-    serves extreme depths (>= 254) and non-TPU backends. Work is bounded
-    by iterations x E (the resident CSR), so the reference's
-    discovered-edge budget does not apply here."""
+    walked on host, run through the dispatch gate under a device_kernel
+    span and a cost timer like every other device step. On TPU the Pallas
+    BFS kernel serves the whole device range (bfs_dist — one dispatch for
+    the whole hop loop, bit-packed distance fetch); the Bellman-Ford
+    relaxation (ops/traversal.sssp) serves extreme depths (>= 254) and
+    non-TPU backends. Work is bounded by iterations x E (the resident
+    CSR), so the reference's discovered-edge budget does not apply here."""
     from dgraph_tpu.ops import traversal
 
     from dgraph_tpu.ops.pallas_bfs import DIST_UNREACHED
@@ -165,8 +167,13 @@ def _device_shortest(attr: str, csr, src: int, dst: int, max_depth: int):
     if csr.num_edges >= _sssp_kernel_min() and max_depth < DIST_UNREACHED:
         from dgraph_tpu.ops import pallas_bfs as pb
 
-        g = pb.pull_graph_for(csr)
-        path = pb.shortest_bfs(g, src, dst, max_depth)
+        g = pb.pull_graph_for(csr)      # host prep: outside the timer
+        with otrace.span("device_kernel", kernel="pb.bfs_dist",
+                         edges=g.num_edges), \
+                costs.kernel("pb.bfs_dist", attr=attr):
+            path = ex.gated(
+                lambda: pb.shortest_bfs(g, src, dst, max_depth),
+                klass="shortest")
         if path is None:
             return None
         return (float(len(path) - 1), path, [attr] * (len(path) - 1))
@@ -178,12 +185,18 @@ def _device_shortest(attr: str, csr, src: int, dst: int, max_depth: int):
         return None              # endpoint outside this predicate's uid space
     # pow2 capacity class: snapshot-to-snapshot uid growth must not retrace
     num_nodes = 1 << max(int(np.ceil(np.log2(hi + 2))), 4)
-    res = traversal.sssp(csr.subjects, csr.indptr, csr.indices, None,
-                         src, num_nodes=num_nodes, max_iters=max_depth)
-    dist = float(np.asarray(res.dist[dst]))
+    def _sssp():
+        res = traversal.sssp(csr.subjects, csr.indptr, csr.indices, None,
+                             src, num_nodes=num_nodes, max_iters=max_depth)
+        d = float(np.asarray(res.dist[dst]))
+        return d, (np.asarray(res.parent) if np.isfinite(d) else None)
+
+    with otrace.span("device_kernel", kernel="traversal.sssp",
+                     edges=csr.num_edges), \
+            costs.kernel("traversal.sssp", attr=attr):
+        dist, parent = ex.gated(_sssp, klass="shortest")
     if not np.isfinite(dist):
         return None
-    parent = np.asarray(res.parent)
     path = [dst]
     while path[-1] != src:
         p = int(parent[path[-1]])
@@ -367,7 +380,7 @@ def shortest_path(ex, sg: SubGraph) -> None:
         dev = _device_csr(ex, sg)
         mesh = _mesh_csrs(ex, sg) if dev is None else None
         if dev is not None:
-            p = _device_shortest(dev[0], dev[1], src, dst, max_depth)
+            p = _device_shortest(ex, dev[0], dev[1], src, dst, max_depth)
             sg.paths = [p] if p is not None else []
         elif mesh is not None and spec.numpaths <= 1:
             p = _mesh_shortest_single(ex, sg, mesh, src, dst)

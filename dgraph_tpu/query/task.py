@@ -248,7 +248,13 @@ def _expand_csr(csr: PredCSR, uids: np.ndarray, first: int = 0,
                                          rows, deg, uids, need, cutover)
             total = need
         else:
-            matrix, total = csr.expand_matrix(uids)
+            with otrace.span("device_kernel", kernel="dist.expand",
+                             need=need,
+                             cutover=int(cutover or HOST_EXPAND_MAX)) as sp, \
+                    costs.kernel("dist.expand"):
+                matrix, total = csr.expand_matrix(uids)
+                if sp:
+                    sp.set(edges=total)
     elif isinstance(csr, OverlayCSR):
         matrix, total = _expand_overlay(csr, uids, cutover)
     else:
@@ -901,16 +907,6 @@ def _case_variants(tri: str) -> list[str]:
 _MAX_PLAN_ALTS = 16     # alternation product cap (planner bail-out)
 
 
-def _sre_parser():
-    """The stdlib regex parser module: re._parser on 3.11+, sre_parse
-    before (same API — the 3.11 rename left the parse() surface intact)."""
-    try:
-        import re._parser as sre
-    except ImportError:
-        import sre_parse as sre
-    return sre
-
-
 def _lit_alternatives(seq) -> list[list[str]] | None:
     """Required-literal analysis of a parsed regex sequence (simplified
     codesearch index/regexp, the planner behind worker/trigram.go:36).
@@ -985,7 +981,7 @@ def _trigram_plan(pattern: str) -> list[list[str]] | None:
     trigram's uid list). None = no branch has a literal >= 3 chars, or the
     pattern is beyond the planner — caller falls back to the full scan."""
     try:
-        parsed = list(_sre_parser().parse(pattern))
+        parsed = list(remod._parser.parse(pattern))
     except Exception:
         return None
     alts = _lit_alternatives(parsed)

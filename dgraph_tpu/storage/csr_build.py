@@ -333,7 +333,7 @@ class GraphSnapshot:
 # ---------------------------------------------------------------------------
 #
 # Eager assembly folded EVERY predicate at snapshot time — ~4 µs/list of
-# Python (PERF.md round 5), i.e. 13-20 s to the first query at 10M edges
+# Python on a CPU host, i.e. 13-20 s to the first query at 10M edges
 # and minutes at LDBC-SNB SF10+. The scale-regime cold path instead
 # registers unfolded tablets as fold-THUNKS: the first read of a predicate
 # (task/engine seams via GraphSnapshot.pred / LazyPreds.get), a residency

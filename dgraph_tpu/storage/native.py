@@ -1,10 +1,12 @@
 """ctypes binding for the native codec (native/codec.cc).
 
-Builds libdgt.so on first import when missing (g++ one-liner — the image has
-no pybind11, and a flat C ABI keeps the binding dependency-free). Every entry
-degrades to the numpy codec when the toolchain or library is unavailable:
-`available()` gates use, and storage/packed.py stays the source of truth for
-the wire format (the native codec is bit-identical and tested against it).
+Builds libdgt.so from the committed source on first use when it is absent
+or older than codec.cc (g++ one-liner — the image has no pybind11, and a
+flat C ABI keeps the binding dependency-free). Every entry degrades to the
+numpy codec when the toolchain or library is unavailable — logged once at
+WARNING, and visible as `status()` on /debug/compiles: `available()` gates
+use, and storage/packed.py stays the source of truth for the wire format
+(the native codec is bit-identical and tested against it).
 """
 
 from __future__ import annotations
@@ -15,11 +17,14 @@ import subprocess
 
 import numpy as np
 
+from dgraph_tpu.utils import log
+
 _DIR = os.path.join(os.path.dirname(__file__), "..", "..", "native")
 _SO = os.path.join(_DIR, "libdgt.so")
 
 _lib = None
 _tried = False
+_status = "unavailable"     # "loaded" | "built" | "unavailable" after _load
 
 
 def _build() -> bool:
@@ -46,27 +51,42 @@ def _build() -> bool:
         return False
 
 
-def _load():
-    global _lib, _tried
-    if _tried:
-        return _lib
-    _tried = True
+def _open():
+    """(CDLL | None, built-this-call): load libdgt.so, building it first
+    when absent or older than the committed source."""
     src = os.path.join(_DIR, "codec.cc")
+    built = False
     if not os.path.exists(_SO) or (
             os.path.exists(src)
             and os.path.getmtime(_SO) < os.path.getmtime(src)):
         if not _build():
-            return None
+            return None, False
+        built = True
     try:
-        lib = ctypes.CDLL(_SO)
+        return ctypes.CDLL(_SO), built
     except OSError:
         # stale/torn .so from an interrupted build: rebuild once
-        if not _build():
-            return None
+        if built or not _build():
+            return None, False
         try:
-            lib = ctypes.CDLL(_SO)
+            return ctypes.CDLL(_SO), True
         except OSError:
-            return None
+            return None, False
+
+
+def _load():
+    global _lib, _tried, _status
+    if _tried:
+        return _lib
+    _tried = True
+    lib, built = _open()
+    if lib is None:
+        log.get_logger("native").warn(
+            "native codec unavailable: the numpy codec is serving "
+            "(g++ or native/codec.cc missing, or the build failed)",
+            so=os.path.normpath(_SO))
+        return None
+    _status = "built" if built else "loaded"
     i64, u64p = ctypes.c_int64, np.ctypeslib.ndpointer(np.uint64, flags="C")
     u32p = np.ctypeslib.ndpointer(np.uint32, flags="C")
     i32p = np.ctypeslib.ndpointer(np.int32, flags="C")
@@ -87,6 +107,14 @@ def _load():
 
 def available() -> bool:
     return _load() is not None
+
+
+def status() -> str:
+    """"loaded" (an up-to-date libdgt.so was on disk), "built" (this
+    process compiled it from native/codec.cc) or "unavailable" (the numpy
+    codec is serving)."""
+    _load()
+    return _status
 
 
 def pack(uids: np.ndarray):

@@ -450,6 +450,12 @@ class Registry:
             labels=("tier",))
         self.keyed_gauges["dgraph_devprof_hbm_highwater_bytes"] = \
             KeyedGauge(labels=("tier",))
+        # where the process runs (utils/runtime.py): one series at 1,
+        # published by serve/worker after backend init; sums to a
+        # per-platform process count on Zero's /metrics/fleet
+        self.keyed_gauges["dgraph_runtime_info"] = KeyedGauge(
+            labels=("platform", "device_kind", "devices", "compile_cache",
+                    "native_codec"))
         # multi-tenant QoS (dgraph_tpu/tenancy/; ISSUE 20): per-tenant
         # cost attribution in cost-ledger units plus the shed counter —
         # labeled series so one Grafana row ranks tenants. Values are

@@ -47,17 +47,12 @@ def transport_errors() -> tuple:
     programming error: connection loss, RPC failure, and the replication
     layer's quorum loss. RuntimeError is included for the repo's
     'no live leader' / 'no connection to group' routing errors."""
+    import grpc
+
     from ..parallel.remote import NoQuorum
 
-    errs: list[type] = [ConnectionError, OSError, TimeoutError,
-                        NoQuorum, RuntimeError]
-    try:
-        import grpc
-
-        errs.append(grpc.RpcError)
-    except ImportError:                       # pragma: no cover
-        pass
-    return tuple(errs)
+    return (ConnectionError, OSError, TimeoutError, NoQuorum, RuntimeError,
+            grpc.RpcError)
 
 
 def backoff_s(attempt: int, base_s: float = 0.05, cap_s: float = 1.0,
