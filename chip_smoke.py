@@ -66,20 +66,23 @@ RECURSE_SEEDS = 128
 RECURSE_DEPTH = 3
 SHORTEST_PAIRS = 10
 
-# the crossovers the graph must sit above for the device tiers to engage
-# by default (values as committed; the smoke only compares against them)
-PLANNER_DEVICE_MIN = 1 << 20    # query/planner.py DEVICE_MIN_EDGES: the
-#   planner keeps an expand it estimates below this on the host gather,
-#   whatever query/task.py HOST_EXPAND_MAX (1 << 16) says
-HOP_GROUPS = 8                  # one-hop root: 8 of the 64 grp values
-KERNEL_MIN_EDGES = 1 << 20      # query/recurse.py _KERNEL_MIN_TPU
-SSSP_MIN_EDGES = 1 << 17        # query/shortest.py
-HOST_AGG_MAX = 1 << 17          # query/groupby.py
+HOP_GROUPS = 8      # one-hop root: 8 of the 64 grp values, a ~68k-subject
+#                     frontier whose expand the planner estimates above its
+#                     device floor. No crossover value is copied here: a
+#                     query that stayed on the host shows no device_kernel
+#                     span of its tier, and that is what fails the run
 
 
 # rmat20 x 16 runs inside the contract's time limit (~420 s on the chip
 # machine), so nothing is cut; a cut of scale would be named here
 SCALE_CUT = None
+
+
+# every phase of a complete run, in order; a run that ends without one of
+# them did not reach its end, whatever else it reports
+PHASES = ("generate", "bulk", "serve_cold", "battery", "device_evidence",
+          "write_readback", "stop", "restart", "restart_readback",
+          "restart_battery", "stop_restart")
 
 
 @dataclass
@@ -305,15 +308,21 @@ def pick_write_edge(g: Graph, seeds: np.ndarray, n_shards: int):
     bounds = g.indptr[np.minimum(np.arange(n_shards + 1) * rows_per,
                                  len(g.subjects))]
     heavy = int(np.argmax(np.diff(bounds))) if n_shards > 1 else -1
-    s0 = next(int(s) for s in seeds[::-1].tolist()
-              if g.row[s] // rows_per != heavy)
     if len(g.indices) % 8192 == 0:
-        raise RuntimeError("edge count sits on a padding-block boundary")
+        raise RuntimeError("edge count sits on a padding-block boundary: "
+                           "one more edge would move a program's shape")
+    s0 = next((int(s) for s in seeds[::-1].tolist()
+               if g.row[s] // rows_per != heavy), None)
+    if s0 is None:
+        raise RuntimeError("every recurse seed sits in the heaviest shard")
     is_dest = np.zeros(g.n, dtype=bool)
     is_dest[g.indices] = True
     light = g.subjects[(g.degree[g.subjects] <= 2) & is_dest[g.subjects]]
-    t0 = next(int(t) for t in light.tolist()
-              if t != s0 and not g.has_edge(s0, t))
+    t0 = next((int(t) for t in light.tolist()
+               if t != s0 and not g.has_edge(s0, t)), None)
+    if t0 is None:
+        raise RuntimeError(f"no light target for a new edge out of "
+                           f"{s0:#x}")
     return s0, t0
 
 
@@ -474,20 +483,28 @@ class Server:
         return found
 
     def stop(self) -> None:
+        """Clean shutdown over /admin/shutdown. A server that has to be
+        signalled instead is a failed run: the restart phases would be
+        reading a WAL the server never closed."""
         if self.proc.poll() is None:
             try:
                 self.call("POST", "/admin/shutdown", "", timeout=30)
-            except (OSError, urllib.error.URLError):
+            except OSError:         # the listener may close mid-reply
                 pass
             try:
                 self.proc.wait(timeout=120)
             except subprocess.TimeoutExpired:
+                self.run.fail(f"serve ({self.tag}) did not exit within 120s "
+                              f"of /admin/shutdown; terminated")
                 self.proc.terminate()
                 try:
                     self.proc.wait(timeout=30)
                 except subprocess.TimeoutExpired:
                     self.proc.kill()
                     self.proc.wait(timeout=30)
+            self.run.require(self.proc.returncode == 0,
+                             f"serve ({self.tag}) exited "
+                             f"{self.proc.returncode}")
         self.log.close()
 
 
@@ -668,10 +685,6 @@ def battery(run: Run, srv: Server, g: Graph, rng) -> dict:
         tag = f"hop{i}"
         deg_sum = int(g.degree[(g.grp >= lo)
                                & (g.grp < lo + HOP_GROUPS)].sum())
-        if not cfg.rehearsal:
-            run.require(deg_sum > PLANNER_DEVICE_MIN,
-                        f"{tag}: frontier degree sum {deg_sum} is not above "
-                        f"the planner's device floor")
         want = ref_onehop(g, lo, sv)
         rec = run_query(run, srv, tag, q_onehop(tag, lo, sv),
                         "dist.expand" if mesh else "csr.expand",
@@ -720,7 +733,8 @@ def battery(run: Run, srv: Server, g: Graph, rng) -> dict:
 
 # -- server-wide evidence -----------------------------------------------------
 
-def check_device_evidence(run: Run, srv: Server, recs: list[dict]) -> dict:
+def check_device_evidence(run: Run, srv: Server, recs: list[dict],
+                          edges: int) -> dict:
     cfg = run.cfg
     comp = srv.call("GET", "/debug/compiles")
     rt = comp["runtime"]
@@ -778,10 +792,16 @@ def check_device_evidence(run: Run, srv: Server, recs: list[dict]) -> dict:
                     f"over {rt['device_count']} devices")
         run.require((m.get("dgraph_mesh_sharded_tablets") or 0) >= 1,
                     "no mesh-sharded tablet")
+        # every shard of `follows` is padded to the heaviest shard, so
+        # each device holds at least an even share of the int32 edge
+        # array; replicated arguments (node-sized) are far below that
+        share = 4 * edges // rt["device_count"]
+        ev["mesh"]["tablet_share_bytes"] = share
         for d in rt["devices"]:
-            run.require(d["peak_bytes_in_use"] > 0,
-                        f"device {d['id']} holds no bytes: the tablet is "
-                        f"not on all devices")
+            run.require(d["peak_bytes_in_use"] >= share,
+                        f"device {d['id']} peaked at "
+                        f"{d['peak_bytes_in_use']} bytes, below its "
+                        f"{share}-byte share of the sharded tablet")
         for r in recs:
             if r["query"].startswith(("hop", "chain", "rec", "sp")):
                 run.require(r["mesh_dispatches"] == 1,
@@ -832,11 +852,6 @@ def run_smoke(run: Run) -> None:
         ph["edges"], ph["subjects"] = len(g.indices), len(g.subjects)
         ph["quads"] = quads
         ph["rdf_mb"] = round(os.path.getsize(rdf) / 1e6, 1)
-        if not cfg.rehearsal:
-            run.require(len(g.indices) >= KERNEL_MIN_EDGES
-                        and len(g.indices) >= SSSP_MIN_EDGES
-                        and len(g.subjects) > HOST_AGG_MAX,
-                        "graph is below a device crossover")
 
     with Phase(run, "bulk") as ph:
         # host only: the parent decides loaded|built before any child can
@@ -878,7 +893,8 @@ def run_smoke(run: Run) -> None:
             plan = battery(run, srv, g, rng)
             ph["queries"] = plan["records"]
         with Phase(run, "device_evidence") as ph:
-            ev = check_device_evidence(run, srv, plan["records"])
+            ev = check_device_evidence(run, srv, plan["records"],
+                                       len(g.indices))
             ph.fields.update(ev)
             summary["cold_compile_seconds"] = round(
                 (ev["compile_ms_total"] or 0) / 1e3, 3)
@@ -890,13 +906,13 @@ def run_smoke(run: Run) -> None:
 
         # after the read battery: a delta overlay on `follows` would take
         # it off the kernel path until compaction
-        seeds0 = plan["recurse_seeds"][0]
-        s0, t0 = pick_write_edge(g, seeds0, rt["device_count"]
-                                 if cfg.mesh else 1)
-        readback = (f"{{ rb(func: uid({hex(s0)})) {{ follows "
-                    f"@filter(uid({hex(t0)})) {{ uid }} }} }}")
-        want_rb = [{"follows": [{"uid": hex(t0)}]}]
         with Phase(run, "write_readback") as ph:
+            seeds0 = plan["recurse_seeds"][0]
+            s0, t0 = pick_write_edge(g, seeds0, rt["device_count"]
+                                     if cfg.mesh else 1)
+            readback = (f"{{ rb(func: uid({hex(s0)})) {{ follows "
+                        f"@filter(uid({hex(t0)})) {{ uid }} }} }}")
+            want_rb = [{"follows": [{"uid": hex(t0)}]}]
             before, _ = srv.query(readback)
             run.require(not before.get("rb"),
                         "edge present before the write")
@@ -913,7 +929,6 @@ def run_smoke(run: Run) -> None:
         with Phase(run, "stop"):
             srv.stop()
 
-    g2 = g.with_edge(s0, t0)
     with Phase(run, "restart") as ph:
         srv = Server(run, postings, "warm")
         ph["banner"] = srv.banner
@@ -926,7 +941,7 @@ def run_smoke(run: Run) -> None:
             # the same text as the cold run's first recurse, over the
             # graph that now holds the acknowledged edge out of a seed
             q = q_recurse("rec0", seeds0)
-            check, _ = check_recurse(g2, "rec0", seeds0)
+            check, _ = check_recurse(g.with_edge(s0, t0), "rec0", seeds0)
             rec = run_query(run, srv, "rec0_restart", q,
                             "mesh.recurse" if cfg.mesh
                             else "pb.recurse_fused", check,
@@ -978,8 +993,10 @@ def main(argv=None) -> int:
     t0 = time.perf_counter()
     try:
         run_smoke(run)
-    except Exception:       # noqa: BLE001 — top boundary: report, exit 1
-        # the failed phase already printed itself and is in run.failures
+    except Exception as e:  # noqa: BLE001 — top boundary: report, exit 1
+        # whatever raised, inside a phase or between two, the run did not
+        # reach its end: that alone is a failure
+        run.failures.append(f"aborted: {type(e).__name__}: {e}")
         traceback.print_exc()
     finally:
         for p in run.procs:
@@ -992,6 +1009,9 @@ def main(argv=None) -> int:
                           errors="replace") as f:
                     dump(name, f.read()[-20000:])
         shutil.rmtree(workdir, ignore_errors=True)
+    skipped = [p for p in PHASES if p not in run.timings]
+    if skipped:
+        run.failures.append(f"phases not run: {', '.join(skipped)}")
     summary = run.summary
     emit({"summary": "chip_smoke", "rehearsal": cfg.rehearsal,
           "mesh": cfg.mesh, "scale": f"rmat{cfg.scale}x{cfg.edge_factor}",

@@ -25,12 +25,14 @@ def _init_backend(lg) -> dict:
     """Bring the JAX backend up before the banner: a platform that cannot
     initialise fails the start, and one that came up without an
     accelerator is announced instead of discovered by the first query."""
+    from dgraph_tpu.storage import native
+
     info = runtime.init_backend()
     if info["platform"] == "cpu":
         lg.warn("no accelerator: device programs run on XLA:CPU and the "
                 "Pallas kernel tiers stay off (interpret mode)",
                 default_backend=info["default_backend"])
-    return runtime.banner_fields(info)
+    return {**runtime.banner_fields(info), "native_codec": native.status()}
 
 
 def cmd_serve(args) -> int:
@@ -78,7 +80,6 @@ def cmd_serve(args) -> int:
                 delta_journal_max_keys=args.delta_journal_max_keys or None,
                 qos=not args.no_qos,
                 tenants=args.tenants or None)
-    runtime.publish(where, node.metrics)
     if args.faults or args.faults_seed is not None:
         from dgraph_tpu.utils import faults as faults_mod
 
@@ -217,7 +218,10 @@ def cmd_worker(args) -> int:
                                 batch_max=args.batch_max,
                                 cost_ledger=not args.no_cost_ledger,
                                 lazy_folds=not args.no_lazy_folds)
-    runtime.publish(where, server.dgt_svc.metrics)
+    # a worker has no HTTP debug surface: the banner fields ride its
+    # Status metrics as one series at 1 (Zero federates it on /metrics/fleet)
+    info_g = server.dgt_svc.metrics.keyed("dgraph_runtime_info")
+    info_g.set("|".join(str(where[k]) for k in info_g.labels), 1)
     if args.zero:
         import threading
 

@@ -42,6 +42,8 @@ from urllib.parse import parse_qs, urlparse
 from dgraph_tpu import tenancy as tnc
 from dgraph_tpu.api.server import Node
 from dgraph_tpu.coord.zero import TxnConflict
+from dgraph_tpu.ops import pallas_bfs
+from dgraph_tpu.storage import native
 from dgraph_tpu.utils import faults, runtime
 from dgraph_tpu.utils.deadline import DeadlineExceeded, ResourceExhausted
 
@@ -569,7 +571,10 @@ class _Handler(BaseHTTPRequestHandler):
             # where this process runs (platform, device kind/count, cache
             # dir, native codec, per-device memory) — served with or
             # without the observatory armed
-            body["runtime"] = runtime.describe()
+            body["runtime"] = {
+                **runtime.describe(),
+                "pallas_interpret": pallas_bfs.interpret_mode(),
+                "native_codec": native.status()}
             self._send(200, json.dumps(body, default=str).encode())
         elif path == "/debug/timeline":
             prof = self.node.devprof

@@ -160,7 +160,9 @@ def _prefix_kernel_sparse(ftab_ref, src_ref, out_ref, carry_ref):
     carry_ref[0] = prefix[prefix.shape[0] - 1, _LANES - 1]
 
 
-def _use_interpret() -> bool:
+def interpret_mode() -> bool:
+    """True when the Pallas kernels run in interpret mode (any backend not
+    named "tpu"): equality only, never a served tier."""
     return jax.default_backend() != "tpu"
 
 
@@ -191,7 +193,7 @@ def active_prefix(words: jax.Array, src_pad: jax.Array, *,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((rows, _LANES), jnp.int32),
         scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
-        interpret=_use_interpret(),
+        interpret=interpret_mode(),
     )(words, src2)
     return out.reshape(e_pad)
 
@@ -216,7 +218,7 @@ def active_prefix_sparse(ftab: jax.Array, src_pad: jax.Array) -> jax.Array:
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((rows, _LANES), jnp.int32),
         scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
-        interpret=_use_interpret(),
+        interpret=interpret_mode(),
     )(ftab, src2)
     return out.reshape(e_pad)
 

@@ -310,16 +310,21 @@ def recurse(ex, sg: SubGraph) -> None:
                 fmask = _seeds_mask(frontier, g.num_nodes)
                 # the device step runs through the dispatch gate: N
                 # concurrent recurse queries pipeline instead of thrashing
+                def _step():
+                    dest_words, trav, seen2, fresh = pb.recurse_step(
+                        g.in_src_pad, g.in_iptr_rank, g.subjects,
+                        g.in_subjects, fmask, st["seen"],
+                        chunks=g.chunks, num_nodes=g.num_nodes,
+                        allow_loop=spec.allow_loop)
+                    # the fetch is the fence, as in _solo_fused: dispatch
+                    # is asynchronous, so the timer and the gate slot
+                    # cover the device step and not only its launch
+                    return jax.device_get((dest_words, trav)), seen2, fresh
+
                 with costs.kernel("pb.recurse_step", attr=cgq.attr):
-                    dest_words, trav, seen2, fresh = ex.gated(
-                        lambda: pb.recurse_step(
-                            g.in_src_pad, g.in_iptr_rank, g.subjects,
-                            g.in_subjects, fmask, st["seen"],
-                            chunks=g.chunks, num_nodes=g.num_nodes,
-                            allow_loop=spec.allow_loop),
-                        klass="recurse")
+                    (dest_words_h, trav_h), seen2, fresh = ex.gated(
+                        _step, klass="recurse")
                 st["seen"] = seen2
-                dest_words_h, trav_h = jax.device_get((dest_words, trav))
                 edges += int(trav_h)
                 if edges > ex.edge_budget():
                     raise QueryError(

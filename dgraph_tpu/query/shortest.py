@@ -147,16 +147,16 @@ def _device_csr(ex, sg: SubGraph):
     return cgq.attr, csr
 
 
-def _device_shortest(ex, attr: str, csr, src: int, dst: int,
-                     max_depth: int):
+def _device_shortest(attr: str, csr, src: int, dst: int, max_depth: int):
     """Unweighted single-source shortest path on device, parent chain
-    walked on host, run through the dispatch gate under a device_kernel
-    span and a cost timer like every other device step. On TPU the Pallas
-    BFS kernel serves the whole device range (bfs_dist — one dispatch for
-    the whole hop loop, bit-packed distance fetch); the Bellman-Ford
-    relaxation (ops/traversal.sssp) serves extreme depths (>= 254) and
-    non-TPU backends. Work is bounded by iterations x E (the resident
-    CSR), so the reference's discovered-edge budget does not apply here."""
+    walked on host, under a device_kernel span and a cost timer (both
+    calls below fetch their result, so the timer sees the device step).
+    On TPU the Pallas BFS kernel serves the whole device range (bfs_dist —
+    one dispatch for the whole hop loop, bit-packed distance fetch); the
+    Bellman-Ford relaxation (ops/traversal.sssp) serves extreme depths
+    (>= 254) and non-TPU backends. Work is bounded by iterations x E (the
+    resident CSR), so the reference's discovered-edge budget does not
+    apply here. This path does not take a dispatch-gate slot."""
     from dgraph_tpu.ops import traversal
 
     from dgraph_tpu.ops.pallas_bfs import DIST_UNREACHED
@@ -171,9 +171,7 @@ def _device_shortest(ex, attr: str, csr, src: int, dst: int,
         with otrace.span("device_kernel", kernel="pb.bfs_dist",
                          edges=g.num_edges), \
                 costs.kernel("pb.bfs_dist", attr=attr):
-            path = ex.gated(
-                lambda: pb.shortest_bfs(g, src, dst, max_depth),
-                klass="shortest")
+            path = pb.shortest_bfs(g, src, dst, max_depth)
         if path is None:
             return None
         return (float(len(path) - 1), path, [attr] * (len(path) - 1))
@@ -185,18 +183,15 @@ def _device_shortest(ex, attr: str, csr, src: int, dst: int,
         return None              # endpoint outside this predicate's uid space
     # pow2 capacity class: snapshot-to-snapshot uid growth must not retrace
     num_nodes = 1 << max(int(np.ceil(np.log2(hi + 2))), 4)
-    def _sssp():
-        res = traversal.sssp(csr.subjects, csr.indptr, csr.indices, None,
-                             src, num_nodes=num_nodes, max_iters=max_depth)
-        d = float(np.asarray(res.dist[dst]))
-        return d, (np.asarray(res.parent) if np.isfinite(d) else None)
-
     with otrace.span("device_kernel", kernel="traversal.sssp",
                      edges=csr.num_edges), \
             costs.kernel("traversal.sssp", attr=attr):
-        dist, parent = ex.gated(_sssp, klass="shortest")
+        res = traversal.sssp(csr.subjects, csr.indptr, csr.indices, None,
+                             src, num_nodes=num_nodes, max_iters=max_depth)
+        dist = float(np.asarray(res.dist[dst]))
     if not np.isfinite(dist):
         return None
+    parent = np.asarray(res.parent)
     path = [dst]
     while path[-1] != src:
         p = int(parent[path[-1]])
@@ -380,7 +375,7 @@ def shortest_path(ex, sg: SubGraph) -> None:
         dev = _device_csr(ex, sg)
         mesh = _mesh_csrs(ex, sg) if dev is None else None
         if dev is not None:
-            p = _device_shortest(ex, dev[0], dev[1], src, dst, max_depth)
+            p = _device_shortest(dev[0], dev[1], src, dst, max_depth)
             sg.paths = [p] if p is not None else []
         elif mesh is not None and spec.numpaths <= 1:
             p = _mesh_shortest_single(ex, sg, mesh, src, dst)

@@ -450,9 +450,9 @@ class Registry:
             labels=("tier",))
         self.keyed_gauges["dgraph_devprof_hbm_highwater_bytes"] = \
             KeyedGauge(labels=("tier",))
-        # where the process runs (utils/runtime.py): one series at 1,
-        # published by serve/worker after backend init; sums to a
-        # per-platform process count on Zero's /metrics/fleet
+        # where a worker runs (__main__.cmd_worker, after backend init):
+        # one series at 1 on its Status metrics — it has no HTTP debug
+        # surface; sums to a per-platform worker count on /metrics/fleet
         self.keyed_gauges["dgraph_runtime_info"] = KeyedGauge(
             labels=("platform", "device_kind", "devices", "compile_cache",
                     "native_codec"))

@@ -9,7 +9,9 @@ came up on XLA:CPU says so in its banner. Host-only subcommands (`bulk`,
 backend — a `zero` holding the chip would starve its own workers —
 and `assert_host_only()` is how they prove it.
 
-Importing this module imports neither jax nor the native codec.
+This is the lowest layer: it knows the JAX backend and nothing of the
+kernels or the storage codec above it (their fields are added where the
+banner and /debug/compiles are assembled). Importing it imports no jax.
 """
 
 from __future__ import annotations
@@ -71,13 +73,10 @@ def _versions() -> dict:
 
 
 def describe() -> dict:
-    """Platform, devices, versions, cache dir, native codec and live
-    per-device memory — the `runtime` section of /debug/compiles.
-    Initialises the backend if nothing has yet."""
+    """Platform, devices, versions, cache dir and live per-device memory —
+    the backend part of /debug/compiles' `runtime` section. Initialises
+    the backend if nothing has yet."""
     import jax
-
-    from dgraph_tpu.ops.pallas_bfs import _use_interpret
-    from dgraph_tpu.storage import native
 
     devs = jax.devices()
     per_device = []
@@ -93,30 +92,18 @@ def describe() -> dict:
         "device_kind": devs[0].device_kind,
         "device_count": len(devs),
         "default_backend": jax.default_backend(),
-        "pallas_interpret": _use_interpret(),
         "compile_cache_dir": jax.config.jax_compilation_cache_dir,
         **_versions(),
-        "native_codec": native.status(),
         "devices": per_device,
     }
 
 
 def banner_fields(info: dict) -> dict:
-    """The subset of describe() a start-up banner (and the
-    dgraph_runtime_info series) carries."""
+    """The subset of describe() a start-up banner carries."""
     return {"platform": info["platform"],
             "device_kind": info["device_kind"],
             "devices": info["device_count"],
-            "compile_cache": info["compile_cache_dir"],
-            "native_codec": info["native_codec"]}
-
-
-def publish(fields: dict, registry) -> None:
-    """banner_fields() as the dgraph_runtime_info{...} 1 series of a
-    metrics registry (serve: /metrics; worker: Status metrics_json, which
-    Zero federates on /metrics/fleet)."""
-    g = registry.keyed("dgraph_runtime_info")
-    g.set("|".join(str(fields[k]) for k in g.labels), 1)
+            "compile_cache": info["compile_cache_dir"]}
 
 
 def backend_initialized() -> bool:

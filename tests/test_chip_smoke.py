@@ -14,8 +14,10 @@ import subprocess
 import sys
 
 import jax
+import numpy as np
 import pytest
 
+from dgraph_tpu.models.rmat import rmat_csr, rmat_edges
 from dgraph_tpu.utils import runtime
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -83,8 +85,86 @@ def test_phase_exception_exits_nonzero():
     assert gen["failed"].startswith("OSError")
     # the run stops at the failed phase: nothing later ran, nothing passed
     assert [ln["phase"] for ln in lines if "phase" in ln] == ["generate"]
-    assert lines[-1]["failures"] == ["phase generate: OSError"]
+    assert lines[-1]["failures"][:2] == ["phase generate: OSError",
+                                         "aborted: OSError: injected"]
     assert not any(ln.get("ok") for ln in lines)
+
+
+def test_exception_in_edge_selection_exits_nonzero():
+    """pick_write_edge raises for graphs it cannot place an edge in (any
+    --seed reaches it). The run must not end as a pass with the write,
+    restart and compile-cache phases silently skipped."""
+    res, lines = _run(_patched(
+        "def boom(*a, **k): raise RuntimeError('injected')\n"
+        "cs.pick_write_edge = boom"))
+    assert res.returncode != 0
+    phases = [ln["phase"] for ln in lines if "phase" in ln]
+    assert phases[-2:] == ["write_readback", "stop"]
+    failures = lines[-1]["failures"]
+    assert "phase write_readback: RuntimeError" in failures
+    assert "aborted: RuntimeError: injected" in failures
+    assert any(f.startswith("phases not run: restart,") for f in failures)
+    assert not any(ln.get("ok") for ln in lines)
+
+
+def test_exception_between_phases_exits_nonzero():
+    """Nothing run_smoke raises may end as exit 0 — also code that sits
+    outside every Phase (here: the Phase constructor itself, after the
+    battery)."""
+    res, lines = _run(_patched(
+        "orig = cs.Phase.__init__\n"
+        "def init(self, run, name):\n"
+        "    if name == 'write_readback': raise RuntimeError('between')\n"
+        "    orig(self, run, name)\n"
+        "cs.Phase.__init__ = init"))
+    assert res.returncode != 0
+    failures = lines[-1]["failures"]
+    assert failures[0] == "aborted: RuntimeError: between"
+    assert not any("failed" in ln for ln in lines if "phase" in ln)
+    assert not any(ln.get("ok") for ln in lines)
+
+
+def test_server_that_must_be_signalled_is_a_failure(monkeypatch):
+    """/admin/shutdown that does not end the child is recorded, not
+    papered over by terminate()."""
+    import subprocess
+
+    import chip_smoke as cs
+
+    run = cs.Run(cs.Config(rehearsal=True), workdir="")
+    srv = object.__new__(cs.Server)
+    srv.run, srv.tag = run, "cold"
+    srv.log = open(os.devnull, "wb")
+    srv.proc = subprocess.Popen([sys.executable, "-c",
+                                 "import time; time.sleep(600)"])
+    monkeypatch.setattr(srv, "call", lambda *a, **k: {})
+    real_wait, waits = srv.proc.wait, []
+
+    def wait(timeout=None):
+        waits.append(timeout)
+        return real_wait(timeout=0.2 if len(waits) == 1 else timeout)
+
+    monkeypatch.setattr(srv.proc, "wait", wait)
+    srv.stop()
+    assert srv.proc.poll() is not None
+    assert any("did not exit within 120s" in f for f in run.failures)
+
+
+# -- the smoke's graph ---------------------------------------------------------
+
+def test_rmat_dedup_equals_row_unique():
+    """rmat_edges dedups on a packed int64 key; the result is the row-wise
+    unique it replaced — same edges, same (src, dst) order — so the graph
+    every record names did not move."""
+    raw = rmat_edges(12, 8, seed=7, dedup=False)
+    want = np.unique(raw, axis=0)
+    got = rmat_edges(12, 8, seed=7)
+    assert len(want) < len(raw)             # there were duplicates to drop
+    assert got.dtype == want.dtype and np.array_equal(got, want)
+    subjects, indptr, indices = rmat_csr(12, 8, seed=7)
+    assert np.array_equal(np.repeat(subjects, np.diff(indptr)),
+                          want[:, 0] + 1)
+    assert np.array_equal(indices, want[:, 1] + 1)
 
 
 # -- compile cache: placed from outside, or one normalised default ------------

@@ -90,6 +90,39 @@ def test_fused_path_taken(rng, monkeypatch):
         recmod.KERNEL_MIN_EDGES = None
 
 
+def test_kernel_steps_fence_inside_the_gate_slot(rng, monkeypatch):
+    """Dispatch is asynchronous on an accelerator: the fetch is the fence.
+    Both recurse kernel paths hand the gate a closure that returns HOST
+    arrays, so the cost timer's device_ms and the gate's "recurse" step
+    estimate cover the device step and not only its launch."""
+    node = _graph_node(rng)
+    gate = node.dispatch_gate
+    real, outs = gate.run, []
+
+    def spy(fn, klass=None):
+        out = real(fn, klass=klass)
+        if klass == "recurse":
+            outs.append(out)
+        return out
+
+    monkeypatch.setattr(gate, "run", spy)
+    recmod.KERNEL_MIN_EDGES = 0
+    try:
+        node.query("{ q(func: uid(0x1, 0x2)) @recurse(depth: 3) { follow } }")
+        assert len(outs) == 1                       # fused: one dispatch
+        masks_h, trav_h, _fresh = outs.pop()
+        assert isinstance(masks_h, np.ndarray)
+        assert isinstance(trav_h, np.ndarray)
+        node.query("{ q(func: uid(0x1)) @recurse(depth: 3) { follow knows } }")
+        assert outs                                 # stepped: one per level
+        for (dest_words_h, trav_h), _seen, _fresh in outs:
+            assert isinstance(dest_words_h, np.ndarray)
+            assert isinstance(trav_h, np.ndarray)
+        assert gate.expected_step("recurse") > 0
+    finally:
+        recmod.KERNEL_MIN_EDGES = None
+
+
 def test_kernel_edge_budget(rng):
     """The budget error must fire on the kernel path too (recurse.go:167)."""
     from dgraph_tpu.query import engine as eng
