@@ -35,6 +35,18 @@ def _init_backend(lg) -> dict:
     return {**runtime.banner_fields(info), "native_codec": native.status()}
 
 
+def _record_startup(node, age_s: float | None, marks: list[float]) -> None:
+    """dgraph_startup_ms{phase=}: `import` is the process's age when
+    cmd_serve had its imports (None: no /proc), the rest the seconds
+    between consecutive marks."""
+    startup = node.metrics.keyed("dgraph_startup_ms", labels=("phase",))
+    if age_s is not None:
+        startup.set("import", int(round(age_s * 1e3)))
+    for phase, a, b in zip(("backend_init", "store_open", "listen"),
+                           marks, marks[1:]):
+        startup.set(phase, int(round((b - a) * 1e3)))
+
+
 def cmd_serve(args) -> int:
     import threading
 
@@ -42,8 +54,16 @@ def cmd_serve(args) -> int:
     from dgraph_tpu.api.server import Node
 
     lg = log.get_logger("serve")
+    # start-up phases for dgraph_startup_ms{phase=}, set once at the banner:
+    # import (process start -> here, the imports above included),
+    # backend_init, store_open (the Node), listen (-> banner)
+    import time
+
+    age = runtime.process_age_s()
+    marks = [time.perf_counter()]
     where = _init_backend(lg)
-    node = Node(dirpath=args.postings, trace_fraction=args.trace,
+    marks.append(time.perf_counter())
+    node = Node(dirpath=args.postings,
                 memory_mb=args.memory_mb or None,
                 plan_cache_size=args.plan_cache,
                 task_cache_mb=args.task_cache_mb,
@@ -80,6 +100,7 @@ def cmd_serve(args) -> int:
                 delta_journal_max_keys=args.delta_journal_max_keys or None,
                 qos=not args.no_qos,
                 tenants=args.tenants or None)
+    marks.append(time.perf_counter())
     if args.faults or args.faults_seed is not None:
         from dgraph_tpu.utils import faults as faults_mod
 
@@ -106,6 +127,8 @@ def cmd_serve(args) -> int:
                 tls=bool(args.tls_cert))
     srv = make_server(node, args.host, args.port,
                       tls_cert=args.tls_cert, tls_key=args.tls_key)
+    marks.append(time.perf_counter())
+    _record_startup(node, age, marks)
     lg.info(f"serving HTTP{'S' if args.tls_cert else ''} on "
             f"{args.host}:{srv.server_address[1]}",
             postings=args.postings or "<memory>", **where)
@@ -451,8 +474,6 @@ def build_parser() -> argparse.ArgumentParser:
     sp.add_argument("-p", "--postings", default=None,
                     help="durable posting dir (default: in-memory)")
     sp.add_argument("--schema", default=None, help="schema file to apply")
-    sp.add_argument("--trace", type=float, default=1.0,
-                    help="fraction of requests to trace (/debug/requests)")
     sp.add_argument("--span_sample", type=float, default=0.01,
                     help="fraction of requests getting a full span trace "
                          "(/debug/traces; Chrome trace JSON per trace; "

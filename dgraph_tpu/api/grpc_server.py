@@ -23,6 +23,7 @@ from concurrent import futures
 import grpc
 
 from ..coord.zero import TxnConflict
+from ..obs import costs
 from ..query import mutation as mut
 from ..query.task import TaskError
 from ..utils.errors import Unavailable
@@ -78,10 +79,17 @@ class DgraphService:
                     # lazy txn open: a txn whose first op is a query must be
                     # able to mutate at the same start_ts afterward
                     start_ts = self.node.new_txn().start_ts
-                out, ctx = self.node.query(
-                    req.query, dict(req.vars) or None, start_ts=start_ts,
-                    read_only=req.read_only)
-                resp.json = json.dumps(out).encode()
+                # this handler owns the request's stage clock (obs/
+                # costs.py): its own time is `grpc`, the JSON is `encode`,
+                # and the reference's Latency split comes off the clock
+                with self.node.clocked("query", "grpc") as clk:
+                    out, ctx = self.node.query(
+                        req.query, dict(req.vars) or None,
+                        start_ts=start_ts, read_only=req.read_only)
+                    with costs.stage("encode"):
+                        resp.json = json.dumps(out).encode()
+                    resp.latency.MergeFrom(
+                        pb.Latency(**clk.server_latency()))
                 resp.txn.CopyFrom(_txn_proto(ctx))
             resp.latency.total_ns = time.perf_counter_ns() - t0
             return resp

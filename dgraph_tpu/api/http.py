@@ -32,6 +32,7 @@ tenant's DQL never sees the prefix, and namespace violations surface as
 
 from __future__ import annotations
 
+import contextlib
 import json
 import re
 import threading
@@ -42,6 +43,7 @@ from urllib.parse import parse_qs, urlparse
 from dgraph_tpu import tenancy as tnc
 from dgraph_tpu.api.server import Node
 from dgraph_tpu.coord.zero import TxnConflict
+from dgraph_tpu.obs import costs
 from dgraph_tpu.ops import pallas_bfs
 from dgraph_tpu.storage import native
 from dgraph_tpu.utils import faults, runtime
@@ -447,11 +449,12 @@ class _Handler(BaseHTTPRequestHandler):
 
     def _send(self, status: int, body: bytes,
               ctype: str = "application/json") -> None:
-        self.send_response(status)
-        self.send_header("Content-Type", ctype)
-        self.send_header("Content-Length", str(len(body)))
-        self.end_headers()
-        self.wfile.write(body)
+        with costs.stage("http.write"):
+            self.send_response(status)
+            self.send_header("Content-Type", ctype)
+            self.send_header("Content-Length", str(len(body)))
+            self.end_headers()
+            self.wfile.write(body)
 
     def _qs(self) -> dict:
         return {k: v[0] for k, v in
@@ -474,10 +477,12 @@ class _Handler(BaseHTTPRequestHandler):
     # the /debug index: one place that names every diagnostic endpoint
     _DEBUG_INDEX = {
         "/debug/vars": "expvar-style dgraph_* counters/histograms",
-        "/debug/requests": "sampled request breadcrumb traces (?n=32)",
         "/debug/metrics": "serving-layer readout: caches, overlay, folds, "
                           "planner, mesh, residency",
-        "/debug/traces": "distributed span traces index (?n=32)",
+        "/debug/traces": "distributed span traces index (?n=32); a "
+                         "sampled /query holds its stage segments (parse, "
+                         "plan, exec, dev.dispatch, dev.wait, ...) as "
+                         "child spans with real durations",
         "/debug/traces/<trace_id>": "one trace as Chrome trace-event JSON "
                                     "(load in Perfetto / chrome://tracing)",
         "/debug/slow": "slow-query log ring (?n=32; cost regressions "
@@ -499,7 +504,12 @@ class _Handler(BaseHTTPRequestHandler):
         "/debug/timeline": "device dispatch timeline ring as Chrome "
                            "trace-event JSON (load in Perfetto; ?view=raw "
                            "for the record list, ?n=256 bounds it)",
-        "/metrics": "Prometheus text exposition of the metrics registry",
+        "/metrics": "Prometheus text exposition of the metrics registry; "
+                    "dgraph_stage_us_total{stage=} / "
+                    "dgraph_stage_requests_total say where a request's "
+                    "time goes, dgraph_kernel_us_total{kernel=} the device "
+                    "windows by kernel, dgraph_startup_ms{phase=} serve's "
+                    "start-up phases",
     }
 
     def _do_get(self):
@@ -527,10 +537,6 @@ class _Handler(BaseHTTPRequestHandler):
         elif path == "/debug/vars":
             # expvar-style metrics dump (reference x/metrics.go /debug/vars)
             self._send(200, json.dumps(self.node.metrics.to_dict()).encode())
-        elif path == "/debug/requests":
-            # recent sampled request traces (net/trace /debug/requests)
-            n = int(self._qs().get("n", "32"))
-            self._send(200, json.dumps(self.node.traces.recent(n)).encode())
         elif path == "/debug/metrics":
             # serving-layer readout: cache hit rates, dispatch gate,
             # per-endpoint QPS + latency histograms (round-6 tier)
@@ -603,6 +609,15 @@ class _Handler(BaseHTTPRequestHandler):
 
     def do_POST(self):
         path = urlparse(self.path).path.rstrip("/")
+        # this handler owns a /query request: it opens the request's stage
+        # clock and mints the root span `query` here, so reading the body
+        # and writing the answer are stages like parse and exec
+        # (obs/costs.py StageClock; Node.query joins the open clock)
+        with self.node.clocked("query", "http.read") if path == "/query" \
+                else contextlib.nullcontext():
+            self._do_post(path)
+
+    def _do_post(self, path: str):
         ep = self._OBSERVED.get(path)
         t0 = time.perf_counter()
         try:
@@ -794,13 +809,19 @@ class _Handler(BaseHTTPRequestHandler):
             edge_limit=int(edge_limit) if edge_limit else None,
             explain=explain,
             timeout_ms=float(timeout_ms) if timeout_ms else None)
+        # the reference's Latency split off the stage clock: parse / plan +
+        # exec + device / encode up to this envelope; total_ns as before
         ext = {"txn": {"start_ts": ctx.start_ts},
-               "server_latency": {"total_ns": time.perf_counter_ns() - t0}}
+               "server_latency": {
+                   **costs.clock().server_latency(),
+                   "total_ns": time.perf_counter_ns() - t0}}
         if explain:
             # the plan tree (est vs actual per step) rides the envelope's
             # extensions, keeping "data" byte-identical to a plain query
             ext["explain"] = out.pop("explain", None)
-        self._send(200, _envelope_ok(out, ext))
+        with costs.stage("encode"):
+            body = _envelope_ok(out, ext)
+        self._send(200, body)
 
     def _subscribe(self):
         """POST /subscribe — live query over Server-Sent Events (ISSUE

@@ -21,6 +21,7 @@ the layers — first-committer-wins snapshot isolation
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from dataclasses import dataclass, field
@@ -75,7 +76,6 @@ class Node:
     """One embedded server (store + zero + snapshot cache)."""
 
     def __init__(self, dirpath: str | None = None, n_groups: int = 1,
-                 trace_fraction: float = 1.0,
                  memory_mb: int | None = None,
                  plan_cache_size: int = 256,
                  task_cache_mb: int = 64,
@@ -144,8 +144,6 @@ class Node:
             budget_bytes=int(device_budget_mb) << 20,
             metrics=self.metrics, pins=tuple(pins))
         self.store.residency = self.residency
-        self.traces = metrics.TraceStore(fraction=trace_fraction,
-                                         rng=trace_rng)
         # span tracing + device profiling (obs/otrace.py): root spans start
         # at query/mutate/alter, children attach via contextvar down to the
         # device kernels; completed traces export as Chrome trace JSON at
@@ -728,8 +726,24 @@ class Node:
         cur = otrace.current()
         if cur is not None:
             return self.tracer.start(name, parent=cur, attrs=attrs)
+        if costs.clock() is not None:
+            # the request's owner already took the sampling decision
+            # (clocked(), below) and it was "no": nothing below re-rolls
+            return otrace.NULL_SPAN
         return self.tracer.root(name, attrs=attrs,
                                 force=self.slow_log.enabled)
+
+    def clocked(self, name: str, first: str):
+        """Own one request: mint its root span `name` (the sampling
+        decision) and open its stage clock in stage `first` (obs/costs.py
+        StageClock). The entry point that owns a request calls this —
+        HTTP do_POST, the gRPC handler, or query() itself when called
+        in-process; where a clock is already open the request is joined,
+        not owned: the open clock comes back and nothing closes here."""
+        clk = costs.clock()
+        if clk is not None:
+            return contextlib.nullcontext(clk)
+        return costs.StageClock(first, self._span(name), self.metrics)
 
     def _parse(self, q: str, variables: dict | None = None) -> dql.ParsedRequest:
         """Parse through the plan cache: hot query shapes skip the lexer +
@@ -835,9 +849,27 @@ class Node:
         physical plan tree with estimated vs actual cardinality per step
         (the ?explain=true HTTP surface). Explain requests bypass the
         whole-query result cache so the actuals are real."""
+        # the stage clock (obs/costs.py): joined when the HTTP / gRPC
+        # entry point opened one, owned here for an in-process caller.
+        # Everything below that is in no narrower stage is `plan`.
+        with self.clocked("query", "plan") as clk, costs.stage("plan"):
+            return self._query(clk, q, variables, start_ts, read_only,
+                               edge_limit, explain, timeout_ms,
+                               _cost_endpoint, _cost_subs)
+
+    def _query(self, clk, q, variables, start_ts, read_only, edge_limit,
+               explain, timeout_ms, _cost_endpoint, _cost_subs):
         qtitle = q.strip().splitlines()[0][:120] if q.strip() else ""
-        tr = self.traces.start("query", qtitle)
-        sp = self._span("query", query=qtitle)
+        if not clk.claimed and (not clk.root
+                                or otrace.current() is clk.root):
+            # the owner's root span IS this query's span (its name is the
+            # operation's, `query`): set the attributes on it, open no
+            # second `query` under it; the clock enters and finishes it
+            clk.claimed = True
+            sp, in_span = clk.root, contextlib.nullcontext()
+            sp.set(query=qtitle)
+        else:
+            sp = in_span = self._span("query", query=qtitle)
         m = self.metrics
         m.counter("dgraph_num_queries_total").inc()
         m.counter("dgraph_pending_queries_total").inc()
@@ -860,10 +892,10 @@ class Node:
             # equally among them
             lg.subs = tuple(_cost_subs)
         try:
-          with sp, self._deadline_scope(timeout_ms), costs.scope(lg):
+          with in_span, self._deadline_scope(timeout_ms), costs.scope(lg):
             self._admit_tenant(tenant)
-            req = self._parse(q, variables)
-            tr.printf("parsed: %d query blocks", len(req.queries))
+            with costs.stage("parse"):
+                req = self._parse(q, variables)
             if req.upsert is not None:
                 # implicit txn commits; an explicit one stays open for the
                 # client's own commit/abort
@@ -885,7 +917,6 @@ class Node:
                 snap = self._ns_view(snap, tenant)
             schema = self._schema_view()
             sp.set(read_ts=int(read_ts))
-            tr.printf("snapshot at ts %d (%d preds)", read_ts, len(snap.preds))
             pf_attrs = None
             if not req.mutations:
                 # plan-driven FOLD prefetch (ISSUE 15): pending lazy folds
@@ -927,7 +958,6 @@ class Node:
                     rkey = (pk, qcache.result_token(req, snap), eff)
                     cached = self.result_cache.get(rkey)
                     if cached is not None:
-                        tr.printf("result cache hit")
                         sp.set(result_cache="hit")
                         costs.note("result_cache_hit")
                         return cached, TxnContext(start_ts=read_ts)
@@ -942,8 +972,7 @@ class Node:
                 def build():
                     return plmod.build_plan(req, snap, schema,
                                             metrics=self.metrics,
-                                            top_k=self.stats_top_k,
-                                            trace=tr)
+                                            top_k=self.stats_top_k)
                 try:
                     plan = (self.plan_cache.plan(q, variables, req, snap,
                                                  build, ns=tenant)
@@ -976,7 +1005,6 @@ class Node:
                            mesh=self.mesh_exec,
                            batcher=self.batcher,
                            on_task=self._count_task).execute(req)
-            tr.printf("executed")
             if rkey is not None:
                 self.result_cache.put(rkey, out)
             if explain:
@@ -988,10 +1016,12 @@ class Node:
                                   else {"planner": "off"})
             return out, TxnContext(start_ts=read_ts)
         except BaseException as e:
-            # EVERY failure shape finishes the breadcrumb trace with its
-            # error, exactly once, via the finally below — including
-            # TxnConflict from the upsert path and non-Exception bases
+            # EVERY failure shape — TxnConflict from the upsert path and
+            # non-Exception bases included — leaves its error on the span
+            # (an owner above may answer the client and swallow it)
             err = str(e) or type(e).__name__
+            if sp:
+                sp.error = f"{type(e).__name__}: {e}"
             from dgraph_tpu.utils.deadline import DeadlineExceeded
 
             if isinstance(e, DeadlineExceeded):
@@ -1007,7 +1037,6 @@ class Node:
                 m.counter("dgraph_first_query_ms").set(
                     (time.perf_counter() - self._birth) * 1e3)
             self._finish_cost(lg, sp)
-            self.traces.finish(tr, error=err)
 
     def _finish_cost(self, lg, sp) -> None:
         """Close one request's cost ledger: observe the aggregatable
@@ -1035,6 +1064,15 @@ class Node:
         lg.finish()
         rec = lg.to_dict()
         total = rec["total"]
+        # this node's device windows by kernel, on /metrics (the record's
+        # `kern` map reaches only /debug/top's ring): integer microseconds
+        # and window counts, beside the stage clock's dev.* stages
+        kern_us, calls = lg.kernel_totals()
+        if kern_us:
+            m.keyed("dgraph_kernel_us_total",
+                    labels=("kernel",)).inc_many(kern_us)
+            m.keyed("dgraph_kernel_calls_total",
+                    labels=("kernel",)).inc_many(calls)
         # per-tenant attribution + quota debit (ISSUE 20): every admitted
         # record's ledger units debit its tenant's buckets and advance
         # the dgraph_tenant_* labeled series. Cache hits are trivial
@@ -1236,8 +1274,6 @@ class Node:
                         "wildcard predicate deletion (S * *) is not "
                         "available inside a tenant namespace")
                 nq.predicate = tnc.prefix(tenant, nq.predicate)
-        tr = self.traces.start(
-            "mutate", f"{len(nquads_set)} set / {len(nquads_del)} del")
         sp = self._span("mutate", set=len(nquads_set),
                         delete=len(nquads_del))
         m = self.metrics
@@ -1245,7 +1281,6 @@ class Node:
         m.counter("dgraph_active_mutations_total").inc()
         m.meter("mutate").mark()
         t0 = time.perf_counter()
-        err = ""
         try:
           with sp, self._deadline_scope(timeout_ms):
             with self._lock:
@@ -1313,15 +1348,11 @@ class Node:
             if commit_now:
                 self.commit(ctx.start_ts)
             return res
-        except BaseException as e:
-            err = str(e) or type(e).__name__
-            raise
         finally:
             m.counter("dgraph_active_mutations_total").dec()
             m.histogram("dgraph_mutation_latency_s").observe(
                 time.perf_counter() - t0,
                 exemplar=sp.trace_id or None)
-            self.traces.finish(tr, error=err)
 
     def run_request(self, q: str, variables: dict | None = None,
                     commit_now: bool = True) -> tuple[dict, MutationResult | None]:
@@ -1351,16 +1382,8 @@ class Node:
         title = ("drop_all" if drop_all else
                  f"drop {drop_attr}" if drop_attr else
                  (schema_text.strip().splitlines() or [""])[0][:120])
-        tr = self.traces.start("alter", title)
-        err = ""
-        try:
-          with self._span("alter", op=title):
+        with self._span("alter", op=title):
             self._alter_locked(schema_text, drop_attr, drop_all)
-        except BaseException as e:
-            err = str(e) or type(e).__name__
-            raise
-        finally:
-            self.traces.finish(tr, error=err)
 
     def _alter_locked(self, schema_text: str, drop_attr: str,
                       drop_all: bool) -> None:

@@ -21,6 +21,15 @@ The unarmed fast path is one contextvar read returning None: a node
 started with --no_cost_ledger must measure nothing (bench.py `obs` gates
 the armed overhead < 2% on the warm mixed battery).
 
+The stage clock (StageClock, below the ledger) answers the question the
+ledger cannot: WHERE inside the request the wall time went. The entry
+point that owns a request opens it; `with costs.stage("parse"):` switches
+the request's one current stage; `costs.kernel(...)` windows are stages
+too (dev.window, or dev.dispatch / dev.wait / dev.post where the site
+splits its window). It is read three ways off one clock: always-on
+/metrics counters (dgraph_stage_us_total{stage=}), and for a sampled
+request child spans in /debug/traces and jax.profiler annotations.
+
 Completed records land in a CostBook: a bounded sliding window that
 powers GET /debug/top (rank plan shapes / predicates / endpoints by
 device ms, bytes, edges over the trailing window) and keeps a per-shape
@@ -38,7 +47,7 @@ import threading
 import time
 from collections import deque
 
-from . import devprof
+from . import devprof, otrace
 
 # gRPC trailing-metadata key for the shipped record (-bin carries bytes)
 WIRE_KEY = "dgt-cost-bin"
@@ -102,8 +111,8 @@ class CostLedger:
     __slots__ = ("_lock", "endpoint", "shape", "tenant", "t0", "wall_ms",
                  "device_ms", "h2d_bytes", "d2h_bytes", "upload_bytes",
                  "edges", "rows", "tasks", "gate_wait_ms", "compile_ms",
-                 "subs", "outcomes", "per_pred", "kernels", "groups",
-                 "_attrs", "_kernel_depth")
+                 "subs", "outcomes", "per_pred", "kernels", "kernel_calls",
+                 "groups", "_attrs", "_kernel_depth")
 
     def __init__(self, endpoint: str = "", shape: str = "",
                  tenant: str = "") -> None:
@@ -127,6 +136,7 @@ class CostLedger:
         # attr -> [device_ms, edges, bytes, tasks]
         self.per_pred: dict[str, list] = {}
         self.kernels: dict[str, float] = {}   # kernel name -> device ms
+        self.kernel_calls: dict[str, int] = {}   # kernel name -> windows
         # worker addr -> merged remote record dict (the shipped payload)
         self.groups: dict[str, dict] = {}
         self._attrs: list[str] = []
@@ -163,6 +173,8 @@ class CostLedger:
             self.h2d_bytes += int(h2d)
             self.d2h_bytes += int(d2h)
             self.kernels[kernel] = self.kernels.get(kernel, 0.0) + ms
+            self.kernel_calls[kernel] = \
+                self.kernel_calls.get(kernel, 0) + 1
             a = attr if attr is not None else \
                 (self._attrs[-1] if self._attrs else "")
             if a.startswith("~"):
@@ -209,6 +221,14 @@ class CostLedger:
         the shapes paying for retraces."""
         with self._lock:
             self.compile_ms += ms
+
+    def kernel_totals(self) -> tuple[dict[str, int], dict[str, int]]:
+        """This node's device windows by kernel: (integer microseconds,
+        window counts) — what /metrics carries per kernel."""
+        with self._lock:
+            return ({k: int(round(ms * 1e3))
+                     for k, ms in self.kernels.items()},
+                    dict(self.kernel_calls))
 
     def in_kernel(self) -> bool:
         """True while a kernel-timing window is open on this ledger — the
@@ -394,9 +414,10 @@ class _KernelTimer:
     clamped at zero against concurrent hedge-thread waits)."""
 
     __slots__ = ("_lg", "_kernel", "_attr", "_t0", "_gw0", "h2d", "d2h",
-                 "ms", "_pushed")
+                 "ms", "_pushed", "_stage")
 
-    def __init__(self, kernel: str, attr: str | None = None) -> None:
+    def __init__(self, kernel: str, attr: str | None = None,
+                 stage: str = "dev.window") -> None:
         self._lg = _current.get()
         self._kernel = kernel
         self._attr = attr
@@ -404,6 +425,7 @@ class _KernelTimer:
         self.d2h = 0
         self.ms = 0.0          # charged wall ms, readable after exit
         self._pushed = False
+        self._stage = _StageScope(stage)
 
     def __enter__(self):
         lg = self._lg
@@ -420,6 +442,9 @@ class _KernelTimer:
                 devprof.push_family(self._kernel)
                 self._pushed = True
             self._t0 = time.perf_counter()
+        # the window is also a stage of the request's clock (below): the
+        # same two instants, read by the ledger and by the clock
+        self._stage.__enter__()
         return self
 
     def set(self, h2d: int = 0, d2h: int = 0) -> None:
@@ -427,6 +452,7 @@ class _KernelTimer:
         self.d2h += int(d2h)
 
     def __exit__(self, *a):
+        self._stage.__exit__()
         lg = self._lg
         if lg is not None:
             dt = (time.perf_counter() - self._t0) * 1e3
@@ -441,8 +467,12 @@ class _KernelTimer:
         return False
 
 
-def kernel(name: str, attr: str | None = None) -> _KernelTimer:
-    return _KernelTimer(name, attr)
+def kernel(name: str, attr: str | None = None,
+           stage: str = "dev.window") -> _KernelTimer:
+    """`stage` is the request-clock stage the window opens in: the sites
+    that split their window (dev.dispatch / dev.wait / dev.post) name the
+    first part, every other site is one unsplit `dev.window`."""
+    return _KernelTimer(name, attr, stage)
 
 
 def note(outcome: str, n: int = 1) -> None:
@@ -469,6 +499,165 @@ def add_gate_wait(ms: float) -> None:
     lg = _current.get()
     if lg is not None:
         lg.add_gate_wait(ms)
+
+
+# ---------------------------------------------------------------------------
+# the stage clock: a request is, at every instant, in exactly one stage
+# ---------------------------------------------------------------------------
+
+_clock: contextvars.ContextVar["StageClock | None"] = \
+    contextvars.ContextVar("dgt_stage_clock", default=None)
+
+
+_get_ident = threading.get_ident
+_now_ns = time.perf_counter_ns
+
+
+def clock() -> "StageClock | None":
+    """The open stage clock of the request THIS thread is serving; None
+    on a pool thread that merely runs in a copy of a request's context."""
+    clk = _clock.get()
+    return clk if clk is not None and clk._tid == _get_ident() else None
+
+
+def _annotate(name: str):
+    """Host-plane event of the profiler for one sampled segment (a no-op
+    TraceMe unless a jax.profiler session is running)."""
+    import jax.profiler
+
+    return jax.profiler.TraceAnnotation(name)
+
+
+class StageClock:
+    """Where one request's time goes, by the program's own clock.
+
+    The entry point that owns the request (HTTP do_POST, the gRPC handler,
+    or Node.query called in-process) opens the clock with `with`; code
+    below switches stages with `with costs.stage("parse"):`. A switch
+    closes the running segment, charges its nanoseconds to its stage and
+    starts the next, so segments never overlap and their sum is the
+    request's time inside its entry point: self time needs no
+    subtraction. Always on: a switch is one perf_counter_ns read and one
+    dict add, no lock (only the opening thread switches: to a pool thread
+    that runs in a copy of the request's context, clock() is None).
+
+    `root` is the request's root span — the ONE sampling decision. When
+    it is a real span, every segment is also a child span of kind
+    "stage" (parent = the span current when the segment opened; handed to
+    the tracer in one piece when the clock closes) and a
+    jax.profiler.TraceAnnotation("dgraph.<stage>"), so /debug/traces and
+    the profiler's host plane read this same clock. Only segments are
+    annotated, never the spans that enclose them.
+
+    Closing flushes integer microseconds to
+    dgraph_stage_us_total{stage=} and counts the request in
+    dgraph_stage_requests_total."""
+
+    __slots__ = ("ns", "root", "claimed", "_metrics", "_tid", "_cur", "_t",
+                 "_segs", "_ann", "_parent", "_wall0", "_t0", "_token")
+
+    def __init__(self, first: str, root, metrics=None) -> None:
+        self.ns: dict[str, int] = {}
+        self.root = root
+        self.claimed = False      # Node.query took `root` as its own span
+        self._metrics = metrics
+        self._tid = _get_ident()
+        self._cur = first
+        # sampled only: closed segments (name, parent span id, start ns,
+        # end ns), the running segment's annotation and parent span id
+        self._segs: list | None = [] if root else None
+        self._ann = None
+        self._parent = ""
+
+    def __enter__(self) -> "StageClock":
+        self._token = _clock.set(self)
+        self.root.__enter__()
+        self._wall0 = time.time()
+        self._t = self._t0 = time.perf_counter_ns()
+        if self._segs is not None:
+            self._open_segment(self._cur)
+        return self
+
+    def __exit__(self, et, ev, tb):
+        self.switch("")
+        if self._segs:
+            self._ship_segments()
+        self.root.__exit__(et, ev, tb)
+        _clock.reset(self._token)
+        m = self._metrics
+        if m is not None:
+            m.keyed("dgraph_stage_us_total", labels=("stage",)).inc_many(
+                {k: v // 1000 for k, v in self.ns.items()})
+            m.counter("dgraph_stage_requests_total").inc()
+        return False
+
+    def switch(self, name: str) -> str:
+        """Close the running segment, start `name`; returns the stage that
+        was running. For the opening thread only (clock() sees to it)."""
+        now = _now_ns()
+        prev = self._cur
+        ns = self.ns
+        ns[prev] = ns.get(prev, 0) + now - self._t
+        self._cur = name
+        if self._segs is not None:
+            self._ann.__exit__(None, None, None)
+            self._segs.append((prev, self._parent, self._t, now))
+            if name:
+                self._open_segment(name)
+        self._t = now
+        return prev
+
+    def _open_segment(self, name: str) -> None:
+        self._parent = (otrace.current() or self.root).span_id
+        self._ann = _annotate("dgraph." + name)
+        self._ann.__enter__()
+
+    def _ship_segments(self) -> None:
+        """The closed segments as finished spans of the root's trace."""
+        root, tracer = self.root, self.root.tracer
+        wall0, t0 = self._wall0, self._t0
+        tracer.add_remote([
+            {"trace_id": root.trace_id, "span_id": tracer._new_id(),
+             "parent_id": parent, "name": name, "kind": "stage",
+             "proc": root.proc, "start": wall0 + (a - t0) * 1e-9,
+             "dur": round((b - a) * 1e-9, 9), "attrs": {}}
+            for name, parent, a, b in self._segs])
+
+    def server_latency(self) -> dict:
+        """The reference's Latency split, from the stages closed so far:
+        parsing = `parse`; processing = `plan` + `exec*` + `dev.*`;
+        encoding = `encode`."""
+        ns = self.ns
+        return {"parsing_ns": ns.get("parse", 0),
+                "processing_ns": sum(
+                    v for k, v in ns.items()
+                    if k == "plan" or k.startswith(("exec", "dev."))),
+                "encoding_ns": ns.get("encode", 0)}
+
+
+class _StageScope:
+    """`with costs.stage("parse"):` — the request is in `parse` until the
+    block ends (also by an exception), then back in the stage it came
+    from. One contextvar read when no clock is open."""
+
+    __slots__ = ("_name", "_clk", "_prev")
+
+    def __init__(self, name: str) -> None:
+        self._name = name
+
+    def __enter__(self):
+        clk = self._clk = clock()
+        if clk is not None:
+            self._prev = clk.switch(self._name)
+        return self
+
+    def __exit__(self, *a):
+        if self._clk is not None:
+            self._clk.switch(self._prev)
+        return False
+
+
+stage = _StageScope
 
 
 # ---------------------------------------------------------------------------

@@ -44,6 +44,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dgraph_tpu.obs import costs
 from dgraph_tpu.ops.csr import degrees as _csr_degrees
 from dgraph_tpu.ops.csr import expand as _csr_expand
 
@@ -603,17 +604,19 @@ def _recurse_tail(prefix, in_iptr_rank, seen, allow_loop: bool):
     plus the bounds-diff reachability (the exactness-critical piece, kept
     in ONE place for the fused and stepped paths alike)."""
     traversed = prefix[-1]
-    prev = jnp.concatenate([jnp.zeros((1,), jnp.int32), prefix[:-1]])
-    active = (prefix - prev) > 0                           # bool[E_pad]
-    if allow_loop:
-        fresh, seen2 = active, seen
-    else:
-        fresh = active & ~seen
-        seen2 = seen | active
-    freshp = jnp.cumsum(fresh.astype(jnp.int32))
-    bounds = jnp.take(freshp, in_iptr_rank - 1, mode="clip")
-    bounds = jnp.where(in_iptr_rank == 0, 0, bounds)
-    reached = (bounds[1:] - bounds[:-1]) > 0               # [Nd]
+    with jax.named_scope("visit"):
+        prev = jnp.concatenate([jnp.zeros((1,), jnp.int32), prefix[:-1]])
+        active = (prefix - prev) > 0                       # bool[E_pad]
+        if allow_loop:
+            fresh, seen2 = active, seen
+        else:
+            fresh = active & ~seen
+            seen2 = seen | active
+    with jax.named_scope("bounds"):
+        freshp = jnp.cumsum(fresh.astype(jnp.int32))
+        bounds = jnp.take(freshp, in_iptr_rank - 1, mode="clip")
+        bounds = jnp.where(in_iptr_rank == 0, 0, bounds)
+        reached = (bounds[1:] - bounds[:-1]) > 0           # [Nd]
     return reached, traversed, seen2, fresh
 
 
@@ -624,7 +627,8 @@ def _recurse_level_core(fbits, stream, n_chunks: int, in_iptr_rank, seen,
     traversed counts EVERY out-edge of every frontier node (the budget the
     reference charges, recurse.go:167); fresh marks first-traversal edges;
     reached_d = dst ranks with >= 1 fresh in-edge."""
-    prefix = _prefix_for(fbits, stream, n_chunks)
+    with jax.named_scope("prefix"):
+        prefix = _prefix_for(fbits, stream, n_chunks)
     return _recurse_tail(prefix, in_iptr_rank, seen, allow_loop)
 
 
@@ -674,8 +678,12 @@ def bfs_dist(in_src_pad, in_src_pad_d, in_iptr_rank, subjects, in_subjects,
     walks the predecessor chain itself from the distance labels (each
     step scans one node's in-edge slice — microseconds)."""
     nd = in_subjects.shape[0]
-    visited0 = jnp.take(seeds_mask, in_subjects)           # [Nd]
-    dist0 = jnp.where(visited0, 0, DIST_UNREACHED).astype(jnp.int32)
+    # the named scopes are op_name metadata only (same compiled program):
+    # a profile's leaf instructions carry the stage of the loop they
+    # belong to — seed / prefix / bounds / visit / pack_dist
+    with jax.named_scope("seed"):
+        visited0 = jnp.take(seeds_mask, in_subjects)       # [Nd]
+        dist0 = jnp.where(visited0, 0, DIST_UNREACHED).astype(jnp.int32)
     fresh0 = jnp.zeros((nd,), dtype=bool)
 
     def cond(c):
@@ -694,22 +702,26 @@ def bfs_dist(in_src_pad, in_src_pad_d, in_iptr_rank, subjects, in_subjects,
             # straight from the fresh dst-rank mask (no remap gather)
             return _prefix_for(fresh, in_src_pad_d, chunks_d)
 
-        prefix = lax.cond(h == 0, first_hop, later_hop, None)
-        bounds = jnp.take(prefix, in_iptr_rank - 1, mode="clip")
-        bounds = jnp.where(in_iptr_rank == 0, 0, bounds)
-        reached = (bounds[1:] - bounds[:-1]) > 0
-        fresh2 = reached & ~visited
-        visited2 = visited | fresh2
-        dist2 = jnp.where(fresh2, h + 1, dist)
-        found2 = jnp.take(visited2, dst_rank)
+        with jax.named_scope("prefix"):
+            prefix = lax.cond(h == 0, first_hop, later_hop, None)
+        with jax.named_scope("bounds"):
+            bounds = jnp.take(prefix, in_iptr_rank - 1, mode="clip")
+            bounds = jnp.where(in_iptr_rank == 0, 0, bounds)
+            reached = (bounds[1:] - bounds[:-1]) > 0
+        with jax.named_scope("visit"):
+            fresh2 = reached & ~visited
+            visited2 = visited | fresh2
+            dist2 = jnp.where(fresh2, h + 1, dist)
+            found2 = jnp.take(visited2, dst_rank)
         return h + 1, fresh2, visited2, dist2, found2
 
     h, _f, _v, dist, found = lax.while_loop(
         cond, body, (jnp.int32(0), fresh0, visited0, dist0,
                      jnp.take(visited0, dst_rank)))
-    planes = jnp.stack([
-        pack_words(((dist >> b) & 1).astype(bool), pack_chunks(nd))
-        for b in range(_DIST_BITS)])
+    with jax.named_scope("pack_dist"):
+        planes = jnp.stack([
+            pack_words(((dist >> b) & 1).astype(bool), pack_chunks(nd))
+            for b in range(_DIST_BITS)])
     return planes, found, h
 
 
@@ -733,9 +745,21 @@ def shortest_bfs(g: PullGraph, src: int, dst: int, max_hops: int):
         g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank, g.subjects,
         g.in_subjects, seeds_mask, jnp.int32(dr), jnp.int32(max_hops),
         chunks=g.chunks, chunks_d=g.chunks_d)
-    planes_h, found_h = jax.device_get((planes, found))  # ONE round-trip
-    if not bool(found_h):
+    # stages of the request's clock (obs/costs.py; no-ops without one):
+    # up to here the caller's kernel window ran as dev.dispatch; blocked
+    # in the fetch is dev.wait; unpack and chain walk are dev.post
+    with costs.stage("dev.wait"):
+        planes_h, found_h = jax.device_get((planes, found))  # ONE round-trip
+    with costs.stage("dev.post"):
+        return _walk_back(g, planes_h, bool(found_h), dr, src, dst)
+
+
+def _walk_back(g: PullGraph, planes_h, found: bool, dr: int, src: int,
+               dst: int):
+    """The uid path [src..dst] from bfs_dist's fetched distance planes."""
+    if not found:
         return None
+    nd = len(g.host_in_subjects)
     dist = np.zeros(nd, dtype=np.int32)
     for b in range(_DIST_BITS):
         dist |= unpack_words(planes_h[b], nd).astype(np.int32) << b
@@ -779,12 +803,13 @@ def _recurse_fused_levels(in_src_pad, in_src_pad_d, in_iptr_rank, subjects,
         fresh_d, seen = carry
         # hop 1 reads seed bits in src-rank space; hops >= 2 read the
         # previous level's fresh dst-rank mask against the dst-rank stream
-        prefix = lax.cond(
-            i == 0,
-            lambda _: _prefix_for(jnp.take(seeds_mask, subjects),
-                                  in_src_pad, chunks),
-            lambda _: _prefix_for(fresh_d, in_src_pad_d, chunks_d),
-            None)
+        with jax.named_scope("prefix"):
+            prefix = lax.cond(
+                i == 0,
+                lambda _: _prefix_for(jnp.take(seeds_mask, subjects),
+                                      in_src_pad, chunks),
+                lambda _: _prefix_for(fresh_d, in_src_pad_d, chunks_d),
+                None)
         reached, traversed, seen2, fresh = _recurse_tail(
             prefix, in_iptr_rank, seen, allow_loop)
         dest_p = pack_words(reached, pack_chunks(nd))

@@ -257,7 +257,31 @@ class Executor:
     # ------------------------------------------------------------------ API
 
     def execute(self, req: dql.ParsedRequest) -> dict:
-        """Run all query blocks in dependency waves (query/query.go:2431)."""
+        """Run all query blocks in dependency waves (query/query.go:2431).
+        Stage `exec` of the request's clock (obs/costs.py), outside the
+        narrower stages the device sites open; the result tree's build is
+        stage `encode`."""
+        with costs.stage("exec"):
+            blocks = self._run_blocks(req)
+        from dgraph_tpu.query.outputnode import encode_result
+
+        out: dict = {}
+        with costs.stage("encode"):
+            for b in blocks:
+                if b.gq.attr == "var":
+                    continue
+                encode_result(self, b, out)
+        if self.mesh is not None and (self._mesh_fused or
+                                      self._mesh_misses or
+                                      self._mesh_touched):
+            # mesh-relevant query: its traversals ran fused / at minimal
+            # dispatch count, or it recorded labeled fallbacks — the
+            # ratio of the two counters is the fused-coverage number the
+            # /debug/metrics mesh section shows
+            self.mesh.note_query(self._mesh_misses == 0)
+        return out
+
+    def _run_blocks(self, req: dql.ParsedRequest) -> list[SubGraph]:
         blocks = [SubGraph(gq=q, attr=q.attr) for q in req.queries]
         pending = list(blocks)
         done_vars: set[str] = set()
@@ -273,22 +297,7 @@ class Executor:
                 self._process_block(b)
                 done_vars.update(_block_defines(b.gq))
             pending = [b for b in pending if b not in runnable]
-        from dgraph_tpu.query.outputnode import encode_result
-
-        out: dict = {}
-        for b in blocks:
-            if b.gq.attr == "var":
-                continue
-            encode_result(self, b, out)
-        if self.mesh is not None and (self._mesh_fused or
-                                      self._mesh_misses or
-                                      self._mesh_touched):
-            # mesh-relevant query: its traversals ran fused / at minimal
-            # dispatch count, or it recorded labeled fallbacks — the
-            # ratio of the two counters is the fused-coverage number the
-            # /debug/metrics mesh section shows
-            self.mesh.note_query(self._mesh_misses == 0)
-        return out
+        return blocks
 
     def _mesh_miss(self, reason: str) -> None:
         """One labeled fused-coverage miss for this query."""

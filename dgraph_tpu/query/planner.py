@@ -291,12 +291,11 @@ def _est_filter(ft: dql.FilterTree | None, snap, schema, metrics,
 # plan construction
 # ---------------------------------------------------------------------------
 
-def build_plan(req, snap, schema, metrics=None, top_k: int = 8,
-               trace=None) -> Plan:
+def build_plan(req, snap, schema, metrics=None, top_k: int = 8) -> Plan:
     """Plan every block of a parsed request against one snapshot's stats."""
     plan = Plan(req, metrics)
     for gq in req.queries:
-        blk = _plan_block(plan, gq, snap, schema, metrics, trace,
+        blk = _plan_block(plan, gq, snap, schema, metrics,
                           frontier_est=None)
         plan.tree.append(blk)
     # EXPLAIN stats header: the read set's live stats, with the top-K
@@ -340,12 +339,7 @@ def _count(metrics, name: str) -> None:
         metrics.counter(name).inc()
 
 
-def _printf(trace, msg: str, *args) -> None:
-    if trace is not None:
-        trace.printf(msg, *args)
-
-
-def _plan_block(plan: Plan, gq, snap, schema, metrics, trace,
+def _plan_block(plan: Plan, gq, snap, schema, metrics,
                 frontier_est: int | None) -> dict:
     """Plan one block (root or nested child level); returns its explain
     subtree."""
@@ -367,7 +361,7 @@ def _plan_block(plan: Plan, gq, snap, schema, metrics, trace,
             parts.append((est, src))
         root_est = sum(e for e, _ in parts) if parts else 0
         source = "+".join(s for _, s in parts) or "empty"
-        swapped = _maybe_swap_root(plan, gq, snap, schema, metrics, trace,
+        swapped = _maybe_swap_root(plan, gq, snap, schema, metrics,
                                    root_est)
         if swapped:
             sw = plan.root_swap[id(gq)]
@@ -385,7 +379,7 @@ def _plan_block(plan: Plan, gq, snap, schema, metrics, trace,
     # -- filters -------------------------------------------------------------
     swap = plan.root_swap.get(id(gq))
     filt_steps = _plan_filter(plan, gq.filter, snap, schema, metrics,
-                              trace, max(root_est, 1), swap)
+                              max(root_est, 1), swap)
     dest_est = _est_filter(gq.filter, snap, schema, metrics,
                            max(root_est, 0), swap)
     dest_est = min(dest_est, max(root_est, 0))
@@ -397,7 +391,7 @@ def _plan_block(plan: Plan, gq, snap, schema, metrics, trace,
         from dgraph_tpu.query import fusedplan
 
         plan.fused_chains[id(gq)] = fusedplan.chain_ir(gq, schema)
-    children = _plan_children(plan, gq, snap, schema, metrics, trace,
+    children = _plan_children(plan, gq, snap, schema, metrics,
                               max(dest_est, 1))
     out = {"block": gq.alias or gq.attr or "q",
            "root": _step_ref(gq, root_step),
@@ -445,7 +439,7 @@ def _plan_groupby(plan: Plan, gq, snap, schema, metrics,
     return _step_ref(gq.groupby, step)
 
 
-def _maybe_swap_root(plan: Plan, gq, snap, schema, metrics, trace,
+def _maybe_swap_root(plan: Plan, gq, snap, schema, metrics,
                      root_est: int) -> bool:
     """Promote the most selective AND-filter index probe to the root when
     it beats the declared root source by ROOT_SWAP_FACTOR. Only when the
@@ -483,12 +477,12 @@ def _maybe_swap_root(plan: Plan, gq, snap, schema, metrics, trace,
     plan.root_swap[id(gq)] = RootSwap(new_func=leaf.func,
                                       orig_func=fn, leaf_id=id(leaf))
     _count(metrics, "dgraph_planner_root_swaps_total")
-    _printf(trace, "planner: root swap %s (est %d) <- %s (est %d)",
-            _fn_desc(leaf.func), est, _fn_desc(fn), root_est)
+    otrace.event("planner.root_swap", to=_fn_desc(leaf.func), est=est,
+                 was=_fn_desc(fn), was_est=root_est)
     return True
 
 
-def _plan_filter(plan: Plan, ft, snap, schema, metrics, trace,
+def _plan_filter(plan: Plan, ft, snap, schema, metrics,
                  frontier_est: int, swap: RootSwap | None) -> list[dict]:
     """Register Steps for every filter leaf and the AND-order decisions.
     Returns the explain entries in PLANNED evaluation order."""
@@ -522,16 +516,16 @@ def _plan_filter(plan: Plan, ft, snap, schema, metrics, trace,
         if order != list(range(len(ft.children))):
             plan.and_order[id(ft)] = order
             _count(metrics, "dgraph_planner_filter_reorders_total")
-            _printf(trace, "planner: AND reorder %s", order)
+            otrace.event("planner.and_reorder", order=order)
         remaining = frontier_est
         for _, _i, c in keyed:
-            out.extend(_plan_filter(plan, c, snap, schema, metrics, trace,
+            out.extend(_plan_filter(plan, c, snap, schema, metrics,
                                     max(remaining, 1), swap))
             remaining = min(remaining, _est_filter(
                 c, snap, schema, metrics, max(remaining, 1), swap))
         return out
     for c in ft.children:       # or / not: parse order, shared frontier
-        out.extend(_plan_filter(plan, c, snap, schema, metrics, trace,
+        out.extend(_plan_filter(plan, c, snap, schema, metrics,
                                 frontier_est, swap))
     return out
 
@@ -559,7 +553,7 @@ def _orderable_children(gq) -> bool:
     return not any(_subtree_uses_vars(c) for c in gq.children)
 
 
-def _plan_children(plan: Plan, gq, snap, schema, metrics, trace,
+def _plan_children(plan: Plan, gq, snap, schema, metrics,
                    frontier_est: int) -> list[dict]:
     out: list[dict] = []
     ests: list[int] = []
@@ -604,7 +598,7 @@ def _plan_children(plan: Plan, gq, snap, schema, metrics, trace,
         if cgq.children or cgq.filter is not None:
             child_frontier = max(min(est_edges,
                                      st.fwd.n_edges or est_edges), 1)
-            sub = _plan_block(plan, cgq, snap, schema, metrics, trace,
+            sub = _plan_block(plan, cgq, snap, schema, metrics,
                               frontier_est=child_frontier)
             ref["filters"] = sub["filters"]
             ref["children"] = sub["children"]
@@ -614,7 +608,7 @@ def _plan_children(plan: Plan, gq, snap, schema, metrics, trace,
         if order != list(range(len(ests))):
             plan.child_order[id(gq)] = order
             _count(metrics, "dgraph_planner_child_reorders_total")
-            _printf(trace, "planner: sibling reorder %s", order)
+            otrace.event("planner.sibling_reorder", order=order)
     return out
 
 

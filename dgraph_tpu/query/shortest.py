@@ -167,10 +167,14 @@ def _device_shortest(attr: str, csr, src: int, dst: int, max_depth: int):
     if csr.num_edges >= _sssp_kernel_min() and max_depth < DIST_UNREACHED:
         from dgraph_tpu.ops import pallas_bfs as pb
 
-        g = pb.pull_graph_for(csr)      # host prep: outside the timer
+        with costs.stage("exec.prep"):
+            g = pb.pull_graph_for(csr)  # host prep: outside the timer
+        # shortest_bfs splits the window: dev.dispatch up to the jitted
+        # call's return, dev.wait in the fetch, dev.post after it
         with otrace.span("device_kernel", kernel="pb.bfs_dist",
                          edges=g.num_edges), \
-                costs.kernel("pb.bfs_dist", attr=attr):
+                costs.kernel("pb.bfs_dist", attr=attr,
+                             stage="dev.dispatch"):
             path = pb.shortest_bfs(g, src, dst, max_depth)
         if path is None:
             return None
@@ -185,19 +189,25 @@ def _device_shortest(attr: str, csr, src: int, dst: int, max_depth: int):
     num_nodes = 1 << max(int(np.ceil(np.log2(hi + 2))), 4)
     with otrace.span("device_kernel", kernel="traversal.sssp",
                      edges=csr.num_edges), \
-            costs.kernel("traversal.sssp", attr=attr):
+            costs.kernel("traversal.sssp", attr=attr,
+                         stage="dev.dispatch"):
         res = traversal.sssp(csr.subjects, csr.indptr, csr.indices, None,
                              src, num_nodes=num_nodes, max_iters=max_depth)
-        dist = float(np.asarray(res.dist[dst]))
+        dist_d = res.dist[dst]
+        with costs.stage("dev.wait"):
+            dist = float(np.asarray(dist_d))
     if not np.isfinite(dist):
         return None
-    parent = np.asarray(res.parent)
-    path = [dst]
-    while path[-1] != src:
-        p = int(parent[path[-1]])
-        if p < 0 or len(path) > max_depth + 1:
-            return None      # broken chain (cannot happen for finite dist)
-        path.append(p)
+    # the parent fetch and the chain walk run after the window closed (the
+    # ledger's device_ms never held them); the clock books them as dev.post
+    with costs.stage("dev.post"):
+        parent = np.asarray(res.parent)
+        path = [dst]
+        while path[-1] != src:
+            p = int(parent[path[-1]])
+            if p < 0 or len(path) > max_depth + 1:
+                return None  # broken chain (cannot happen for finite dist)
+            path.append(p)
     return (dist, path[::-1], [attr] * (len(path) - 1))
 
 

@@ -1,6 +1,5 @@
-"""Metrics + request tracing (reference: x/metrics.go expvar counters at
-/debug/vars, golang.org/x/net/trace request traces at /debug/requests with
-sampled LazyPrintf breadcrumbs, edgraph/server.go:289,388).
+"""Metrics (reference: x/metrics.go expvar counters at /debug/vars). Request
+tracing is obs/otrace.py's spans and obs/costs.py's stage clock.
 
 Design: one Registry per server Node (tests run many embedded nodes — a
 process-global expvar table like the reference's would bleed counts between
@@ -11,7 +10,6 @@ rather than maintaining buckets (the /debug surface is low-QPS)."""
 from __future__ import annotations
 
 import bisect
-import random
 import threading
 import time
 from collections import deque
@@ -229,6 +227,16 @@ class KeyedGauge:
             else:
                 self._vals.pop(key, None)
 
+    def inc_many(self, items: dict[str, int]) -> None:
+        """inc() for several keys under one lock acquisition."""
+        with self._lock:
+            for key, n in items.items():
+                v = self._vals.get(key, 0) + n
+                if v:
+                    self._vals[key] = v
+                else:
+                    self._vals.pop(key, None)
+
     def get(self, key: str) -> int:
         # dict reads race dict writes in free-threaded builds, and even on
         # the GIL a concurrent resize can surface torn iteration states —
@@ -396,6 +404,9 @@ class Registry:
                      "dgraph_fold_pending_tablets",
                      "dgraph_cold_open_ms",
                      "dgraph_first_query_ms",
+                     # requests whose stage clock closed (obs/costs.py
+                     # StageClock): the divisor of dgraph_stage_us_total
+                     "dgraph_stage_requests_total",
                      # device aggregation + whole-graph analytics
                      # (ops/segments.py, query/groupby.py,
                      # query/analytics.py; ISSUE 17)
@@ -469,6 +480,20 @@ class Registry:
             labels=("tenant",))
         self.keyed_gauges["dgraph_tenant_shed_total"] = KeyedGauge(
             labels=("tenant",))
+        # where a request's time goes (obs/costs.py StageClock): integer
+        # microseconds per named stage, summed over closed requests; and
+        # the cost ledger's per-kernel device windows (lg.kernels), which
+        # otherwise reach only /debug/top's ring
+        self.keyed_gauges["dgraph_stage_us_total"] = KeyedGauge(
+            labels=("stage",))
+        self.keyed_gauges["dgraph_kernel_us_total"] = KeyedGauge(
+            labels=("kernel",))
+        self.keyed_gauges["dgraph_kernel_calls_total"] = KeyedGauge(
+            labels=("kernel",))
+        # serve's start-up phases, set once before the banner
+        # (__main__.cmd_serve): import / backend_init / store_open / listen
+        self.keyed_gauges["dgraph_startup_ms"] = KeyedGauge(
+            labels=("phase",))
         for name in ("dgraph_query_latency_s", "dgraph_mutation_latency_s",
                      "dgraph_commit_latency_s", "dgraph_compaction_s",
                      "dgraph_planner_est_error_log2",
@@ -507,7 +532,10 @@ class Registry:
 
     def counter(self, name: str) -> Counter:
         with self._lock:
-            return self.counters.setdefault(name, Counter())
+            c = self.counters.get(name)
+            if c is None:     # no throw-away Counter (and its lock) a call
+                c = self.counters[name] = Counter()
+            return c
 
     def histogram(self, name: str) -> Histogram:
         with self._lock:
@@ -524,7 +552,10 @@ class Registry:
     def keyed(self, name: str,
               labels: tuple[str, ...] | None = None) -> KeyedGauge:
         with self._lock:
-            return self.keyed_gauges.setdefault(name, KeyedGauge(labels))
+            g = self.keyed_gauges.get(name)
+            if g is None:
+                g = self.keyed_gauges[name] = KeyedGauge(labels)
+            return g
 
     def to_dict(self) -> dict:
         """expvar-style dump for /debug/vars."""
@@ -594,74 +625,3 @@ def merge_exports(snaps: list[dict]) -> dict:
             for k, v in g.get("vals", {}).items():
                 cur["vals"][k] = cur["vals"].get(k, 0) + int(v)
     return out
-
-
-class Trace:
-    """One request's breadcrumb trail (net/trace analog)."""
-
-    __slots__ = ("kind", "title", "t0", "events", "error", "elapsed")
-
-    def __init__(self, kind: str, title: str) -> None:
-        self.kind = kind
-        self.title = title
-        self.t0 = time.perf_counter()
-        self.events: list[tuple[float, str]] = []
-        self.error = ""
-        self.elapsed = 0.0            # frozen by TraceStore.finish
-
-    def printf(self, msg: str, *args) -> None:
-        self.events.append((time.perf_counter() - self.t0,
-                            msg % args if args else msg))
-
-    def to_dict(self) -> dict:
-        return {"kind": self.kind, "title": self.title,
-                "elapsed_s": round(self.elapsed, 6),
-                "error": self.error,
-                "events": [{"t": round(t, 6), "msg": m}
-                           for t, m in self.events]}
-
-
-class _NullTrace:
-    """Unsampled requests get a no-op trace — zero overhead breadcrumbs."""
-
-    def printf(self, msg: str, *args) -> None:
-        pass
-
-    error = ""
-
-
-NULL_TRACE = _NullTrace()
-
-
-class TraceStore:
-    """Sampled request traces, newest-first ring (reference: --trace fraction
-    gating tr.New, /debug/requests rendering).
-
-    rng is injectable (anything with .random()) so tests drive the
-    sampling decision deterministically instead of flaking on the global
-    unseeded generator."""
-
-    def __init__(self, fraction: float = 1.0, keep: int = 64,
-                 rng=None) -> None:
-        self.fraction = fraction
-        self.rng = rng if rng is not None else random
-        self._ring: deque[Trace] = deque(maxlen=keep)
-        self._lock = threading.Lock()
-
-    def start(self, kind: str, title: str):
-        if self.fraction <= 0 or \
-                (self.fraction < 1.0 and self.rng.random() >= self.fraction):
-            return NULL_TRACE
-        return Trace(kind, title)
-
-    def finish(self, tr, error: str = "") -> None:
-        if tr is NULL_TRACE:
-            return
-        tr.error = error
-        tr.elapsed = time.perf_counter() - tr.t0
-        with self._lock:
-            self._ring.appendleft(tr)
-
-    def recent(self, n: int = 32) -> list[dict]:
-        with self._lock:
-            return [t.to_dict() for i, t in enumerate(self._ring) if i < n]

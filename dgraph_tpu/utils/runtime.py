@@ -19,6 +19,7 @@ from __future__ import annotations
 import functools
 import os
 import sys
+import time
 from importlib import metadata
 
 # <checkout>/.jax_cache, normalised: the path is part of what makes a
@@ -36,6 +37,20 @@ _CACHE_THRESHOLDS = (
     ("jax_persistent_cache_min_compile_time_secs",
      "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", 0.0),
 )
+
+
+def process_age_s() -> float | None:
+    """Seconds since the kernel started this process (its /proc start time
+    against the boot clock, 10 ms ticks): what interpreter start and
+    imports cost before any code of ours could read a clock. None where
+    /proc or the boot clock is missing."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        return time.clock_gettime(time.CLOCK_BOOTTIME) \
+            - ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
 
 
 def configure_compile_cache() -> str:

@@ -43,27 +43,6 @@ def test_abort_counter():
     assert n.metrics.counters["dgraph_num_aborts_total"].value == 1
 
 
-def test_traces_record_breadcrumbs_and_errors():
-    n = Node(trace_fraction=1.0)
-    n.alter(schema_text="name: string @index(exact) .")
-    n.query('{ q(func: has(name)) { name } }')
-    recent = n.traces.recent()
-    assert recent and recent[0]["kind"] == "query"
-    msgs = [e["msg"] for e in recent[0]["events"]]
-    assert any("parsed" in m for m in msgs)
-    assert any("executed" in m for m in msgs)
-    with pytest.raises(Exception):
-        n.query("{ bad dql !!!")
-    assert n.traces.recent()[0]["error"]
-
-
-def test_trace_sampling_off():
-    n = Node(trace_fraction=0.0)
-    n.alter(schema_text="name: string .")
-    n.query("{ q(func: has(name)) { name } }")
-    assert n.traces.recent() == []
-
-
 def test_debug_http_endpoints():
     n = Node()
     n.alter(schema_text="name: string @index(exact) .")
@@ -79,9 +58,6 @@ def test_debug_http_endpoints():
             f"http://127.0.0.1:{port}/debug/vars", timeout=5).read())
         assert v["dgraph_num_queries_total"] >= 1
         assert "dgraph_query_latency_s" in v
-        tr = json.loads(urllib.request.urlopen(
-            f"http://127.0.0.1:{port}/debug/requests", timeout=5).read())
-        assert tr and tr[0]["kind"] == "query"
     finally:
         srv.shutdown()
 
@@ -146,42 +122,6 @@ def test_keyed_gauge_get_is_locked_and_consistent():
     assert not errors
     g.set("x", 5)
     assert g.get("x") == 5 and g.get("missing") == 0
-
-
-def test_trace_store_injectable_rng():
-    class Seq:
-        def __init__(self, vals):
-            self.vals = list(vals)
-
-        def random(self):
-            return self.vals.pop(0)
-
-    ts = metrics.TraceStore(fraction=0.5, rng=Seq([0.1, 0.9, 0.4, 0.6]))
-    picks = [ts.start("query", "t") is not metrics.NULL_TRACE
-             for _ in range(4)]
-    assert picks == [True, False, True, False]
-    # fraction 1.0 never consults the rng (hot path stays coin-flip free)
-    ts_all = metrics.TraceStore(fraction=1.0, rng=Seq([]))
-    assert ts_all.start("query", "t") is not metrics.NULL_TRACE
-
-
-def test_traces_finish_on_every_error_path():
-    """query/mutate/alter breadcrumb traces must finish (with the error)
-    on every failure shape — parse errors, unknown txns, bad schema."""
-    n = Node()
-    n.alter(schema_text="name: string @index(exact) .")
-    with pytest.raises(Exception):
-        n.query("{ q(func: bogus~~ }")                    # parse error
-    with pytest.raises(Exception):
-        n.mutate(set_nquads='<0x1> <name> "x" .', start_ts=999999)
-    with pytest.raises(Exception):
-        n.alter(schema_text="name: notatype .")
-    kinds = [(t["kind"], t["error"] != "") for t in n.traces.recent()]
-    assert ("query", True) in kinds
-    assert ("mutate", True) in kinds
-    assert ("alter", True) in kinds
-    # the span-trace buffers drained too (no active-trace leaks)
-    assert n.tracer.active_traces() == 0
 
 
 def test_meter_rate_wider_window_clamps_to_retention():

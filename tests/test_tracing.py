@@ -213,6 +213,7 @@ def test_chrome_trace_export_loads_structurally(http_node):
     node, base = http_node
     _post(base, "/query", '{ q(func: eq(name, "ann")) { name follows '
                           '{ name } } }')
+    _closed(node, 1)     # the root span finishes after the answer is written
     st, body = _get(base, "/debug/traces")
     assert st == 200
     idx = json.loads(body)
@@ -270,6 +271,7 @@ def test_slow_query_log_captures_plan_and_tree(http_node):
     node, base = http_node
     _post(base, "/query", '{ q(func: eq(name, "ann")) { name follows '
                           '{ name } } }')
+    _closed(node, 1)
     st, body = _get(base, "/debug/slow")
     entries = json.loads(body)
     assert entries, "threshold 0.1us should log every query"
@@ -340,3 +342,413 @@ def test_prom_level_shaped_totals_render_as_gauges():
     assert "# TYPE dgraph_pending_queries_total gauge" in text
     assert "# TYPE dgraph_active_mutations_total gauge" in text
     assert "# TYPE dgraph_num_queries_total counter" in text
+
+
+# ---------------------------------------------------------------------------
+# the stage clock (obs/costs.py StageClock): one clock inside a request,
+# read by /metrics counters, the sampled spans and the profiler's host plane
+# ---------------------------------------------------------------------------
+
+import threading
+import time
+
+from dgraph_tpu.obs import costs
+from dgraph_tpu.query import shortest as shmod
+
+CHAIN = "\n".join(f"<0x{i:x}> <follows> <0x{i + 1:x}> ." for i in range(1, 9)) \
+    + '\n<0x1> <name> "ann" .\n<0x9> <name> "zed" .'
+SHORTEST = ("{ p as shortest(from: 0x1, to: 0x6) { follows } "
+            "r(func: uid(p)) { uid } }")
+
+
+def _chain_node(**kw):
+    node = Node(**kw)
+    node.alter(schema_text=SCHEMA)
+    node.mutate(set_nquads=CHAIN, commit_now=True)
+    return node
+
+
+def _serve(node):
+    srv = make_server(node, "127.0.0.1", 0)
+    threading.Thread(target=srv.serve_forever, daemon=True).start()
+    return srv, f"http://127.0.0.1:{srv.server_address[1]}"
+
+
+def _closed(node, n, timeout=5.0):
+    """Wait until `n` request clocks have closed: an HTTP client has its
+    answer before the handler finishes the root span and flushes."""
+    deadline = time.monotonic() + timeout
+    c = node.metrics.counter("dgraph_stage_requests_total")
+    while c.value < n and time.monotonic() < deadline:
+        time.sleep(0.002)
+    assert c.value == n, (c.value, n)
+
+
+def _force_tier(monkeypatch, tier):
+    """`shortest` on the Pallas kernel tier (interpret mode off-TPU) or on
+    the Bellman-Ford tier, on a graph far below either floor."""
+    monkeypatch.setattr(shmod, "DEVICE_SSSP_MIN_EDGES", 0)
+    monkeypatch.setattr(shmod, "SSSP_KERNEL_MIN",
+                        0 if tier == "kernel" else 1 << 62)
+
+
+TILE_CASES = {
+    # case -> (query, tier, stages that must show, stages that must not)
+    "parse_error": ("{ q(func: bogus~~ }", None, {"parse"}, {"exec"}),
+    "result_cache_hit": ('{ q(func: eq(name, "ann")) { name } }', None,
+                         {"parse", "plan"}, {"exec", "encode"}),
+    "schema_request": ("schema {}", None, {"parse", "plan"}, {"exec"}),
+    "shortest_kernel": (SHORTEST, "kernel",
+                        {"parse", "plan", "exec", "exec.prep",
+                         "dev.dispatch", "dev.wait", "dev.post", "encode"},
+                        {"dev.window"}),
+    "shortest_sssp": (SHORTEST, "sssp",
+                      {"parse", "plan", "exec", "dev.dispatch", "dev.wait",
+                       "dev.post", "encode"}, {"dev.window", "exec.prep"}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TILE_CASES))
+def test_stages_tile_the_entry_points_wall_time(case, monkeypatch):
+    """Segments never overlap and leave no hole: the stages' nanoseconds
+    add up to the time between the clock's opening and its closing, which
+    is the entry point's own wall time but for opening and closing it."""
+    q, tier, want, never = TILE_CASES[case]
+    if tier:
+        _force_tier(monkeypatch, tier)
+    node = _chain_node(span_sample=0.0)
+    try:
+        if case == "result_cache_hit":
+            node.query(q)                      # fill the tier
+        if tier:
+            node.query(q.replace("0x6", "0x5"))   # compile outside the clock
+        closed = node.metrics.counter("dgraph_stage_requests_total")
+        before = closed.value
+        t0 = time.perf_counter_ns()
+        with node.clocked("query", "owner") as clk:
+            t1 = time.perf_counter_ns()
+            try:
+                node.query(q)
+                failed = False
+            except Exception:       # noqa: BLE001 — the parse-error case
+                failed = True
+            t2 = time.perf_counter_ns()
+        t3 = time.perf_counter_ns()
+    finally:
+        node.close()
+    assert failed == (case == "parse_error")
+    total = sum(clk.ns.values())
+    inner, outer = t2 - t1, t3 - t0
+    assert inner <= total <= outer
+    # within 2% of the owner's wall time, or by less than opening the
+    # clock and flushing it cost on a loaded box
+    assert outer - total <= max(0.02 * outer, 200_000), (clk.ns, outer)
+    assert want <= set(clk.ns), clk.ns
+    assert not never & set(clk.ns), clk.ns
+    assert all(v >= 0 for v in clk.ns.values())
+    # Node.query joined the open clock: one request, flushed by the owner
+    assert closed.value == before + 1
+
+
+def test_stage_closes_on_exception_and_restores_the_previous():
+    clk = costs.StageClock("a", otrace.NULL_SPAN)
+    with clk:
+        with costs.stage("b"):
+            with pytest.raises(ValueError):
+                with costs.stage("c"):
+                    assert clk._cur == "c"
+                    raise ValueError("boom")
+            assert clk._cur == "b"
+        assert clk._cur == "a"
+    assert set(clk.ns) == {"a", "b", "c"}
+    assert costs.clock() is None
+    # no clock open: a stage is a no-op
+    with costs.stage("nowhere"):
+        pass
+
+
+def test_kernel_window_is_a_stage_and_restores_on_exception():
+    clk = costs.StageClock("exec", otrace.NULL_SPAN)
+    with clk, costs.scope(costs.CostLedger()) as lg:
+        with costs.kernel("csr.expand"):
+            assert clk._cur == "dev.window"
+        assert clk._cur == "exec"
+        with pytest.raises(RuntimeError):
+            with costs.kernel("pb.bfs_dist", stage="dev.dispatch"):
+                with costs.stage("dev.wait"):
+                    raise RuntimeError("device fell over")
+        assert clk._cur == "exec"
+    assert {"exec", "dev.window", "dev.dispatch", "dev.wait"} == set(clk.ns)
+    # one window, two clocks: the ledger's ms and the stages' ns agree
+    dev_ns = clk.ns["dev.dispatch"] + clk.ns["dev.wait"]
+    assert abs(lg.kernels["pb.bfs_dist"] * 1e6 - dev_ns) < 200_000
+    assert lg.kernel_calls == {"csr.expand": 1, "pb.bfs_dist": 1}
+    # a context copied to another thread sees the clock and leaves it alone
+    clk2 = costs.StageClock("exec", otrace.NULL_SPAN)
+    with clk2:
+        import contextvars
+
+        ctx = contextvars.copy_context()
+        seen = []
+        t = threading.Thread(target=lambda: ctx.run(
+            lambda: (seen.append(costs.clock()),
+                     costs.stage("dev.window").__enter__())))
+        t.start()
+        t.join()
+        assert seen == [None] and clk2._cur == "exec"
+        assert costs.clock() is clk2
+
+
+class _Counting:
+    """Stands in for a class and counts its constructions."""
+
+    def __init__(self, real):
+        self.real, self.n = real, 0
+
+    def __call__(self, *a, **kw):
+        self.n += 1
+        return self.real(*a, **kw)
+
+
+def test_unsampled_request_builds_no_span_and_no_annotation(monkeypatch):
+    import jax.profiler
+
+    spans = _Counting(otrace.Span)
+    anns = _Counting(jax.profiler.TraceAnnotation)
+    monkeypatch.setattr(otrace, "Span", spans)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", anns)
+    _force_tier(monkeypatch, "sssp")
+    for sample, some in ((0.0, False), (1.0, True)):
+        node = _chain_node(span_sample=sample)
+        srv, base = _serve(node)
+        spans.n = anns.n = 0
+        try:
+            out = _post(base, "/query", SHORTEST)
+            _closed(node, 1)
+            traces = node.tracer.sink.index()
+        finally:
+            srv.shutdown()
+            node.close()
+        assert out["data"]["_path_"]
+        assert bool(spans.n) == some and bool(anns.n) == some, \
+            (sample, spans.n, anns.n)
+        if some:        # one annotation a segment, and only segments:
+            #             never the root, task:* or device_kernel spans
+            rec = node.tracer.sink.get(traces[0]["trace_id"])
+            kinds = [s["kind"] for s in rec["spans"]]
+            assert anns.n == kinds.count("stage") > 0
+            assert spans.n == len(kinds) - anns.n >= 2
+
+
+def test_sampled_request_holds_its_stage_spans(monkeypatch):
+    _force_tier(monkeypatch, "kernel")
+    node = _chain_node(span_sample=1.0, trace_rng=random.Random(5))
+    srv, base = _serve(node)
+    try:
+        node.query(SHORTEST.replace("0x6", "0x5"))       # compile first
+        _post(base, "/query", SHORTEST)
+        _closed(node, 2)
+        rec = node.tracer.sink.get(node.tracer.sink.index(1)[0]["trace_id"])
+    finally:
+        srv.shutdown()
+        node.close()
+    spans = rec["spans"]
+    root = _links_intact(spans)
+    assert root["name"] == "query" and rec["root"] == "query"
+    assert root["attrs"]["query"].startswith("{ p as shortest")
+    assert [s["name"] for s in spans].count("query") == 1
+    assert {s["trace_id"] for s in spans} == {rec["trace_id"]}
+    stages = sorted((s for s in spans if s["kind"] == "stage"),
+                    key=lambda s: s["start"])
+    names = [s["name"] for s in stages]
+    # the handler's own time is http.read: the body before the query, and
+    # its latency histogram after the answer is written
+    assert names[0] == "http.read" and names[-2:] == ["http.write",
+                                                      "http.read"]
+    order = [names.index(n) for n in ("parse", "exec", "exec.prep",
+                                      "dev.dispatch", "dev.wait",
+                                      "dev.post", "encode")]
+    assert order == sorted(order), names
+    eps = 200e-6       # start is the wall clock, dur the monotonic one
+    for a, b in zip(stages, stages[1:]):
+        assert a["start"] + a["dur"] <= b["start"] + eps, (a, b)
+    assert root["start"] - eps <= stages[0]["start"]
+    assert stages[-1]["start"] + stages[-1]["dur"] <= \
+        root["start"] + root["dur"] + eps
+    dk = next(s for s in spans if s["name"] == "device_kernel")
+    for s in stages:
+        if s["name"].startswith("dev."):
+            assert s["parent_id"] == dk["span_id"]
+            assert dk["start"] - eps <= s["start"] and \
+                s["start"] + s["dur"] <= dk["start"] + dk["dur"] + eps
+
+
+def test_metrics_carry_stage_kernel_and_startup_series(monkeypatch):
+    from dgraph_tpu.__main__ import _record_startup
+
+    _force_tier(monkeypatch, "kernel")
+    monkeypatch.setattr(taskmod, "HOST_EXPAND_MAX", 0)   # csr.expand window
+    node = _chain_node(span_sample=0.0, planner=False)
+    srv, base = _serve(node)
+    try:
+        _post(base, "/query", SHORTEST)
+        _post(base, "/query", '{ q(func: uid(0x1, 0x2)) { follows { uid } } }')
+        _closed(node, 2)
+        _record_startup(node, 1.25, [10.0, 10.5, 12.0, 12.25])
+        series = prom.parse(_get(base, "/metrics")[1].decode())
+    finally:
+        srv.shutdown()
+        node.close()
+
+    def labelled(name, label):
+        return {lb[label]: v for lb, v in series[name]}
+
+    stage_us = labelled("dgraph_stage_us_total", "stage")
+    assert {"http.read", "parse", "plan", "exec", "exec.prep",
+            "dev.dispatch", "dev.wait", "dev.post", "dev.window", "encode",
+            "http.write"} <= set(stage_us)
+    assert all(v > 0 and v == int(v) for v in stage_us.values())
+    assert series["dgraph_stage_requests_total"][0][1] == 2
+    kernel_us = labelled("dgraph_kernel_us_total", "kernel")
+    calls = labelled("dgraph_kernel_calls_total", "kernel")
+    assert calls["pb.bfs_dist"] == 1 and kernel_us["pb.bfs_dist"] > 0
+    assert set(kernel_us) == set(calls) and len(calls) >= 2
+    # one window, timed by the ledger and by the stage clock
+    dev = sum(stage_us[s] for s in ("dev.dispatch", "dev.wait", "dev.post"))
+    assert abs(kernel_us["pb.bfs_dist"] - dev) <= max(0.02 * dev, 200)
+    assert labelled("dgraph_startup_ms", "phase") == {
+        "import": 1250, "backend_init": 500, "store_open": 1500,
+        "listen": 250}
+
+
+def test_server_latency_parts_sum_below_the_total(http_node):
+    _node, base = http_node
+    out = _post(base, "/query", '{ q(func: has(name)) { name follows '
+                                '{ name } } }')
+    lat = out["extensions"]["server_latency"]
+    assert set(lat) == {"parsing_ns", "processing_ns", "encoding_ns",
+                        "total_ns"}
+    assert lat["parsing_ns"] > 0 and lat["processing_ns"] > 0 \
+        and lat["encoding_ns"] > 0
+    assert lat["parsing_ns"] + lat["processing_ns"] + lat["encoding_ns"] \
+        <= lat["total_ns"]
+
+
+def test_grpc_handler_owns_the_clock_and_fills_latency():
+    from dgraph_tpu.api.grpc_server import DgraphService
+    from dgraph_tpu.protos import api_pb2 as pb
+
+    node = _chain_node(span_sample=1.0, trace_rng=random.Random(9))
+    try:
+        resp = DgraphService(node).query(
+            pb.Request(query='{ q(func: has(name)) { name } }',
+                       read_only=True), None)
+        rec = node.tracer.sink.get(node.tracer.sink.index(1)[0]["trace_id"])
+    finally:
+        node.close()
+    lat = resp.latency
+    assert lat.parsing_ns > 0 and lat.processing_ns > 0 \
+        and lat.encoding_ns > 0
+    assert lat.parsing_ns + lat.processing_ns + lat.encoding_ns \
+        <= lat.total_ns
+    assert rec["root"] == "query"
+    assert {"grpc", "parse", "plan", "exec", "encode"} <= \
+        {s["name"] for s in rec["spans"] if s["kind"] == "stage"}
+
+
+def test_bfs_dist_lowering_holds_the_scope_names():
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dgraph_tpu.ops import pallas_bfs as pb
+
+    subjects = np.arange(1, 9, dtype=np.int64)
+    indptr = np.arange(9, dtype=np.int64)
+    indices = np.arange(2, 10, dtype=np.int64)
+    g = pb.prep_pull(subjects, indptr, indices, 10, with_host_arrays=True)
+    text = pb.bfs_dist.lower(
+        g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank, g.subjects,
+        g.in_subjects, jnp.zeros((g.num_nodes,), bool), jnp.int32(0),
+        jnp.int32(4), chunks=g.chunks, chunks_d=g.chunks_d
+    ).as_text(debug_info=True)
+    for scope in ("seed", "prefix", "bounds", "visit", "pack_dist"):
+        assert f"/{scope}/" in text, scope
+
+
+# the behaviours the removed breadcrumb store (utils/metrics.TraceStore)
+# was tested for, on the span tracer that replaces it
+
+def test_every_error_path_leaves_its_error_on_a_finished_trace():
+    node = Node(span_sample=1.0, trace_rng=random.Random(11))
+    node.alter(schema_text=SCHEMA)
+    with pytest.raises(Exception):
+        node.query("{ q(func: bogus~~ }")                  # parse error
+    with pytest.raises(Exception):
+        node.mutate(set_nquads='<0x1> <name> "x" .', start_ts=999999)
+    with pytest.raises(Exception):
+        node.alter(schema_text="name: notatype .")
+    kinds = {(t["root"], t["error"] != "") for t in node.tracer.sink.index()}
+    assert {("query", True), ("mutate", True), ("alter", True)} <= kinds
+    assert ("alter", False) in kinds
+    # the failed query still shows where its time went
+    tid = next(t["trace_id"] for t in node.tracer.sink.index()
+               if t["root"] == "query")
+    assert "parse" in {s["name"] for s in node.tracer.sink.get(tid)["spans"]}
+    assert node.tracer.active_traces() == 0
+    # over HTTP the handler answers 400 and swallows the exception: the
+    # error still lands on the root span
+    srv, base = _serve(node)
+    try:
+        with pytest.raises(urllib.error.HTTPError):
+            _post(base, "/query", "{ q(func: bogus~~ }")
+        _closed(node, 2)
+        newest = node.tracer.sink.index(1)[0]
+    finally:
+        srv.shutdown()
+        node.close()
+    assert newest["root"] == "query" and newest["error"]
+    assert node.tracer.active_traces() == 0
+
+
+def test_span_sampling_off_records_nothing():
+    node = Node(span_sample=0.0)
+    node.alter(schema_text="name: string .")
+    node.mutate(set_nquads='<0x1> <name> "x" .', commit_now=True)
+    node.query("{ q(func: has(name)) { name } }")
+    assert len(node.tracer.sink) == 0 and node.tracer.active_traces() == 0
+    node.close()
+
+
+def test_owner_takes_the_one_sampling_decision_from_an_injected_rng():
+    class Seq:
+        def __init__(self, vals):
+            self.vals, self.asked, self.ids = list(vals), 0, 0
+
+        def random(self):
+            self.asked += 1
+            return self.vals.pop(0)
+
+        def getrandbits(self, n):
+            self.ids += 1
+            return self.ids
+
+    rng = Seq([0.1, 0.9, 0.4, 0.6])
+    node = _chain_node(span_sample=0.0)
+    node.tracer.fraction, node.tracer.rng = 0.5, rng
+    srv, base = _serve(node)
+    try:
+        before = len(node.tracer.sink)
+        picks = []
+        for i in range(4):
+            # an upsert block runs mutate + commit under the query: nothing
+            # below the owner rolls the dice again
+            _post(base, "/query", 'upsert { query { v as var(func: eq(name, '
+                  '"ann")) } mutation { set { uid(v) <age> "%d" . } } }' % i)
+            _closed(node, i + 1)
+            picks.append(len(node.tracer.sink) - before)
+            before = len(node.tracer.sink)
+    finally:
+        srv.shutdown()
+        node.close()
+    assert picks == [1, 0, 1, 0]
+    assert rng.asked == 4
