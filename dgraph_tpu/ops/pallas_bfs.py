@@ -660,29 +660,34 @@ def recurse_step(in_src_pad, in_iptr_rank, subjects, in_subjects,
     return dest_p, trav, seen2, fresh
 
 
-_DIST_BITS = 8          # BFS distance planes (max_hops clamped below 255)
-DIST_UNREACHED = (1 << _DIST_BITS) - 1
+DIST_UNREACHED = 255    # uint8 distance label of a vertex never reached
 
 
 @partial(jax.jit, static_argnames=("chunks", "chunks_d"))
 def bfs_dist(in_src_pad, in_src_pad_d, in_iptr_rank, subjects, in_subjects,
-             seeds_mask, dst_rank, max_hops, *, chunks: int, chunks_d: int):
+             query, *, chunks: int, chunks_d: int):
     """Unweighted single-source BFS distances, early-exiting when dst is
     reached — the kernel behind `shortest` on large CSRs (replaces the
     Bellman-Ford E-gather of ops/traversal.sssp, an element-granularity
     gather; here each hop is one Pallas E-stream).
 
-    The whole hop loop runs in ONE dispatch (lax.while_loop); per-dst-rank
-    distances return BIT-PACKED as 8 bit planes (value DIST_UNREACHED =
-    never reached), so the host fetch is ~Nd bits, not Nd ints. The host
-    walks the predecessor chain itself from the distance labels (each
-    step scans one node's in-edge slice — microseconds)."""
+    What crosses the host–device boundary for one search: IN, beside the
+    resident graph, `query` = int32[3] (source UID, destination rank,
+    max_hops) — one transfer; three scalar arguments are three, 0.5 ms
+    more of dispatch on a v5e; OUT, one array — the per-dst-rank distance
+    labels as uint8[Nd] (DIST_UNREACHED = never reached; max_hops is
+    clamped below it). The whole hop loop runs in ONE dispatch
+    (lax.while_loop). The host walks the predecessor chain itself from
+    the labels (each step scans one node's in-edge slice — microseconds)."""
     nd = in_subjects.shape[0]
+    src, dst_rank, max_hops = query[0], query[1], query[2]
     # the named scopes are op_name metadata only (same compiled program):
     # a profile's leaf instructions carry the stage of the loop they
-    # belong to — seed / prefix / bounds / visit / pack_dist
+    # belong to — seed / prefix / bounds / visit
     with jax.named_scope("seed"):
-        visited0 = jnp.take(seeds_mask, in_subjects)       # [Nd]
+        # both uid lists are sorted and unique: comparing with the source
+        # uid IS the one-hot seed mask gathered into that rank space
+        visited0 = in_subjects == src                      # [Nd]
         dist0 = jnp.where(visited0, 0, DIST_UNREACHED).astype(jnp.int32)
     fresh0 = jnp.zeros((nd,), dtype=bool)
 
@@ -694,8 +699,7 @@ def bfs_dist(in_src_pad, in_src_pad_d, in_iptr_rank, subjects, in_subjects,
         h, fresh, visited, dist, _found = c
 
         def first_hop(_):
-            return _prefix_for(jnp.take(seeds_mask, subjects), in_src_pad,
-                               chunks)
+            return _prefix_for(subjects == src, in_src_pad, chunks)
 
         def later_hop(_):
             # a hop>=2 frontier is a subset of destinations: gather bits
@@ -715,55 +719,48 @@ def bfs_dist(in_src_pad, in_src_pad_d, in_iptr_rank, subjects, in_subjects,
             found2 = jnp.take(visited2, dst_rank)
         return h + 1, fresh2, visited2, dist2, found2
 
-    h, _f, _v, dist, found = lax.while_loop(
+    _h, _f, _v, dist, _found = lax.while_loop(
         cond, body, (jnp.int32(0), fresh0, visited0, dist0,
                      jnp.take(visited0, dst_rank)))
-    with jax.named_scope("pack_dist"):
-        planes = jnp.stack([
-            pack_words(((dist >> b) & 1).astype(bool), pack_chunks(nd))
-            for b in range(_DIST_BITS)])
-    return planes, found, h
+    return dist.astype(jnp.uint8)
 
 
 def shortest_bfs(g: PullGraph, src: int, dst: int, max_hops: int):
-    """Host orchestration: run bfs_dist, fetch packed distances once, walk
-    the predecessor chain on the host in-adjacency. Returns the uid path
-    [src..dst] or None (unreachable within max_hops). Requires a PullGraph
-    built with host arrays (pull_graph_for)."""
+    """Host orchestration: run bfs_dist, fetch its one output (the uint8
+    distance labels) once, walk the predecessor chain on the host
+    in-adjacency. Returns the uid path [src..dst] or None (unreachable
+    within max_hops). Requires a PullGraph built with host arrays
+    (pull_graph_for)."""
     nd = len(g.host_in_subjects)
     if nd == 0:
         return None
     dr = int(np.searchsorted(g.host_in_subjects, dst))
     if dr >= nd or g.host_in_subjects[dr] != dst:
         return None              # dst has no in-edges: unreachable
-    max_hops = min(int(max_hops), DIST_UNREACHED - 1)
-    seeds_mask = jnp.zeros((g.num_nodes,), dtype=bool)
     if src >= g.num_nodes:
         return None
-    seeds_mask = seeds_mask.at[src].set(True)
-    planes, found, _h = bfs_dist(
+    max_hops = min(int(max_hops), DIST_UNREACHED - 1)
+    # a numpy array, built on the host: nothing runs on the device per
+    # request but the jitted program itself
+    dist = bfs_dist(
         g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank, g.subjects,
-        g.in_subjects, seeds_mask, jnp.int32(dr), jnp.int32(max_hops),
+        g.in_subjects, np.asarray([src, dr, max_hops], dtype=np.int32),
         chunks=g.chunks, chunks_d=g.chunks_d)
     # stages of the request's clock (obs/costs.py; no-ops without one):
     # up to here the caller's kernel window ran as dev.dispatch; blocked
-    # in the fetch is dev.wait; unpack and chain walk are dev.post
+    # in the fetch is dev.wait; the chain walk is dev.post
     with costs.stage("dev.wait"):
-        planes_h, found_h = jax.device_get((planes, found))  # ONE round-trip
+        dist_h = jax.device_get(dist)                      # ONE round-trip
     with costs.stage("dev.post"):
-        return _walk_back(g, planes_h, bool(found_h), dr, src, dst)
+        return _walk_back(g, dist_h, dr, src, dst)
 
 
-def _walk_back(g: PullGraph, planes_h, found: bool, dr: int, src: int,
-               dst: int):
-    """The uid path [src..dst] from bfs_dist's fetched distance planes."""
-    if not found:
+def _walk_back(g: PullGraph, dist: np.ndarray, dr: int, src: int, dst: int):
+    """The uid path [src..dst] from bfs_dist's fetched uint8 labels, or
+    None when dst was not reached."""
+    if dist[dr] == DIST_UNREACHED:
         return None
-    nd = len(g.host_in_subjects)
-    dist = np.zeros(nd, dtype=np.int32)
-    for b in range(_DIST_BITS):
-        dist |= unpack_words(planes_h[b], nd).astype(np.int32) << b
-
+    nd = len(dist)
     iptr, in_src = g.host_in_iptr, g.host_in_src
     map_s2d = g.host_map_s2d
     sub_uids = g.host_subjects   # uid of a src rank

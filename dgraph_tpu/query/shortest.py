@@ -9,10 +9,11 @@ materialized as a `_path_` block (:598).
 
 TPU shape: a single-predicate unweighted `shortest` runs FULLY ON DEVICE —
 on TPU the Pallas BFS kernel covers the whole device range
-(ops/pallas_bfs.bfs_dist: the whole hop loop in one dispatch, bit-packed
-distance fetch, host predecessor walk); ops/traversal.sssp edge relaxation
-remains the device path for extreme depths (>= 254 hops) and for non-TPU
-backends/tests. MESH MODE (ISSUE 12): blocks over mesh-sharded tablets —
+(ops/pallas_bfs.bfs_dist: the whole hop loop in one dispatch, one fetch of
+the uint8 distance labels, host predecessor walk); ops/traversal.sssp edge
+relaxation remains the device path for extreme depths (>= 254 hops) and
+for non-TPU backends/tests.
+MESH MODE (ISSUE 12): blocks over mesh-sharded tablets —
 multi-predicate included — run the whole expandOut loop as ONE
 `lax.while_loop` dispatch (mesh_exec.run_bfs) with frontier, visited set,
 and distance vector device-resident between hops; single paths
@@ -97,18 +98,18 @@ def _build_adjacency(ex, sg: SubGraph, src: int, dst: int):
 DEVICE_SSSP_MIN_EDGES = 1 << 17
 
 # above this edge count the Pallas BFS kernel (ops/pallas_bfs.bfs_dist:
-# whole hop loop in one dispatch, bit-packed distance fetch) replaces the
+# whole hop loop in one dispatch, one uint8 label fetch) replaces the
 # Bellman-Ford E-gather of traversal.sssp. Tests set the module global to
 # 0 to force it (interpret mode off-TPU).
 SSSP_KERNEL_MIN: int | None = None
 
 
 _SSSP_KERNEL_MIN_TPU = 1 << 17   # == the device tier's default floor —
-# the kernel's bit-packed distance fetch (~Nd/8 bytes) moves 64x fewer
-# bytes to the host than Bellman-Ford's dist+parent fetch (8 B/node) at
-# every size the device path serves. A SEPARATE constant: tests monkeypatch
-# DEVICE_SSSP_MIN_EDGES to force the sssp tier on tiny graphs, and the
-# kernel floor must not follow it down.
+# the kernel's distance fetch (one byte a destination: Nd bytes) moves 8x
+# fewer bytes to the host than Bellman-Ford's dist+parent fetch (8 B/node)
+# at every size the device path serves. A SEPARATE constant: tests
+# monkeypatch DEVICE_SSSP_MIN_EDGES to force the sssp tier on tiny graphs,
+# and the kernel floor must not follow it down.
 
 
 def _sssp_kernel_min() -> int:
@@ -152,7 +153,7 @@ def _device_shortest(attr: str, csr, src: int, dst: int, max_depth: int):
     walked on host, under a device_kernel span and a cost timer (both
     calls below fetch their result, so the timer sees the device step).
     On TPU the Pallas BFS kernel serves the whole device range (bfs_dist —
-    one dispatch for the whole hop loop, bit-packed distance fetch); the
+    one dispatch for the whole hop loop, one uint8 label fetch); the
     Bellman-Ford relaxation (ops/traversal.sssp) serves extreme depths
     (>= 254) and non-TPU backends. Work is bounded by iterations x E (the
     resident CSR), so the reference's discovered-edge budget does not

@@ -657,7 +657,6 @@ def test_grpc_handler_owns_the_clock_and_fills_latency():
 
 
 def test_bfs_dist_lowering_holds_the_scope_names():
-    import jax.numpy as jnp
     import numpy as np
 
     from dgraph_tpu.ops import pallas_bfs as pb
@@ -668,11 +667,13 @@ def test_bfs_dist_lowering_holds_the_scope_names():
     g = pb.prep_pull(subjects, indptr, indices, 10, with_host_arrays=True)
     text = pb.bfs_dist.lower(
         g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank, g.subjects,
-        g.in_subjects, jnp.zeros((g.num_nodes,), bool), jnp.int32(0),
-        jnp.int32(4), chunks=g.chunks, chunks_d=g.chunks_d
+        g.in_subjects, np.asarray([1, 0, 4], dtype=np.int32),
+        chunks=g.chunks, chunks_d=g.chunks_d
     ).as_text(debug_info=True)
-    for scope in ("seed", "prefix", "bounds", "visit", "pack_dist"):
+    for scope in ("seed", "prefix", "bounds", "visit"):
         assert f"/{scope}/" in text, scope
+    # one output, the uint8 labels; no pack of bit planes behind the loop
+    assert "/pack_dist/" not in text
 
 
 # the behaviours the removed breadcrumb store (utils/metrics.TraceStore)
