@@ -224,14 +224,22 @@ def active_prefix_sparse(ftab: jax.Array, src_pad: jax.Array) -> jax.Array:
     return out.reshape(e_pad)
 
 
-def _frontier_table(frontier: jax.Array) -> jax.Array:
-    """bool[num_nodes] (popcount <= FRONTIER_CAP) -> (33,128) search table."""
-    imax = jnp.int32(np.iinfo(np.int32).max)
-    flist = jnp.nonzero(frontier, size=FRONTIER_CAP, fill_value=imax)[0]
-    flist = flist.astype(jnp.int32)        # sorted ascending, pads at end
+_INT32_MAX = np.iinfo(np.int32).max    # pad of a frontier list: no real rank
+
+
+def _table_of_list(flist: jax.Array) -> jax.Array:
+    """int32[FRONTIER_CAP] sorted ascending, _INT32_MAX pads last ->
+    (33,128) search table."""
     buckets = flist.reshape(_LANES, 32)
     seps = buckets[:, 31]                  # per-bucket max
     return jnp.concatenate([seps[None, :], buckets.T], axis=0)
+
+
+def _frontier_table(frontier: jax.Array) -> jax.Array:
+    """bool[num_nodes] (popcount <= FRONTIER_CAP) -> (33,128) search table."""
+    flist = jnp.nonzero(frontier, size=FRONTIER_CAP,
+                        fill_value=jnp.int32(_INT32_MAX))[0]
+    return _table_of_list(flist.astype(jnp.int32))
 
 
 class PullGraph(NamedTuple):
@@ -267,6 +275,8 @@ class PullGraph(NamedTuple):
     host_map_s2d: np.ndarray | None = None     # HOST int32[Ns]
     host_in_subjects: np.ndarray | None = None  # HOST int64[Nd]
     host_subjects: np.ndarray | None = None     # HOST int64[Ns]
+    host_fwd_indptr: np.ndarray | None = None   # HOST int[Ns+1]: a
+    # source's out-degree, which picks the first level of a search
 
 
 def prep_pull(subjects: np.ndarray, indptr: np.ndarray,
@@ -329,7 +339,8 @@ def prep_pull(subjects: np.ndarray, indptr: np.ndarray,
         np.int32)                    # every dst IS in in_subjects
     snt = np.int32(np.iinfo(np.int32).max)
     map_d2s = host_rank_of(subjects, in_subjects, snt).astype(np.int32)
-    inv_order = hi_iptr = hi_src = hi_m = hi_subs = hi_fsubs = None
+    inv_order = hi_iptr = hi_src = hi_m = hi_subs = None
+    hi_fsubs = hi_findptr = None
     if with_host_arrays:     # engine paths only (recurse materialization +
         # shortest backtrack); bench/BFS callers skip the host RAM
         inv_order = np.empty(E, dtype=np.int32)
@@ -337,6 +348,7 @@ def prep_pull(subjects: np.ndarray, indptr: np.ndarray,
         hi_iptr, hi_src = iptr, src_sorted
         hi_m, hi_subs = map_s2d, in_subjects.astype(np.int64)
         hi_fsubs = subjects.astype(np.int64)
+        hi_findptr = np.asarray(indptr)
     return PullGraph(jnp.asarray(src_pad), jnp.asarray(src_pad_d),
                      jnp.asarray(iptr),
                      jnp.asarray(subjects.astype(np.int32)),
@@ -347,7 +359,7 @@ def prep_pull(subjects: np.ndarray, indptr: np.ndarray,
                      jnp.asarray(map_d2s),
                      int(num_nodes), int(E), int(chunks), int(chunks_d),
                      inv_order, hi_iptr, hi_src, hi_m, hi_subs,
-                     hi_fsubs)
+                     hi_fsubs, hi_findptr)
 
 
 def pack_words(mask: jax.Array, chunks: int) -> jax.Array:
@@ -661,68 +673,146 @@ def recurse_step(in_src_pad, in_iptr_rank, subjects, in_subjects,
 
 
 DIST_UNREACHED = 255    # uint8 distance label of a vertex never reached
+FIRST_HOP_CAP = FRONTIER_CAP   # out-degree of a root at/below which level 1
+                               # of a search reads the root's forward row
 
 
-@partial(jax.jit, static_argnames=("chunks", "chunks_d"))
+def first_hop_pushes(degree, cap: int):
+    """THE predicate of a search's first level, shared by the program (a
+    traced degree) and the host's counter (a Python one): push over the
+    root's own forward row, or stream every in-edge."""
+    return degree <= cap
+
+
+@partial(jax.jit, static_argnames=("chunks", "chunks_d", "first_hop_cap"))
 def bfs_dist(in_src_pad, in_src_pad_d, in_iptr_rank, subjects, in_subjects,
-             query, *, chunks: int, chunks_d: int):
+             fwd_indptr, fwd_dst_rank, query, *, chunks: int, chunks_d: int,
+             first_hop_cap: int = FIRST_HOP_CAP):
     """Unweighted single-source BFS distances, early-exiting when dst is
     reached — the kernel behind `shortest` on large CSRs (replaces the
     Bellman-Ford E-gather of ops/traversal.sssp, an element-granularity
-    gather; here each hop is one Pallas E-stream).
+    gather; here each hop from the second on is one Pallas E-stream).
+
+    Level 1 has one vertex in its frontier, so it reads that vertex's row
+    of the forward CSR (one contiguous slice, one scatter) and not the
+    whole in-edge stream, and level 2 takes that row, sorted, as its
+    frontier list (the sparse kernel's table without a nonzero over the
+    mask). A root with more than `first_hop_cap` out-edges (the slice's
+    static width) keeps the stream for level 1 and the mask for level 2.
+    The program chooses by the degree it reads in `fwd_indptr`.
 
     What crosses the host–device boundary for one search: IN, beside the
-    resident graph, `query` = int32[3] (source UID, destination rank,
-    max_hops) — one transfer; three scalar arguments are three, 0.5 ms
-    more of dispatch on a v5e; OUT, one array — the per-dst-rank distance
-    labels as uint8[Nd] (DIST_UNREACHED = never reached; max_hops is
-    clamped below it). The whole hop loop runs in ONE dispatch
-    (lax.while_loop). The host walks the predecessor chain itself from
-    the labels (each step scans one node's in-edge slice — microseconds)."""
+    resident graph, `query` = int32[4] (source UID, source rank — Ns for a
+    source with no out-edge —, destination rank, max_hops) — one transfer;
+    scalar arguments are one each, 0.25 ms of dispatch apiece on a v5e;
+    OUT, one array — the per-dst-rank distance labels as uint8[Nd]
+    (DIST_UNREACHED = never reached; max_hops is clamped below it). The
+    whole hop loop runs in ONE dispatch (lax.while_loop). The host walks
+    the predecessor chain itself from the labels (each step scans one
+    node's in-edge slice — microseconds)."""
     nd = in_subjects.shape[0]
-    src, dst_rank, max_hops = query[0], query[1], query[2]
+    n_edges = fwd_dst_rank.shape[0]
+    width = min(first_hop_cap, n_edges)    # static: the slice's lanes
+    if not 0 < width <= FRONTIER_CAP:      # the row has to fit the table
+        raise ValueError(f"bfs_dist: first_hop_cap={first_hop_cap} over "
+                         f"{n_edges} edges; want 1..{FRONTIER_CAP} lanes")
+    src, src_rank, dst_rank, max_hops = (query[i] for i in range(4))
     # the named scopes are op_name metadata only (same compiled program):
-    # a profile's leaf instructions carry the stage of the loop they
-    # belong to — seed / prefix / bounds / visit
+    # a profile's leaf instructions carry the stage of the search they
+    # belong to — seed / push / prefix / bounds / visit
     with jax.named_scope("seed"):
         # both uid lists are sorted and unique: comparing with the source
         # uid IS the one-hot seed mask gathered into that rank space
         visited0 = in_subjects == src                      # [Nd]
         dist0 = jnp.where(visited0, 0, DIST_UNREACHED).astype(jnp.int32)
-    fresh0 = jnp.zeros((nd,), dtype=bool)
+        found0 = jnp.take(visited0, dst_rank)
 
-    def cond(c):
-        h, fresh, _visited, _dist, found = c
-        return (~found) & (h < max_hops) & ((h == 0) | fresh.any())
-
-    def body(c):
-        h, fresh, visited, dist, _found = c
-
-        def first_hop(_):
-            return _prefix_for(subjects == src, in_src_pad, chunks)
-
-        def later_hop(_):
-            # a hop>=2 frontier is a subset of destinations: gather bits
-            # straight from the fresh dst-rank mask (no remap gather)
-            return _prefix_for(fresh, in_src_pad_d, chunks_d)
-
-        with jax.named_scope("prefix"):
-            prefix = lax.cond(h == 0, first_hop, later_hop, None)
+    def reached_by(prefix):
         with jax.named_scope("bounds"):
             bounds = jnp.take(prefix, in_iptr_rank - 1, mode="clip")
             bounds = jnp.where(in_iptr_rank == 0, 0, bounds)
-            reached = (bounds[1:] - bounds[:-1]) > 0
-        with jax.named_scope("visit"):
-            fresh2 = reached & ~visited
-            visited2 = visited | fresh2
-            dist2 = jnp.where(fresh2, h + 1, dist)
-            found2 = jnp.take(visited2, dst_rank)
-        return h + 1, fresh2, visited2, dist2, found2
+            return (bounds[1:] - bounds[:-1]) > 0
 
-    _h, _f, _v, dist, _found = lax.while_loop(
-        cond, body, (jnp.int32(0), fresh0, visited0, dist0,
-                     jnp.take(visited0, dst_rank)))
+    def visit(h, reached, visited, dist):
+        with jax.named_scope("visit"):
+            fresh = reached & ~visited
+            visited2 = visited | fresh
+            return (h + 1, fresh, visited2, jnp.where(fresh, h + 1, dist),
+                    jnp.take(visited2, dst_rank))
+
+    with jax.named_scope("push"):
+        # indptr[Ns] = indptr[Ns + 1 (clipped)] = E: the "no out-edge"
+        # rank reads an empty row; so does a search that may not expand
+        row_at = jnp.take(fwd_indptr, src_rank + jnp.arange(2), mode="clip")
+        start = row_at[0]
+        degree = jnp.where(found0 | (max_hops <= 0), 0, row_at[1] - start)
+
+    def push_hop(_):
+        with jax.named_scope("push"):
+            # dynamic_slice clamps a window that would pass the end of
+            # the array: clamp first, and mask by edge position
+            at = jnp.clip(start, 0, n_edges - width)
+            lanes = at + jnp.arange(width, dtype=jnp.int32)
+            row = jnp.where((lanes >= start) & (lanes < start + degree),
+                            lax.dynamic_slice(fwd_dst_rank, (at,), (width,)),
+                            _INT32_MAX)
+            return jnp.zeros((nd,), bool).at[row].set(True, mode="drop"), row
+
+    def stream_hop(_):
+        with jax.named_scope("prefix"):
+            # src-rank space: a source with out-edges and no in-edge
+            # exists only here
+            prefix = _prefix_for(subjects == src, in_src_pad, chunks)
+        return reached_by(prefix), jnp.full((width,), _INT32_MAX, jnp.int32)
+
+    pushes = first_hop_pushes(degree, first_hop_cap)
+    reached, row = lax.cond(pushes, push_hop, stream_hop, None)
+
+    def cond(c):
+        h, fresh, _visited, _dist, found = c
+        return (~found) & (h < max_hops) & fresh.any()
+
+    def row_hop(c):
+        # level 2 after a push: the row level 1 read IS the frontier, as a
+        # list of dst ranks — the sparse kernel's table without a nonzero
+        # over the mask. A vertex of the row that was visited before (the
+        # root, by a self-loop) reaches only visited vertices.
+        h, _fresh, visited, dist, _found = c
+        with jax.named_scope("push"):
+            flist = jnp.pad(jnp.sort(row), (0, FRONTIER_CAP - width),
+                            constant_values=_INT32_MAX)
+            prefix = active_prefix_sparse(_table_of_list(flist), in_src_pad_d)
+        return visit(h, reached_by(prefix), visited, dist)
+
+    def body(c):
+        h, fresh, visited, dist, _found = c
+        with jax.named_scope("prefix"):
+            # a hop>=2 frontier is a subset of destinations: gather bits
+            # straight from the fresh dst-rank mask (no remap gather)
+            prefix = _prefix_for(fresh, in_src_pad_d, chunks_d)
+        return visit(h, reached_by(prefix), visited, dist)
+
+    c = visit(jnp.int32(0), reached, visited0, dist0)
+    c = lax.cond(pushes & cond(c), row_hop, lambda c: c, c)
+    _h, _f, _v, dist, _found = lax.while_loop(cond, body, c)
     return dist.astype(jnp.uint8)
+
+
+def _source_row(g: PullGraph, src: int) -> tuple[int, int]:
+    """(source rank, out-degree) of a uid, from the host arrays; a uid
+    with no out-edge has the rank Ns, whose row bfs_dist reads as empty."""
+    ns = len(g.host_subjects)
+    sr = int(np.searchsorted(g.host_subjects, src))
+    if sr >= ns or g.host_subjects[sr] != src:
+        return ns, 0
+    return sr, int(g.host_fwd_indptr[sr + 1] - g.host_fwd_indptr[sr])
+
+
+def first_hop_mode(g: PullGraph, src: int) -> str:
+    """"push" or "stream": the branch bfs_dist takes for level 1 of a
+    search from `src` (the label of dgraph_bfs_first_hop_total)."""
+    degree = _source_row(g, src)[1]
+    return "push" if first_hop_pushes(degree, FIRST_HOP_CAP) else "stream"
 
 
 def shortest_bfs(g: PullGraph, src: int, dst: int, max_hops: int):
@@ -739,13 +829,15 @@ def shortest_bfs(g: PullGraph, src: int, dst: int, max_hops: int):
         return None              # dst has no in-edges: unreachable
     if src >= g.num_nodes:
         return None
+    sr = _source_row(g, src)[0]
     max_hops = min(int(max_hops), DIST_UNREACHED - 1)
     # a numpy array, built on the host: nothing runs on the device per
     # request but the jitted program itself
     dist = bfs_dist(
         g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank, g.subjects,
-        g.in_subjects, np.asarray([src, dr, max_hops], dtype=np.int32),
-        chunks=g.chunks, chunks_d=g.chunks_d)
+        g.in_subjects, g.fwd_indptr, g.fwd_dst_rank,
+        np.asarray([src, sr, dr, max_hops], dtype=np.int32),
+        chunks=g.chunks, chunks_d=g.chunks_d, first_hop_cap=FIRST_HOP_CAP)
     # stages of the request's clock (obs/costs.py; no-ops without one):
     # up to here the caller's kernel window ran as dev.dispatch; blocked
     # in the fetch is dev.wait; the chain walk is dev.post

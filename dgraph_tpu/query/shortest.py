@@ -148,7 +148,8 @@ def _device_csr(ex, sg: SubGraph):
     return cgq.attr, csr
 
 
-def _device_shortest(attr: str, csr, src: int, dst: int, max_depth: int):
+def _device_shortest(attr: str, csr, src: int, dst: int, max_depth: int,
+                     metrics=None):
     """Unweighted single-source shortest path on device, parent chain
     walked on host, under a device_kernel span and a cost timer (both
     calls below fetch their result, so the timer sees the device step).
@@ -170,10 +171,16 @@ def _device_shortest(attr: str, csr, src: int, dst: int, max_depth: int):
 
         with costs.stage("exec.prep"):
             g = pb.pull_graph_for(csr)  # host prep: outside the timer
+        # level 1 of the search, by the root's out-degree: its own row
+        # ("push") or the whole in-edge stream ("stream")
+        first_hop = pb.first_hop_mode(g, src)
+        if metrics is not None:
+            metrics.keyed("dgraph_bfs_first_hop_total",
+                          labels=("mode",)).inc(first_hop)
         # shortest_bfs splits the window: dev.dispatch up to the jitted
         # call's return, dev.wait in the fetch, dev.post after it
         with otrace.span("device_kernel", kernel="pb.bfs_dist",
-                         edges=g.num_edges), \
+                         edges=g.num_edges, first_hop=first_hop), \
                 costs.kernel("pb.bfs_dist", attr=attr,
                              stage="dev.dispatch"):
             path = pb.shortest_bfs(g, src, dst, max_depth)
@@ -386,7 +393,8 @@ def shortest_path(ex, sg: SubGraph) -> None:
         dev = _device_csr(ex, sg)
         mesh = _mesh_csrs(ex, sg) if dev is None else None
         if dev is not None:
-            p = _device_shortest(dev[0], dev[1], src, dst, max_depth)
+            p = _device_shortest(dev[0], dev[1], src, dst, max_depth,
+                                 getattr(ex.snap, "metrics", None))
             sg.paths = [p] if p is not None else []
         elif mesh is not None and spec.numpaths <= 1:
             p = _mesh_shortest_single(ex, sg, mesh, src, dst)

@@ -203,39 +203,41 @@ class KeyedGauge:
     `labels` names multi-dimensional keys: when set, keys are the label
     VALUES joined with '|' (e.g. labels=("pred", "group"), key
     "follows|2") and obs/prom.py renders them as separate Prometheus
-    labels instead of the default key="..."."""
+    labels instead of the default key="...".
 
-    __slots__ = ("_vals", "_lock", "labels")
+    `keep` names the keys of a closed label set that stay at zero instead
+    of dropping: a fresh node scrapes them as 0 (the counters'
+    pre-registration invariant), so a reader can tell "none yet" from
+    "a program without the series"."""
 
-    def __init__(self, labels: tuple[str, ...] | None = None) -> None:
-        self._vals: dict[str, int] = {}
+    __slots__ = ("_vals", "_lock", "labels", "_keep")
+
+    def __init__(self, labels: tuple[str, ...] | None = None,
+                 keep: tuple[str, ...] = ()) -> None:
+        self._vals: dict[str, int] = dict.fromkeys(keep, 0)
         self._lock = threading.Lock()
         self.labels = labels
+        self._keep = frozenset(keep)
+
+    def _put(self, key: str, v: int) -> None:
+        if v or key in self._keep:
+            self._vals[key] = v
+        else:
+            self._vals.pop(key, None)
 
     def set(self, key: str, v: int) -> None:
         with self._lock:
-            if v:
-                self._vals[key] = v
-            else:
-                self._vals.pop(key, None)
+            self._put(key, v)
 
     def inc(self, key: str, n: int = 1) -> None:
         with self._lock:
-            v = self._vals.get(key, 0) + n
-            if v:
-                self._vals[key] = v
-            else:
-                self._vals.pop(key, None)
+            self._put(key, self._vals.get(key, 0) + n)
 
     def inc_many(self, items: dict[str, int]) -> None:
         """inc() for several keys under one lock acquisition."""
         with self._lock:
             for key, n in items.items():
-                v = self._vals.get(key, 0) + n
-                if v:
-                    self._vals[key] = v
-                else:
-                    self._vals.pop(key, None)
+                self._put(key, self._vals.get(key, 0) + n)
 
     def get(self, key: str) -> int:
         # dict reads race dict writes in free-threaded builds, and even on
@@ -490,6 +492,10 @@ class Registry:
             labels=("kernel",))
         self.keyed_gauges["dgraph_kernel_calls_total"] = KeyedGauge(
             labels=("kernel",))
+        # level 1 of a pb.bfs_dist search (ops/pallas_bfs.first_hop_mode):
+        # mode="push" reads the root's forward row, "stream" every in-edge
+        self.keyed_gauges["dgraph_bfs_first_hop_total"] = KeyedGauge(
+            labels=("mode",), keep=("push", "stream"))
         # serve's start-up phases, set once before the banner
         # (__main__.cmd_serve): import / backend_init / store_open / listen
         self.keyed_gauges["dgraph_startup_ms"] = KeyedGauge(

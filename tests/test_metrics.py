@@ -11,6 +11,7 @@ import pytest
 from dgraph_tpu.api.http import make_server
 from dgraph_tpu.api.server import Node
 from dgraph_tpu.coord.zero import TxnConflict
+from dgraph_tpu.obs import prom
 from dgraph_tpu.utils import metrics
 
 
@@ -90,6 +91,31 @@ def test_meter_rate_prunes_expired_marks():
     assert m.rate(window=1.0) == pytest.approx(3 / 1.0)
     assert len(m._ring) == 4
     assert m.rate() == pytest.approx(4 / 10.0)
+
+
+@pytest.mark.parametrize("op", ["set", "inc", "inc_many"])
+def test_keyed_gauge_keeps_its_closed_keys_at_zero(op):
+    """A zero drops its key, except a key of the closed set the gauge was
+    made with: those show from the start and never leave."""
+    g = metrics.KeyedGauge(labels=("mode",), keep=("push", "stream"))
+    assert g.snapshot() == {"push": 0, "stream": 0}
+
+    def put(key, n):        # move `key` by n from where it stands
+        if op == "set":
+            g.set(key, g.get(key) + n)
+        elif op == "inc":
+            g.inc(key, n)
+        else:
+            g.inc_many({key: n})
+
+    for key in ("push", "other"):
+        put(key, 2)
+        assert g.get(key) == 2
+        put(key, -2)
+    assert g.snapshot() == {"push": 0, "stream": 0}      # "other" dropped
+    text = prom.render(metrics.Registry())
+    assert 'dgraph_bfs_first_hop_total{mode="push"} 0' in text
+    assert 'dgraph_bfs_first_hop_total{mode="stream"} 0' in text
 
 
 def test_keyed_gauge_get_is_locked_and_consistent():

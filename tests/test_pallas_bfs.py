@@ -239,3 +239,33 @@ def test_hops_zero_returns_seeds_as_frontier(rng):
     res = pb.k_hop_pull_pallas(g, mask, hops=0)
     np.testing.assert_array_equal(np.asarray(res.frontier), np.asarray(mask))
     assert int(res.traversed) == 0
+
+
+@pytest.mark.parametrize("n_set", [0, 1, 33, pb.FRONTIER_CAP])
+def test_table_of_a_sorted_list_is_the_table_of_its_mask(rng, n_set):
+    """The sparse kernel's search table has two makers: _frontier_table
+    from a mask (a nonzero over it), and its second half, _table_of_list,
+    from a list that a caller already holds (bfs_dist: the row that level
+    1 read). Same members, same table, same active prefix."""
+    n = 20_000
+    members = np.sort(rng.choice(n, size=n_set, replace=False))
+    mask = np.zeros(n, dtype=bool)
+    mask[members] = True
+    flist = np.full(pb.FRONTIER_CAP, np.iinfo(np.int32).max, dtype=np.int32)
+    flist[:n_set] = members
+    by_mask = np.asarray(pb._frontier_table(jnp.asarray(mask)))
+    by_list = np.asarray(pb._table_of_list(jnp.asarray(flist)))
+    assert by_mask.shape == (33, 128) and by_mask.dtype == np.int32
+    np.testing.assert_array_equal(by_mask, by_list)
+    # a member listed twice (where a pad leaves room for it) changes the
+    # table and not what it answers
+    twice = flist.copy()
+    if 0 < n_set < pb.FRONTIER_CAP:
+        twice[n_set] = members[0]
+    twice.sort()
+    src = np.full(pb.EDGE_BLOCK, n, dtype=np.int32)
+    src[: 2 * len(members): 2] = members
+    want = np.cumsum(np.isin(src, members)).astype(np.int32)
+    for table in (by_list, np.asarray(pb._table_of_list(jnp.asarray(twice)))):
+        got = pb.active_prefix_sparse(jnp.asarray(table), jnp.asarray(src))
+        np.testing.assert_array_equal(np.asarray(got), want)

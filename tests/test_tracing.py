@@ -621,6 +621,54 @@ def test_metrics_carry_stage_kernel_and_startup_series(monkeypatch):
         "listen": 250}
 
 
+def test_first_hop_is_counted_by_mode_and_named_on_the_span(monkeypatch):
+    """dgraph_bfs_first_hop_total{mode=} grows by one a search, under the
+    branch the root's out-degree picks; the device_kernel span says the
+    same; the modes sum to the pb.bfs_dist windows."""
+    from dgraph_tpu.ops import pallas_bfs as pb
+
+    _force_tier(monkeypatch, "kernel")
+    monkeypatch.setattr(pb, "FIRST_HOP_CAP", 2)
+    node = Node(span_sample=1.0, trace_rng=random.Random(13))
+    node.alter(schema_text=SCHEMA)
+    node.mutate(set_nquads=CHAIN + "\n<0x2> <follows> <0x7> ."
+                "\n<0x2> <follows> <0x8> .", commit_now=True)
+    srv, base = _serve(node)
+
+    def modes():
+        series = prom.parse(_get(base, "/metrics")[1].decode())
+        got = {lb["mode"]: v
+               for lb, v in series.get("dgraph_bfs_first_hop_total", [])}
+        calls = {lb["kernel"]: v
+                 for lb, v in series.get("dgraph_kernel_calls_total", [])}
+        return got, calls.get("pb.bfs_dist", 0)
+
+    try:
+        # both modes show from the start, at 0: a reader can tell "no
+        # search yet" from "a program without the counter"
+        want = {"push": 0, "stream": 0}
+        assert modes() == (want, 0)
+        # 0x2 has three out-edges, over the cap of 2; the others one
+        for n, (src, dst, mode) in enumerate(
+                [("0x1", "0x6", "push"), ("0x2", "0x5", "stream"),
+                 ("0x3", "0x6", "push"), ("0x2", "0x8", "stream")], 1):
+            out = _post(base, "/query", SHORTEST.replace("0x1", src)
+                        .replace("0x6", dst))
+            assert out["data"]["_path_"]
+            _closed(node, n)
+            want[mode] += 1
+            got, calls = modes()
+            assert got == want and calls == n == sum(got.values())
+            rec = node.tracer.sink.get(
+                node.tracer.sink.index(1)[0]["trace_id"])
+            dk = [s for s in rec["spans"] if s["name"] == "device_kernel"]
+            assert [s["attrs"]["first_hop"] for s in dk] == [mode]
+            assert dk[0]["attrs"]["kernel"] == "pb.bfs_dist"
+    finally:
+        srv.shutdown()
+        node.close()
+
+
 def test_server_latency_parts_sum_below_the_total(http_node):
     _node, base = http_node
     out = _post(base, "/query", '{ q(func: has(name)) { name follows '
@@ -667,10 +715,11 @@ def test_bfs_dist_lowering_holds_the_scope_names():
     g = pb.prep_pull(subjects, indptr, indices, 10, with_host_arrays=True)
     text = pb.bfs_dist.lower(
         g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank, g.subjects,
-        g.in_subjects, np.asarray([1, 0, 4], dtype=np.int32),
+        g.in_subjects, g.fwd_indptr, g.fwd_dst_rank,
+        np.asarray([1, 0, 0, 4], dtype=np.int32),
         chunks=g.chunks, chunks_d=g.chunks_d
     ).as_text(debug_info=True)
-    for scope in ("seed", "prefix", "bounds", "visit"):
+    for scope in ("seed", "push", "prefix", "bounds", "visit"):
         assert f"/{scope}/" in text, scope
     # one output, the uint8 labels; no pack of bit planes behind the loop
     assert "/pack_dist/" not in text
