@@ -669,7 +669,7 @@ def check_groupby(g: Graph, tag: str):
 
 
 def battery(run: Run, srv: Server, g: Graph, rng) -> dict:
-    """Configs 2, 3b, 4, 5 of BASELINE.md over HTTP, plus a two-hop chain
+    """Configs 2, 3b, 4, 5 of BASELINE.json over HTTP, plus a two-hop chain
     (the shape `serve --mesh` fuses into one mesh.plan dispatch). Each
     shape runs twice with different parameters: the first pays compile +
     fold + pull-graph prep + upload, the second is warm but can hit no

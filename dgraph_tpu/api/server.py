@@ -153,7 +153,7 @@ class Node:
                                     rng=trace_rng, slowlog=self.slow_log)
         # round-6 serving tier: parsed-plan cache, snapshot-keyed task
         # result LRU (+ singleflight), bounded device-dispatch gate.
-        # Size 0 disables a tier (bench.py's cold-cache mode).
+        # Size 0 disables a tier.
         self.plan_cache = (qcache.PlanCache(plan_cache_size, self.metrics)
                            if plan_cache_size > 0 else None)
         self.task_cache = (qcache.TaskResultCache(task_cache_mb << 20,
@@ -353,9 +353,7 @@ class Node:
             if d is None:
                 return {}
             return {"dist.expand":
-                    d._expand_program.cache_info().currsize,
-                    "dist.k_hop":
-                    d._k_hop_program.cache_info().currsize}
+                    d._expand_program.cache_info().currsize}
 
         def ops_jit_caches():
             # only modules ALREADY imported by an executed path — the
@@ -380,8 +378,8 @@ class Node:
         devprof_mod.register(prof)
 
     def set_devprof(self, on: bool) -> None:
-        """Arm/disarm the device-runtime observatory live (bench.py's
-        armed-vs-disarmed A/B runs toggle this between battery passes)."""
+        """Arm/disarm the device-runtime observatory live (an armed-vs-
+        disarmed A/B toggles this between passes: tests/test_devprof.py)."""
         from dgraph_tpu.obs import devprof as devprof_mod
 
         if on and self.devprof is None:

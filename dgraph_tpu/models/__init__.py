@@ -2,7 +2,7 @@
 
 The reference benchmarks against a 1.1M-edge film graph ("goldendata",
 contrib/scripts/load-test.sh) and the north star targets LDBC-SNB-style
-friends-of-friends traversal (BASELINE.md). This package provides:
+friends-of-friends traversal (BASELINE.json). This package provides:
 
   rmat:  R-MAT power-law graph generator (LDBC-ish degree skew) — the
          benchmark workload generator.

@@ -1,7 +1,7 @@
 """R-MAT graph generator (Chakrabarti et al.) — vectorized numpy.
 
 Generates power-law directed graphs with LDBC-like degree skew for the
-traversal benchmarks (BASELINE.md: LDBC-SNB 3-hop friends-of-friends).
+traversal benchmarks (BASELINE.json: LDBC-SNB 3-hop friends-of-friends).
 """
 
 from __future__ import annotations

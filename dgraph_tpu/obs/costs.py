@@ -18,8 +18,9 @@ payload), so the root assembles ONE cluster-wide cost record with
 per-group sub-records; there is no out-of-band collector.
 
 The unarmed fast path is one contextvar read returning None: a node
-started with --no_cost_ledger must measure nothing (bench.py `obs` gates
-the armed overhead < 2% on the warm mixed battery).
+started with --no_cost_ledger must measure nothing. What the armed ledger
+costs a request has not been measured on the chip; tests/test_costs.py
+holds what it books.
 
 The stage clock (StageClock, below the ledger) answers the question the
 ledger cannot: WHERE inside the request the wall time went. The entry

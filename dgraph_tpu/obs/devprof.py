@@ -2,9 +2,9 @@
 HBM telemetry, and a dispatch-timeline utilization profiler.
 
 The gap after PR 13: the cost ledger answers "what did THIS query cost"
-in wall/device ms, but not WHY — LDBC_r15.json shows mesh losing to host
-at SF0.1 and nothing on /debug decomposes that into compiles vs queue
-gaps vs kernel time. Three surfaces close it:
+in wall/device ms, but not WHY — when a mesh run loses to the host,
+nothing on /debug decomposes that into compiles vs queue gaps vs kernel
+time. Three surfaces close it:
 
   * compile observatory — every jitted-program build site (mesh_exec's
     program cache, dist.py's lru builders) notes its build through a

@@ -11,7 +11,7 @@ collector to deploy.
 
 The not-sampled fast path is one contextvar read returning NULL_SPAN
 (falsy, no-op everywhere): tracing at 0% must cost nothing measurable
-(bench.py `trace` gates <2% QPS overhead at 1% sampling).
+(what the instrumentation costs off and on: PERF.md §6, PR 26).
 
 Completed traces land in a bounded TraceSink ring and export as Chrome
 trace-event JSON (loadable in Perfetto / chrome://tracing) at

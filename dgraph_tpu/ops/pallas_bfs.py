@@ -28,8 +28,12 @@ Per hop:   active[e] = frontier_bit[in_src[e]]          (Pallas, streaming)
            frontier' = reached & ~visited               (node-sized)
 
 Reference semantics preserved: `traversed` counts every out-edge of every
-frontier node per hop (== active in-edges), and `visited` matches
-traversal.k_hop_pull / the host BFS exactly (bench.py's equality gate).
+frontier node per level (== active in-edges). Three programs are built on
+the kernel, and they are what a request reaches: bfs_dist (`shortest`,
+query/shortest.py), recurse_fused / recurse_fused_multi (`@recurse` alone
+and batched, query/recurse.py and query/batch.py) and recurse_step (a
+recurse that needs the host between levels). tests/test_pallas_bfs.py holds
+them to a plain host BFS at every edge of the blocking scheme.
 """
 
 from __future__ import annotations
@@ -45,8 +49,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from dgraph_tpu.obs import costs
-from dgraph_tpu.ops.csr import degrees as _csr_degrees
-from dgraph_tpu.ops.csr import expand as _csr_expand
 
 WORDS_PER_CHUNK = 1024          # one 8x128 int32 vreg
 NODES_PER_CHUNK = WORDS_PER_CHUNK * 32
@@ -250,41 +252,41 @@ class PullGraph(NamedTuple):
     and reachability is computed per destination *rank* — power-law graphs
     leave ~half the uid space with no edges at all, so rank spaces halve the
     bitmap chunk loop (the kernel's per-edge cost), the frontier pack, and
-    the node-phase bounds gather. One full-uid-space scatter at the very end
-    restores the reference's visited/frontier semantics."""
+    the node-phase bounds gather. The device arrays are the programs'
+    arguments; the host arrays are what the engine reads between launches
+    (a search's first level and its backtrack, a recurse's uidMatrix)."""
 
     in_src_pad: jax.Array       # int32[E_pad] source SRC-RANKS, dst-sorted
     in_src_pad_d: jax.Array     # int32[E_pad] source DST-RANKS, dst-sorted
     in_iptr_rank: jax.Array     # int32[Nd+1] edge offsets per dst rank
     subjects: jax.Array         # int32[Ns] sorted uids with out-edges
     in_subjects: jax.Array      # int32[Nd] sorted uids with in-edges
-    map_s2d: jax.Array          # int32[Ns] dst rank of subject j, or Nd
-    fwd_indptr: jax.Array       # int32[Ns+1] forward CSR (push path)
+    fwd_indptr: jax.Array       # int32[Ns+1] forward CSR (a search's level 1)
     fwd_dst_rank: jax.Array     # int32[E] dst RANKS in forward edge order
-    map_d2s: jax.Array          # int32[Nd] src rank of dst i, or SENTINEL
     num_nodes: int
     num_edges: int
     chunks: int                 # bitmap chunks over the SRC-RANK space
     chunks_d: int               # bitmap chunks over the DST-RANK space
-    inv_order: np.ndarray | None = None  # HOST int32[E]: fwd edge position →
+    inv_order: np.ndarray       # HOST int32[E]: fwd edge position →
     # dst-sorted edge position (the kernel's per-edge flag space); used to
     # materialize per-source fresh-target lists lazily (recurse uidMatrix)
-    host_in_iptr: np.ndarray | None = None     # HOST int32[Nd+1]
-    host_in_src: np.ndarray | None = None      # HOST int32[E] src ranks,
-    # dst-sorted — the in-adjacency the shortest-path backtrack walks
-    host_map_s2d: np.ndarray | None = None     # HOST int32[Ns]
-    host_in_subjects: np.ndarray | None = None  # HOST int64[Nd]
-    host_subjects: np.ndarray | None = None     # HOST int64[Ns]
-    host_fwd_indptr: np.ndarray | None = None   # HOST int[Ns+1]: a
-    # source's out-degree, which picks the first level of a search
+    host_in_iptr: np.ndarray    # HOST int32[Nd+1]
+    host_in_src: np.ndarray     # HOST int32[E] src ranks, dst-sorted — the
+    # in-adjacency the shortest-path backtrack walks
+    host_map_s2d: np.ndarray    # HOST int32[Ns] dst rank of subject j, or Nd
+    host_in_subjects: np.ndarray  # HOST int64[Nd]
+    host_subjects: np.ndarray   # HOST int64[Ns]
+    host_fwd_indptr: np.ndarray  # HOST int[Ns+1]: a source's out-degree,
+    # which picks the first level of a search
 
 
 def prep_pull(subjects: np.ndarray, indptr: np.ndarray,
-              indices: np.ndarray, num_nodes: int,
-              with_host_arrays: bool = False) -> PullGraph:
+              indices: np.ndarray, num_nodes: int) -> PullGraph:
     """Host-side once-per-snapshot prep: transpose to dst-sorted in-edges,
     remap both endpoints to rank spaces, pad the edge stream to the kernel
-    block size pointing at an always-zero bitmap word."""
+    block size pointing at an always-zero bitmap word. The host arrays are
+    what the engine reads between launches (recurse materialization, the
+    shortest-path backtrack)."""
     E = len(indices)
     if E and int(np.max(indices)) >= num_nodes:
         raise ValueError(
@@ -334,32 +336,21 @@ def prep_pull(subjects: np.ndarray, indptr: np.ndarray,
     src_pad_d = np.full(e_pad, pad_src_d, dtype=np.int32)
     src_pad_d[:E] = src_d
 
-    # push-path (direction-optimizing) forward layout
+    # forward layout: the row a search's first level reads
     fwd_dst_rank = np.searchsorted(in_subjects, np.asarray(indices)).astype(
         np.int32)                    # every dst IS in in_subjects
-    snt = np.int32(np.iinfo(np.int32).max)
-    map_d2s = host_rank_of(subjects, in_subjects, snt).astype(np.int32)
-    inv_order = hi_iptr = hi_src = hi_m = hi_subs = None
-    hi_fsubs = hi_findptr = None
-    if with_host_arrays:     # engine paths only (recurse materialization +
-        # shortest backtrack); bench/BFS callers skip the host RAM
-        inv_order = np.empty(E, dtype=np.int32)
-        inv_order[order] = np.arange(E, dtype=np.int32)
-        hi_iptr, hi_src = iptr, src_sorted
-        hi_m, hi_subs = map_s2d, in_subjects.astype(np.int64)
-        hi_fsubs = subjects.astype(np.int64)
-        hi_findptr = np.asarray(indptr)
+    inv_order = np.empty(E, dtype=np.int32)
+    inv_order[order] = np.arange(E, dtype=np.int32)
     return PullGraph(jnp.asarray(src_pad), jnp.asarray(src_pad_d),
                      jnp.asarray(iptr),
                      jnp.asarray(subjects.astype(np.int32)),
                      jnp.asarray(in_subjects.astype(np.int32)),
-                     jnp.asarray(map_s2d),
                      jnp.asarray(np.asarray(indptr).astype(np.int32)),
                      jnp.asarray(fwd_dst_rank),
-                     jnp.asarray(map_d2s),
                      int(num_nodes), int(E), int(chunks), int(chunks_d),
-                     inv_order, hi_iptr, hi_src, hi_m, hi_subs,
-                     hi_fsubs, hi_findptr)
+                     inv_order, iptr, src_sorted, map_s2d,
+                     in_subjects.astype(np.int64), subjects.astype(np.int64),
+                     np.asarray(indptr))
 
 
 def pack_words(mask: jax.Array, chunks: int) -> jax.Array:
@@ -378,169 +369,8 @@ def pack_words(mask: jax.Array, chunks: int) -> jax.Array:
     return words
 
 
-class PullBFSResult(NamedTuple):
-    visited: jax.Array       # bool[num_nodes]
-    frontier: jax.Array      # bool[num_nodes]
-    traversed: jax.Array     # int32
-
-
-PUSH_CAP = 1 << 17     # push-path edge-gather capacity (targets buffer)
 SPARSE_MAX = FRONTIER_CAP   # frontier popcount at/below which the sparse
                             # search-table kernel beats pack+dense (tunable)
-
-
-@partial(jax.jit, static_argnames=("hops", "chunks", "chunks_d", "num_nodes",
-                                   "have_seeds"))
-def _k_hop_impl(in_src_pad: jax.Array, in_src_pad_d: jax.Array,
-                in_iptr_rank: jax.Array,
-                subjects: jax.Array, in_subjects: jax.Array,
-                map_s2d: jax.Array, fwd_indptr: jax.Array,
-                fwd_dst_rank: jax.Array, map_d2s: jax.Array,
-                seeds_mask: jax.Array, seeds_ranks: jax.Array, *, hops: int,
-                chunks: int, chunks_d: int, num_nodes: int,
-                have_seeds: bool) -> PullBFSResult:
-    """Direction-optimizing hop loop, entirely in rank spaces.
-
-    Three regimes per hop (Beamer-style DOBFS, chosen at runtime):
-      push   — frontier known as an explicit src-rank list (<= FRONTIER_CAP)
-               with bounded degree sum: gather ONLY its out-edges through
-               the forward CSR (work ∝ frontier, not E) and scatter the
-               targets; the next list comes from the targets themselves.
-      sparse — mask frontier, <= FRONTIER_CAP bits set: stream E against a
-               2-level search table in the Pallas kernel.
-      dense  — mask frontier: stream E against the packed VMEM bitmap.
-
-    Carry: fresh set by DESTINATION rank (the only uids ever reachable),
-    visited by dst rank, plus the push list + validity flag. The mask paths
-    map fresh dst-ranks to src-rank bits lazily at the START of the next
-    hop (so the final hop never pays it). Hop 1 is special in both paths: a
-    seed with out-edges but no in-edges must still expand, so the mask path
-    seeds src bits from the full-space seed mask and the push path takes
-    pre-mapped seed src-ranks."""
-    if hops == 0:
-        # degenerate: no expansion — frontier IS the seed set (the old
-        # fori_loop(0, 0) carry-through behavior, kept for callers that
-        # treat frontier as "nodes at distance exactly k")
-        return PullBFSResult(seeds_mask, seeds_mask, jnp.int32(0))
-
-    nd = in_subjects.shape[0]
-    snt = jnp.int32(np.iinfo(np.int32).max)
-
-    def push_hop(args, build_next: bool):
-        flist, _fresh_d, visited_d, traversed = args
-        res = _csr_expand(fwd_indptr, fwd_dst_rank, flist, PUSH_CAP)
-        traversed = traversed + res.total.astype(jnp.int32)
-        tmask = jnp.zeros((nd,), bool).at[res.targets].set(
-            True, mode="drop")                     # sentinel pads drop
-        fresh = tmask & ~visited_d
-        visited2 = visited_d | fresh
-        if build_next:
-            tsort = jnp.sort(res.targets)          # sentinels collect at end
-            valid = tsort < nd
-            dup = jnp.concatenate(
-                [jnp.zeros((1,), bool), tsort[1:] == tsort[:-1]])
-            was = jnp.take(visited_d, jnp.clip(tsort, 0, max(nd - 1, 0)),
-                           mode="clip") & valid
-            keep = valid & ~dup & ~was
-            nfresh = jnp.sum(keep, dtype=jnp.int32)
-            idxs = jnp.nonzero(keep, size=FRONTIER_CAP,
-                               fill_value=PUSH_CAP)[0]
-            cand_d = jnp.where(idxs < PUSH_CAP,
-                               jnp.take(tsort, jnp.clip(idxs, 0, PUSH_CAP - 1),
-                                        mode="clip"), nd)
-            flist2 = jnp.where(cand_d < nd,
-                               jnp.take(map_d2s, jnp.clip(cand_d, 0,
-                                                          max(nd - 1, 0)),
-                                        mode="clip"), snt)
-            ok2 = nfresh <= FRONTIER_CAP
-        else:
-            flist2, ok2 = flist, jnp.bool_(False)
-        return flist2, ok2, fresh, visited2, traversed
-
-    def mask_hop(args, first: bool):
-        flist, fresh_d, visited_d, traversed = args
-        if first:
-            # src-rank space: a seed with out-edges but no in-edges exists
-            # only here
-            frontier, stream, n_chunks = (
-                jnp.take(seeds_mask, subjects), in_src_pad, chunks)
-        else:
-            # dst-rank space: a hop>=2 frontier is a subset of destinations,
-            # so the fresh mask IS the kernel's bitmap — no remap gather
-            frontier, stream, n_chunks = fresh_d, in_src_pad_d, chunks_d
-        prefix = _prefix_for(frontier, stream, n_chunks)
-        traversed = traversed + prefix[-1]
-        bounds = jnp.take(prefix, in_iptr_rank - 1,
-                          mode="clip")               # prefix[iptr-1], iptr>=0
-        bounds = jnp.where(in_iptr_rank == 0, 0, bounds)
-        reached = (bounds[1:] - bounds[:-1]) > 0     # [Nd]
-        fresh = reached & ~visited_d
-        return flist, jnp.bool_(False), fresh, visited_d | fresh, traversed
-
-    visited_d = jnp.take(seeds_mask, in_subjects)    # seeds, dst-rank space
-    fresh_d = jnp.zeros((nd,), dtype=bool)
-    traversed = jnp.int32(0)
-    flist = seeds_ranks if have_seeds else jnp.full(
-        (FRONTIER_CAP,), snt, jnp.int32)
-    flist_ok = jnp.bool_(bool(have_seeds))
-
-    carry = (flist, flist_ok, fresh_d, visited_d, traversed)
-    for h in range(hops):                            # hops is static + small
-        flist, flist_ok, fresh_d, visited_d, traversed = carry
-        deg_sum = jnp.sum(_csr_degrees(fwd_indptr, flist), dtype=jnp.int32)
-        push_ok = flist_ok & (deg_sum <= PUSH_CAP)
-        build_next = h + 1 < hops
-        carry = lax.cond(
-            push_ok,
-            partial(push_hop, build_next=build_next),
-            partial(mask_hop, first=(h == 0)),
-            (flist, fresh_d, visited_d, traversed))
-    _flist, _ok, fresh_d, visited_d, traversed = carry
-
-    # restore full-uid-space semantics (once, not per hop): one combined
-    # 2-bit scatter instead of two (scatter cost scales with index count)
-    both = (visited_d.astype(jnp.int32)
-            | (fresh_d.astype(jnp.int32) << 1))
-    packed = jnp.zeros((num_nodes,), jnp.int32).at[in_subjects].set(
-        both, mode="drop")
-    visited = seeds_mask | ((packed & 1) > 0)
-    frontier = (packed & 2) > 0
-    return PullBFSResult(visited, frontier, traversed)
-
-
-def k_hop_pull_pallas(g: PullGraph, seeds_mask: jax.Array, *, hops: int,
-                      seed_uids: jax.Array | np.ndarray | None = None
-                      ) -> PullBFSResult:
-    """k-hop BFS with the Pallas active-prefix kernel per hop.
-
-    seed_uids: optional explicit seed uid list (<= FRONTIER_CAP entries,
-    must match seeds_mask) — enables the push fast path for hop 1 without
-    paying a full-space compaction."""
-    if seed_uids is not None:
-        # dedup: a repeated seed would be expanded once per occurrence by
-        # the push path, inflating traversed and the PUSH_CAP admission
-        seed_uids = np.unique(np.asarray(seed_uids))
-    if seed_uids is not None and len(seed_uids) <= FRONTIER_CAP:
-        seeds = jnp.asarray(seed_uids, dtype=jnp.int32)
-        pos = jnp.searchsorted(g.subjects, seeds)
-        pos_c = jnp.clip(pos, 0, max(g.subjects.shape[0] - 1, 0))
-        hit = (g.subjects.shape[0] > 0) & (
-            jnp.take(g.subjects, pos_c, mode="clip") == seeds)
-        ranks = jnp.where(hit, pos_c.astype(jnp.int32),
-                          jnp.int32(np.iinfo(np.int32).max))
-        pad = jnp.full((FRONTIER_CAP - seeds.shape[0],),
-                       np.iinfo(np.int32).max, jnp.int32)
-        seeds_ranks = jnp.concatenate([ranks, pad])
-        have_seeds = True
-    else:
-        seeds_ranks = jnp.full((FRONTIER_CAP,), np.iinfo(np.int32).max,
-                               jnp.int32)
-        have_seeds = False
-    return _k_hop_impl(g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank,
-                       g.subjects, g.in_subjects, g.map_s2d, g.fwd_indptr,
-                       g.fwd_dst_rank, g.map_d2s, seeds_mask, seeds_ranks,
-                       hops=hops, chunks=g.chunks, chunks_d=g.chunks_d,
-                       num_nodes=g.num_nodes, have_seeds=have_seeds)
 
 
 # ---------------------------------------------------------------------------
@@ -561,7 +391,7 @@ def pull_graph_for(csr) -> PullGraph:
         hi = max(int(subjects[-1]) if len(subjects) else 0,
                  int(indices.max()) if len(indices) else 0)
         g = prep_pull(np.asarray(subjects), np.asarray(indptr),
-                      np.asarray(indices), hi + 1, with_host_arrays=True)
+                      np.asarray(indices), hi + 1)
         csr._pull_graph = g
     return g
 
@@ -819,8 +649,7 @@ def shortest_bfs(g: PullGraph, src: int, dst: int, max_hops: int):
     """Host orchestration: run bfs_dist, fetch its one output (the uint8
     distance labels) once, walk the predecessor chain on the host
     in-adjacency. Returns the uid path [src..dst] or None (unreachable
-    within max_hops). Requires a PullGraph built with host arrays
-    (pull_graph_for)."""
+    within max_hops)."""
     nd = len(g.host_in_subjects)
     if nd == 0:
         return None
@@ -921,7 +750,7 @@ def recurse_fused(in_src_pad, in_src_pad_d, in_iptr_rank, subjects,
     >= 2 stay entirely in DST-RANK space (a recurse frontier is the
     previous level's fresh destinations): no full-uid scatter, no src-rank
     remap gather, and the bitmap pack runs over the compressed rank space
-    (the same dual-space trick as the BFS kernel's mask_hop).
+    (the same dual-space trick as bfs_dist's levels >= 2).
 
     Returns stacked per-level (dest_words [D,Cd*8,128] BIT-PACKED
     DST-RANK masks — the host fetches these every query, so
@@ -964,7 +793,6 @@ def recurse_fused_multi(in_src_pad, in_src_pad_d, in_iptr_rank, subjects,
 JIT_PROGRAMS = {
     "pb.active_prefix": active_prefix,
     "pb.active_prefix_sparse": active_prefix_sparse,
-    "pb.k_hop": _k_hop_impl,
     "pb.pack_mask_rows": pack_mask_rows,
     "pb.pack_mask": pack_mask,
     "pb.recurse_step": recurse_step,

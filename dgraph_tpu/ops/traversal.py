@@ -1,21 +1,12 @@
-"""Pure-device iterative traversal: k-hop BFS and SSSP as SpMSpV under jit.
+"""Pure-device SSSP: Bellman-Ford edge relaxation under jit.
 
-Reference semantics: query/recurse.go expandRecurse (level-synchronous
-frontier loop with a reach-set) and query/shortest.go (host Dijkstra over a
-hash-map adjacency). On TPU both become iterative sparse ops over the
-HBM-resident CSR with NO host round-trips inside the loop:
-
-  - k_hop: lax.fori_loop over levels; each level is one CSR gather
-    (ops.csr.expand) + dedup + visited-mask filter. The visited set is a
-    dense bool vector over the uid space — the reach-map of recurse.go:129
-    becomes a vectorized scatter/gather.
-  - sssp: Bellman-Ford edge relaxation — one segment-min per iteration over
-    all E edges, lax.while_loop until fixpoint. Replaces pointer-chasing
-    Dijkstra for the device path (the exact k-shortest-path semantics stay in
-    query/shortest.py, which feeds off device-expanded adjacency).
-
-These are the benchmark kernels (BASELINE.md: 3-hop traversed-edges/sec,
-k-shortest p50).
+Reference semantics: query/shortest.go (host Dijkstra over a hash-map
+adjacency). On the device it becomes an iterative sparse op over the
+HBM-resident CSR with NO host round-trips inside the loop: one segment-min
+per iteration over all E edges, lax.while_loop until fixpoint. Replaces
+pointer-chasing Dijkstra for the device path (the exact k-shortest-path
+semantics stay in query/shortest.py, which takes this tier only where
+ops/pallas_bfs.bfs_dist does not apply).
 """
 
 from __future__ import annotations
@@ -26,71 +17,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-from dgraph_tpu.ops.uidset import sentinel
-from dgraph_tpu.ops.csr import expand
-
-
-class KHopResult(NamedTuple):
-    visited: jax.Array          # bool[num_nodes] — every uid reached (incl. seeds)
-    frontier: jax.Array         # final frontier uid set (sentinel-padded)
-    traversed: jax.Array        # total edges traversed (int32)
-    frontier_sizes: jax.Array   # int32[hops] frontier size after each hop
-
-
-@partial(jax.jit, static_argnames=("hops", "frontier_cap", "num_nodes", "edge_cap"))
-def k_hop(subjects: jax.Array, indptr: jax.Array, indices: jax.Array,
-          seeds: jax.Array, *, hops: int, frontier_cap: int,
-          num_nodes: int, edge_cap: int | None = None) -> KHopResult:
-    """BFS k hops from `seeds` (uid set) over one predicate's CSR.
-
-    num_nodes: static bound on the uid space (max uid + 1).
-    edge_cap: static capacity of one level's edge gather — must cover the
-    largest level (indices.shape[0] is always safe); defaults to frontier_cap.
-    frontier_cap: static frontier set size; both are capacity classes — if a
-    level's true total (reported in `traversed`) exceeded them the host
-    re-issues with the next class up (the ErrTooBig contract).
-    """
-    snt = sentinel(jnp.int32)
-    edge_cap = edge_cap or frontier_cap
-
-    def resolve_rows(uids):
-        pos = jnp.searchsorted(subjects, uids)
-        pos_c = jnp.clip(pos, 0, subjects.shape[0] - 1)
-        ok = (jnp.take(subjects, pos_c, mode="clip") == uids) & (uids != snt)
-        return jnp.where(ok, pos_c, snt).astype(jnp.int32)
-
-    def body(_i, carry):
-        frontier, visited, traversed, sizes, level = carry
-        rows = resolve_rows(frontier)
-        res = expand(indptr, indices, rows, edge_cap)
-        # dedup targets then drop already-visited uids
-        dest = jnp.sort(res.targets)
-        dup = jnp.concatenate([jnp.zeros((1,), bool), dest[1:] == dest[:-1]])
-        dest = jnp.where(dup, snt, dest)
-        safe = jnp.where(dest == snt, num_nodes, dest)  # scatter-drop sentinel
-        was_visited = jnp.take(visited, jnp.clip(safe, 0, num_nodes - 1),
-                               mode="clip") & (dest != snt)
-        fresh = jnp.sort(jnp.where(was_visited | (dest == snt), snt, dest))[:frontier_cap]
-        visited = visited.at[jnp.where(fresh == snt, num_nodes, fresh)].set(
-            True, mode="drop")
-        size = jnp.sum(fresh != snt).astype(jnp.int32)
-        sizes = sizes.at[level].set(size)
-        return fresh, visited, traversed + res.total.astype(jnp.int32), sizes, level + 1
-
-    visited0 = jnp.zeros((num_nodes,), dtype=bool)
-    seeds_safe = jnp.where(seeds == snt, num_nodes, seeds)
-    visited0 = visited0.at[seeds_safe].set(True, mode="drop")
-    sizes0 = jnp.zeros((hops,), dtype=jnp.int32)
-    # carry shape is static: widen (or truncate) seeds to the frontier capacity
-    if seeds.shape[0] < frontier_cap:
-        seeds = jnp.concatenate(
-            [seeds, jnp.full((frontier_cap - seeds.shape[0],), snt, jnp.int32)])
-    else:
-        seeds = jnp.sort(seeds)[:frontier_cap]
-    frontier, visited, traversed, sizes, _ = lax.fori_loop(
-        0, hops, body, (seeds, visited0, jnp.int32(0), sizes0, jnp.int32(0)))
-    return KHopResult(visited, frontier, traversed, sizes)
 
 
 class SSSPResult(NamedTuple):
@@ -145,116 +71,9 @@ def sssp(subjects: jax.Array, indptr: jax.Array, indices: jax.Array,
     return SSSPResult(dist, parent, it)
 
 
-class DenseBFSResult(NamedTuple):
-    visited: jax.Array       # bool[num_nodes]
-    frontier: jax.Array      # bool[num_nodes] — final frontier mask
-    traversed: jax.Array     # int32 total edges scanned
-
-
-@partial(jax.jit, static_argnames=("hops", "num_nodes"))
-def k_hop_dense(subjects: jax.Array, indptr: jax.Array, indices: jax.Array,
-                edge_src_row: jax.Array, seeds_mask: jax.Array, *, hops: int,
-                num_nodes: int) -> DenseBFSResult:
-    """Dense-frontier BFS: frontier and visited are bit-vectors over the uid
-    space; one hop = one gather over E edges + one scatter — NO sorts.
-
-    This is the throughput kernel for the 3-hop benchmark: compared to the
-    sorted-set variant (k_hop) it trades O(F log F) bitonic sorts for O(E)
-    streaming gathers, the right trade whenever a level touches a large
-    fraction of the edge set (LDBC 3-hop does). edge_src_row[e] = CSR row of
-    edge e's source (precompute once: searchsorted(indptr, arange(E), 'right')-1).
-
-    Semantics match k_hop: traversed counts every adjacency entry of every
-    frontier uid per hop (the reference's per-uid posting-list scan).
-    """
-
-    def body(_i, carry):
-        frontier, visited, traversed = carry
-        f_row = jnp.take(frontier, subjects)            # [R] row active?
-        active = jnp.take(f_row, edge_src_row)          # [E] edge active?
-        traversed = traversed + jnp.sum(active, dtype=jnp.int32)
-        tgt = jnp.where(active, indices, num_nodes)     # drop inactive edges
-        nxt = jnp.zeros((num_nodes,), dtype=bool).at[tgt].set(True, mode="drop")
-        nxt = nxt & ~visited
-        return nxt, visited | nxt, traversed
-
-    frontier, visited, traversed = lax.fori_loop(
-        0, hops, body, (seeds_mask, seeds_mask, jnp.int32(0)))
-    return DenseBFSResult(visited, frontier, traversed)
-
-
-def edge_src_rows(indptr: jax.Array) -> jax.Array:
-    """Per-edge source row for k_hop_dense (edge e belongs to the row r with
-    indptr[r] <= e < indptr[r+1])."""
-    E = int(indptr[-1])
-    return (jnp.searchsorted(indptr, jnp.arange(E, dtype=indptr.dtype),
-                             side="right") - 1).astype(jnp.int32)
-
-
-@partial(jax.jit, static_argnames=("hops", "num_nodes"))
-def k_hop_pull(subjects: jax.Array, indptr: jax.Array,
-               in_subjects: jax.Array, in_indptr: jax.Array,
-               in_src: jax.Array, seeds_mask: jax.Array, *, hops: int,
-               num_nodes: int) -> DenseBFSResult:
-    """Pull-style dense BFS — the HBM-bandwidth-shaped formulation.
-
-    Uses BOTH orientations of the predicate CSR (the @reverse tablet the
-    storage layer already maintains, posting/index.go:190):
-
-      traversed += Σ out-degree over frontier rows           (R-sized)
-      active[e]  = frontier[in_src[e]]                       (E-sized gather)
-      reached[r] = segment-any(active) via one cumsum + diff (no E-scatter)
-      frontier'  = reached & ~visited                        (R-sized scatter)
-
-    The only per-edge ops are a streaming gather and a cumsum; scatters are
-    node-sized. This is what makes 3-hop throughput HBM-bound instead of
-    scatter-bound (k_hop_dense) or sort-bound (k_hop).
-    """
-    out_deg = indptr[1:] - indptr[:-1]
-
-    def body(_i, carry):
-        frontier, visited, traversed = carry
-        f_rows = jnp.take(frontier, subjects)
-        traversed = traversed + jnp.sum(
-            jnp.where(f_rows, out_deg, 0), dtype=jnp.int32)
-        active = jnp.take(frontier, in_src).astype(jnp.int32)   # [E]
-        c = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(active)])
-        seg = jnp.take(c, in_indptr[1:]) - jnp.take(c, in_indptr[:-1])
-        reached = seg > 0                                        # [R_in]
-        fresh = reached & ~jnp.take(visited, in_subjects)
-        nxt = jnp.zeros((num_nodes,), dtype=bool).at[in_subjects].set(
-            fresh, mode="drop")
-        return nxt, visited | nxt, traversed
-
-    frontier, visited, traversed = lax.fori_loop(
-        0, hops, body, (seeds_mask, seeds_mask, jnp.int32(0)))
-    return DenseBFSResult(visited, frontier, traversed)
-
-
-def reverse_csr(subjects: "np.ndarray", indptr: "np.ndarray",
-                indices: "np.ndarray"):
-    """Host-side transpose: (in_subjects, in_indptr, in_src) where in_src
-    lists, per destination node, the source uids of its incoming edges."""
-    import numpy as np
-
-    E = len(indices)
-    src = np.repeat(subjects, np.diff(indptr))
-    order = np.argsort(indices, kind="stable")
-    dst_sorted = indices[order]
-    src_sorted = src[order]
-    in_subjects, counts = np.unique(dst_sorted, return_counts=True)
-    in_indptr = np.zeros(len(in_subjects) + 1, dtype=np.int64)
-    np.cumsum(counts, out=in_indptr[1:])
-    return (in_subjects.astype(np.int32), in_indptr.astype(np.int32),
-            src_sorted.astype(np.int32))
-
-
 # device-runtime observatory (obs/devprof.py, ISSUE 19): jitted entry
 # points by program family, probed for live jit-cache size on
 # /debug/compiles (see ops/segments.py).
 JIT_PROGRAMS = {
-    "traversal.k_hop": k_hop,
     "traversal.sssp": sssp,
-    "traversal.k_hop_dense": k_hop_dense,
-    "traversal.k_hop_pull": k_hop_pull,
 }

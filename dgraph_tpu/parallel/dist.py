@@ -6,7 +6,7 @@ query/query.go merges the returned uidMatrix. Here the fan-out is remapped to
 the mesh (BASELINE north star): the CSR row space is range-partitioned across
 devices, the frontier is replicated, every shard expands its local rows in
 one CSR gather, and an all_gather + merge over ICI replaces the gRPC
-scatter-gather. Edge totals combine with psum.
+scatter-gather (the host sums the per-shard edge counts).
 
 Layout notes (How-to-Scale mental model):
   - frontier: replicated — it's small (<= frontier_cap int32) and every shard
@@ -256,74 +256,3 @@ class DistPredCSR:
             self.metrics.counter("dgraph_mesh_traversed_edges_total").inc(
                 total)
         return matrix, total
-
-
-@lru_cache(maxsize=64)
-def _k_hop_program(mesh: Mesh, hops: int, frontier_cap: int, num_nodes: int,
-                   edge_cap: int):
-    """Cached jitted k-hop program — building the shard_map closure inside
-    dist_k_hop made EVERY call a fresh function identity, so jax retraced
-    the whole hop loop per query (the dominant fixed cost of the early
-    multi-device dry runs)."""
-    devprof.note_build("dist.k_hop",
-                       (hops, frontier_cap, num_nodes, edge_cap))
-
-    def step(sub, ptr, idx, frontier, visited):
-        # sub/ptr/idx are this shard's blocks (leading axis stripped by shard_map)
-        rows = _local_rows(sub[0], frontier)
-        res = expand(ptr[0], idx[0], rows, edge_cap)
-        dest = _dedup_sorted(jnp.sort(res.targets))
-        gathered = lax.all_gather(dest, "shard")         # [S, edge_cap] on ICI
-        merged = _dedup_sorted(jnp.sort(gathered.reshape(-1)))[:frontier_cap]
-        safe = jnp.where(merged == SNT, num_nodes, merged)
-        seen = jnp.take(visited, jnp.clip(safe, 0, num_nodes - 1), mode="clip") \
-            & (merged != SNT)
-        fresh = jnp.sort(jnp.where(seen | (merged == SNT), SNT, merged))
-        visited = visited.at[jnp.where(fresh == SNT, num_nodes, fresh)].set(
-            True, mode="drop")
-        traversed = lax.psum(res.total.astype(jnp.int32), "shard")
-        return fresh, visited, traversed
-
-    @partial(
-        shard_map, mesh=mesh,
-        in_specs=(P("shard"), P("shard"), P("shard"), P(), P()),
-        out_specs=(P(), P(), P()),
-        check_vma=False,
-    )
-    def run(sub, ptr, idx, seeds_in, visited0):
-        def body(_i, carry):
-            frontier, visited, total = carry
-            f, v, t = step(sub, ptr, idx, frontier, visited)
-            return f, v, total + t
-        return lax.fori_loop(0, hops, body,
-                             (seeds_in, visited0, jnp.int32(0)))
-
-    # seeds + visited are donated: the hop loop's carries reuse their
-    # HBM across iterations instead of re-allocating per hop (both are
-    # freshly built by dist_k_hop each call, never read back)
-    return jax.jit(run, donate_argnums=(3, 4))
-
-
-def dist_k_hop(csr: ShardedCSR, seeds: jax.Array, mesh: Mesh, *, hops: int,
-               frontier_cap: int, num_nodes: int, edge_cap: int | None = None):
-    """Multi-device k-hop BFS. Returns (visited bool[num_nodes], frontier,
-    traversed:int32) — all replicated.
-
-    Per hop, per shard: resolve frontier against local subjects → local CSR
-    gather → local dedup; then ONE all_gather of [edge_cap]-sized dest sets
-    over ICI and a replicated merge + visited update. psum sums edge counts.
-    edge_cap must cover one shard's largest per-level edge gather (a shard's
-    total edge count, csr.indices.shape[-1], is always safe).
-    """
-    edge_cap = edge_cap or frontier_cap
-    if seeds.shape[0] < frontier_cap:
-        seeds = jnp.concatenate(
-            [seeds, jnp.full((frontier_cap - seeds.shape[0],), SNT, jnp.int32)])
-    else:
-        seeds = jnp.sort(seeds)[:frontier_cap]
-    visited0 = jnp.zeros((num_nodes,), dtype=bool)
-    visited0 = visited0.at[jnp.where(seeds == SNT, num_nodes, seeds)].set(
-        True, mode="drop")
-    with mesh:
-        return _k_hop_program(mesh, hops, frontier_cap, num_nodes, edge_cap)(
-            csr.subjects, csr.indptr, csr.indices, seeds, visited0)

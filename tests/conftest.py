@@ -24,7 +24,7 @@ import jax  # noqa: E402
 
 from dgraph_tpu.utils import runtime  # noqa: E402
 
-# Persistent compilation cache (the same place serve/worker/bench.py use):
+# Persistent compilation cache (the same place serve and worker use):
 # makes repeated test runs cheap.
 runtime.configure_compile_cache()
 assert len(jax.devices()) >= 8, (

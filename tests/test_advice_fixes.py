@@ -1,4 +1,4 @@
-"""Regression tests for the round-1 advisor findings (ADVICE.md).
+"""Regression tests for the round-1 advisor findings.
 
 1. Untagged value reads must NOT fall back to lang-tagged values; only the
    explicit "." tag does (reference posting/list.go postingForLangs).

@@ -1,4 +1,4 @@
-"""Regression tests for the round-3 advisor findings (ADVICE.md r3).
+"""Regression tests for the round-3 advisor findings.
 
 1. (high) Numeric frontier-compare fast path must not fire for [type] list
    predicates: num_values_host holds one representative element per subject,

@@ -108,13 +108,13 @@ def test_compile_listener_attributes_family_and_books_ledger():
     try:
         lg = costs.CostLedger(endpoint="query", shape="{ q }")
         with costs.scope(lg):
-            devprof_mod.push_family("pb.k_hop")
+            devprof_mod.push_family("pb.bfs_dist")
             try:
                 devprof_mod._on_duration_event(
                     "/jax/core/compile/backend_compile_duration", 0.025)
             finally:
                 devprof_mod.pop_family()
-        f = prof.compiles_snapshot()["families"]["pb.k_hop"]
+        f = prof.compiles_snapshot()["families"]["pb.bfs_dist"]
         assert f["compiles"] == 1
         assert f["compile_ms"] == pytest.approx(25.0)
         assert lg.compile_ms == pytest.approx(25.0)

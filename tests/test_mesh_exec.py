@@ -3,9 +3,8 @@
 no-op anywhere that fixture is absent).
 
 Covers the ISSUE-6 acceptance gates: shard_csr padding/sentinel rows,
-dist_k_hop program reuse, the fused multi-hop chain executing as ONE
-device dispatch (vs one per hop on the per-task path), and mesh-mode
-results byte-identical to the single-device executor on the golden query
+the fused multi-hop chain executing as ONE device dispatch (vs one per
+hop on the per-task path), and mesh-mode results byte-identical to the single-device executor on the golden query
 corpus (tests/golden/expected.json — the same battery the wire cluster is
 diffed against in contrib/scripts/smoke_mesh.sh)."""
 
@@ -100,25 +99,6 @@ def test_expand_program_cached_across_calls():
     after = dist._expand_program.cache_info()
     assert after.misses == before.misses       # no rebuild per call
     assert after.hits > before.hits
-
-
-def test_dist_k_hop_program_cached():
-    rng = np.random.default_rng(5)
-    from tests.test_dist import build_host_csr
-    from dgraph_tpu.ops import uidset as us
-
-    subjects, indptr, indices = build_host_csr(rng, 200, 1500)
-    mesh = make_mesh(8)
-    sh = dist.shard_csr(subjects, indptr, indices, mesh)
-    seeds = us.make_set([0, 3], capacity=8)
-    r1 = dist.dist_k_hop(sh, seeds, mesh, hops=2, frontier_cap=512,
-                         num_nodes=200)
-    before = dist._k_hop_program.cache_info()
-    r2 = dist.dist_k_hop(sh, seeds, mesh, hops=2, frontier_cap=512,
-                         num_nodes=200)
-    after = dist._k_hop_program.cache_info()
-    assert after.misses == before.misses
-    np.testing.assert_array_equal(np.asarray(r1[1]), np.asarray(r2[1]))
 
 
 # ---------------------------------------------------------------------------

@@ -2,11 +2,18 @@
 
 The kernel (ops/pallas_bfs.py) is the TPU-native replacement for the
 reference's bp128-unpack + per-uid posting iteration hot loop
-(worker/task.go:476-602). These tests pin its semantics to a plain host
-BFS across the shape edge cases the kernel's blocking scheme creates:
-sparse<->dense frontier switch at FRONTIER_CAP, bitmap chunk boundaries
-(num_nodes = 32768 +/- 1), edge streams not divisible by EDGE_BLOCK,
-multi-chunk bitmaps, and empty frontiers.
+(worker/task.go:476-602). These tests pin the programs a request reaches
+to a plain host BFS across the shape edge cases the kernel's blocking
+scheme creates: sparse<->dense frontier switch at FRONTIER_CAP, bitmap
+chunk boundaries (num_nodes = 32768 +/- 1), edge streams not divisible by
+EDGE_BLOCK, multi-chunk bitmaps, and empty frontiers.
+
+Every case runs under each program that can express it:
+  push / stream  bfs_dist (`shortest`) from single roots, its first level
+                 over the root's forward row (first_hop_cap at its default)
+                 or over the in-edge stream (first_hop_cap=1: every root
+                 with more than one out-edge streams);
+  recurse        recurse_fused (`@recurse`) from the whole seed set.
 """
 
 import numpy as np
@@ -16,16 +23,21 @@ import jax.numpy as jnp
 from dgraph_tpu.models.rmat import rmat_csr
 from dgraph_tpu.ops import pallas_bfs as pb
 
+FIRST_HOP_CAPS = {"push": pb.FIRST_HOP_CAP, "stream": 1}
+programs = pytest.mark.parametrize("program", ["push", "stream", "recurse"])
+
 
 def host_k_hop(subjects, indptr, indices, seed_uids, num_nodes, hops):
-    """Reference host BFS: visited mask + traversed out-edge count per hop."""
+    """Reference host BFS: visited mask + traversed out-edge count per hop,
+    and the level each uid was first reached at (DIST_UNREACHED = never)."""
     adj = {int(s): indices[indptr[i]:indptr[i + 1]]
            for i, s in enumerate(subjects)}
     visited = np.zeros(num_nodes, dtype=bool)
     visited[seed_uids] = True
+    dist = np.where(visited, 0, pb.DIST_UNREACHED).astype(np.uint8)
     frontier = np.unique(np.asarray(seed_uids, dtype=np.int64))
     traversed = 0
-    for _ in range(hops):
+    for h in range(hops):
         dests = [adj[int(u)] for u in frontier if int(u) in adj]
         total = sum(len(d) for d in dests)
         traversed += total
@@ -35,85 +47,173 @@ def host_k_hop(subjects, indptr, indices, seed_uids, num_nodes, hops):
         dest = np.unique(np.concatenate(dests))
         fresh = dest[~visited[dest]]
         visited[fresh] = True
+        dist[fresh] = h + 1
         frontier = fresh
-    return visited, traversed
+    return visited, traversed, dist
 
 
-def run_both(subjects, indptr, indices, seed_uids, num_nodes, hops):
+def host_recurse(subjects, indptr, indices, seed_uids, depth):
+    """Reference host @recurse (edge dedup, recurse.go expandRecurse): per
+    level, the uids reached over a never-traversed edge and the count of
+    every out-edge of the level's frontier."""
+    row_of = {int(s): i for i, s in enumerate(subjects)}
+    seen = np.zeros(len(indices), dtype=bool)
+    frontier = np.unique(np.asarray(seed_uids, dtype=np.int64))
+    levels = []
+    for _ in range(depth):
+        rows = [row_of[int(u)] for u in frontier if int(u) in row_of]
+        edges = (np.concatenate([np.arange(indptr[r], indptr[r + 1])
+                                 for r in rows])
+                 if rows else np.zeros(0, dtype=np.int64))
+        fresh = edges[~seen[edges]]
+        seen[fresh] = True
+        frontier = np.unique(indices[fresh])
+        levels.append((frontier, len(edges)))
+    return levels
+
+
+def check_search(g, csr, root, hops, first_hop_cap):
+    """bfs_dist's uint8[Nd] labels from `root` against the host BFS levels.
+    The destination is a vertex the search never finds within `hops`, or
+    else one of the farthest: the early exit then cuts nothing off."""
+    visited, _traversed, dist = host_k_hop(*csr, [root], g.num_nodes, hops)
+    want = dist[g.host_in_subjects]
+    labels = np.asarray(pb.bfs_dist(
+        g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank, g.subjects,
+        g.in_subjects, g.fwd_indptr, g.fwd_dst_rank,
+        np.asarray([root, pb._source_row(g, root)[0], int(np.argmax(want)),
+                    hops], dtype=np.int32),
+        chunks=g.chunks, chunks_d=g.chunks_d, first_hop_cap=first_hop_cap))
+    assert labels.dtype == np.uint8
+    np.testing.assert_array_equal(labels, want)
+    # visited within k hops = label <= k, scattered through in_subjects
+    got = np.zeros(g.num_nodes, dtype=bool)
+    got[root] = True
+    got[g.host_in_subjects[labels <= hops]] = True
+    np.testing.assert_array_equal(got, visited)
+
+
+def check_recurse(g, csr, seed_uids, hops):
+    """recurse_fused's per-level reached sets and traversed counts against
+    the host recurse; their union with the seeds is the BFS's visited set.
+    Returns the per-level traversed counts."""
+    seeds_mask = np.zeros(g.num_nodes, dtype=bool)
+    seeds_mask[seed_uids] = True
+    masks_p, trav, _fresh = pb.recurse_fused(
+        g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank, g.subjects,
+        g.in_subjects, jnp.asarray(seeds_mask), depth=hops, chunks=g.chunks,
+        chunks_d=g.chunks_d, allow_loop=False)
+    masks_h, trav = np.asarray(masks_p), np.asarray(trav)
+    nd = len(g.host_in_subjects)
+    union = seeds_mask.copy()
+    levels = host_recurse(*csr, seed_uids, hops)
+    for lvl, (want_reached, want_traversed) in enumerate(levels):
+        reached = g.host_in_subjects[pb.unpack_words(masks_h[lvl], nd)]
+        np.testing.assert_array_equal(reached, want_reached)
+        assert int(trav[lvl]) == want_traversed
+        union[reached] = True
+    visited, _traversed, _dist = host_k_hop(
+        *csr, seed_uids, g.num_nodes, hops)
+    np.testing.assert_array_equal(union, visited)
+    return trav
+
+
+def run_both(program, subjects, indptr, indices, seed_uids, num_nodes, hops):
+    """The host BFS against `program` (module docstring) from `seed_uids`:
+    a search from each one, or one recurse from all of them."""
+    csr = (subjects, indptr, indices)
     g = pb.prep_pull(subjects, indptr, indices, num_nodes)
-    seeds_mask = jnp.zeros(num_nodes, dtype=bool)
-    if len(seed_uids):
-        seeds_mask = seeds_mask.at[jnp.asarray(np.asarray(seed_uids))].set(True)
-    res = pb.k_hop_pull_pallas(g, seeds_mask, hops=hops)
-    h_visited, h_traversed = host_k_hop(
-        subjects, indptr, indices, seed_uids, num_nodes, hops)
-    np.testing.assert_array_equal(np.asarray(res.visited), h_visited)
-    assert int(res.traversed) == h_traversed
-    # push fast path (explicit seed list) must agree with the mask-only run
-    res_p = pb.k_hop_pull_pallas(
-        g, seeds_mask, hops=hops,
-        seed_uids=np.asarray(seed_uids, dtype=np.int64))
-    np.testing.assert_array_equal(np.asarray(res_p.visited), h_visited)
-    assert int(res_p.traversed) == h_traversed
-    return res
+    if program == "recurse":
+        return check_recurse(g, csr, seed_uids, hops)
+    cap = FIRST_HOP_CAPS[program]
+    for root in seed_uids:
+        check_search(g, csr, int(root), hops, cap)
+    return [pb.first_hop_pushes(pb._source_row(g, int(root))[1], cap)
+            for root in seed_uids]
 
 
-def random_csr(rng, num_nodes, num_edges):
-    src = rng.integers(0, num_nodes, size=num_edges)
-    dst = rng.integers(0, num_nodes, size=num_edges)
-    keep = np.unique(np.stack([src, dst], axis=1), axis=0)
+def fan_out_root(subjects, indptr):
+    """A uid with the most out-edges: first_hop_cap=1 makes it stream."""
+    return int(subjects[np.argmax(np.diff(indptr))])
+
+
+def csr_of(src, dst):
+    """CSR (subjects, indptr, indices) of the distinct (src, dst) pairs."""
+    keep = np.unique(np.stack([src, dst], axis=1), axis=0)   # sorts by row
     src, dst = keep[:, 0], keep[:, 1]
-    order = np.lexsort((dst, src))
-    src, dst = src[order], dst[order]
     subjects, counts = np.unique(src, return_counts=True)
     indptr = np.zeros(len(subjects) + 1, dtype=np.int64)
     np.cumsum(counts, out=indptr[1:])
     return subjects.astype(np.int64), indptr, dst.astype(np.int64)
 
 
-def test_rmat_multi_hop_matches_host(rng):
+def random_csr(rng, num_nodes, num_edges):
+    """num_edges distinct edges, uniform over the pairs."""
+    pairs = rng.choice(num_nodes * num_nodes, size=num_edges, replace=False)
+    return csr_of(pairs // num_nodes, pairs % num_nodes)
+
+
+@programs
+def test_rmat_multi_hop_matches_host(rng, program):
     subjects, indptr, indices = rmat_csr(12, 8, seed=5)
     num_nodes = int(max(subjects.max(), indices.max())) + 2
     seeds = np.unique(rng.choice(subjects, size=16, replace=False))
-    run_both(subjects, indptr, indices, seeds, num_nodes, hops=3)
+    if program != "recurse":
+        seeds = np.append(seeds[:3], fan_out_root(subjects, indptr))
+    out = run_both(program, subjects, indptr, indices, seeds, num_nodes,
+                   hops=3)
+    if program != "recurse":
+        assert out[-1] == (program == "push")
 
 
-def test_empty_frontier():
+@pytest.mark.parametrize("program", ["push", "recurse"])
+def test_empty_frontier(program):
+    """Nothing to expand: a recurse from no seed, a search of zero hops."""
     subjects, indptr, indices = rmat_csr(8, 4, seed=1)
     num_nodes = int(max(subjects.max(), indices.max())) + 2
-    res = run_both(subjects, indptr, indices, np.zeros(0, np.int64),
-                   num_nodes, hops=2)
-    assert int(res.traversed) == 0
-    assert not np.asarray(res.visited).any()
+    if program == "recurse":
+        trav = run_both(program, subjects, indptr, indices,
+                        np.zeros(0, np.int64), num_nodes, hops=2)
+        assert not trav.any()
+    else:
+        run_both(program, subjects, indptr, indices, subjects[:1],
+                 num_nodes, hops=0)
 
 
-def test_frontier_with_no_out_edges():
+@programs
+def test_frontier_with_no_out_edges(program):
     # seed uid exists but has no row in the CSR
     subjects = np.array([1, 2], dtype=np.int64)
     indptr = np.array([0, 1, 2], dtype=np.int64)
     indices = np.array([5, 6], dtype=np.int64)
-    run_both(subjects, indptr, indices, np.array([40]), 64, hops=2)
+    run_both(program, subjects, indptr, indices, np.array([40]), 64, hops=2)
 
 
+@programs
 @pytest.mark.parametrize("delta", [-1, 0, 1])
-def test_chunk_boundary_num_nodes(rng, delta):
-    """num_nodes at 32768 +/- 1: the single/multi-chunk switch and the
-    pad-node-outside-uid-space rule (prep_pull adds a chunk when the uid
-    space exactly fills the bitmap)."""
+def test_chunk_boundary_num_nodes(rng, delta, program):
+    """num_nodes at 32768 +/- 1, every uid a source and a destination (the
+    bitmaps are over the rank spaces): the single/multi-chunk switch and the
+    pad-rank-outside-the-ranks rule (prep_pull adds a chunk when the ranks
+    exactly fill the bitmap)."""
     num_nodes = pb.NODES_PER_CHUNK + delta
-    subjects, indptr, indices = random_csr(rng, num_nodes, 6000)
-    # force edges touching the top of the uid space
-    hi = num_nodes - 1
-    subjects_l = list(subjects)
-    if hi not in subjects_l:
-        subjects = np.append(subjects, hi)
-        indptr = np.append(indptr, indptr[-1] + 1)
-        indices = np.append(indices, 0)
-    seeds = np.array([int(subjects[0]), hi], dtype=np.int64)
-    run_both(subjects, indptr, indices, seeds, num_nodes, hops=3)
+    ring = np.arange(num_nodes)
+    subjects, indptr, indices = csr_of(
+        np.concatenate([ring, rng.integers(0, num_nodes, size=6000)]),
+        np.concatenate([(ring + 1) % num_nodes,
+                        rng.integers(0, num_nodes, size=6000)]))
+    g = pb.prep_pull(subjects, indptr, indices, num_nodes)
+    assert g.chunks == g.chunks_d == (1 if delta < 0 else 2)
+    # the top of the uid space, and a root that streams at first_hop_cap=1
+    seeds = np.array([fan_out_root(subjects, indptr), num_nodes - 1])
+    out = run_both(program, subjects, indptr, indices, seeds, num_nodes,
+                   hops=3)
+    if program != "recurse":
+        assert out[0] == (program == "push")
 
 
-def test_multi_chunk_bitmap(rng):
+@programs
+def test_multi_chunk_bitmap(rng, program):
     """3+ bitmap chunks with edges crossing chunk boundaries. The chunk
     space is SOURCE-RANK-compressed, so >= 2*NODES_PER_CHUNK distinct
     sources are needed to exercise the multi-chunk path."""
@@ -125,27 +225,35 @@ def test_multi_chunk_bitmap(rng):
                                        size=n_edges - num_nodes)])
     # half the edges deliberately cross into a different chunk
     dst = (src + pb.NODES_PER_CHUNK + rng.integers(0, 100, size=n_edges)) % num_nodes
-    order = np.lexsort((dst, src))
-    src, dst = src[order], dst[order]
-    subjects, counts = np.unique(src, return_counts=True)
-    indptr = np.zeros(len(subjects) + 1, dtype=np.int64)
-    np.cumsum(counts, out=indptr[1:])
+    subjects, indptr, dst = csr_of(src, dst)
     seeds = np.unique(rng.choice(subjects, size=8))
-    res = run_both(subjects, indptr, dst, seeds, num_nodes, hops=3)
+    if program != "recurse":
+        seeds = np.append(seeds[:2], fan_out_root(subjects, indptr))
+    out = run_both(program, subjects, indptr, dst, seeds, num_nodes, hops=3)
     g = pb.prep_pull(subjects, indptr, dst, num_nodes)
     assert g.chunks >= 3
-    assert int(res.traversed) > 0
+    if program == "recurse":
+        assert out.all()
+    else:
+        assert out[-1] == (program == "push")
 
 
+@programs
 @pytest.mark.parametrize("extra", [0, 1, 7])
-def test_edge_count_not_block_aligned(rng, extra):
+def test_edge_count_not_block_aligned(rng, extra, program):
     """E % EDGE_BLOCK != 0 (and E < EDGE_BLOCK): padding edges must never
     count as active or mark nodes."""
     num_nodes = 2048
     num_edges = pb.EDGE_BLOCK + extra if extra else 300
     subjects, indptr, indices = random_csr(rng, num_nodes, num_edges)
+    assert len(indices) == num_edges
     seeds = np.unique(rng.choice(subjects, size=4))
-    run_both(subjects, indptr, indices, seeds, num_nodes, hops=2)
+    if program != "recurse":
+        seeds = np.append(seeds, fan_out_root(subjects, indptr))
+    out = run_both(program, subjects, indptr, indices, seeds, num_nodes,
+                   hops=2)
+    if program != "recurse":
+        assert out[-1] == (program == "push")
 
 
 def _star_graph(n_spokes, num_nodes):
@@ -162,27 +270,33 @@ def _star_graph(n_spokes, num_nodes):
     return subjects, indptr, indices
 
 
+@programs
 @pytest.mark.parametrize("n_spokes", [pb.FRONTIER_CAP - 1,
                                       pb.FRONTIER_CAP,
                                       pb.FRONTIER_CAP + 1])
-def test_sparse_dense_crossover(n_spokes):
+def test_sparse_dense_crossover(n_spokes, program):
     """Hop 2's frontier is exactly at/under/over FRONTIER_CAP, driving the
-    sparse (2-level bucket search) vs dense (chunked bitmap) kernel choice.
-    Both must agree with the host BFS."""
+    sparse (2-level bucket search) vs dense (chunked bitmap) kernel choice —
+    and, for a search at the default first_hop_cap, whether the hub's row
+    still fits the push. All must agree with the host BFS."""
     num_nodes = pb.FRONTIER_CAP + 1000
     subjects, indptr, indices = _star_graph(n_spokes, num_nodes)
-    res = run_both(subjects, indptr, indices, np.array([0]), num_nodes, hops=2)
-    # hop1 traverses n_spokes hub edges; hop2 traverses n_spokes spoke edges
-    assert int(res.traversed) == 2 * n_spokes
+    out = run_both(program, subjects, indptr, indices, np.array([0]),
+                   num_nodes, hops=2)
+    if program == "recurse":
+        # level 1 traverses n_spokes hub edges, level 2 n_spokes spoke edges
+        assert out.tolist() == [n_spokes, n_spokes]
+    else:
+        assert out == [program == "push" and n_spokes <= pb.FIRST_HOP_CAP]
 
 
 def test_dense_seed_frontier(rng):
-    """Seed frontier itself above FRONTIER_CAP: first hop takes the dense
-    path immediately."""
+    """Seed frontier itself above FRONTIER_CAP: the first level takes the
+    dense path immediately (a recurse: a search has one root)."""
     num_nodes = 40000  # spans 2 chunks
     subjects, indptr, indices = random_csr(rng, num_nodes, 30000)
     seeds = np.unique(rng.choice(subjects, size=pb.FRONTIER_CAP + 500))
-    run_both(subjects, indptr, indices, seeds, num_nodes, hops=2)
+    run_both("recurse", subjects, indptr, indices, seeds, num_nodes, hops=2)
 
 
 def test_prep_pull_rejects_out_of_range_uids():
@@ -194,51 +308,6 @@ def test_prep_pull_rejects_out_of_range_uids():
     with pytest.raises(ValueError, match="num_nodes"):
         pb.prep_pull(np.array([100], np.int64), indptr,
                      np.array([0], np.int64), num_nodes=50)
-
-
-def test_matches_xla_pull_path(rng):
-    """Cross-check against ops.traversal.k_hop_pull (the XLA formulation the
-    kernel replaces) on a mid-size R-MAT graph."""
-    from dgraph_tpu.ops import traversal
-
-    subjects, indptr, indices = rmat_csr(11, 8, seed=9)
-    num_nodes = int(max(subjects.max(), indices.max())) + 2
-    seeds = np.unique(rng.choice(subjects, size=32, replace=False))
-
-    g = pb.prep_pull(subjects, indptr, indices, num_nodes)
-    seeds_mask = jnp.zeros(num_nodes, dtype=bool).at[jnp.asarray(seeds)].set(True)
-    res = pb.k_hop_pull_pallas(g, seeds_mask, hops=3)
-
-    in_sub, in_ptr, in_src = traversal.reverse_csr(subjects, indptr, indices)
-    ref = traversal.k_hop_pull(
-        jnp.asarray(subjects), jnp.asarray(indptr), jnp.asarray(in_sub),
-        jnp.asarray(in_ptr), jnp.asarray(in_src), seeds_mask, hops=3,
-        num_nodes=num_nodes)
-    np.testing.assert_array_equal(np.asarray(res.visited),
-                                  np.asarray(ref.visited))
-    assert int(res.traversed) == int(ref.traversed)
-
-
-def test_duplicate_seed_uids_not_overcounted(rng):
-    """A repeated seed must not be expanded once per occurrence (review r4)."""
-    subjects = np.array([0, 1])
-    indptr = np.array([0, 1, 2])
-    indices = np.array([1, 2])
-    g = pb.prep_pull(subjects, indptr, indices, 4)
-    mask = jnp.zeros(4, dtype=bool).at[0].set(True)
-    res = pb.k_hop_pull_pallas(g, mask, hops=1, seed_uids=np.array([0, 0, 0]))
-    assert int(res.traversed) == 1
-
-
-def test_hops_zero_returns_seeds_as_frontier(rng):
-    subjects = np.array([0])
-    indptr = np.array([0, 1])
-    indices = np.array([1])
-    g = pb.prep_pull(subjects, indptr, indices, 4)
-    mask = jnp.zeros(4, dtype=bool).at[0].set(True)
-    res = pb.k_hop_pull_pallas(g, mask, hops=0)
-    np.testing.assert_array_equal(np.asarray(res.frontier), np.asarray(mask))
-    assert int(res.traversed) == 0
 
 
 @pytest.mark.parametrize("n_set", [0, 1, 33, pb.FRONTIER_CAP])

@@ -240,8 +240,8 @@ def test_tiered_serving_byte_identical_10x_budget(force_device):
     resident = _build_node()
     want = _run_all(resident)
     tiered = _build_node(device_budget_mb=1)
-    # refine the MB-granular flag to exactly graph/10 (bench.py residency
-    # does the same): bigger than one tablet, 10x smaller than the graph
+    # refine the MB-granular flag to exactly graph/10: bigger than one
+    # tablet, 10x smaller than the graph
     total = _graph_device_bytes(tiered)
     tiered.residency.budget = total // 10
     tiered.residency.evict_to(tiered.residency.budget)

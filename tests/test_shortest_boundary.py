@@ -25,8 +25,7 @@ def _pull_graph(edges):
     indptr = np.zeros(len(subjects) + 1, dtype=np.int64)
     np.cumsum(counts, out=indptr[1:])
     return pb.prep_pull(subjects, indptr, dst,
-                        int(max(src.max(), dst.max())) + 1,
-                        with_host_arrays=True)
+                        int(max(src.max(), dst.max())) + 1)
 
 
 def _host_levels(edges, src):
@@ -221,7 +220,7 @@ def test_a_row_in_any_order_is_level_twos_frontier():
     subjects = np.asarray([1, 3, 5, 7, 9], dtype=np.int64)
     indptr = np.asarray([0, 4, 5, 6, 7, 8], dtype=np.int64)
     indices = np.asarray([9, 3, 7, 5, 23, 25, 27, 29], dtype=np.int64)
-    g = pb.prep_pull(subjects, indptr, indices, 30, with_host_arrays=True)
+    g = pb.prep_pull(subjects, indptr, indices, 30)
     assert np.asarray(g.fwd_dst_rank)[:4].tolist() == [3, 0, 2, 1]
     for dst in (23, 25, 27, 29):
         assert pb.shortest_bfs(g, 1, dst, 8) == [1, dst - 20, dst]

@@ -712,7 +712,7 @@ def test_bfs_dist_lowering_holds_the_scope_names():
     subjects = np.arange(1, 9, dtype=np.int64)
     indptr = np.arange(9, dtype=np.int64)
     indices = np.arange(2, 10, dtype=np.int64)
-    g = pb.prep_pull(subjects, indptr, indices, 10, with_host_arrays=True)
+    g = pb.prep_pull(subjects, indptr, indices, 10)
     text = pb.bfs_dist.lower(
         g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank, g.subjects,
         g.in_subjects, g.fwd_indptr, g.fwd_dst_rank,
