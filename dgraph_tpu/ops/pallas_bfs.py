@@ -16,15 +16,26 @@ a Pallas kernel where it can't miss:
     the chunk that owns it (chunks = ceil(num_nodes / 32768); a scale-20
     graph needs 33 — ~5 VPU ops per edge per chunk).
   - the edge stream (in_src, sorted by destination) is the ONLY O(E) HBM
-    traffic: 4 bytes in + 4 bytes out per edge, at streaming rate.
+    read: 4 bytes per edge, at streaming rate.
   - the kernel fuses the inclusive prefix-sum of the per-edge active
     flags (two-level lane/sublane scan + a sequential-grid carry in
     SMEM), so the XLA side needs no E-sized cumsum: per-node reachability
     is diff-of-prefix at the dense in-CSR row boundaries — node-sized.
+  - two emits over that one lookup + scan. A recurse level dedups EDGES,
+    so its kernels (active_prefix*) write the whole prefix back, 4 bytes
+    per edge, and XLA gathers it at the row boundaries. A search level
+    wants only VERTICES, so its kernels (row_end_prefix*) keep the block's
+    prefix in VMEM, pick it at the ends of the rows that end in the block
+    (a graph-static table of in-block positions, RowEnds) and write one
+    int32 per destination rank: nothing edge-sized is written and XLA
+    gathers nothing.
 
 Per hop:   active[e] = frontier_bit[in_src[e]]          (Pallas, streaming)
            prefix    = cumsum(active)                   (fused in kernel)
-           reached_v = prefix[iptr[v+1]] - prefix[iptr[v]] > 0   (node-sized)
+  recurse: reached_v = prefix[iptr[v+1]-1] - prefix[iptr[v]-1] > 0
+                                    (XLA gather of the E-sized prefix)
+  search:  bounds_v  = prefix[iptr[v+1]-1]       (picked in the kernel)
+           reached_v = bounds_v - bounds_{v-1} > 0         (node-sized)
            frontier' = reached & ~visited               (node-sized)
 
 Reference semantics preserved: `traversed` counts every out-edge of every
@@ -77,25 +88,19 @@ def _block_prefix(active: jax.Array) -> jax.Array:
     return (lane + row_off).astype(jnp.int32)
 
 
-def _prefix_kernel(words_ref, src_ref, out_ref, carry_ref, *, chunks: int):
-    """One grid step: EDGE_BLOCK edges -> inclusive active-prefix values.
+def _active_dense(words_ref, src, chunks: int):
+    """0/1 per edge of a (R, 128) tile of source ranks: its bit in the
+    VMEM-resident frontier bitmap. The lookup of every dense kernel.
 
     The frontier-word lookup runs as a chunk loop: each chunk is 1024 words
     laid out (8, 128); Mosaic's dynamic_gather handles the lane dimension
     (take_along_axis along axis=1, single-vreg form) and an 8-way masked
     select handles the sublane row (masks hoisted out of the chunk loop) —
     zero HBM traffic for the bitmap (VMEM-resident throughout)."""
-    blk = pl.program_id(0)
-
-    @pl.when(blk == 0)
-    def _():
-        carry_ref[0] = 0
-
     # bit-plane word layout (see pack_words): node n lives in chunk n>>15,
     # panel row (n>>12)&7, lane n&127, bit (n>>7)&31 — chosen so packing a
     # node mask into words is 32 lane-aligned shift-ors, not a 32-wide
     # cross-lane reduction
-    src = src_ref[:]                                   # (R, 128) int32
     bit = jnp.bitwise_and(lax.shift_right_logical(src, 7), 31)
     cidx = lax.shift_right_logical(src, 15)            # owning chunk
     col = jnp.bitwise_and(src, _LANES - 1)
@@ -112,7 +117,19 @@ def _prefix_kernel(words_ref, src_ref, out_ref, carry_ref, *, chunks: int):
         return acc
 
     wordv = lax.fori_loop(0, chunks, body, jnp.zeros_like(src))
-    active = jnp.bitwise_and(lax.shift_right_logical(wordv, bit), 1)
+    return jnp.bitwise_and(lax.shift_right_logical(wordv, bit), 1)
+
+
+def _prefix_kernel(words_ref, src_ref, out_ref, carry_ref, *, chunks: int):
+    """One grid step: EDGE_BLOCK edges -> inclusive active-prefix values
+    (the recurse programs' emit: edge dedup wants every edge's flag)."""
+    blk = pl.program_id(0)
+
+    @pl.when(blk == 0)
+    def _():
+        carry_ref[0] = 0
+
+    active = _active_dense(words_ref, src_ref[:], chunks)  # (R, 128) int32
 
     # inclusive scan in row-major (flattened-edge) order + sequential carry
     prefix = _block_prefix(active) + carry_ref[0]
@@ -123,22 +140,16 @@ def _prefix_kernel(words_ref, src_ref, out_ref, carry_ref, *, chunks: int):
 FRONTIER_CAP = 4096    # sparse-path capacity: 128 buckets x 32 entries
 
 
-def _prefix_kernel_sparse(ftab_ref, src_ref, out_ref, carry_ref):
-    """Sparse-frontier variant: membership test against a sorted frontier
-    list (<= FRONTIER_CAP uids) in a 2-level 128-ary layout instead of the
-    full-bitmap chunk loop — ~5x fewer VPU ops per edge, the win for the
-    early BFS hops where the frontier is small.
+def _active_sparse(ftab_ref, src):
+    """0/1 per edge of a (R, 128) tile of source ranks: membership in a
+    sorted frontier list (<= FRONTIER_CAP uids) in a 2-level 128-ary layout
+    instead of the full-bitmap chunk loop — ~5x fewer VPU ops per edge, the
+    win for the early BFS hops where the frontier is small. The lookup of
+    every sparse kernel.
 
     ftab layout (33, 128): row 0 = per-bucket max (bucket g = sorted
     frontier[32g:32g+32]); rows 1+j = element j of every bucket. Padding
     slots hold INT32_MAX (never equal to a real uid)."""
-    blk = pl.program_id(0)
-
-    @pl.when(blk == 0)
-    def _():
-        carry_ref[0] = 0
-
-    src = src_ref[:]                                   # (R, 128) int32
     seps = jnp.broadcast_to(ftab_ref[0:1, :], src.shape)
 
     # branchless lower-bound over the 128 bucket separators:
@@ -157,6 +168,18 @@ def _prefix_kernel_sparse(ftab_ref, src_ref, out_ref, carry_ref):
         lane = jnp.broadcast_to(ftab_ref[1 + j : 2 + j, :], src.shape)
         v = jnp.take_along_axis(lane, b, axis=1)
         active = jnp.bitwise_or(active, (v == src).astype(jnp.int32))
+    return active
+
+
+def _prefix_kernel_sparse(ftab_ref, src_ref, out_ref, carry_ref):
+    """Sparse-frontier variant of _prefix_kernel."""
+    blk = pl.program_id(0)
+
+    @pl.when(blk == 0)
+    def _():
+        carry_ref[0] = 0
+
+    active = _active_sparse(ftab_ref, src_ref[:])      # (R, 128) int32
 
     prefix = _block_prefix(active) + carry_ref[0]
     out_ref[:] = prefix
@@ -226,6 +249,163 @@ def active_prefix_sparse(ftab: jax.Array, src_pad: jax.Array) -> jax.Array:
     return out.reshape(e_pad)
 
 
+RANK_TILE = 1024       # destination ranks per output tile: one 8x128 vreg
+_ITEM_CLASS = 32       # grid steps of a row-end kernel come in this multiple
+
+
+def _rank_tiles(n_ranks: int) -> int:
+    """Output tiles of a row-end kernel over n_ranks destinations."""
+    return max(1, -(-n_ranks // RANK_TILE))
+
+
+class RowEnds(NamedTuple):
+    """The grid of a row-end kernel (graph-static, built once by prep_pull):
+    a list of ITEMS, one per (edge block, tile of RANK_TILE destination
+    ranks) pair such that a row of the tile ends in the block, in stream
+    order; an edge block in which no row ends has one item too, so every
+    block is streamed. A row ends in exactly one block, so exactly one
+    item picks it. The list is padded to a multiple of _ITEM_CLASS with
+    items that repeat the last block and tile."""
+
+    block: jax.Array      # int32[n_items] edge block of an item
+    tile: jax.Array       # int32[n_items] rank tile of an item
+
+
+def _row_ends(iptr: np.ndarray, e_pad: int) -> RowEnds:
+    """The RowEnds of a dst-sorted stream of e_pad edges whose row v is
+    iptr[v]..iptr[v+1] (every row non-empty)."""
+    nd = len(iptr) - 1
+    n_blocks = e_pad // EDGE_BLOCK
+    n_tiles = _rank_tiles(nd)
+    blk_of = (iptr[1:].astype(np.int64) - 1) // EDGE_BLOCK   # [Nd] sorted
+    count = np.bincount(blk_of, minlength=n_blocks)
+    first = np.cumsum(count) - count                   # first rank per block
+    # the tiles a block's ranks first..first+count-1 touch; a block with no
+    # row end sits on the tile the next rank will land in
+    t_lo = np.minimum(first // RANK_TILE, n_tiles - 1)
+    t_hi = np.where(count > 0, (first + count - 1) // RANK_TILE, t_lo)
+    per_block = t_hi - t_lo + 1
+    start = np.cumsum(per_block) - per_block           # first item per block
+    n_real = int(per_block.sum())
+    n_items = -(-n_real // _ITEM_CLASS) * _ITEM_CLASS
+    block = np.full(n_items, n_blocks - 1, dtype=np.int32)
+    block[:n_real] = np.repeat(np.arange(n_blocks), per_block)
+    tile = np.full(n_items, n_tiles - 1, dtype=np.int32)
+    tile[:n_real] = (np.arange(n_real) - start[block[:n_real]]
+                     + t_lo[block[:n_real]])
+    return RowEnds(jnp.asarray(block), jnp.asarray(tile))
+
+
+def _last_edges(in_iptr_rank: jax.Array) -> jax.Array:
+    """int32[n_tiles*8, 128]: the stream position of each destination
+    rank's last in-edge, rank v at [v // 128, v % 128]; -1 past Nd. What
+    a row-end kernel reads a rank tile of."""
+    nd = in_iptr_rank.shape[0] - 1
+    n_tiles = _rank_tiles(nd)
+    return jnp.pad(in_iptr_rank[1:] - 1, (0, n_tiles * RANK_TILE - nd),
+                   constant_values=-1).reshape(n_tiles * 8, _LANES)
+
+
+def _row_end_kernel(block_ref, tile_ref, table_ref, src_ref, last_ref,
+                    out_ref, prefix_ref, carry_ref, *, active_of):
+    """One grid step of a row-end kernel (a search's emit: it wants the
+    vertices, not the edges): item i -> the inclusive active-prefix at the
+    last in-edge of each destination rank of the item's tile whose row
+    ends in the item's edge block, written to the rank's slot of the
+    output tile. Nothing edge-sized leaves the kernel.
+
+    An item that opens an edge block runs the membership test
+    (`active_of(table_ref, src tile)`: _active_dense over the frontier
+    bitmap or _active_sparse over the search table) and the block scan,
+    and keeps the block's own prefix in VMEM for the block's later items;
+    carry_ref = [prefix before this block, prefix after it]. Consecutive
+    items on one output tile find it resident (its block index has not
+    changed) and fill disjoint slots; the item that opens a tile zeroes
+    it."""
+    i = pl.program_id(0)
+    before = jnp.maximum(i - 1, 0)
+    opens_block = (i == 0) | (block_ref[i] != block_ref[before])
+    opens_tile = (i == 0) | (tile_ref[i] != tile_ref[before])
+
+    @pl.when(i == 0)
+    def _():
+        carry_ref[1] = 0
+
+    @pl.when(opens_block)
+    def _():
+        prefix = _block_prefix(active_of(table_ref, src_ref[:]))
+        prefix_ref[:] = prefix
+        carry_ref[0] = carry_ref[1]
+        carry_ref[1] = carry_ref[1] + prefix[prefix.shape[0] - 1, _LANES - 1]
+
+    @pl.when(opens_tile)
+    def _():
+        out_ref[:] = jnp.zeros_like(out_ref)
+
+    # the pick: a rank whose last in-edge sits at `at` inside this block
+    # wants prefix[at >> 7, at & 127]. The lane is a dynamic gather inside
+    # one vreg, the row a masked select over the block's 64 prefix rows; a
+    # rank whose row ends in another block matches no row
+    at = last_ref[:] - block_ref[i] * EDGE_BLOCK       # (8, 128) int32
+    row = lax.shift_right_arithmetic(at, 7)
+    lane = jnp.bitwise_and(at, _LANES - 1)
+    picked = jnp.zeros_like(at)
+    for r in range(EDGE_BLOCK // _LANES):
+        row_r = jnp.broadcast_to(prefix_ref[r : r + 1, :], at.shape)
+        g = jnp.take_along_axis(row_r, lane, axis=1)       # in-vreg gather
+        picked = jnp.where(row == r, g, picked)
+    ends_here = (at >= 0) & (at < EDGE_BLOCK)
+    out_ref[:] = jnp.where(ends_here, picked + carry_ref[0], out_ref[:])
+
+
+def _row_end_call(active_of, table, src_pad, ends: RowEnds, last):
+    """The pallas_call of both row-end kernels: `table` (the frontier
+    bitmap or the search table) whole in VMEM, the edge stream by the
+    item's block, `last` (_last_edges) and the output by the item's tile.
+    Returns int32[n_tiles*8, 128], rank v at [v // 128, v % 128]."""
+    rblk = EDGE_BLOCK // _LANES
+    return pl.pallas_call(
+        partial(_row_end_kernel, active_of=active_of),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(ends.block.shape[0],),
+            in_specs=[
+                pl.BlockSpec(table.shape, lambda i, blk, tile: (0, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((rblk, _LANES), lambda i, blk, tile: (blk[i], 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((8, _LANES), lambda i, blk, tile: (tile[i], 0),
+                             memory_space=pltpu.VMEM),
+            ],
+            out_specs=pl.BlockSpec((8, _LANES),
+                                   lambda i, blk, tile: (tile[i], 0),
+                                   memory_space=pltpu.VMEM),
+            scratch_shapes=[pltpu.VMEM((rblk, _LANES), jnp.int32),
+                            pltpu.SMEM((2,), jnp.int32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct(last.shape, jnp.int32),
+        interpret=interpret_mode(),
+    )(ends.block, ends.tile, table, src_pad.reshape(-1, _LANES), last)
+
+
+@partial(jax.jit, static_argnames=("chunks",))
+def row_end_prefix(words: jax.Array, src_pad: jax.Array, ends: RowEnds,
+                   last: jax.Array, *, chunks: int) -> jax.Array:
+    """active_prefix taken at each destination rank's last in-edge, and
+    only there, in `last`'s layout: non-decreasing over the ranks; rank v
+    was reached iff its value is above rank v-1's (0 before rank 0). The
+    edge-sized prefix never exists outside the kernel."""
+    return _row_end_call(partial(_active_dense, chunks=chunks), words,
+                         src_pad, ends, last)
+
+
+@jax.jit
+def row_end_prefix_sparse(ftab: jax.Array, src_pad: jax.Array,
+                          ends: RowEnds, last: jax.Array) -> jax.Array:
+    """Sparse-frontier row_end_prefix (ftab: (33,128) 2-level layout)."""
+    return _row_end_call(_active_sparse, ftab, src_pad, ends, last)
+
+
 _INT32_MAX = np.iinfo(np.int32).max    # pad of a frontier list: no real rank
 
 
@@ -252,13 +432,18 @@ class PullGraph(NamedTuple):
     and reachability is computed per destination *rank* — power-law graphs
     leave ~half the uid space with no edges at all, so rank spaces halve the
     bitmap chunk loop (the kernel's per-edge cost), the frontier pack, and
-    the node-phase bounds gather. The device arrays are the programs'
-    arguments; the host arrays are what the engine reads between launches
-    (a search's first level and its backtrack, a recurse's uidMatrix)."""
+    the node phase (a recurse's bounds gather, a search's row-end slots).
+    The device arrays are the programs' arguments; the host arrays are
+    what the engine reads between launches (a search's first level and its
+    backtrack, a recurse's uidMatrix)."""
 
     in_src_pad: jax.Array       # int32[E_pad] source SRC-RANKS, dst-sorted
     in_src_pad_d: jax.Array     # int32[E_pad] source DST-RANKS, dst-sorted
-    in_iptr_rank: jax.Array     # int32[Nd+1] edge offsets per dst rank
+    in_iptr_rank: jax.Array     # int32[Nd+1] edge offsets per dst rank: a
+    # recurse level gathers its per-edge prefix at these, a search's kernels
+    # pick theirs at them block by block
+    row_ends: RowEnds           # the (edge block, rank tile) pairs those
+    # kernels walk
     subjects: jax.Array         # int32[Ns] sorted uids with out-edges
     in_subjects: jax.Array      # int32[Nd] sorted uids with in-edges
     fwd_indptr: jax.Array       # int32[Ns+1] forward CSR (a search's level 1)
@@ -342,7 +527,7 @@ def prep_pull(subjects: np.ndarray, indptr: np.ndarray,
     inv_order = np.empty(E, dtype=np.int32)
     inv_order[order] = np.arange(E, dtype=np.int32)
     return PullGraph(jnp.asarray(src_pad), jnp.asarray(src_pad_d),
-                     jnp.asarray(iptr),
+                     jnp.asarray(iptr), _row_ends(iptr, e_pad),
                      jnp.asarray(subjects.astype(np.int32)),
                      jnp.asarray(in_subjects.astype(np.int32)),
                      jnp.asarray(np.asarray(indptr).astype(np.int32)),
@@ -425,20 +610,48 @@ def unpack_words(words: np.ndarray, n: int) -> np.ndarray:
     return bits.reshape(-1)[:n].astype(bool)
 
 
-def _prefix_for(frontier_bits, stream, n_chunks: int):
-    """Active-edge inclusive prefix for one frontier (sparse search-table
-    kernel below SPARSE_MAX set bits, dense bitmap kernel above)."""
+def _hop_for(frontier_bits, n_chunks: int, sparse, dense):
+    """One frontier through the kernel its size picks: `sparse(search
+    table)` at or below SPARSE_MAX set bits, `dense(bitmap words)` above."""
     fcount = jnp.sum(frontier_bits, dtype=jnp.int32)
 
     def sparse_hop(f):
-        return active_prefix_sparse(_frontier_table(f), stream)
+        return sparse(_frontier_table(f))
 
     def dense_hop(f):
-        return active_prefix(pack_words(f, n_chunks), stream,
-                             chunks=n_chunks)
+        return dense(pack_words(f, n_chunks))
 
     return lax.cond(fcount <= SPARSE_MAX, sparse_hop, dense_hop,
                     frontier_bits)
+
+
+def _prefix_for(frontier_bits, stream, n_chunks: int):
+    """Active-edge inclusive prefix for one frontier, int32[E_pad] (a
+    recurse level's emit)."""
+    return _hop_for(
+        frontier_bits, n_chunks,
+        lambda ftab: active_prefix_sparse(ftab, stream),
+        lambda words: active_prefix(words, stream, chunks=n_chunks))
+
+
+def _reached_from(bounds, n_ranks: int):
+    """bool[n_ranks]: the ranks with an active in-edge, from the
+    active-prefix at each rank's last in-edge (row_end_prefix)."""
+    with jax.named_scope("bounds"):
+        bounds = bounds.reshape(-1)[:n_ranks]
+        return bounds > jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                         bounds])[:-1]
+
+
+def _reached_for(frontier_bits, stream, n_chunks: int, ends: RowEnds, last):
+    """Active-edge inclusive prefix at the row ends for one frontier, in
+    `last`'s layout (a search level's emit): nothing edge-sized leaves
+    either branch."""
+    return _hop_for(
+        frontier_bits, n_chunks,
+        lambda ftab: row_end_prefix_sparse(ftab, stream, ends, last),
+        lambda words: row_end_prefix(words, stream, ends, last,
+                                     chunks=n_chunks))
 
 
 def _recurse_tail(prefix, in_iptr_rank, seen, allow_loop: bool):
@@ -515,13 +728,16 @@ def first_hop_pushes(degree, cap: int):
 
 
 @partial(jax.jit, static_argnames=("chunks", "chunks_d", "first_hop_cap"))
-def bfs_dist(in_src_pad, in_src_pad_d, in_iptr_rank, subjects, in_subjects,
-             fwd_indptr, fwd_dst_rank, query, *, chunks: int, chunks_d: int,
-             first_hop_cap: int = FIRST_HOP_CAP):
+def bfs_dist(in_src_pad, in_src_pad_d, in_iptr_rank, row_ends, subjects,
+             in_subjects, fwd_indptr, fwd_dst_rank, query, *, chunks: int,
+             chunks_d: int, first_hop_cap: int = FIRST_HOP_CAP):
     """Unweighted single-source BFS distances, early-exiting when dst is
     reached — the kernel behind `shortest` on large CSRs (replaces the
     Bellman-Ford E-gather of ops/traversal.sssp, an element-granularity
-    gather; here each hop from the second on is one Pallas E-stream).
+    gather; here each hop from the second on is one Pallas E-stream that
+    reads 4 bytes an edge and writes 4 bytes a destination, row_end_prefix:
+    the program holds no edge-sized intermediate and no node-sized gather
+    or scatter in a level that streams).
 
     Level 1 has one vertex in its frontier, so it reads that vertex's row
     of the forward CSR (one contiguous slice, one scatter) and not the
@@ -556,12 +772,11 @@ def bfs_dist(in_src_pad, in_src_pad_d, in_iptr_rank, subjects, in_subjects,
         visited0 = in_subjects == src                      # [Nd]
         dist0 = jnp.where(visited0, 0, DIST_UNREACHED).astype(jnp.int32)
         found0 = jnp.take(visited0, dst_rank)
+        last = _last_edges(in_iptr_rank)       # what every level's pick reads
 
-    def reached_by(prefix):
-        with jax.named_scope("bounds"):
-            bounds = jnp.take(prefix, in_iptr_rank - 1, mode="clip")
-            bounds = jnp.where(in_iptr_rank == 0, 0, bounds)
-            return (bounds[1:] - bounds[:-1]) > 0
+    def reached_by(frontier_bits, stream, n_chunks):
+        return _reached_from(
+            _reached_for(frontier_bits, stream, n_chunks, row_ends, last), nd)
 
     def visit(h, reached, visited, dist):
         with jax.named_scope("visit"):
@@ -592,8 +807,8 @@ def bfs_dist(in_src_pad, in_src_pad_d, in_iptr_rank, subjects, in_subjects,
         with jax.named_scope("prefix"):
             # src-rank space: a source with out-edges and no in-edge
             # exists only here
-            prefix = _prefix_for(subjects == src, in_src_pad, chunks)
-        return reached_by(prefix), jnp.full((width,), _INT32_MAX, jnp.int32)
+            reached = reached_by(subjects == src, in_src_pad, chunks)
+        return reached, jnp.full((width,), _INT32_MAX, jnp.int32)
 
     pushes = first_hop_pushes(degree, first_hop_cap)
     reached, row = lax.cond(pushes, push_hop, stream_hop, None)
@@ -611,16 +826,17 @@ def bfs_dist(in_src_pad, in_src_pad_d, in_iptr_rank, subjects, in_subjects,
         with jax.named_scope("push"):
             flist = jnp.pad(jnp.sort(row), (0, FRONTIER_CAP - width),
                             constant_values=_INT32_MAX)
-            prefix = active_prefix_sparse(_table_of_list(flist), in_src_pad_d)
-        return visit(h, reached_by(prefix), visited, dist)
+            reached = _reached_from(row_end_prefix_sparse(
+                _table_of_list(flist), in_src_pad_d, row_ends, last), nd)
+        return visit(h, reached, visited, dist)
 
     def body(c):
         h, fresh, visited, dist, _found = c
         with jax.named_scope("prefix"):
             # a hop>=2 frontier is a subset of destinations: gather bits
             # straight from the fresh dst-rank mask (no remap gather)
-            prefix = _prefix_for(fresh, in_src_pad_d, chunks_d)
-        return visit(h, reached_by(prefix), visited, dist)
+            reached = reached_by(fresh, in_src_pad_d, chunks_d)
+        return visit(h, reached, visited, dist)
 
     c = visit(jnp.int32(0), reached, visited0, dist0)
     c = lax.cond(pushes & cond(c), row_hop, lambda c: c, c)
@@ -663,8 +879,8 @@ def shortest_bfs(g: PullGraph, src: int, dst: int, max_hops: int):
     # a numpy array, built on the host: nothing runs on the device per
     # request but the jitted program itself
     dist = bfs_dist(
-        g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank, g.subjects,
-        g.in_subjects, g.fwd_indptr, g.fwd_dst_rank,
+        g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank, g.row_ends,
+        g.subjects, g.in_subjects, g.fwd_indptr, g.fwd_dst_rank,
         np.asarray([src, sr, dr, max_hops], dtype=np.int32),
         chunks=g.chunks, chunks_d=g.chunks_d, first_hop_cap=FIRST_HOP_CAP)
     # stages of the request's clock (obs/costs.py; no-ops without one):
@@ -793,6 +1009,8 @@ def recurse_fused_multi(in_src_pad, in_src_pad_d, in_iptr_rank, subjects,
 JIT_PROGRAMS = {
     "pb.active_prefix": active_prefix,
     "pb.active_prefix_sparse": active_prefix_sparse,
+    "pb.row_end_prefix": row_end_prefix,
+    "pb.row_end_prefix_sparse": row_end_prefix_sparse,
     "pb.pack_mask_rows": pack_mask_rows,
     "pb.pack_mask": pack_mask,
     "pb.recurse_step": recurse_step,

@@ -6,7 +6,9 @@ reference's bp128-unpack + per-uid posting iteration hot loop
 to a plain host BFS across the shape edge cases the kernel's blocking
 scheme creates: sparse<->dense frontier switch at FRONTIER_CAP, bitmap
 chunk boundaries (num_nodes = 32768 +/- 1), edge streams not divisible by
-EDGE_BLOCK, multi-chunk bitmaps, and empty frontiers.
+EDGE_BLOCK, multi-chunk bitmaps, empty frontiers, and where destination rows
+end relative to the edge blocks and the rank tiles (a search's kernels hand
+back one value a row, picked inside the block the row ends in).
 
 Every case runs under each program that can express it:
   push / stream  bfs_dist (`shortest`) from single roots, its first level
@@ -15,6 +17,8 @@ Every case runs under each program that can express it:
                  with more than one out-edge streams);
   recurse        recurse_fused (`@recurse`) from the whole seed set.
 """
+
+import hashlib
 
 import numpy as np
 import pytest
@@ -79,8 +83,8 @@ def check_search(g, csr, root, hops, first_hop_cap):
     visited, _traversed, dist = host_k_hop(*csr, [root], g.num_nodes, hops)
     want = dist[g.host_in_subjects]
     labels = np.asarray(pb.bfs_dist(
-        g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank, g.subjects,
-        g.in_subjects, g.fwd_indptr, g.fwd_dst_rank,
+        g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank, g.row_ends,
+        g.subjects, g.in_subjects, g.fwd_indptr, g.fwd_dst_rank,
         np.asarray([root, pb._source_row(g, root)[0], int(np.argmax(want)),
                     hops], dtype=np.int32),
         chunks=g.chunks, chunks_d=g.chunks_d, first_hop_cap=first_hop_cap))
@@ -297,6 +301,168 @@ def test_dense_seed_frontier(rng):
     subjects, indptr, indices = random_csr(rng, num_nodes, 30000)
     seeds = np.unique(rng.choice(subjects, size=pb.FRONTIER_CAP + 500))
     run_both("recurse", subjects, indptr, indices, seeds, num_nodes, hops=2)
+
+
+def planted_csr(rng, in_degrees, num_nodes):
+    """A graph whose destination rank v (uid v + 1) has exactly
+    in_degrees[v] in-edges from distinct sources, so where each row ends
+    in the dst-sorted stream is the caller's choice. Sources are uniform,
+    but: the root (uid num_nodes - 1, never a destination) points at three
+    destinations, and each of those at about 70% of all rows — level 2 of a
+    search from the root reaches most of the graph, so level 3's frontier is
+    dense wherever there are enough destinations. Returns the CSR and the
+    root."""
+    nd = len(in_degrees)
+    root = num_nodes - 1
+    mids = 1 + rng.choice(np.flatnonzero(np.asarray(in_degrees) >= 4),
+                          size=3, replace=False)
+    src, dst = [], []
+    for v, d in enumerate(in_degrees):
+        row = rng.choice(num_nodes - 1, size=d, replace=False)
+        for j, m in enumerate(mids[: d]):
+            if rng.random() < 0.7 and m not in row:
+                row[j] = m
+        if v + 1 in mids and root not in row:
+            row[3] = root
+        src.append(row)
+        dst.append(np.full(d, v + 1))
+    assert nd + 1 < num_nodes
+    return csr_of(np.concatenate(src), np.concatenate(dst)), root
+
+
+def _fill(rng, n_rows):
+    return rng.integers(1, 6, size=n_rows).tolist()
+
+
+# in-degrees by destination rank, by what the stream's first blocks hold
+ROW_END_SHAPES = {
+    # row 1 ends on block 0's last lane, row 2 starts on block 1's first
+    "last_lane_first_lane": lambda rng: (
+        [pb.EDGE_BLOCK - 192, 192, 300] + _fill(rng, 6000)),
+    # row 1 runs from block 0 into block 2: no row ends in block 1
+    "row_spans_three_blocks": lambda rng: (
+        [100, 2 * pb.EDGE_BLOCK + 3000] + _fill(rng, 6000)),
+    # block 1 holds EDGE_BLOCK rows of one edge: nine rank tiles' worth
+    "every_edge_ends_a_row": lambda rng: (
+        [pb.EDGE_BLOCK] + [1] * pb.EDGE_BLOCK + _fill(rng, 2000)),
+}
+
+
+@programs
+@pytest.mark.parametrize("shape", sorted(ROW_END_SHAPES))
+def test_row_ends_against_the_edge_blocks(rng, shape, program):
+    """Where rows end relative to the kernel's edge blocks: a row-end kernel
+    picks a row's value in the block the row ends in, whatever block it
+    began in. Level 2 runs the sparse kernel, level 3 the dense one."""
+    in_degrees = ROW_END_SHAPES[shape](rng)
+    (subjects, indptr, indices), root = planted_csr(rng, in_degrees, 24_000)
+    g = pb.prep_pull(subjects, indptr, indices, 24_000)
+    np.testing.assert_array_equal(np.diff(g.host_in_iptr), in_degrees)
+    ends_in = np.bincount((g.host_in_iptr[1:] - 1) // pb.EDGE_BLOCK)
+    last = g.host_in_iptr[1:] - 1
+    assert {"last_lane_first_lane": pb.EDGE_BLOCK - 1 in last
+            and pb.EDGE_BLOCK in g.host_in_iptr,
+            "row_spans_three_blocks": ends_in[1] == 0 and ends_in[2] > 0,
+            "every_edge_ends_a_row": ends_in[1] == pb.EDGE_BLOCK}[shape]
+    # level 3's frontier (level 2's fresh vertices) takes the dense kernel
+    _visited, _trav, dist = host_k_hop(subjects, indptr, indices, [root],
+                                       24_000, 3)
+    assert np.count_nonzero(dist == 2) > pb.SPARSE_MAX
+    assert np.count_nonzero(dist == 3) > 0
+    out = run_both(program, subjects, indptr, indices, np.array([root]),
+                   24_000, hops=3)
+    if program != "recurse":
+        assert out == [program == "push"]
+
+
+@programs
+@pytest.mark.parametrize("nd", [127, 128, 129, 1023, 1024, 1025,
+                                5119, 5120, 5121])
+def test_destination_count_against_the_rank_tiles(rng, nd, program):
+    """Nd one under, at and over a lane row (128) and a rank tile
+    (RANK_TILE = 1024) of the row-end kernels' output; from 5 * 1024 on,
+    level 3's frontier is dense."""
+    assert pb.RANK_TILE == 1024
+    num_nodes = nd + 500
+    (subjects, indptr, indices), root = planted_csr(
+        rng, _fill(rng, nd), num_nodes)
+    g = pb.prep_pull(subjects, indptr, indices, num_nodes)
+    assert len(g.host_in_subjects) == nd
+    _visited, _trav, dist = host_k_hop(subjects, indptr, indices, [root],
+                                       num_nodes, 3)
+    assert (np.count_nonzero(dist == 2) > pb.SPARSE_MAX) == (nd > 5000)
+    run_both(program, subjects, indptr, indices, np.array([root]),
+             num_nodes, hops=3)
+
+
+@pytest.mark.parametrize("shape", sorted(ROW_END_SHAPES))
+def test_row_end_items_hold_every_row_once(rng, shape):
+    """prep_pull's RowEnds: every destination rank has exactly one item of
+    its rank tile and of the edge block its last in-edge is in; every edge
+    block has an item; items come in stream order, in a multiple of the
+    class size, the padding repeating the last item."""
+    in_degrees = ROW_END_SHAPES[shape](rng)
+    (subjects, indptr, indices), _root = planted_csr(rng, in_degrees, 24_000)
+    g = pb.prep_pull(subjects, indptr, indices, 24_000)
+    block, tile = (np.asarray(a) for a in g.row_ends)
+    n_blocks = g.in_src_pad.shape[0] // pb.EDGE_BLOCK
+    assert len(block) % pb._ITEM_CLASS == 0 and len(block) == len(tile)
+    items = list(zip(block.tolist(), tile.tolist()))
+    real = sorted(set(items))
+    assert items == real + [real[-1]] * (len(items) - len(real))
+    assert (np.diff(tile) >= 0).all()
+    last = g.host_in_iptr[1:] - 1
+    want = set(zip((last // pb.EDGE_BLOCK).tolist(),
+                   (np.arange(len(last)) // pb.RANK_TILE).tolist()))
+    assert want <= set(real)
+    # what is there beyond that: one item for each block no row ends in
+    assert sorted(b for b, _t in set(real) - want) == sorted(
+        set(range(n_blocks)) - set((last // pb.EDGE_BLOCK).tolist()))
+    # the positions the kernels read, on the device: rank v at [v // 128,
+    # v % 128], -1 past the last rank
+    got = np.asarray(pb._last_edges(g.in_iptr_rank)).reshape(-1)
+    np.testing.assert_array_equal(got[: len(last)], last)
+    assert (got[len(last):] == -1).all() and len(got) % pb.RANK_TILE == 0
+
+
+# sha256 of the lowered text (CPU: the kernels in interpret mode, inlined)
+# of the recurse programs for rmat_csr(12, 8, seed=5) at commit 8092e9c
+# (PR 32) — the programs a search shares its membership tests with. A PR
+# that means to change a recurse program replaces its line
+RECURSE_LOWERINGS = {
+    "recurse_fused":
+        "c5ba443bcac0843511372fc4f08e1c433210b2c838604d14bf8b72394dea5187",
+    "recurse_fused_multi":
+        "b0970b841e082259d3c96d3575fc56558b4bce8daf9775a1e8d47b1b6fc4b283",
+    "recurse_step":
+        "d11239991ffe57f200fad83652dcde5c159e18cf5f3d24a41811e592a463ce76",
+}
+
+
+@pytest.mark.parametrize("program", sorted(RECURSE_LOWERINGS))
+def test_recurse_programs_lower_to_the_text_they_had(program):
+    """The shared lookups (_active_dense, _active_sparse) were factored out
+    of the prefix kernels for the search's own emit: the recurse programs
+    still lower to the text they had before, character for character."""
+    subjects, indptr, indices = rmat_csr(12, 8, seed=5)
+    n = int(max(subjects.max(), indices.max())) + 1
+    g = pb.prep_pull(subjects, indptr, indices, n)
+    fused = dict(depth=3, chunks=g.chunks, chunks_d=g.chunks_d,
+                 allow_loop=False)
+    layout = (g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank, g.subjects,
+              g.in_subjects)
+    lowered = {
+        "recurse_fused": lambda: pb.recurse_fused.lower(
+            *layout, jnp.zeros((n,), bool), **fused),
+        "recurse_fused_multi": lambda: pb.recurse_fused_multi.lower(
+            *layout, jnp.zeros((2, n), bool), **fused),
+        "recurse_step": lambda: pb.recurse_step.lower(
+            g.in_src_pad, g.in_iptr_rank, g.subjects, g.in_subjects,
+            jnp.zeros((n,), bool), jnp.zeros(g.in_src_pad.shape, bool),
+            chunks=g.chunks, num_nodes=n, allow_loop=False),
+    }[program]().as_text()
+    assert hashlib.sha256(lowered.encode()).hexdigest() == \
+        RECURSE_LOWERINGS[program]
 
 
 def test_prep_pull_rejects_out_of_range_uids():

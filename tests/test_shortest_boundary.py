@@ -53,8 +53,8 @@ def _labels(g, src, dst, max_hops):
     out = np.flatnonzero(g.host_subjects == src)
     sr = int(out[0]) if len(out) else len(g.host_subjects)
     return np.asarray(pb.bfs_dist(
-        g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank, g.subjects,
-        g.in_subjects, g.fwd_indptr, g.fwd_dst_rank,
+        g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank, g.row_ends,
+        g.subjects, g.in_subjects, g.fwd_indptr, g.fwd_dst_rank,
         np.asarray([src, sr, dr, max_hops], dtype=np.int32),
         chunks=g.chunks, chunks_d=g.chunks_d,
         first_hop_cap=pb.FIRST_HOP_CAP))

@@ -714,8 +714,8 @@ def test_bfs_dist_lowering_holds_the_scope_names():
     indices = np.arange(2, 10, dtype=np.int64)
     g = pb.prep_pull(subjects, indptr, indices, 10)
     text = pb.bfs_dist.lower(
-        g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank, g.subjects,
-        g.in_subjects, g.fwd_indptr, g.fwd_dst_rank,
+        g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank, g.row_ends,
+        g.subjects, g.in_subjects, g.fwd_indptr, g.fwd_dst_rank,
         np.asarray([1, 0, 0, 4], dtype=np.int32),
         chunks=g.chunks, chunks_d=g.chunks_d
     ).as_text(debug_info=True)
