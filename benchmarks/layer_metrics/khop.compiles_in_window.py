@@ -1,0 +1,8 @@
+"""Programs compiled or loaded from the persistent cache between the `khop`
+window's first and last request (/debug/compiles: compiles + cache hits).
+Should be 0: four static depths, each warmed in set-up."""
+
+
+def read(run):
+    return float(run.after["programs_loaded"]
+                 - run.before["programs_loaded"])

@@ -1,0 +1,5 @@
+"""k-hop neighbour count, k = 2: harness/khop.py holds the op."""
+
+from harness.khop import answer, draw_for, parse, request, verify  # noqa: F401
+
+draw = draw_for(2)

@@ -23,6 +23,19 @@ TPU shape — one hot path, benched and served alike (worker/task.go:605):
     dispatch+sync cost, host numpy wins).
   * Tablet-routed (is_dist) predicates expand over the wire with
     (attr, from, to) edge-key dedup, exactly recurse.go:129-141.
+
+Variables. The block's own uid variable holds its roots, as any block's.
+A uid variable on a CHILD (`{ var(func: uid(r)) @recurse(depth: k)
+{ v as follows } }`) holds the sorted union of that child's destinations
+over every level (query/query.go populateUidValVar merges a recurse
+child's levels). With `loop: false` the traversal dedups edges, so a
+level's destinations are those of its fresh edges: on an undirected graph
+stored in both directions `v` is every vertex within k hops of the root,
+plus the root itself from k = 2 on (it comes back over the reverse edge).
+All three tiers record through `_record_vars`; the plain reference is
+dgraph_tpu/models/khop.py. A `var` block renders nothing, so the fused
+tier builds no SubGraph chain for it: no LazyRecurseMatrix, no fresh-flag
+fetch, one OR over the fetched level masks.
 """
 
 from __future__ import annotations
@@ -33,7 +46,7 @@ import jax.numpy as jnp
 
 from dgraph_tpu.obs import costs, otrace
 from dgraph_tpu.query import dql
-from dgraph_tpu.query.engine import QueryError, SubGraph
+from dgraph_tpu.query.engine import QueryError, SubGraph, VarValue
 from dgraph_tpu.query.task import TaskQuery, process_task
 from dgraph_tpu.utils.types import TypeID
 
@@ -88,18 +101,21 @@ class LazyRecurseMatrix:
     materialization is inherently ragged → host-side by design)."""
 
     def __init__(self, csr, g, frontier: np.ndarray, fresh: FreshFlags,
-                 level, allow_loop: bool):
+                 level, allow_loop: bool, metrics=None):
         self._csr = csr
         self._g = g
         self._frontier = np.asarray(frontier, dtype=np.int64)
         self._fresh = fresh
         self._level = level              # row of the stacked buffer, or None
         self._allow_loop = allow_loop
+        self._metrics = metrics
         self._rows: list[np.ndarray] | None = None
 
     def _materialize(self) -> list[np.ndarray]:
         if self._rows is not None:
             return self._rows
+        if self._metrics is not None:
+            self._metrics.counter("dgraph_recurse_materialized_total").inc()
         pos, offs, targets = _gather_frontier_edges(self._csr, self._frontier)
         if self._allow_loop:
             keep = np.ones(len(pos), dtype=bool)
@@ -195,6 +211,47 @@ def _seeds_mask(uids: np.ndarray, num_nodes: int) -> jnp.ndarray:
     return m
 
 
+def _record_vars(ex, sg: SubGraph, level_dests: dict) -> None:
+    """The one place a recurse block's variables are recorded, whichever
+    tier ran it: the block's own (its roots), and for each uid child that
+    names one the sorted union of its destinations over all levels
+    (`level_dests`: id(child gq) -> the levels' destination arrays)."""
+    gq = sg.gq
+    ex._record_uid_var(gq, sg)
+    for cgq in gq.children:
+        parts = level_dests.get(id(cgq))
+        if parts is None:
+            continue
+        # a level's destinations are sorted and distinct already: only
+        # several levels need the merge
+        ex.vars[cgq.var_name] = VarValue(
+            uids=parts[0] if len(parts) == 1
+            else np.unique(np.concatenate(parts)) if parts
+            else np.zeros(0, np.int64))
+
+
+def _book_edges(attr: str, n: int) -> None:
+    """Edges a level traversed here (host mirror, stepped or fused kernel,
+    the mesh tier's replay), on the request's cost ledger, as the
+    dispatched tasks book theirs (engine run_ledgered: the wire tier's
+    levels go that way). Without it a host-mirror recurse closes a
+    ledger that says nothing ran, and /debug/top drops the request."""
+    lg = costs.current()
+    if lg is not None:
+        lg.add_task(attr[1:] if attr.startswith("~") else attr, n)
+
+
+def _count_levels(ex, live: int, empty: int = 0) -> None:
+    """dgraph_recurse_levels_total: levels a device recurse program ran,
+    by whether their frontier held a vertex. The fused scan runs all
+    `depth` of them; a stepped level is live by construction."""
+    metrics = getattr(ex.snap, "metrics", None)
+    if metrics is not None:
+        metrics.keyed("dgraph_recurse_levels_total",
+                      labels=("state",)).inc_many(
+            {"live": live, "empty": empty})
+
+
 def recurse(ex, sg: SubGraph) -> None:
     gq = sg.gq
     spec = gq.recurse
@@ -209,6 +266,10 @@ def recurse(ex, sg: SubGraph) -> None:
     kstates: dict[str, dict] = {}              # kernel path: attr -> g, seen
     seen_edges: set[tuple[str, int, int]] = set()   # dist-CSR fallback only
     edges = 0
+    # id(child gq) -> each level's destinations, for the children that
+    # name a uid variable (_record_vars)
+    level_dests: dict[int, list[np.ndarray]] = {
+        id(c): [] for c in uid_children if c.var_name and not c.is_count}
 
     def _csr_for(cgq):
         attr = cgq.attr
@@ -240,8 +301,9 @@ def recurse(ex, sg: SubGraph) -> None:
         cgq = uid_children[0]
         csr = _csr_for(cgq)
         if _use_kernel(csr):
-            _recurse_fused_path(ex, sg, cgq, csr, depth, spec.allow_loop)
-            ex._record_uid_var(gq, sg)
+            _recurse_fused_path(ex, sg, cgq, csr, depth, spec.allow_loop,
+                                level_dests)
+            _record_vars(ex, sg, level_dests)
             return
     # ---- mesh fused path: single uid child, filters compile to allow-set
     # formulas, value children layer host-side per level (ISSUE 12) ---------
@@ -278,8 +340,8 @@ def recurse(ex, sg: SubGraph) -> None:
             if ok:
                 _mesh_recurse_path(ex, sg, cgq, csr, depth,
                                    spec.allow_loop, mesh, formula, sets,
-                                   val_children)
-                ex._record_uid_var(gq, sg)
+                                   val_children, level_dests)
+                _record_vars(ex, sg, level_dests)
                 return
 
     def build_level(frontier: np.ndarray, remaining: int) -> list[SubGraph]:
@@ -325,12 +387,15 @@ def recurse(ex, sg: SubGraph) -> None:
                     (dest_words_h, trav_h), seen2, fresh = ex.gated(
                         _step, klass="recurse")
                 st["seen"] = seen2
+                _count_levels(ex, live=1)
+                _book_edges(cgq.attr, int(trav_h))
                 edges += int(trav_h)
                 if edges > ex.edge_budget():
                     raise QueryError(
                         "recurse exceeded edge budget (ErrTooBig)")
                 m = LazyRecurseMatrix(csr, g, frontier, FreshFlags(fresh),
-                                      None, spec.allow_loop)
+                                      None, spec.allow_loop,
+                                      getattr(ex.snap, "metrics", None))
                 child.uid_matrix = m
                 child.counts = LazyCounts(m)
                 child.dest_uids = np.flatnonzero(pb.unpack_words(
@@ -343,6 +408,7 @@ def recurse(ex, sg: SubGraph) -> None:
                     csr, frontier, seen_masks.get(cgq.attr),
                     spec.allow_loop) if len(frontier)
                     else ([], 0))
+                _book_edges(cgq.attr, total)
                 edges += total
                 if edges > ex.edge_budget():
                     raise QueryError(
@@ -368,18 +434,20 @@ def recurse(ex, sg: SubGraph) -> None:
                     matrix.append(np.asarray(kept, dtype=np.int64))
                 _set_list_result(child, matrix)
             child.dest_uids = ex._apply_filter(cgq.filter, child.dest_uids)
+            if id(cgq) in level_dests:
+                level_dests[id(cgq)].append(child.dest_uids)
             if len(child.dest_uids):
                 child.children = build_level(child.dest_uids, remaining - 1)
             out.append(child)
         return out
 
     sg.children = build_level(sg.dest_uids, depth)
-    ex._record_uid_var(gq, sg)
+    _record_vars(ex, sg, level_dests)
 
 
 def _mesh_recurse_path(ex, sg: SubGraph, cgq, csr, depth: int,
                        allow_loop: bool, mesh, formula=None, sets=None,
-                       val_children=()) -> None:
+                       val_children=(), level_dests=()) -> None:
     """All levels of a mesh-sharded recurse in ONE device dispatch: the
     seen-edge vector lives per shard on device across levels, the fresh
     dest blocks all-gather into the next frontier over ICI, and the
@@ -418,6 +486,7 @@ def _mesh_recurse_path(ex, sg: SubGraph, cgq, csr, depth: int,
         if depth - lvl > 0:
             matrix, total = _expand_dedup(csr, fr_sorted, seen,
                                           allow_loop)
+            _book_edges(cgq.attr, total)
             cum += total
             if cum > ex.edge_budget():
                 raise QueryError("recurse exceeded edge budget (ErrTooBig)")
@@ -425,6 +494,8 @@ def _mesh_recurse_path(ex, sg: SubGraph, cgq, csr, depth: int,
             _set_list_result(child, matrix)
             child.dest_uids = ex._apply_filter(cgq.filter,
                                                child.dest_uids)
+            if id(cgq) in level_dests:
+                level_dests[id(cgq)].append(child.dest_uids)
             cur.append(child)
             # cross-check the device program's level frontiers against
             # the host replay (the host — which evaluates the REAL
@@ -442,23 +513,43 @@ def _mesh_recurse_path(ex, sg: SubGraph, cgq, csr, depth: int,
         frontier = child.dest_uids
 
 
+def _fused_levels(masks_h: np.ndarray) -> tuple[int, np.ndarray]:
+    """(levels of a fused scan whose frontier held a vertex, OR of their
+    packed destination masks). The scan runs every level: after the first
+    one that reached nothing, each streams the graph for an empty
+    frontier and hands back an all-zero mask."""
+    depth = masks_h.shape[0]
+    reached = masks_h.reshape(depth, -1).any(axis=1)
+    live = depth if reached.all() else int(np.argmin(reached)) + 1
+    return live, np.bitwise_or.reduce(masks_h[:live], axis=0)
+
+
 def _recurse_fused_path(ex, sg: SubGraph, cgq, csr, depth: int,
-                        allow_loop: bool) -> None:
+                        allow_loop: bool, level_dests: dict) -> None:
     """All levels in one device dispatch; SubGraph chain built from the
     stacked per-level masks. Matches build_level's output for the
-    single-uid-child no-filter shape exactly (tests equality-gate it)."""
+    single-uid-child no-filter shape exactly (tests equality-gate it).
+
+    Stages of the request's clock (obs/costs.py): pull_graph_for is
+    exec.prep; the seed mask's eager programs and the jitted call are
+    dev.dispatch; blocked in the fetch is dev.wait; everything the host
+    does with the fetched masks is dev.post."""
     from dgraph_tpu.ops import pallas_bfs as pb
 
-    g = pb.pull_graph_for(csr)
+    with costs.stage("exec.prep"):
+        g = pb.pull_graph_for(csr)
+    nd = len(g.host_in_subjects)
     seeds = np.sort(np.asarray(sg.dest_uids, dtype=np.int64))
-    seeds_mask = _seeds_mask(seeds, g.num_nodes)
+    with costs.stage("dev.dispatch"):
+        seeds_mask = _seeds_mask(seeds, g.num_nodes)
     # batched-dispatch seam (query/batch.py): compatible concurrent
     # traversals stack their seed masks into one multi-source dispatch;
     # without a batcher this is exactly the old gated solo call
     def _solo_fused():
         with otrace.span("device_kernel", kernel="pb.recurse_fused",
                          depth=depth, edges=g.num_edges) as sp, \
-                costs.kernel("pb.recurse_fused", attr=cgq.attr) as ck:
+                costs.kernel("pb.recurse_fused", attr=cgq.attr,
+                             stage="dev.dispatch") as ck:
             masks_p, trav, fresh = pb.recurse_fused(
                 g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank, g.subjects,
                 g.in_subjects, seeds_mask,
@@ -466,39 +557,56 @@ def _recurse_fused_path(ex, sg: SubGraph, cgq, csr, depth: int,
                 allow_loop=allow_loop)
             # the fetch is the fence: dispatch is asynchronous, so the
             # timer (and the gate slot) must cover it to book device time
-            masks_h, trav_h = jax.device_get((masks_p, trav))
-            d2h = int(masks_h.nbytes + trav_h.nbytes)
-            ck.set(h2d=int(seeds_mask.nbytes), d2h=d2h)
-            if sp:
-                sp.set(transfer_h2d_bytes=int(seeds_mask.nbytes),
-                       transfer_d2h_bytes=d2h)
+            with costs.stage("dev.wait"):
+                masks_h, trav_h = jax.device_get((masks_p, trav))
+            with costs.stage("dev.post"):
+                d2h = int(masks_h.nbytes + trav_h.nbytes)
+                ck.set(h2d=int(seeds_mask.nbytes), d2h=d2h)
+                if sp:
+                    live, union = _fused_levels(masks_h)
+                    sp.set(transfer_h2d_bytes=int(seeds_mask.nbytes),
+                           transfer_d2h_bytes=d2h, levels_live=live,
+                           reached=int(pb.unpack_words(union, nd).sum()))
             return masks_h, trav_h, fresh
 
     masks_p, trav, fresh = ex.batched_recurse(
         g, seeds_mask, depth, allow_loop, _solo_fused)
-    # ONE host round-trip for the whole traversal, bit-packed in DST-RANK
-    # space (fresh flags stay on device until a lazy uidMatrix
-    # materialization needs them); host maps ranks -> uids. A no-op after
-    # the solo closure, which fetched under its timer; the batched runner
-    # hands back device slices.
-    masks_h, trav_h = jax.device_get((masks_p, trav))
-    nd = len(g.host_in_subjects)
-    shared_fresh = FreshFlags(fresh)
-    frontier = seeds
-    attach = sg.children = []
-    cum = 0
-    for lvl in range(depth):
-        if len(frontier) == 0:
-            break
-        cum += int(trav_h[lvl])
-        if cum > ex.edge_budget():
+    with costs.stage("dev.post"):
+        # ONE host round-trip for the whole traversal, bit-packed in
+        # DST-RANK space (fresh flags stay on device until a lazy
+        # uidMatrix materialization needs them); host maps ranks -> uids.
+        # A no-op after the solo closure, which fetched under its timer;
+        # the batched runner hands back device slices.
+        masks_h, trav_h = jax.device_get((masks_p, trav))
+        live, union = _fused_levels(masks_h)
+        _count_levels(ex, live, depth - live)
+        traversed = int(trav_h[:live].sum())
+        _book_edges(cgq.attr, traversed)
+        if traversed > ex.edge_budget():
             raise QueryError("recurse exceeded edge budget (ErrTooBig)")
-        child = SubGraph(gq=cgq, attr=cgq.attr, src_uids=frontier)
-        m = LazyRecurseMatrix(csr, g, frontier, shared_fresh, lvl, allow_loop)
-        child.uid_matrix = m
-        child.counts = LazyCounts(m)
-        ranks = np.flatnonzero(pb.unpack_words(masks_h[lvl], nd))
-        child.dest_uids = g.host_in_subjects[ranks].astype(np.int64)
-        attach.append(child)
-        attach = child.children
-        frontier = child.dest_uids
+
+        def uids_of(words) -> np.ndarray:
+            ranks = np.flatnonzero(pb.unpack_words(words, nd))
+            return g.host_in_subjects[ranks].astype(np.int64)
+
+        if sg.gq.attr == "var":
+            # nothing renders: the variable is all a later block can read
+            if id(cgq) in level_dests:
+                level_dests[id(cgq)].append(uids_of(union))
+            return
+        shared_fresh = FreshFlags(fresh)
+        metrics = getattr(ex.snap, "metrics", None)
+        frontier = seeds
+        attach = sg.children = []
+        for lvl in range(live):
+            child = SubGraph(gq=cgq, attr=cgq.attr, src_uids=frontier)
+            m = LazyRecurseMatrix(csr, g, frontier, shared_fresh, lvl,
+                                  allow_loop, metrics)
+            child.uid_matrix = m
+            child.counts = LazyCounts(m)
+            child.dest_uids = uids_of(masks_h[lvl])
+            if id(cgq) in level_dests:
+                level_dests[id(cgq)].append(child.dest_uids)
+            attach.append(child)
+            attach = child.children
+            frontier = child.dest_uids

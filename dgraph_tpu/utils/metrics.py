@@ -327,6 +327,10 @@ class Registry:
                      "dgraph_vector_ivf_probes_total",
                      "dgraph_vector_fused_pipelines_total",
                      "dgraph_vector_mesh_dispatches_total",
+                     # @recurse level matrices materialised on the host
+                     # (query/recurse.py LazyRecurseMatrix): stays flat
+                     # while only variables and counts of unions are read
+                     "dgraph_recurse_materialized_total",
                      # self-driving shard placement (coord/placement.py;
                      # ISSUE 10): controller ticks, actions, replica
                      # freshness ships, and the replica read/fallback
@@ -496,6 +500,11 @@ class Registry:
         # mode="push" reads the root's forward row, "stream" every in-edge
         self.keyed_gauges["dgraph_bfs_first_hop_total"] = KeyedGauge(
             labels=("mode",), keep=("push", "stream"))
+        # levels the device @recurse programs ran (query/recurse.py): a
+        # fused scan runs all `depth` of them, "empty" are those whose
+        # frontier held no vertex — a whole stream of the graph for nothing
+        self.keyed_gauges["dgraph_recurse_levels_total"] = KeyedGauge(
+            labels=("state",), keep=("live", "empty"))
         # serve's start-up phases, set once before the banner
         # (__main__.cmd_serve): import / backend_init / store_open / listen
         self.keyed_gauges["dgraph_startup_ms"] = KeyedGauge(

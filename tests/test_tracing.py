@@ -353,12 +353,15 @@ import threading
 import time
 
 from dgraph_tpu.obs import costs
+from dgraph_tpu.query import recurse as recmod
 from dgraph_tpu.query import shortest as shmod
 
 CHAIN = "\n".join(f"<0x{i:x}> <follows> <0x{i + 1:x}> ." for i in range(1, 9)) \
     + '\n<0x1> <name> "ann" .\n<0x9> <name> "zed" .'
 SHORTEST = ("{ p as shortest(from: 0x1, to: 0x6) { follows } "
             "r(func: uid(p)) { uid } }")
+KHOP = ("{ var(func: uid(0x6)) @recurse(depth: 5) { v as follows } "
+        "khop(func: uid(v)) { count(uid) } }")
 
 
 def _chain_node(**kw):
@@ -386,7 +389,11 @@ def _closed(node, n, timeout=5.0):
 
 def _force_tier(monkeypatch, tier):
     """`shortest` on the Pallas kernel tier (interpret mode off-TPU) or on
-    the Bellman-Ford tier, on a graph far below either floor."""
+    the Bellman-Ford tier, or `@recurse` on its kernel tier, on a graph
+    far below every floor."""
+    if tier == "recurse":
+        monkeypatch.setattr(recmod, "KERNEL_MIN_EDGES", 0)
+        return
     monkeypatch.setattr(shmod, "DEVICE_SSSP_MIN_EDGES", 0)
     monkeypatch.setattr(shmod, "SSSP_KERNEL_MIN",
                         0 if tier == "kernel" else 1 << 62)
@@ -405,6 +412,9 @@ TILE_CASES = {
     "shortest_sssp": (SHORTEST, "sssp",
                       {"parse", "plan", "exec", "dev.dispatch", "dev.wait",
                        "dev.post", "encode"}, {"dev.window", "exec.prep"}),
+    "khop_fused": (KHOP, "recurse",
+                   {"parse", "plan", "exec", "exec.prep", "dev.dispatch",
+                    "dev.wait", "dev.post", "encode"}, {"dev.window"}),
 }
 
 
@@ -619,6 +629,44 @@ def test_metrics_carry_stage_kernel_and_startup_series(monkeypatch):
     assert labelled("dgraph_startup_ms", "phase") == {
         "import": 1250, "backend_init": 500, "store_open": 1500,
         "listen": 250}
+
+
+def test_fused_recurse_span_says_what_the_traversal_did(monkeypatch):
+    """The pb.recurse_fused window is split like pb.bfs_dist's, and its
+    device_kernel span carries depth, levels_live and reached."""
+    _force_tier(monkeypatch, "recurse")
+    node = _chain_node(span_sample=1.0, trace_rng=random.Random(5))
+    srv, base = _serve(node)
+    try:
+        node.query(KHOP.replace("0x6", "0x5"))            # compile first
+        out = _post(base, "/query", KHOP)
+        _closed(node, 2)
+        rec = node.tracer.sink.get(node.tracer.sink.index(1)[0]["trace_id"])
+        series = prom.parse(_get(base, "/metrics")[1].decode())
+    finally:
+        srv.shutdown()
+        node.close()
+    # 0x6 -> 0x7 -> 0x8 -> 0x9, which has no out-edge: the fourth level's
+    # frontier is {0x9}, the fifth's is empty
+    assert out["data"] == {"khop": [{"count": 3}]}
+    spans = rec["spans"]
+    dk = [s for s in spans if s["name"] == "device_kernel"]
+    assert [s["attrs"]["kernel"] for s in dk] == ["pb.recurse_fused"]
+    attrs = dk[0]["attrs"]
+    assert (attrs["depth"], attrs["levels_live"], attrs["reached"]) == \
+        (5, 4, 3)
+    inside = [s["name"] for s in sorted(
+        (s for s in spans if s["kind"] == "stage"
+         and s["parent_id"] == dk[0]["span_id"]), key=lambda s: s["start"])]
+    # a scope that ends hands back to the one around it for an instant
+    assert list(dict.fromkeys(inside))[:3] == ["dev.dispatch", "dev.wait",
+                                               "dev.post"]
+    assert "dev.window" not in inside
+    levels = {lb["state"]: v
+              for lb, v in series["dgraph_recurse_levels_total"]}
+    # two requests: from 0x5 all five levels hold a vertex
+    assert levels == {"live": 9, "empty": 1}
+    assert series["dgraph_recurse_materialized_total"][0][1] == 0
 
 
 def test_first_hop_is_counted_by_mode_and_named_on_the_span(monkeypatch):
