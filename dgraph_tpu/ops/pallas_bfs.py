@@ -19,32 +19,35 @@ a Pallas kernel where it can't miss:
     read: 4 bytes per edge, at streaming rate.
   - the kernel fuses the inclusive prefix-sum of the per-edge active
     flags (two-level lane/sublane scan + a sequential-grid carry in
-    SMEM), so the XLA side needs no E-sized cumsum: per-node reachability
-    is diff-of-prefix at the dense in-CSR row boundaries — node-sized.
-  - two emits over that one lookup + scan. A recurse level dedups EDGES,
-    so its kernels (active_prefix*) write the whole prefix back, 4 bytes
-    per edge, and XLA gathers it at the row boundaries. A search level
-    wants only VERTICES, so its kernels (row_end_prefix*) keep the block's
-    prefix in VMEM, pick it at the ends of the rows that end in the block
-    (a graph-static table of in-block positions, RowEnds) and write one
-    int32 per destination rank: nothing edge-sized is written and XLA
-    gathers nothing.
+    SMEM) and keeps the block's prefix in VMEM: it picks it at the ends of
+    the rows that end in the block (a graph-static table of in-block
+    positions, RowEnds) and writes one int32 per destination rank.
+    Nothing edge-sized is written and XLA gathers nothing: per-node
+    reachability is a diff of neighbouring ranks — node-sized.
+  - one emit (row_end_prefix*) for every program. A search wants the
+    VERTICES a frontier reaches. A recurse dedups EDGES, but an edge is
+    active by its source alone, so every out-edge of a vertex is first
+    traversed in the one level that vertex is first in a frontier: "edge
+    e was traversed before" is "src(e) was in an earlier frontier", a
+    node-sized set (`expanded`), and a level's fresh edges are the
+    out-edges of `frontier & ~expanded` — again a frontier to reach from.
 
 Per hop:   active[e] = frontier_bit[in_src[e]]          (Pallas, streaming)
            prefix    = cumsum(active)                   (fused in kernel)
-  recurse: reached_v = prefix[iptr[v+1]-1] - prefix[iptr[v]-1] > 0
-                                    (XLA gather of the E-sized prefix)
-  search:  bounds_v  = prefix[iptr[v+1]-1]       (picked in the kernel)
+           bounds_v  = prefix[iptr[v+1]-1]       (picked in the kernel)
            reached_v = bounds_v - bounds_{v-1} > 0         (node-sized)
-           frontier' = reached & ~visited               (node-sized)
+  search:  frontier' = reached & ~visited               (node-sized)
+  recurse: frontier' = reached; the kernel's frontier is
+           frontier & ~expanded; expanded |= frontier   (node-sized)
 
 Reference semantics preserved: `traversed` counts every out-edge of every
-frontier node per level (== active in-edges). Three programs are built on
-the kernel, and they are what a request reaches: bfs_dist (`shortest`,
-query/shortest.py), recurse_fused / recurse_fused_multi (`@recurse` alone
-and batched, query/recurse.py and query/batch.py) and recurse_step (a
-recurse that needs the host between levels). tests/test_pallas_bfs.py holds
-them to a plain host BFS at every edge of the blocking scheme.
+frontier node per level (re-entered vertices included). Three programs
+are built on the kernel, and they are what a request reaches: bfs_dist
+(`shortest`, query/shortest.py), recurse_fused / recurse_fused_multi
+(`@recurse` alone and batched, query/recurse.py and query/batch.py) and
+recurse_step (a recurse that needs the host between levels).
+tests/test_pallas_bfs.py holds them to a plain host BFS at every edge of
+the blocking scheme.
 """
 
 from __future__ import annotations
@@ -120,23 +123,6 @@ def _active_dense(words_ref, src, chunks: int):
     return jnp.bitwise_and(lax.shift_right_logical(wordv, bit), 1)
 
 
-def _prefix_kernel(words_ref, src_ref, out_ref, carry_ref, *, chunks: int):
-    """One grid step: EDGE_BLOCK edges -> inclusive active-prefix values
-    (the recurse programs' emit: edge dedup wants every edge's flag)."""
-    blk = pl.program_id(0)
-
-    @pl.when(blk == 0)
-    def _():
-        carry_ref[0] = 0
-
-    active = _active_dense(words_ref, src_ref[:], chunks)  # (R, 128) int32
-
-    # inclusive scan in row-major (flattened-edge) order + sequential carry
-    prefix = _block_prefix(active) + carry_ref[0]
-    out_ref[:] = prefix
-    carry_ref[0] = prefix[prefix.shape[0] - 1, _LANES - 1]
-
-
 FRONTIER_CAP = 4096    # sparse-path capacity: 128 buckets x 32 entries
 
 
@@ -171,82 +157,10 @@ def _active_sparse(ftab_ref, src):
     return active
 
 
-def _prefix_kernel_sparse(ftab_ref, src_ref, out_ref, carry_ref):
-    """Sparse-frontier variant of _prefix_kernel."""
-    blk = pl.program_id(0)
-
-    @pl.when(blk == 0)
-    def _():
-        carry_ref[0] = 0
-
-    active = _active_sparse(ftab_ref, src_ref[:])      # (R, 128) int32
-
-    prefix = _block_prefix(active) + carry_ref[0]
-    out_ref[:] = prefix
-    carry_ref[0] = prefix[prefix.shape[0] - 1, _LANES - 1]
-
-
 def interpret_mode() -> bool:
     """True when the Pallas kernels run in interpret mode (any backend not
     named "tpu"): equality only, never a served tier."""
     return jax.default_backend() != "tpu"
-
-
-@partial(jax.jit, static_argnames=("chunks",))
-def active_prefix(words: jax.Array, src_pad: jax.Array, *,
-                  chunks: int) -> jax.Array:
-    """Inclusive prefix-count of frontier-active edges.
-
-    words: (chunks*8, 128) int32 frontier bitmap (word w at [w>>7, w&127]).
-    src_pad: int32[E_pad] (E_pad % EDGE_BLOCK == 0; padding points at an
-    always-zero word). Returns int32[E_pad]; prefix[-1] is the active total.
-    """
-    e_pad = src_pad.shape[0]
-    assert e_pad % EDGE_BLOCK == 0
-    rows = e_pad // _LANES
-    rblk = EDGE_BLOCK // _LANES
-    src2 = src_pad.reshape(rows, _LANES)
-    out = pl.pallas_call(
-        partial(_prefix_kernel, chunks=chunks),
-        grid=(rows // rblk,),
-        in_specs=[
-            pl.BlockSpec((chunks * 8, _LANES), lambda i: (0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((rblk, _LANES), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((rblk, _LANES), lambda i: (i, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((rows, _LANES), jnp.int32),
-        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
-        interpret=interpret_mode(),
-    )(words, src2)
-    return out.reshape(e_pad)
-
-
-@jax.jit
-def active_prefix_sparse(ftab: jax.Array, src_pad: jax.Array) -> jax.Array:
-    """Sparse-frontier inclusive prefix (ftab: (33,128) 2-level layout)."""
-    e_pad = src_pad.shape[0]
-    rows = e_pad // _LANES
-    rblk = EDGE_BLOCK // _LANES
-    src2 = src_pad.reshape(rows, _LANES)
-    out = pl.pallas_call(
-        _prefix_kernel_sparse,
-        grid=(rows // rblk,),
-        in_specs=[
-            pl.BlockSpec((33, _LANES), lambda i: (0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((rblk, _LANES), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((rblk, _LANES), lambda i: (i, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((rows, _LANES), jnp.int32),
-        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
-        interpret=interpret_mode(),
-    )(ftab, src2)
-    return out.reshape(e_pad)
 
 
 RANK_TILE = 1024       # destination ranks per output tile: one 8x128 vreg
@@ -308,11 +222,11 @@ def _last_edges(in_iptr_rank: jax.Array) -> jax.Array:
 
 def _row_end_kernel(block_ref, tile_ref, table_ref, src_ref, last_ref,
                     out_ref, prefix_ref, carry_ref, *, active_of):
-    """One grid step of a row-end kernel (a search's emit: it wants the
-    vertices, not the edges): item i -> the inclusive active-prefix at the
-    last in-edge of each destination rank of the item's tile whose row
-    ends in the item's edge block, written to the rank's slot of the
-    output tile. Nothing edge-sized leaves the kernel.
+    """One grid step of a row-end kernel (every program's emit: a level
+    wants the vertices reached, not the edges): item i -> the inclusive
+    active-prefix at the last in-edge of each destination rank of the
+    item's tile whose row ends in the item's edge block, written to the
+    rank's slot of the output tile. Nothing edge-sized leaves the kernel.
 
     An item that opens an edge block runs the membership test
     (`active_of(table_ref, src tile)`: _active_dense over the frontier
@@ -391,10 +305,11 @@ def _row_end_call(active_of, table, src_pad, ends: RowEnds, last):
 @partial(jax.jit, static_argnames=("chunks",))
 def row_end_prefix(words: jax.Array, src_pad: jax.Array, ends: RowEnds,
                    last: jax.Array, *, chunks: int) -> jax.Array:
-    """active_prefix taken at each destination rank's last in-edge, and
-    only there, in `last`'s layout: non-decreasing over the ranks; rank v
-    was reached iff its value is above rank v-1's (0 before rank 0). The
-    edge-sized prefix never exists outside the kernel."""
+    """The inclusive prefix-count of frontier-active edges, taken at each
+    destination rank's last in-edge and only there, in `last`'s layout:
+    non-decreasing over the ranks; rank v was reached iff its value is
+    above rank v-1's (0 before rank 0). The edge-sized prefix never exists
+    outside the kernel."""
     return _row_end_call(partial(_active_dense, chunks=chunks), words,
                          src_pad, ends, last)
 
@@ -432,29 +347,28 @@ class PullGraph(NamedTuple):
     and reachability is computed per destination *rank* — power-law graphs
     leave ~half the uid space with no edges at all, so rank spaces halve the
     bitmap chunk loop (the kernel's per-edge cost), the frontier pack, and
-    the node phase (a recurse's bounds gather, a search's row-end slots).
+    the node phase (the row-end slots).
     The device arrays are the programs' arguments; the host arrays are
     what the engine reads between launches (a search's first level and its
-    backtrack, a recurse's uidMatrix)."""
+    backtrack)."""
 
     in_src_pad: jax.Array       # int32[E_pad] source SRC-RANKS, dst-sorted
     in_src_pad_d: jax.Array     # int32[E_pad] source DST-RANKS, dst-sorted
-    in_iptr_rank: jax.Array     # int32[Nd+1] edge offsets per dst rank: a
-    # recurse level gathers its per-edge prefix at these, a search's kernels
-    # pick theirs at them block by block
+    in_iptr_rank: jax.Array     # int32[Nd+1] edge offsets per dst rank: the
+    # kernels pick their prefix at these, block by block
     row_ends: RowEnds           # the (edge block, rank tile) pairs those
     # kernels walk
     subjects: jax.Array         # int32[Ns] sorted uids with out-edges
     in_subjects: jax.Array      # int32[Nd] sorted uids with in-edges
     fwd_indptr: jax.Array       # int32[Ns+1] forward CSR (a search's level 1)
     fwd_dst_rank: jax.Array     # int32[E] dst RANKS in forward edge order
+    out_degree_d: jax.Array     # int32[Nd] out-degree by DST rank (0: the
+    # destination has no out-edge): what a recurse level >= 2 charges; by
+    # src rank it is diff(fwd_indptr)
     num_nodes: int
     num_edges: int
     chunks: int                 # bitmap chunks over the SRC-RANK space
     chunks_d: int               # bitmap chunks over the DST-RANK space
-    inv_order: np.ndarray       # HOST int32[E]: fwd edge position →
-    # dst-sorted edge position (the kernel's per-edge flag space); used to
-    # materialize per-source fresh-target lists lazily (recurse uidMatrix)
     host_in_iptr: np.ndarray    # HOST int32[Nd+1]
     host_in_src: np.ndarray     # HOST int32[E] src ranks, dst-sorted — the
     # in-adjacency the shortest-path backtrack walks
@@ -470,8 +384,8 @@ def prep_pull(subjects: np.ndarray, indptr: np.ndarray,
     """Host-side once-per-snapshot prep: transpose to dst-sorted in-edges,
     remap both endpoints to rank spaces, pad the edge stream to the kernel
     block size pointing at an always-zero bitmap word. The host arrays are
-    what the engine reads between launches (recurse materialization, the
-    shortest-path backtrack)."""
+    what the engine reads between launches (the shortest-path
+    backtrack)."""
     E = len(indices)
     if E and int(np.max(indices)) >= num_nodes:
         raise ValueError(
@@ -524,16 +438,16 @@ def prep_pull(subjects: np.ndarray, indptr: np.ndarray,
     # forward layout: the row a search's first level reads
     fwd_dst_rank = np.searchsorted(in_subjects, np.asarray(indices)).astype(
         np.int32)                    # every dst IS in in_subjects
-    inv_order = np.empty(E, dtype=np.int32)
-    inv_order[order] = np.arange(E, dtype=np.int32)
+    out_degree_d = np.zeros(nd + 1, dtype=np.int32)    # slot Nd: dropped
+    out_degree_d[map_s2d] = np.diff(indptr)
     return PullGraph(jnp.asarray(src_pad), jnp.asarray(src_pad_d),
                      jnp.asarray(iptr), _row_ends(iptr, e_pad),
                      jnp.asarray(subjects.astype(np.int32)),
                      jnp.asarray(in_subjects.astype(np.int32)),
                      jnp.asarray(np.asarray(indptr).astype(np.int32)),
-                     jnp.asarray(fwd_dst_rank),
+                     jnp.asarray(fwd_dst_rank), jnp.asarray(out_degree_d[:nd]),
                      int(num_nodes), int(E), int(chunks), int(chunks_d),
-                     inv_order, iptr, src_sorted, map_s2d,
+                     iptr, src_sorted, map_s2d,
                      in_subjects.astype(np.int64), subjects.astype(np.int64),
                      np.asarray(indptr))
 
@@ -562,9 +476,10 @@ SPARSE_MAX = FRONTIER_CAP   # frontier popcount at/below which the sparse
 # edge-dedup traversal: the production @recurse path (reference
 # query/recurse.go:31-177 expandRecurse). Unlike BFS (node-visited), recurse
 # dedups EDGES: a node reached again over a never-traversed edge re-appears
-# at the deeper level. The kernel's fused active-prefix provides exactly the
-# per-edge active flags edge-dedup needs; "seen" is a bool vector over the
-# dst-sorted edge stream carried on device across levels.
+# at the deeper level. An edge is traversed when its source is in a
+# frontier, so the reach-set of recurse.go:129 is kept as the set of
+# vertices that were in an earlier frontier (`expanded`, node-sized,
+# carried on device across levels): see _recurse_tail.
 # ---------------------------------------------------------------------------
 
 
@@ -581,24 +496,10 @@ def pull_graph_for(csr) -> PullGraph:
     return g
 
 
-@jax.jit
-def pack_mask_rows(masks: jax.Array) -> jax.Array:
-    """Row-wise pack_mask for a stacked [D, n] bool buffer — ONE dispatch
-    and one fetch for every level's flags."""
-    return jax.vmap(lambda m: pack_words(m, pack_chunks(masks.shape[1])))(
-        masks)
-
-
 def pack_chunks(n: int) -> int:
     """Minimal chunk count whose word capacity covers n bits (pure packing —
     no kernel pad-rank slot needed)."""
     return max(1, (n + NODES_PER_CHUNK - 1) // NODES_PER_CHUNK)
-
-
-@jax.jit
-def pack_mask(mask: jax.Array) -> jax.Array:
-    """Bit-pack a bool vector for a host fetch (8x fewer bytes)."""
-    return pack_words(mask, pack_chunks(mask.shape[0]))
 
 
 def unpack_words(words: np.ndarray, n: int) -> np.ndarray:
@@ -625,15 +526,6 @@ def _hop_for(frontier_bits, n_chunks: int, sparse, dense):
                     frontier_bits)
 
 
-def _prefix_for(frontier_bits, stream, n_chunks: int):
-    """Active-edge inclusive prefix for one frontier, int32[E_pad] (a
-    recurse level's emit)."""
-    return _hop_for(
-        frontier_bits, n_chunks,
-        lambda ftab: active_prefix_sparse(ftab, stream),
-        lambda words: active_prefix(words, stream, chunks=n_chunks))
-
-
 def _reached_from(bounds, n_ranks: int):
     """bool[n_ranks]: the ranks with an active in-edge, from the
     active-prefix at each rank's last in-edge (row_end_prefix)."""
@@ -645,8 +537,8 @@ def _reached_from(bounds, n_ranks: int):
 
 def _reached_for(frontier_bits, stream, n_chunks: int, ends: RowEnds, last):
     """Active-edge inclusive prefix at the row ends for one frontier, in
-    `last`'s layout (a search level's emit): nothing edge-sized leaves
-    either branch."""
+    `last`'s layout (a level's emit): nothing edge-sized leaves either
+    branch."""
     return _hop_for(
         frontier_bits, n_chunks,
         lambda ftab: row_end_prefix_sparse(ftab, stream, ends, last),
@@ -654,65 +546,50 @@ def _reached_for(frontier_bits, stream, n_chunks: int, ends: RowEnds, last):
                                      chunks=n_chunks))
 
 
-def _recurse_tail(prefix, in_iptr_rank, seen, allow_loop: bool):
-    """Shared prefix→(reached_d, traversed, seen', fresh) tail: edge-dedup
-    plus the bounds-diff reachability (the exactness-critical piece, kept
-    in ONE place for the fused and stepped paths alike)."""
-    traversed = prefix[-1]
+def _recurse_tail(frontier, expanded, out_degree, stream, n_chunks: int,
+                  ends: RowEnds, last, nd: int, allow_loop: bool):
+    """One recurse level in the stream's rank space: (frontier bits,
+    the vertices that were in an earlier frontier) → (reached [Nd],
+    traversed, expanded'). Edge dedup, exactly, on vertices: an edge is
+    active iff its source is in the frontier, so it was traversed before
+    iff its source was in an earlier frontier, and the level's fresh edges
+    are the out-edges of `frontier & ~expanded` — the frontier the kernel
+    reaches from. traversed counts EVERY out-edge of every frontier node,
+    re-entered ones included (the budget the reference charges,
+    recurse.go:167); reached = dst ranks with >= 1 fresh in-edge. The
+    exactness-critical piece, kept in ONE place for the fused and stepped
+    paths alike."""
     with jax.named_scope("visit"):
-        prev = jnp.concatenate([jnp.zeros((1,), jnp.int32), prefix[:-1]])
-        active = (prefix - prev) > 0                       # bool[E_pad]
+        traversed = jnp.sum(jnp.where(frontier, out_degree, 0),
+                            dtype=jnp.int32)
         if allow_loop:
-            fresh, seen2 = active, seen
+            live, expanded2 = frontier, expanded
         else:
-            fresh = active & ~seen
-            seen2 = seen | active
-    with jax.named_scope("bounds"):
-        freshp = jnp.cumsum(fresh.astype(jnp.int32))
-        bounds = jnp.take(freshp, in_iptr_rank - 1, mode="clip")
-        bounds = jnp.where(in_iptr_rank == 0, 0, bounds)
-        reached = (bounds[1:] - bounds[:-1]) > 0           # [Nd]
-    return reached, traversed, seen2, fresh
-
-
-def _recurse_level_core(fbits, stream, n_chunks: int, in_iptr_rank, seen,
-                        allow_loop: bool):
-    """One recurse level in RANK space: frontier bits (in the stream's
-    source-ID space) → (reached_d [Nd], traversed, seen', fresh).
-    traversed counts EVERY out-edge of every frontier node (the budget the
-    reference charges, recurse.go:167); fresh marks first-traversal edges;
-    reached_d = dst ranks with >= 1 fresh in-edge."""
+            live = frontier & ~expanded
+            expanded2 = expanded | frontier
     with jax.named_scope("prefix"):
-        prefix = _prefix_for(fbits, stream, n_chunks)
-    return _recurse_tail(prefix, in_iptr_rank, seen, allow_loop)
-
-
-def _recurse_level(in_src_pad, in_iptr_rank, subjects, in_subjects,
-                   frontier_mask, seen, *, chunks: int, num_nodes: int,
-                   allow_loop: bool):
-    """Full-uid-space recurse level (stepped path: multi-predicate
-    frontiers are not confined to this predicate's destinations)."""
-    fbits = jnp.take(frontier_mask, subjects)              # [Ns] rank space
-    reached, traversed, seen2, fresh = _recurse_level_core(
-        fbits, in_src_pad, chunks, in_iptr_rank, seen, allow_loop)
-    dest_mask = jnp.zeros((num_nodes,), bool).at[in_subjects].set(
-        reached, mode="drop")
-    return dest_mask, traversed, seen2, fresh
+        bounds = _reached_for(live, stream, n_chunks, ends, last)
+    return _reached_from(bounds, nd), traversed, expanded2
 
 
 @partial(jax.jit, static_argnames=("chunks", "num_nodes", "allow_loop"))
-def recurse_step(in_src_pad, in_iptr_rank, subjects, in_subjects,
-                 frontier_mask, seen, *, chunks: int, num_nodes: int,
-                 allow_loop: bool):
+def recurse_step(in_src_pad, in_iptr_rank, row_ends, subjects, in_subjects,
+                 fwd_indptr, frontier_mask, expanded, *, chunks: int,
+                 num_nodes: int, allow_loop: bool):
     """Single stepped level (used when filters / multiple recurse children
-    force host control between levels). Host-bound outputs (dest mask,
-    fresh flags) come back BIT-PACKED — the host fetch, not the kernel, is
-    the latency floor of a single query."""
-    dest, trav, seen2, fresh = _recurse_level(
-        in_src_pad, in_iptr_rank, subjects, in_subjects, frontier_mask, seen,
-        chunks=chunks, num_nodes=num_nodes, allow_loop=allow_loop)
-    dest_p = pack_words(dest, pack_chunks(num_nodes))
-    return dest_p, trav, seen2, fresh
+    force host control between levels), over the full uid space:
+    multi-predicate frontiers are not confined to this predicate's
+    destinations. `expanded` is bool[Ns], the subjects that were in an
+    earlier frontier of this traversal, carried between calls. Returns
+    (dest mask BIT-PACKED — the host fetch, not the kernel, is the latency
+    floor of a single query —, traversed, expanded')."""
+    reached, trav, expanded2 = _recurse_tail(
+        jnp.take(frontier_mask, subjects), expanded, jnp.diff(fwd_indptr),
+        in_src_pad, chunks, row_ends, _last_edges(in_iptr_rank),
+        in_subjects.shape[0], allow_loop)
+    dest = jnp.zeros((num_nodes,), bool).at[in_subjects].set(
+        reached, mode="drop")
+    return pack_words(dest, pack_chunks(num_nodes)), trav, expanded2
 
 
 DIST_UNREACHED = 255    # uint8 distance label of a vertex never reached
@@ -925,42 +802,53 @@ def _walk_back(g: PullGraph, dist: np.ndarray, dr: int, src: int, dst: int):
     return path[::-1]
 
 
-def _recurse_fused_levels(in_src_pad, in_src_pad_d, in_iptr_rank, subjects,
-                          in_subjects, seeds_mask, *, depth: int, chunks: int,
+def _recurse_fused_levels(in_src_pad, in_src_pad_d, in_iptr_rank, row_ends,
+                          subjects, in_subjects, fwd_indptr, out_degree_d,
+                          seeds_mask, *, depth: int, chunks: int,
                           chunks_d: int, allow_loop: bool):
     """Traced body shared by recurse_fused (one seed mask) and
     recurse_fused_multi (a stacked batch of seed masks): all `depth`
-    levels as one lax.scan over the SAME per-level kernel."""
+    levels as one lax.scan over the SAME per-level tail."""
     nd = in_subjects.shape[0]
+    last = _last_edges(in_iptr_rank)           # what every level's pick reads
 
     def body(carry, i):
-        fresh_d, seen = carry
-        # hop 1 reads seed bits in src-rank space; hops >= 2 read the
-        # previous level's fresh dst-rank mask against the dst-rank stream
-        with jax.named_scope("prefix"):
-            prefix = lax.cond(
-                i == 0,
-                lambda _: _prefix_for(jnp.take(seeds_mask, subjects),
-                                      in_src_pad, chunks),
-                lambda _: _prefix_for(fresh_d, in_src_pad_d, chunks_d),
-                None)
-        reached, traversed, seen2, fresh = _recurse_tail(
-            prefix, in_iptr_rank, seen, allow_loop)
-        dest_p = pack_words(reached, pack_chunks(nd))
-        return (reached, seen2), (dest_p, traversed, fresh)
+        frontier_d, expanded = carry
 
-    seen0 = jnp.zeros((in_src_pad.shape[0],), dtype=bool)  # device-side alloc
-    fresh0 = jnp.zeros((nd,), dtype=bool)
-    (_m, _s), (masks_p, trav, fresh) = lax.scan(
-        body, (fresh0, seen0), jnp.arange(depth), length=depth)
-    return masks_p, trav, fresh
+        def from_seeds(_):
+            # src-rank space: a seed with out-edges and no in-edge exists
+            # only here. Nothing is expanded yet
+            seeds_s = jnp.take(seeds_mask, subjects)
+            reached, traversed, _ = _recurse_tail(
+                seeds_s, jnp.zeros_like(seeds_s), jnp.diff(fwd_indptr),
+                in_src_pad, chunks, row_ends, last, nd, allow_loop)
+            return reached, traversed, expanded
+
+        def from_reached(_):
+            # a level >= 2 frontier is the previous level's destinations:
+            # bits straight from the dst-rank mask (no remap gather)
+            return _recurse_tail(frontier_d, expanded, out_degree_d,
+                                 in_src_pad_d, chunks_d, row_ends, last, nd,
+                                 allow_loop)
+
+        reached, traversed, expanded2 = lax.cond(
+            i == 0, from_seeds, from_reached, None)
+        return (reached, expanded2), (pack_words(reached, pack_chunks(nd)),
+                                      traversed)
+
+    # `expanded` lives in dst-rank space and starts from the seeds that are
+    # destinations: a seed with no in-edge can never come back
+    carry0 = (jnp.zeros((nd,), dtype=bool), jnp.take(seeds_mask, in_subjects))
+    _carry, (masks_p, trav) = lax.scan(body, carry0, jnp.arange(depth),
+                                       length=depth)
+    return masks_p, trav
 
 
 @partial(jax.jit, static_argnames=("depth", "chunks", "chunks_d",
                                    "allow_loop"))
-def recurse_fused(in_src_pad, in_src_pad_d, in_iptr_rank, subjects,
-                  in_subjects, seeds_mask, *, depth: int, chunks: int,
-                  chunks_d: int, allow_loop: bool):
+def recurse_fused(in_src_pad, in_src_pad_d, in_iptr_rank, row_ends, subjects,
+                  in_subjects, fwd_indptr, out_degree_d, seeds_mask, *,
+                  depth: int, chunks: int, chunks_d: int, allow_loop: bool):
     """All `depth` levels in ONE dispatch (lax.scan): no host round-trip
     between levels. Single-predicate shape, so levels
     >= 2 stay entirely in DST-RANK space (a recurse frontier is the
@@ -970,20 +858,23 @@ def recurse_fused(in_src_pad, in_src_pad_d, in_iptr_rank, subjects,
 
     Returns stacked per-level (dest_words [D,Cd*8,128] BIT-PACKED
     DST-RANK masks — the host fetches these every query, so
-    packed-and-rank-compressed is the cheapest form to move; traversed [D]; fresh [D,E_pad] bools that STAY on device until
-    a lazy uidMatrix materialization packs+fetches them). Only for the
+    packed-and-rank-compressed is the cheapest form to move; traversed
+    [D]). Nothing edge-sized is held or returned: which rows of a level's
+    uidMatrix are fresh the host tells from the level frontiers it
+    fetched (query/recurse.py LazyRecurseMatrix). Only for the
     single-uid-child no-filter recurse shape (the common + benchmarked
     one); anything needing host logic between levels uses recurse_step."""
     return _recurse_fused_levels(
-        in_src_pad, in_src_pad_d, in_iptr_rank, subjects, in_subjects,
-        seeds_mask, depth=depth, chunks=chunks, chunks_d=chunks_d,
-        allow_loop=allow_loop)
+        in_src_pad, in_src_pad_d, in_iptr_rank, row_ends, subjects,
+        in_subjects, fwd_indptr, out_degree_d, seeds_mask, depth=depth,
+        chunks=chunks, chunks_d=chunks_d, allow_loop=allow_loop)
 
 
 @partial(jax.jit, static_argnames=("depth", "chunks", "chunks_d",
                                    "allow_loop"))
-def recurse_fused_multi(in_src_pad, in_src_pad_d, in_iptr_rank, subjects,
-                        in_subjects, seeds_masks, *, depth: int, chunks: int,
+def recurse_fused_multi(in_src_pad, in_src_pad_d, in_iptr_rank, row_ends,
+                        subjects, in_subjects, fwd_indptr, out_degree_d,
+                        seeds_masks, *, depth: int, chunks: int,
                         chunks_d: int, allow_loop: bool):
     """Multi-source batched recurse: seeds_masks [B, num_nodes] stacks B
     concurrent queries' seed masks and the whole batch runs as ONE device
@@ -992,14 +883,13 @@ def recurse_fused_multi(in_src_pad, in_src_pad_d, in_iptr_rank, subjects,
     exact recurse_fused body, so slice b of the stacked outputs is
     bit-identical to a solo recurse_fused call with seeds_masks[b] (the
     per-level ops are integer/boolean — no float reassociation). Each
-    query keeps its own seen-edge vector: batching never entangles
-    traversals. Returns (masks_p [B, depth, ...], traversed [B, depth],
-    fresh [B, depth, E_pad])."""
+    query keeps its own expanded set: batching never entangles
+    traversals. Returns (masks_p [B, depth, ...], traversed [B, depth])."""
     return lax.map(
         lambda sm: _recurse_fused_levels(
-            in_src_pad, in_src_pad_d, in_iptr_rank, subjects, in_subjects,
-            sm, depth=depth, chunks=chunks, chunks_d=chunks_d,
-            allow_loop=allow_loop),
+            in_src_pad, in_src_pad_d, in_iptr_rank, row_ends, subjects,
+            in_subjects, fwd_indptr, out_degree_d, sm, depth=depth,
+            chunks=chunks, chunks_d=chunks_d, allow_loop=allow_loop),
         seeds_masks)
 
 
@@ -1007,12 +897,8 @@ def recurse_fused_multi(in_src_pad, in_src_pad_d, in_iptr_rank, subjects,
 # points by program family, probed for live jit-cache size on
 # /debug/compiles (see ops/segments.py).
 JIT_PROGRAMS = {
-    "pb.active_prefix": active_prefix,
-    "pb.active_prefix_sparse": active_prefix_sparse,
     "pb.row_end_prefix": row_end_prefix,
     "pb.row_end_prefix_sparse": row_end_prefix_sparse,
-    "pb.pack_mask_rows": pack_mask_rows,
-    "pb.pack_mask": pack_mask,
     "pb.recurse_step": recurse_step,
     "pb.bfs_dist": bfs_dist,
     "pb.recurse_fused": recurse_fused,

@@ -679,8 +679,7 @@ class DeviceBatcher:
         """Stacked seed masks through recurse_fused_multi; slice b of the
         stacked outputs is bit-identical to a solo recurse_fused call (the
         per-level ops are integer/boolean). Each entry receives its
-        (masks_p, traversed, fresh) triple; fresh stays a device slice
-        until a lazy uidMatrix materialization fetches it."""
+        (masks_p, traversed) pair."""
         import jax.numpy as jnp
 
         from dgraph_tpu.ops import pallas_bfs as pb
@@ -697,14 +696,15 @@ class DeviceBatcher:
 
         def kernel():
             return pb.recurse_fused_multi(
-                g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank, g.subjects,
-                g.in_subjects, seeds, depth=depth, chunks=g.chunks,
-                chunks_d=g.chunks_d, allow_loop=allow_loop)
+                g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank, g.row_ends,
+                g.subjects, g.in_subjects, g.fwd_indptr, g.out_degree_d,
+                seeds, depth=depth, chunks=g.chunks, chunks_d=g.chunks_d,
+                allow_loop=allow_loop)
 
         with otrace.span("device_kernel", kernel="batch.recurse",
                          depth=depth, batch=nbatch):
-            (masks_p, trav, fresh), dt_ms = self._timed_gate_run(
+            (masks_p, trav), dt_ms = self._timed_gate_run(
                 kernel, "recurse")
             self._charge(entries, "batch.recurse", dt_ms)
         for i, e in enumerate(entries):
-            e.result = (masks_p[i], trav[i], fresh[i])
+            e.result = (masks_p[i], trav[i])

@@ -7,17 +7,20 @@ edges (:129-141) unless `loop: true`; bounded by the edge budget (:167).
 
 TPU shape — one hot path, benched and served alike (worker/task.go:605):
 
-  * Large resident CSRs run the SAME Pallas active-prefix kernel the
+  * Large resident CSRs run the SAME Pallas row-end kernels the
     benchmark measures (ops/pallas_bfs): per level, the kernel streams the
-    dst-sorted edge array against the VMEM frontier bitmap; the fused
-    per-edge prefix yields active flags, and edge-dedup is two streaming
-    masks on device (fresh = active & ~seen, seen |= active) plus a
-    node-sized bounds-diff for the next frontier. The reach-set of
-    recurse.go:129 is a device-resident bool vector over the edge stream.
+    dst-sorted edge array against the VMEM frontier bitmap and hands back
+    one prefix value a destination; the next frontier is a node-sized
+    diff. Edge dedup is kept on vertices: an edge was traversed iff its
+    source was in an earlier frontier, so the reach-set of recurse.go:129
+    is a device-resident bool vector over the vertices (`expanded`) and a
+    level reaches from `frontier & ~expanded`.
     The common single-child no-filter shape runs ALL levels in one
     dispatch (recurse_fused lax.scan) — no host sync between levels.
     Per-source target lists (uidMatrix) stay CSR-shaped and deferred
-    (LazyRecurseMatrix): output encoders materialize on demand.
+    (LazyRecurseMatrix): output encoders materialize on demand, a
+    frontier vertex's whole row if it was in no earlier frontier, else
+    nothing — told from the level frontiers the host already holds.
   * Small CSRs keep the vectorized host-mirror gather (the size-adaptive
     dispatch rule of task.HOST_EXPAND_MAX: below the device's fixed
     dispatch+sync cost, host numpy wins).
@@ -34,8 +37,8 @@ stored in both directions `v` is every vertex within k hops of the root,
 plus the root itself from k = 2 on (it comes back over the reverse edge).
 All three tiers record through `_record_vars`; the plain reference is
 dgraph_tpu/models/khop.py. A `var` block renders nothing, so the fused
-tier builds no SubGraph chain for it: no LazyRecurseMatrix, no fresh-flag
-fetch, one OR over the fetched level masks.
+tier builds no SubGraph chain for it: no LazyRecurseMatrix, one OR over
+the fetched level masks.
 """
 
 from __future__ import annotations
@@ -55,7 +58,7 @@ from dgraph_tpu.utils.types import TypeID
 # the module global to 0 to force the kernel (interpret mode off-TPU).
 KERNEL_MIN_EDGES: int | None = None       # None = backend-dependent default
 _KERNEL_MIN_TPU = 1 << 20
-FUSED_MAX_DEPTH = 8   # fresh-flag buffer is depth × E_pad bools
+FUSED_MAX_DEPTH = 8   # one compiled program per static depth
 
 
 def _kernel_min() -> int:
@@ -66,48 +69,23 @@ def _kernel_min() -> int:
     return 1 << 62    # interpret-mode Pallas: host path always wins
 
 
-class FreshFlags:
-    """Host cache of a traversal's per-edge fresh flags, shared by every
-    level's LazyRecurseMatrix: ONE device pack + one bit-packed fetch for
-    the whole [depth, E_pad] (or [E_pad]) buffer, however many levels the
-    encoder materializes."""
-
-    def __init__(self, fresh_dev):
-        self._dev = fresh_dev            # [E_pad] or [depth, E_pad]
-        self._h: np.ndarray | None = None
-
-    def level(self, lvl) -> np.ndarray:
-        if self._h is None:
-            from dgraph_tpu.ops import pallas_bfs as pb
-
-            d = self._dev
-            if d.ndim == 1:
-                self._h = pb.unpack_words(np.asarray(pb.pack_mask(d)),
-                                          d.shape[0])
-            else:
-                packed = np.asarray(pb.pack_mask_rows(d))
-                self._h = np.stack([pb.unpack_words(packed[i], d.shape[1])
-                                    for i in range(d.shape[0])])
-        return self._h if self._dev.ndim == 1 else self._h[lvl]
-
-
 class LazyRecurseMatrix:
     """A recurse level's uidMatrix in deferred CSR form.
 
-    The kernel path's native result is device state (per-edge fresh flags in
-    the dst-sorted stream + the next frontier mask); ragged per-source
-    target lists are materialized host-side only when an output encoder,
-    cascade, or count actually reads them (SURVEY §7: result
-    materialization is inherently ragged → host-side by design)."""
+    The kernel path's native result is the next frontier mask; ragged
+    per-source target lists are materialized host-side only when an output
+    encoder, cascade, or count actually reads them (SURVEY §7: result
+    materialization is inherently ragged → host-side by design). Edge
+    dedup on the host is the device's, on vertices: a frontier vertex's
+    out-edges are all fresh if it was in no earlier frontier of the
+    traversal (`first`, a bool per frontier vertex; None = every one, the
+    `loop: true` rule), else all seen."""
 
-    def __init__(self, csr, g, frontier: np.ndarray, fresh: FreshFlags,
-                 level, allow_loop: bool, metrics=None):
+    def __init__(self, csr, frontier: np.ndarray,
+                 first: np.ndarray | None, metrics=None):
         self._csr = csr
-        self._g = g
         self._frontier = np.asarray(frontier, dtype=np.int64)
-        self._fresh = fresh
-        self._level = level              # row of the stacked buffer, or None
-        self._allow_loop = allow_loop
+        self._first = first
         self._metrics = metrics
         self._rows: list[np.ndarray] | None = None
 
@@ -116,15 +94,17 @@ class LazyRecurseMatrix:
             return self._rows
         if self._metrics is not None:
             self._metrics.counter("dgraph_recurse_materialized_total").inc()
-        pos, offs, targets = _gather_frontier_edges(self._csr, self._frontier)
-        if self._allow_loop:
-            keep = np.ones(len(pos), dtype=bool)
+        if self._first is None:
+            at = np.arange(len(self._frontier))
         else:
-            fresh_h = self._fresh.level(self._level)
-            keep = fresh_h[self._g.inv_order[pos]]
-        self._rows = [targets[offs[i]: offs[i + 1]][keep[offs[i]: offs[i + 1]]]
-                      for i in range(len(self._frontier))]
-        return self._rows
+            at = np.flatnonzero(self._first)
+        _pos, offs, targets = _gather_frontier_edges(
+            self._csr, self._frontier[at])
+        rows = [targets[:0]] * len(self._frontier)
+        for j, i in enumerate(at):
+            rows[i] = targets[offs[j]: offs[j + 1]]
+        self._rows = rows
+        return rows
 
     def __len__(self) -> int:
         return len(self._frontier)
@@ -175,6 +155,17 @@ def _gather_frontier_edges(csr, frontier: np.ndarray):
     np.cumsum(counts, out=offs[1:])
     pos = np.repeat(starts - offs[:-1], counts) + np.arange(total)
     return pos, offs, indices[pos].astype(np.int64)
+
+
+def _first_visits(expanded: np.ndarray, frontier: np.ndarray) -> np.ndarray:
+    """A bool per frontier vertex: it was in no earlier frontier of the
+    traversal. `expanded` (bool[num_nodes]: the vertices that were) is
+    updated in place; a uid past it has no row to dedup."""
+    inside = frontier < len(expanded)
+    first = np.ones(len(frontier), dtype=bool)
+    first[inside] = ~expanded[frontier[inside]]
+    expanded[frontier[inside]] = True
+    return first
 
 
 def _expand_dedup(csr, frontier: np.ndarray, seen: np.ndarray,
@@ -263,7 +254,7 @@ def recurse(ex, sg: SubGraph) -> None:
                     or c.attr.startswith("~")]
     val_children = [c for c in gq.children if c not in uid_children]
     seen_masks: dict[str, np.ndarray] = {}     # host path: attr -> bool[E]
-    kstates: dict[str, dict] = {}              # kernel path: attr -> g, seen
+    kstates: dict[str, dict] = {}     # kernel path: attr -> g, expanded
     seen_edges: set[tuple[str, int, int]] = set()   # dist-CSR fallback only
     edges = 0
     # id(child gq) -> each level's destinations, for the children that
@@ -289,9 +280,13 @@ def recurse(ex, sg: SubGraph) -> None:
         st = kstates.get(attr)
         if st is None:
             g = pb.pull_graph_for(csr)
+            # the vertices that were in an earlier frontier of this
+            # predicate's traversal: on the device by src rank (the
+            # program's), on the host by uid (the lazy matrices')
             st = kstates[attr] = {
                 "g": g,
-                "seen": jnp.zeros((g.in_src_pad.shape[0],), dtype=bool)}
+                "expanded": jnp.zeros((len(g.host_subjects),), dtype=bool),
+                "expanded_h": np.zeros(g.num_nodes, dtype=bool)}
         return st
 
     # ---- fused fast path: single uid child, no filters/val children -------
@@ -373,29 +368,30 @@ def recurse(ex, sg: SubGraph) -> None:
                 # the device step runs through the dispatch gate: N
                 # concurrent recurse queries pipeline instead of thrashing
                 def _step():
-                    dest_words, trav, seen2, fresh = pb.recurse_step(
-                        g.in_src_pad, g.in_iptr_rank, g.subjects,
-                        g.in_subjects, fmask, st["seen"],
-                        chunks=g.chunks, num_nodes=g.num_nodes,
-                        allow_loop=spec.allow_loop)
+                    dest_words, trav, expanded = pb.recurse_step(
+                        g.in_src_pad, g.in_iptr_rank, g.row_ends,
+                        g.subjects, g.in_subjects, g.fwd_indptr, fmask,
+                        st["expanded"], chunks=g.chunks,
+                        num_nodes=g.num_nodes, allow_loop=spec.allow_loop)
                     # the fetch is the fence, as in _solo_fused: dispatch
                     # is asynchronous, so the timer and the gate slot
                     # cover the device step and not only its launch
-                    return jax.device_get((dest_words, trav)), seen2, fresh
+                    return jax.device_get((dest_words, trav)), expanded
 
                 with costs.kernel("pb.recurse_step", attr=cgq.attr):
-                    (dest_words_h, trav_h), seen2, fresh = ex.gated(
+                    (dest_words_h, trav_h), st["expanded"] = ex.gated(
                         _step, klass="recurse")
-                st["seen"] = seen2
                 _count_levels(ex, live=1)
                 _book_edges(cgq.attr, int(trav_h))
                 edges += int(trav_h)
                 if edges > ex.edge_budget():
                     raise QueryError(
                         "recurse exceeded edge budget (ErrTooBig)")
-                m = LazyRecurseMatrix(csr, g, frontier, FreshFlags(fresh),
-                                      None, spec.allow_loop,
-                                      getattr(ex.snap, "metrics", None))
+                m = LazyRecurseMatrix(
+                    csr, frontier,
+                    None if spec.allow_loop
+                    else _first_visits(st["expanded_h"], frontier),
+                    getattr(ex.snap, "metrics", None))
                 child.uid_matrix = m
                 child.counts = LazyCounts(m)
                 child.dest_uids = np.flatnonzero(pb.unpack_words(
@@ -550,11 +546,11 @@ def _recurse_fused_path(ex, sg: SubGraph, cgq, csr, depth: int,
                          depth=depth, edges=g.num_edges) as sp, \
                 costs.kernel("pb.recurse_fused", attr=cgq.attr,
                              stage="dev.dispatch") as ck:
-            masks_p, trav, fresh = pb.recurse_fused(
-                g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank, g.subjects,
-                g.in_subjects, seeds_mask,
-                depth=depth, chunks=g.chunks, chunks_d=g.chunks_d,
-                allow_loop=allow_loop)
+            masks_p, trav = pb.recurse_fused(
+                g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank, g.row_ends,
+                g.subjects, g.in_subjects, g.fwd_indptr, g.out_degree_d,
+                seeds_mask, depth=depth, chunks=g.chunks,
+                chunks_d=g.chunks_d, allow_loop=allow_loop)
             # the fetch is the fence: dispatch is asynchronous, so the
             # timer (and the gate slot) must cover it to book device time
             with costs.stage("dev.wait"):
@@ -567,14 +563,13 @@ def _recurse_fused_path(ex, sg: SubGraph, cgq, csr, depth: int,
                     sp.set(transfer_h2d_bytes=int(seeds_mask.nbytes),
                            transfer_d2h_bytes=d2h, levels_live=live,
                            reached=int(pb.unpack_words(union, nd).sum()))
-            return masks_h, trav_h, fresh
+            return masks_h, trav_h
 
-    masks_p, trav, fresh = ex.batched_recurse(
+    masks_p, trav = ex.batched_recurse(
         g, seeds_mask, depth, allow_loop, _solo_fused)
     with costs.stage("dev.post"):
         # ONE host round-trip for the whole traversal, bit-packed in
-        # DST-RANK space (fresh flags stay on device until a lazy
-        # uidMatrix materialization needs them); host maps ranks -> uids.
+        # DST-RANK space; host maps ranks -> uids.
         # A no-op after the solo closure, which fetched under its timer;
         # the batched runner hands back device slices.
         masks_h, trav_h = jax.device_get((masks_p, trav))
@@ -594,14 +589,16 @@ def _recurse_fused_path(ex, sg: SubGraph, cgq, csr, depth: int,
             if id(cgq) in level_dests:
                 level_dests[id(cgq)].append(uids_of(union))
             return
-        shared_fresh = FreshFlags(fresh)
         metrics = getattr(ex.snap, "metrics", None)
+        expanded = None if allow_loop else np.zeros(g.num_nodes, dtype=bool)
         frontier = seeds
         attach = sg.children = []
         for lvl in range(live):
             child = SubGraph(gq=cgq, attr=cgq.attr, src_uids=frontier)
-            m = LazyRecurseMatrix(csr, g, frontier, shared_fresh, lvl,
-                                  allow_loop, metrics)
+            m = LazyRecurseMatrix(
+                csr, frontier,
+                None if allow_loop else _first_visits(expanded, frontier),
+                metrics)
             child.uid_matrix = m
             child.counts = LazyCounts(m)
             child.dest_uids = uids_of(masks_h[lvl])

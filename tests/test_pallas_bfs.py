@@ -16,16 +16,25 @@ Every case runs under each program that can express it:
                  or over the in-edge stream (first_hop_cap=1: every root
                  with more than one out-edge streams);
   recurse        recurse_fused (`@recurse`) from the whole seed set.
+
+The recurse programs dedup edges on VERTICES (a vertex's out-edges are all
+first traversed in the level the vertex is first in a frontier);
+test_recurse_dedups_edges holds them, recurse_step and the host's lazy
+matrices to a plain loop over a per-edge `seen`, on graphs built to
+re-enter vertices.
 """
 
 import hashlib
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 from dgraph_tpu.models.rmat import rmat_csr
 from dgraph_tpu.ops import pallas_bfs as pb
+from dgraph_tpu.query import recurse as recmod
 
 FIRST_HOP_CAPS = {"push": pb.FIRST_HOP_CAP, "stream": 1}
 programs = pytest.mark.parametrize("program", ["push", "stream", "recurse"])
@@ -56,24 +65,39 @@ def host_k_hop(subjects, indptr, indices, seed_uids, num_nodes, hops):
     return visited, traversed, dist
 
 
-def host_recurse(subjects, indptr, indices, seed_uids, depth):
-    """Reference host @recurse (edge dedup, recurse.go expandRecurse): per
-    level, the uids reached over a never-traversed edge and the count of
-    every out-edge of the level's frontier."""
+def host_recurse(subjects, indptr, indices, seed_uids, depth,
+                 allow_loop=False):
+    """Reference host @recurse (edge dedup, recurse.go expandRecurse) over
+    a per-EDGE `seen`: per level, the uids reached over a never-traversed
+    edge (any edge under `allow_loop`), the count of every out-edge of the
+    level's frontier, and the level's uid matrix: for each vertex of its
+    sorted frontier, the targets of the fresh edges of its row."""
     row_of = {int(s): i for i, s in enumerate(subjects)}
     seen = np.zeros(len(indices), dtype=bool)
     frontier = np.unique(np.asarray(seed_uids, dtype=np.int64))
     levels = []
     for _ in range(depth):
-        rows = [row_of[int(u)] for u in frontier if int(u) in row_of]
-        edges = (np.concatenate([np.arange(indptr[r], indptr[r + 1])
-                                 for r in rows])
-                 if rows else np.zeros(0, dtype=np.int64))
-        fresh = edges[~seen[edges]]
-        seen[fresh] = True
-        frontier = np.unique(indices[fresh])
-        levels.append((frontier, len(edges)))
+        traversed, fresh, matrix = 0, [], []
+        for u in frontier:
+            r = row_of.get(int(u))
+            edges = (np.arange(indptr[r], indptr[r + 1]) if r is not None
+                     else np.zeros(0, dtype=np.int64))
+            traversed += len(edges)
+            if not allow_loop:
+                edges = edges[~seen[edges]]
+                seen[edges] = True
+            fresh.append(edges)
+            matrix.append(indices[edges])
+        frontier = (np.unique(indices[np.concatenate(fresh)]) if fresh
+                    else np.zeros(0, dtype=np.int64))
+        levels.append((frontier, traversed, matrix))
     return levels
+
+
+def fused_layout(g):
+    """The graph arguments of recurse_fused / recurse_fused_multi."""
+    return (g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank, g.row_ends,
+            g.subjects, g.in_subjects, g.fwd_indptr, g.out_degree_d)
 
 
 def check_search(g, csr, root, hops, first_hop_cap):
@@ -103,15 +127,14 @@ def check_recurse(g, csr, seed_uids, hops):
     Returns the per-level traversed counts."""
     seeds_mask = np.zeros(g.num_nodes, dtype=bool)
     seeds_mask[seed_uids] = True
-    masks_p, trav, _fresh = pb.recurse_fused(
-        g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank, g.subjects,
-        g.in_subjects, jnp.asarray(seeds_mask), depth=hops, chunks=g.chunks,
-        chunks_d=g.chunks_d, allow_loop=False)
+    masks_p, trav = pb.recurse_fused(
+        *fused_layout(g), jnp.asarray(seeds_mask), depth=hops,
+        chunks=g.chunks, chunks_d=g.chunks_d, allow_loop=False)
     masks_h, trav = np.asarray(masks_p), np.asarray(trav)
     nd = len(g.host_in_subjects)
     union = seeds_mask.copy()
     levels = host_recurse(*csr, seed_uids, hops)
-    for lvl, (want_reached, want_traversed) in enumerate(levels):
+    for lvl, (want_reached, want_traversed, _matrix) in enumerate(levels):
         reached = g.host_in_subjects[pb.unpack_words(masks_h[lvl], nd)]
         np.testing.assert_array_equal(reached, want_reached)
         assert int(trav[lvl]) == want_traversed
@@ -426,43 +449,56 @@ def test_row_end_items_hold_every_row_once(rng, shape):
 
 
 # sha256 of the lowered text (CPU: the kernels in interpret mode, inlined)
-# of the recurse programs for rmat_csr(12, 8, seed=5) at commit 8092e9c
-# (PR 32) — the programs a search shares its membership tests with. A PR
-# that means to change a recurse program replaces its line
-RECURSE_LOWERINGS = {
+# of the programs a request reaches, for rmat_csr(12, 8, seed=5): the
+# recurse programs as PR 35 left them (one emit, row_end_prefix*, for
+# every program; edge dedup on vertices), bfs_dist as it was at commit
+# ab2061a (PR 34), before that PR — the search shares its kernels, its
+# membership tests and _hop_for with them. A PR that means to change a
+# program replaces its line
+PROGRAM_LOWERINGS = {
     "recurse_fused":
-        "c5ba443bcac0843511372fc4f08e1c433210b2c838604d14bf8b72394dea5187",
+        "f949fee3a0c0057b01a81127a9c41e559a3f8b58ce76df9b125dad9157d10a65",
     "recurse_fused_multi":
-        "b0970b841e082259d3c96d3575fc56558b4bce8daf9775a1e8d47b1b6fc4b283",
+        "e939cc21f4bd62d393c6fb5092346a3a978841baddfe9eabd1a464b2e2a7005e",
     "recurse_step":
-        "d11239991ffe57f200fad83652dcde5c159e18cf5f3d24a41811e592a463ce76",
+        "4466867cced45d75ff48c0a2ad6f03625b1e8310091440167409d66f2b607386",
+    "bfs_dist":
+        "2ba50790fd26e29c394e3818bb001924b2c3dd32e6bbb1c44b00e5efdbf44375",
 }
 
 
-@pytest.mark.parametrize("program", sorted(RECURSE_LOWERINGS))
-def test_recurse_programs_lower_to_the_text_they_had(program):
-    """The shared lookups (_active_dense, _active_sparse) were factored out
-    of the prefix kernels for the search's own emit: the recurse programs
-    still lower to the text they had before, character for character."""
-    subjects, indptr, indices = rmat_csr(12, 8, seed=5)
+def lower_program(program, subjects, indptr, indices):
+    """`program` of PROGRAM_LOWERINGS, lowered for a CSR."""
     n = int(max(subjects.max(), indices.max())) + 1
     g = pb.prep_pull(subjects, indptr, indices, n)
     fused = dict(depth=3, chunks=g.chunks, chunks_d=g.chunks_d,
                  allow_loop=False)
-    layout = (g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank, g.subjects,
-              g.in_subjects)
-    lowered = {
+    return {
         "recurse_fused": lambda: pb.recurse_fused.lower(
-            *layout, jnp.zeros((n,), bool), **fused),
+            *fused_layout(g), jnp.zeros((n,), bool), **fused),
         "recurse_fused_multi": lambda: pb.recurse_fused_multi.lower(
-            *layout, jnp.zeros((2, n), bool), **fused),
+            *fused_layout(g), jnp.zeros((2, n), bool), **fused),
         "recurse_step": lambda: pb.recurse_step.lower(
-            g.in_src_pad, g.in_iptr_rank, g.subjects, g.in_subjects,
-            jnp.zeros((n,), bool), jnp.zeros(g.in_src_pad.shape, bool),
+            g.in_src_pad, g.in_iptr_rank, g.row_ends, g.subjects,
+            g.in_subjects, g.fwd_indptr, jnp.zeros((n,), bool),
+            jnp.zeros(g.subjects.shape, bool),
             chunks=g.chunks, num_nodes=n, allow_loop=False),
-    }[program]().as_text()
+        "bfs_dist": lambda: pb.bfs_dist.lower(
+            g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank, g.row_ends,
+            g.subjects, g.in_subjects, g.fwd_indptr, g.fwd_dst_rank,
+            np.zeros(4, np.int32), chunks=g.chunks, chunks_d=g.chunks_d,
+            first_hop_cap=pb.FIRST_HOP_CAP),
+    }[program]()
+
+
+@pytest.mark.parametrize("program", sorted(PROGRAM_LOWERINGS))
+def test_programs_lower_to_the_text_they_had(program):
+    """The programs share their kernels and their frontier plumbing: a
+    change meant for one of them that reaches another shows here,
+    character for character."""
+    lowered = lower_program(program, *rmat_csr(12, 8, seed=5)).as_text()
     assert hashlib.sha256(lowered.encode()).hexdigest() == \
-        RECURSE_LOWERINGS[program]
+        PROGRAM_LOWERINGS[program]
 
 
 def test_prep_pull_rejects_out_of_range_uids():
@@ -481,7 +517,8 @@ def test_table_of_a_sorted_list_is_the_table_of_its_mask(rng, n_set):
     """The sparse kernel's search table has two makers: _frontier_table
     from a mask (a nonzero over it), and its second half, _table_of_list,
     from a list that a caller already holds (bfs_dist: the row that level
-    1 read). Same members, same table, same active prefix."""
+    1 read). Same members, same table, same active prefix (read at the
+    row ends of a stream in which every edge is a row)."""
     n = 20_000
     members = np.sort(rng.choice(n, size=n_set, replace=False))
     mask = np.zeros(n, dtype=bool)
@@ -501,6 +538,214 @@ def test_table_of_a_sorted_list_is_the_table_of_its_mask(rng, n_set):
     src = np.full(pb.EDGE_BLOCK, n, dtype=np.int32)
     src[: 2 * len(members): 2] = members
     want = np.cumsum(np.isin(src, members)).astype(np.int32)
+    # every edge a row of its own: the row ends are the whole prefix
+    iptr = np.arange(len(src) + 1, dtype=np.int32)
+    ends = pb._row_ends(iptr, len(src))
+    last = pb._last_edges(jnp.asarray(iptr))
     for table in (by_list, np.asarray(pb._table_of_list(jnp.asarray(twice)))):
-        got = pb.active_prefix_sparse(jnp.asarray(table), jnp.asarray(src))
-        np.testing.assert_array_equal(np.asarray(got), want)
+        got = pb.row_end_prefix_sparse(jnp.asarray(table), jnp.asarray(src),
+                                       ends, last)
+        np.testing.assert_array_equal(np.asarray(got).reshape(-1)[:len(src)],
+                                      want)
+
+
+def _undirected_rmat(rng):
+    """An R-MAT stored in both directions: every level re-enters the one
+    before it over the reverse edges."""
+    subjects, indptr, indices = rmat_csr(9, 6, seed=3)
+    src = np.repeat(subjects, np.diff(indptr))
+    csr = csr_of(np.concatenate([src, indices]),
+                 np.concatenate([indices, src]))
+    return csr, rng.choice(csr[0], size=3, replace=False), 5
+
+
+def _directed_cycle(rng):
+    """0 -> 1 -> ... -> 6 -> 0, walked once and a half around: the seed
+    comes back as a destination and its one edge is seen by then."""
+    n = 7
+    at = np.arange(n)
+    return csr_of(at, (at + 1) % n), np.array([2]), 10
+
+
+def _self_loops(rng):
+    """A random graph in which a third of the vertices, the seeds among
+    them, point at themselves: a vertex re-enters the level after it
+    entered."""
+    n = 300
+    subjects, indptr, indices = random_csr(rng, n, 1200)
+    src = np.repeat(subjects, np.diff(indptr))
+    loops = np.arange(0, n, 3)
+    return (csr_of(np.concatenate([src, loops]),
+                   np.concatenate([indices, loops])),
+            np.array([0, 3, 7]), 5)
+
+
+def _seed_with_no_in_edge(rng):
+    """Seed 50 has out-edges and no in-edge (it exists in the src-rank
+    space only) and feeds a cycle 0 -> 1 -> 2 -> 3 -> 0 with a chord."""
+    src = np.array([50, 50, 0, 1, 2, 3, 1])
+    dst = np.array([0, 2, 1, 2, 3, 0, 3])
+    return csr_of(src, dst), np.array([50]), 6
+
+
+def _seed_reaching_seed(rng):
+    """Two seeds on one chain, the second two steps down from the first:
+    it is expanded at level 1 and reached again at level 2."""
+    at = np.arange(9)
+    src = np.concatenate([at, [9, 4]])
+    dst = np.concatenate([at + 1, [2, 0]])
+    return csr_of(src, dst), np.array([0, 2]), 6
+
+
+def _across_sparse_max(rng):
+    """Frontiers of 1, 5000 (over SPARSE_MAX: the dense kernel), 51, and
+    the 5000 again — all of them expanded by then, so an empty frontier
+    for the kernel and 5000 rows to charge — level by level."""
+    hubs = np.arange(1, 5001)
+    leaves = 5001 + hubs % 50
+    back = hubs[hubs % 7 == 0]
+    assert len(hubs) > pb.SPARSE_MAX
+    src = np.concatenate([np.zeros_like(hubs), hubs, back,
+                          5001 + (hubs - 1) // 100])
+    dst = np.concatenate([hubs, leaves, np.zeros_like(back), hubs])
+    return csr_of(src, dst), np.array([0]), 5
+
+
+REENTRY_GRAPHS = {
+    "undirected_rmat": _undirected_rmat,
+    "directed_cycle": _directed_cycle,
+    "self_loops": _self_loops,
+    "seed_with_no_in_edge": _seed_with_no_in_edge,
+    "seed_reaching_seed": _seed_reaching_seed,
+    "across_sparse_max": _across_sparse_max,
+}
+
+
+def fused_levels(g, seeds_mask, depth, allow_loop):
+    """Per level (reached uids, traversed) of one recurse_fused call."""
+    masks_p, trav = pb.recurse_fused(
+        *fused_layout(g), jnp.asarray(seeds_mask), depth=depth,
+        chunks=g.chunks, chunks_d=g.chunks_d, allow_loop=allow_loop)
+    masks_h, nd = np.asarray(masks_p), len(g.host_in_subjects)
+    return [(g.host_in_subjects[pb.unpack_words(masks_h[lvl], nd)],
+             int(t)) for lvl, t in enumerate(np.asarray(trav))]
+
+
+def stepped_levels(g, seeds_mask, depth, allow_loop):
+    """The same from a chain of recurse_step calls, each fed the one
+    before's destinations and its `expanded`."""
+    frontier = jnp.asarray(seeds_mask)
+    expanded = jnp.zeros(g.subjects.shape, dtype=bool)
+    levels = []
+    for _ in range(depth):
+        dest_p, trav, expanded = pb.recurse_step(
+            g.in_src_pad, g.in_iptr_rank, g.row_ends, g.subjects,
+            g.in_subjects, g.fwd_indptr, frontier, expanded,
+            chunks=g.chunks, num_nodes=g.num_nodes, allow_loop=allow_loop)
+        dest = pb.unpack_words(np.asarray(dest_p), g.num_nodes)
+        levels.append((np.flatnonzero(dest), int(trav)))
+        frontier = jnp.asarray(dest)
+    return levels
+
+
+@pytest.mark.parametrize("allow_loop", [False, True], ids=["dedup", "loop"])
+@pytest.mark.parametrize("levels_of", [fused_levels, stepped_levels],
+                         ids=["fused", "stepped"])
+@pytest.mark.parametrize("graph", sorted(REENTRY_GRAPHS))
+def test_recurse_dedups_edges(rng, graph, levels_of, allow_loop):
+    """Edge dedup kept on vertices is edge dedup: on graphs that re-enter
+    vertices, each level's reached set, its traversed count (re-entered
+    vertices charged again) and the uid matrix the executor would render
+    (LazyRecurseMatrix over _first_visits, as query/recurse.py builds it)
+    equal a plain loop over a per-edge `seen`."""
+    csr, seeds, depth = REENTRY_GRAPHS[graph](rng)
+    num_nodes = int(max(csr[0].max(), csr[2].max())) + 2
+    g = pb.prep_pull(*csr, num_nodes)
+    seeds_mask = np.zeros(num_nodes, dtype=bool)
+    seeds_mask[seeds] = True
+    want = host_recurse(*csr, seeds, depth, allow_loop)
+    got = levels_of(g, seeds_mask, depth, allow_loop)
+    store = SimpleNamespace(host_arrays=lambda: csr)
+    expanded = np.zeros(num_nodes, dtype=bool)
+    frontier = np.sort(seeds)
+    for (reached, traversed), (want_reached, want_traversed, want_matrix) \
+            in zip(got, want, strict=True):
+        np.testing.assert_array_equal(reached, want_reached)
+        assert traversed == want_traversed
+        matrix = recmod.LazyRecurseMatrix(
+            store, frontier,
+            None if allow_loop else recmod._first_visits(expanded, frontier))
+        assert len(matrix) == len(want_matrix)
+        for row, want_row in zip(matrix, want_matrix):
+            np.testing.assert_array_equal(row, want_row)
+        frontier = reached
+    # the graphs do re-enter: some level charges edges it does not follow
+    if not allow_loop:
+        assert any(sum(len(r) for r in m) < t for _f, t, m in want)
+
+
+@pytest.fixture(scope="module")
+def one_v5e():
+    """A described (not attached) v5e chip to compile for: the TPU's own
+    compiler and its memory analysis, with no chip. Skips where this
+    installation cannot describe one."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")   # or the compiler logs to /tmp
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        # a compile for a described chip can be written to the persistent
+        # cache and never read back (the next one would warn); these are
+        # the file's last tests, so no other compile goes uncached
+        cached = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            jax.config.update("jax_enable_compilation_cache", cached)
+            compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("program", ["recurse_fused", "recurse_fused_multi",
+                                     "recurse_step"])
+def test_recurse_programs_hold_nothing_edge_sized(one_v5e, monkeypatch,
+                                                  program):
+    """A recurse level dedups vertices: beside the two edge streams it is
+    given, a recurse program compiled for the chip holds nothing with an
+    element an edge — no output (the bool[depth, E_pad] fresh flags are
+    gone) and no temporary (the per-edge prefix, `seen`, `fresh` and their
+    cumsum were 14 bytes an edge and more): outputs and temporaries
+    together stay under one BYTE an edge. 2.1M edges over 40k + 40k
+    ranks, shapes no other test traces (the kernels are lowered for the
+    chip here, not for the interpreter)."""
+    monkeypatch.setattr(pb, "interpret_mode", lambda: False)
+    e_pad, n_items = 256 * pb.EDGE_BLOCK, 320
+    ns, nd, n = 40_000, 40_001, 65_536
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e)
+
+    ends = pb.RowEnds(arg((n_items,)), arg((n_items,)))
+    fused = dict(depth=3, chunks=2, chunks_d=2, allow_loop=False)
+    layout = (arg((e_pad,)), arg((e_pad,)), arg((nd + 1,)), ends, arg((ns,)),
+              arg((nd,)), arg((ns + 1,)), arg((nd,)))
+    compiled = {
+        "recurse_fused": lambda: pb.recurse_fused.lower(
+            *layout, arg((n,), bool), **fused),
+        "recurse_fused_multi": lambda: pb.recurse_fused_multi.lower(
+            *layout, arg((2, n), bool), **fused),
+        "recurse_step": lambda: pb.recurse_step.lower(
+            arg((e_pad,)), arg((nd + 1,)), ends, arg((ns,)), arg((nd,)),
+            arg((ns + 1,)), arg((n,), bool), arg((ns,), bool),
+            chunks=2, num_nodes=n, allow_loop=False),
+    }[program]().compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.output_size_in_bytes < e_pad

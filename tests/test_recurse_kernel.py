@@ -110,12 +110,12 @@ def test_kernel_steps_fence_inside_the_gate_slot(rng, monkeypatch):
     try:
         node.query("{ q(func: uid(0x1, 0x2)) @recurse(depth: 3) { follow } }")
         assert len(outs) == 1                       # fused: one dispatch
-        masks_h, trav_h, _fresh = outs.pop()
+        masks_h, trav_h = outs.pop()
         assert isinstance(masks_h, np.ndarray)
         assert isinstance(trav_h, np.ndarray)
         node.query("{ q(func: uid(0x1)) @recurse(depth: 3) { follow knows } }")
         assert outs                                 # stepped: one per level
-        for (dest_words_h, trav_h), _seen, _fresh in outs:
+        for (dest_words_h, trav_h), _expanded in outs:
             assert isinstance(dest_words_h, np.ndarray)
             assert isinstance(trav_h, np.ndarray)
         assert gate.expected_step("recurse") > 0
