@@ -965,13 +965,23 @@ def _split_mutation_blocks(body: str) -> tuple[str, str]:
     return sets, dels
 
 
+class _Server(ThreadingHTTPServer):
+    """A thread a connection, and a listen backlog that holds a pool of
+    clients connecting at once: http.server's own 5 resets the sixth
+    connection that arrives while the accept loop waits for the
+    interpreter (or leaves its SYN to a retry a second later) — 22 arrive
+    together in RedisGraph's parallel-requests test."""
+
+    request_queue_size = 128
+
+
 def make_server(node: Node, host: str = "127.0.0.1", port: int = 8080,
                 tls_cert: str | None = None,
                 tls_key: str | None = None) -> ThreadingHTTPServer:
     """HTTP (or HTTPS when a cert+key pair is given — the reference's
     x/tls_helper.go server-side TLS surface)."""
     handler = type("BoundHandler", (_Handler,), {"node": node})
-    srv = ThreadingHTTPServer((host, port), handler)
+    srv = _Server((host, port), handler)
     if tls_cert and tls_key:
         import ssl
         ctx = ssl.SSLContext(ssl.PROTOCOL_TLS_SERVER)

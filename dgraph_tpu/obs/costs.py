@@ -27,7 +27,10 @@ ledger cannot: WHERE inside the request the wall time went. The entry
 point that owns a request opens it; `with costs.stage("parse"):` switches
 the request's one current stage; `costs.kernel(...)` windows are stages
 too (dev.window, or dev.dispatch / dev.wait / dev.post where the site
-splits its window). It is read three ways off one clock: always-on
+splits its window), and so are the two waits of a request that shares
+the device: `gate.wait` (queued for a slot of the dispatch gate) and
+`batch.wait` (a batch leader's wait for companions, a follower's wait
+for its leader's launch). It is read three ways off one clock: always-on
 /metrics counters (dgraph_stage_us_total{stage=}), and for a sampled
 request child spans in /debug/traces and jax.profiler annotations.
 
@@ -626,13 +629,14 @@ class StageClock:
 
     def server_latency(self) -> dict:
         """The reference's Latency split, from the stages closed so far:
-        parsing = `parse`; processing = `plan` + `exec*` + `dev.*`;
-        encoding = `encode`."""
+        parsing = `parse`; processing = `plan` + `exec*` + `dev.*` +
+        `batch.wait` + `gate.wait`; encoding = `encode`."""
         ns = self.ns
         return {"parsing_ns": ns.get("parse", 0),
                 "processing_ns": sum(
                     v for k, v in ns.items()
-                    if k == "plan" or k.startswith(("exec", "dev."))),
+                    if k == "plan" or k.startswith(
+                        ("exec", "dev.", "batch.", "gate."))),
                 "encoding_ns": ns.get("encode", 0)}
 
 

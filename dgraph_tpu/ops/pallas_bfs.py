@@ -870,27 +870,44 @@ def recurse_fused(in_src_pad, in_src_pad_d, in_iptr_rank, row_ends, subjects,
         chunks=chunks, chunks_d=chunks_d, allow_loop=allow_loop)
 
 
-@partial(jax.jit, static_argnames=("depth", "chunks", "chunks_d",
-                                   "allow_loop"))
+@partial(jax.jit, static_argnames=("num_nodes", "depth", "chunks",
+                                   "chunks_d", "allow_loop"))
 def recurse_fused_multi(in_src_pad, in_src_pad_d, in_iptr_rank, row_ends,
                         subjects, in_subjects, fwd_indptr, out_degree_d,
-                        seeds_masks, *, depth: int, chunks: int,
+                        seeds, *, num_nodes: int, depth: int, chunks: int,
                         chunks_d: int, allow_loop: bool):
-    """Multi-source batched recurse: seeds_masks [B, num_nodes] stacks B
-    concurrent queries' seed masks and the whole batch runs as ONE device
-    dispatch — the one-extra-dimension extension of recurse_fused the
-    batched-dispatch tier launches (query/batch.py). lax.map over the
-    exact recurse_fused body, so slice b of the stacked outputs is
-    bit-identical to a solo recurse_fused call with seeds_masks[b] (the
-    per-level ops are integer/boolean — no float reassociation). Each
-    query keeps its own expanded set: batching never entangles
-    traversals. Returns (masks_p [B, depth, ...], traversed [B, depth])."""
-    return lax.map(
-        lambda sm: _recurse_fused_levels(
+    """Multi-source batched recurse: seeds int32[B, S] lists the seed uids
+    of B concurrent queries, one row a query, each row padded with
+    `num_nodes` (past the uid space: the scatter drops it) and a row of
+    pads only for a slot no query holds. The whole batch runs as ONE
+    device dispatch — the one-extra-dimension extension of recurse_fused
+    the batched-dispatch tier launches (query/batch.py), which hands it
+    one host array: the seed masks are built here, inside the program, so
+    no occupancy has eager programs of its own and B is the batcher's
+    capacity whatever the occupancy. lax.map over the exact recurse_fused
+    body, so slice b of the stacked outputs is bit-identical to a solo
+    recurse_fused call with row b's seed mask (the per-level ops are
+    integer/boolean — no float reassociation); a row without a seed skips
+    the body and hands back zeros, which is what the body gives an empty
+    frontier. Each query keeps its own expanded set: batching never
+    entangles traversals. Returns (masks_p [B, depth, ...], traversed
+    [B, depth])."""
+    nd = in_subjects.shape[0]
+
+    def levels(row):
+        mask = jnp.zeros((num_nodes,), bool).at[row].set(True, mode="drop")
+        return _recurse_fused_levels(
             in_src_pad, in_src_pad_d, in_iptr_rank, row_ends, subjects,
-            in_subjects, fwd_indptr, out_degree_d, sm, depth=depth,
-            chunks=chunks, chunks_d=chunks_d, allow_loop=allow_loop),
-        seeds_masks)
+            in_subjects, fwd_indptr, out_degree_d, mask, depth=depth,
+            chunks=chunks, chunks_d=chunks_d, allow_loop=allow_loop)
+
+    def nothing(_row):
+        return (jnp.zeros((depth, pack_chunks(nd) * 8, _LANES), jnp.int32),
+                jnp.zeros((depth,), jnp.int32))
+
+    return lax.map(
+        lambda row: lax.cond(jnp.any(row < num_nodes), levels, nothing, row),
+        seeds)
 
 
 # device-runtime observatory (obs/devprof.py, ISSUE 19): jitted entry

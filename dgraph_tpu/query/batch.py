@@ -93,10 +93,13 @@ class _VectorWork:
 
 
 class _RecurseWork:
-    __slots__ = ("g", "seeds_mask")
+    """One fused recurse: its PullGraph and its seed uids (a host array,
+    every one below g.num_nodes)."""
 
-    def __init__(self, g, seeds_mask):
-        self.g, self.seeds_mask = g, seeds_mask
+    __slots__ = ("g", "seeds")
+
+    def __init__(self, g, seeds):
+        self.g, self.seeds = g, seeds
 
 
 def classify(snap, schema, q):
@@ -240,6 +243,10 @@ class _Batch:
         self.closed = False
 
 
+# the device_kernel family of a batched launch, by kind
+_FAMILY = {"expand": "batch.expand", "vector": "batch.vector_topk",
+           "recurse": "batch.recurse"}
+
 # follower safety net: a leader always sets every entry's event in its
 # finally block, so this only fires on catastrophic leader death
 _FOLLOWER_WAIT_S = 120.0
@@ -277,6 +284,9 @@ class DeviceBatcher:
         m = self.metrics
         self._formed = m.counter("dgraph_batch_formed_total")
         self._tasks = m.counter("dgraph_batch_tasks_total")
+        # its le="1" bucket counts the launches that took one task alone,
+        # so tasks_total less that bucket is the tasks answered from a
+        # launch of two or more
         self._occupancy = m.histogram("dgraph_batch_occupancy")
         self._window_waits = m.counter("dgraph_batch_window_waits_total")
         self._bypass = m.counter("dgraph_batch_deadline_bypass_total")
@@ -358,6 +368,30 @@ class DeviceBatcher:
             if n > 1:
                 en.lg.note("batched")
 
+    def _collect(self, b: _Batch, kind: str) -> None:
+        """The leader's wait for companions: one window, or until the
+        batch fills."""
+        t0 = time.perf_counter()
+        # dgraph: allow(deadline-wait) leader window wait is bounded by
+        # the ~2ms collection window constant; tight budgets bypassed the
+        # window entirely upstream
+        b.full.wait(self.window_s)
+        # continuous collection: while the device is busy (a step running
+        # or queued at the gate) the window is free — the batch would
+        # only sit in the gate queue anyway, so keep it open and
+        # collecting until the slot is imminent (~one expected step) or
+        # it fills. The device never idles waiting on a window; the
+        # window only bounds the wait when firing immediately is
+        # actually possible.
+        cap = self.window_s + (
+            self.gate.expected_step(kind)
+            if self.gate is not None else 0.0)
+        while (not b.full.is_set()) and self._busy() and \
+                time.perf_counter() - t0 < cap:
+            # dgraph: allow(deadline-wait) bounded by `cap` (one window +
+            # one expected step) in the loop condition
+            b.full.wait(self.window_s)
+
     def _submit(self, key: tuple, kind: str, work,
                 runner: Callable[[list[_Entry]], None], solo=None):
         """Join an open compatible batch or lead a new one. The leader
@@ -365,7 +399,13 @@ class DeviceBatcher:
         freezes the batch, runs `runner` (which must fill every entry's
         result or error), and wakes the followers. A batch of ONE runs its
         solo closure instead — identical kernels, spans, and compiled
-        programs as the pre-batching path for unaccompanied traffic."""
+        programs as the pre-batching path for unaccompanied traffic.
+
+        On the request's stage clock (obs/costs.py) a follower's wait for
+        its leader and a leader's wait for companions are `batch.wait`;
+        the follower's wait is also a device_kernel span of the batch's
+        family (role="follower"), so its trace says which launch answered
+        it as the leader's (role="leader", opened by the runner) does."""
         entry = _Entry(work, solo)
         with self._lock:
             b = self._open.get(key)
@@ -383,13 +423,18 @@ class DeviceBatcher:
             rem = dl.remaining()
             wait_s = _FOLLOWER_WAIT_S if rem is None else \
                 min(_FOLLOWER_WAIT_S, max(rem, 0.0) + 0.1)
-            if not entry.event.wait(wait_s):
-                # own budget gone while the batch still runs: typed
-                # DeadlineExceeded (the lifeline contract: never a hang
-                # past the budget), the batch result is discarded
-                dl.check(f"batched {kind} dispatch")
-                raise RuntimeError(
-                    f"batched {kind} dispatch leader never completed")
+            with otrace.span("device_kernel", kernel=_FAMILY[kind],
+                             role="follower") as sp, \
+                    costs.stage("batch.wait"):
+                if not entry.event.wait(wait_s):
+                    # own budget gone while the batch still runs: typed
+                    # DeadlineExceeded (the lifeline contract: never a
+                    # hang past the budget), the batch result is discarded
+                    dl.check(f"batched {kind} dispatch")
+                    raise RuntimeError(
+                        f"batched {kind} dispatch leader never completed")
+                if sp:
+                    sp.set(batch=entry.batch_size)
             otrace.event("batched", kind=kind, size=entry.batch_size)
             if entry.error is not None:
                 raise entry.error
@@ -399,26 +444,8 @@ class DeviceBatcher:
                     not (self.idle_fire and not self._busy()
                          and time.perf_counter() >= self._burst_until):
                 self._window_waits.inc()
-                t0 = time.perf_counter()
-                # dgraph: allow(deadline-wait) leader window wait is
-                # bounded by the ~2ms collection window constant; tight
-                # budgets bypassed the window entirely upstream
-                b.full.wait(self.window_s)
-                # continuous collection: while the device is busy (a step
-                # running or queued at the gate) the window is free — the
-                # batch would only sit in the gate queue anyway, so keep
-                # it open and collecting until the slot is imminent
-                # (~one expected step) or it fills. The device never
-                # idles waiting on a window; the window only bounds the
-                # wait when firing immediately is actually possible.
-                cap = self.window_s + (
-                    self.gate.expected_step(kind)
-                    if self.gate is not None else 0.0)
-                while (not b.full.is_set()) and self._busy() and \
-                        time.perf_counter() - t0 < cap:
-                    # dgraph: allow(deadline-wait) bounded by `cap` (one
-                    # window + one expected step) in the loop condition
-                    b.full.wait(self.window_s)
+                with costs.stage("batch.wait"):
+                    self._collect(b, kind)
         finally:
             with self._lock:
                 b.closed = True
@@ -490,17 +517,18 @@ class DeviceBatcher:
         return self._submit(key, kind, work, runner,
                             solo=lambda: solo(q, klass=kind))
 
-    def dispatch_recurse(self, g, seeds_mask, depth: int, allow_loop: bool,
-                         solo: Callable):
+    def dispatch_recurse(self, g, seeds: np.ndarray, depth: int,
+                         allow_loop: bool, solo: Callable):
         """The fused-recurse seam (query/recurse.py): compatible concurrent
         traversals (same PullGraph — which pins tablet + snapshot — same
-        depth, same loop rule) stack their seed masks into ONE multi-source
-        recurse_fused_multi dispatch. `solo` is the ungated single-query
+        depth, same loop rule) stack their seed uids into ONE multi-source
+        recurse_fused_multi dispatch. `seeds` is a host array of uids
+        below g.num_nodes; `solo` is the ungated single-query
         recurse_fused closure."""
         key = ("recurse", id(g), depth, allow_loop)
         if self._deadline_bypasses("recurse"):
             return self._gate_run(solo, "recurse")
-        work = _RecurseWork(g, seeds_mask)
+        work = _RecurseWork(g, seeds)
 
         def runner(entries: list[_Entry]) -> None:
             self._run_recurse(entries, depth, allow_loop)
@@ -551,7 +579,8 @@ class DeviceBatcher:
 
         try:
             with otrace.span("device_kernel", kernel="batch.expand",
-                             need=total, batch=nbatch) as sp:
+                             need=total, batch=nbatch,
+                             role="leader") as sp:
                 targets, dt_ms = self._timed_gate_run(kernel, "expand")
                 self._charge(entries, "batch.expand", dt_ms,
                              weights=[float(e.work.need) for e in entries],
@@ -630,7 +659,8 @@ class DeviceBatcher:
 
         try:
             with otrace.span("device_kernel", kernel="batch.vector_topk",
-                             rows=int(vi.n), k=kprime, batch=nbatch) as sp:
+                             rows=int(vi.n), k=kprime, batch=nbatch,
+                             role="leader") as sp:
                 (nd_h, rows_h), dt_ms = self._timed_gate_run(kernel,
                                                              "vector")
                 self._charge(entries, "batch.vector_topk", dt_ms,
@@ -676,35 +706,51 @@ class DeviceBatcher:
 
     def _run_recurse(self, entries: list[_Entry], depth: int,
                      allow_loop: bool) -> None:
-        """Stacked seed masks through recurse_fused_multi; slice b of the
-        stacked outputs is bit-identical to a solo recurse_fused call (the
-        per-level ops are integer/boolean). Each entry receives its
-        (masks_p, traversed) pair."""
-        import jax.numpy as jnp
+        """The members' seed uids, one row each of ONE host array, through
+        recurse_fused_multi; slice b of the stacked outputs is
+        bit-identical to a solo recurse_fused call (the per-level ops are
+        integer/boolean). Host arrays in, one jitted call, one fetch: the
+        array always has max_batch rows (a row of pads skips the
+        traversal on the device), so every occupancy runs one program and
+        none has eager programs of its own; its width is a pow2 class of
+        the longest seed list. Each entry receives its (masks, traversed)
+        pair as slices of the fetched host arrays. The leader's clock is
+        in dev.dispatch until the call returned its futures and in
+        dev.wait in the fetch, as the solo closure's."""
+        import jax
 
         from dgraph_tpu.ops import pallas_bfs as pb
 
         g = entries[0].work.g
         nbatch = len(entries)
-        # pad the batch dimension to a pow2 class (all-false seed masks
-        # traverse nothing) so B=2..16 share a handful of compiled
-        # programs instead of one per occupancy
-        bcap = 1 << max(int(np.ceil(np.log2(nbatch))), 0)
-        seeds = jnp.stack(
-            [e.work.seeds_mask for e in entries] +
-            [jnp.zeros_like(entries[0].work.seeds_mask)] * (bcap - nbatch))
+        width = max(1, *(len(e.work.seeds) for e in entries))
+        seeds = np.full((self.max_batch, 1 << (width - 1).bit_length()),
+                        g.num_nodes, dtype=np.int32)
+        for i, e in enumerate(entries):
+            seeds[i, : len(e.work.seeds)] = e.work.seeds
 
         def kernel():
-            return pb.recurse_fused_multi(
-                g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank, g.row_ends,
-                g.subjects, g.in_subjects, g.fwd_indptr, g.out_degree_d,
-                seeds, depth=depth, chunks=g.chunks, chunks_d=g.chunks_d,
-                allow_loop=allow_loop)
+            with costs.stage("dev.dispatch"):
+                out = pb.recurse_fused_multi(
+                    g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank,
+                    g.row_ends, g.subjects, g.in_subjects, g.fwd_indptr,
+                    g.out_degree_d, seeds, num_nodes=g.num_nodes,
+                    depth=depth, chunks=g.chunks, chunks_d=g.chunks_d,
+                    allow_loop=allow_loop)
+            # the fetch is the fence, as in the solo closure: the timer
+            # and the gate slot cover the device run, not its enqueue
+            with costs.stage("dev.wait"):
+                return jax.device_get(out)
 
         with otrace.span("device_kernel", kernel="batch.recurse",
-                         depth=depth, batch=nbatch):
-            (masks_p, trav), dt_ms = self._timed_gate_run(
+                         depth=depth, batch=nbatch, role="leader") as sp:
+            (masks_h, trav_h), dt_ms = self._timed_gate_run(
                 kernel, "recurse")
-            self._charge(entries, "batch.recurse", dt_ms)
+            d2h = int(masks_h.nbytes + trav_h.nbytes)
+            self._charge(entries, "batch.recurse", dt_ms,
+                         h2d=int(seeds.nbytes), d2h=d2h)
+            if sp:
+                sp.set(transfer_h2d_bytes=int(seeds.nbytes),
+                       transfer_d2h_bytes=d2h)
         for i, e in enumerate(entries):
-            e.result = (masks_p[i], trav[i])
+            e.result = (masks_h[i], trav_h[i])

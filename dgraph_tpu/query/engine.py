@@ -242,15 +242,18 @@ class Executor:
         return self.gate.run(fn, klass=klass) if self.gate is not None \
             else fn()
 
-    def batched_recurse(self, g, seeds_mask, depth: int, allow_loop: bool,
+    def batched_recurse(self, g, seeds, depth: int, allow_loop: bool,
                         solo):
         """Fused-recurse seam of the dispatch batcher: compatible
         concurrent traversals (same PullGraph object — which pins tablet
-        and snapshot — same depth and loop rule) stack their seed masks
-        into ONE multi-source dispatch (ops/pallas_bfs.recurse_fused_multi)
-        instead of serializing through the gate one fused scan each."""
+        and snapshot — same depth and loop rule) stack their seed uids
+        (`seeds`: a host array, each below g.num_nodes) into ONE
+        multi-source dispatch (ops/pallas_bfs.recurse_fused_multi)
+        instead of serializing through the gate one fused scan each.
+        Either way the caller gets host arrays: (packed level masks,
+        traversed per level)."""
         if self.batcher is not None:
-            return self.batcher.dispatch_recurse(g, seeds_mask, depth,
+            return self.batcher.dispatch_recurse(g, seeds, depth,
                                                  allow_loop, solo)
         return self.gated(solo, klass="recurse")
 

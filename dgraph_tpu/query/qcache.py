@@ -448,7 +448,9 @@ class DispatchGate:
 
     def _acquire(self, klass: str | None = None) -> None:
         """Budget-aware semaphore acquisition. Raises typed errors instead
-        of waiting past the caller's deadline."""
+        of waiting past the caller's deadline. Queued for a slot, the
+        request's stage clock (obs/costs.py) is in `gate.wait`; the slot
+        taken at once switches nothing."""
         fair = self.fair
         if fair is not None and self.tenant_fn is not None:
             # tenant-fair admission SUBSUMES the non-blocking fast path:
@@ -487,7 +489,9 @@ class DispatchGate:
             # deadline-safe: acquire() parks in dl.clamp(0.05) slices and
             # raises a typed DeadlineExceeded once the budget expires, so
             # a budgeted request can never hang in the fair queue
-            if fair.acquire(self.tenant_fn(), self._sem):
+            with costs.stage("gate.wait"):
+                waited = fair.acquire(self.tenant_fn(), self._sem)
+            if waited:
                 self._waits.inc()
                 costs.add_gate_wait((time.perf_counter() - t0) * 1e3)
             return
@@ -497,7 +501,8 @@ class DispatchGate:
         rem = dl.remaining()
         if rem is None:
             t0 = time.perf_counter()
-            self._sem.acquire()
+            with costs.stage("gate.wait"):
+                self._sem.acquire()
             costs.add_gate_wait((time.perf_counter() - t0) * 1e3)
             return
         # shed before queueing: a request whose remaining budget cannot
@@ -531,7 +536,8 @@ class DispatchGate:
                 f"shed: dispatch queue full ({queued} waiting)")
         t0 = time.perf_counter()
         try:
-            ok = self._sem.acquire(timeout=rem)
+            with costs.stage("gate.wait"):
+                ok = self._sem.acquire(timeout=rem)
         finally:
             with self._wlock:
                 self._waiting -= 1

@@ -529,19 +529,23 @@ def _recurse_fused_path(ex, sg: SubGraph, cgq, csr, depth: int,
     Stages of the request's clock (obs/costs.py): pull_graph_for is
     exec.prep; the seed mask's eager programs and the jitted call are
     dev.dispatch; blocked in the fetch is dev.wait; everything the host
-    does with the fetched masks is dev.post."""
+    does with the fetched masks is dev.post. In a stacked launch
+    (query/batch.py) only the leader has dev.dispatch and dev.wait: a
+    follower is in batch.wait until its slices are there."""
     from dgraph_tpu.ops import pallas_bfs as pb
 
     with costs.stage("exec.prep"):
         g = pb.pull_graph_for(csr)
     nd = len(g.host_in_subjects)
     seeds = np.sort(np.asarray(sg.dest_uids, dtype=np.int64))
-    with costs.stage("dev.dispatch"):
-        seeds_mask = _seeds_mask(seeds, g.num_nodes)
     # batched-dispatch seam (query/batch.py): compatible concurrent
-    # traversals stack their seed masks into one multi-source dispatch;
-    # without a batcher this is exactly the old gated solo call
+    # traversals hand their seed uids to one multi-source dispatch, which
+    # builds the masks inside its program; a traversal that runs alone
+    # builds its own here (eager programs, S5 (a)), inside its gate slot.
+    # Without a batcher this is exactly the old gated solo call
     def _solo_fused():
+        with costs.stage("dev.dispatch"):
+            seeds_mask = _seeds_mask(seeds, g.num_nodes)
         with otrace.span("device_kernel", kernel="pb.recurse_fused",
                          depth=depth, edges=g.num_edges) as sp, \
                 costs.kernel("pb.recurse_fused", attr=cgq.attr,
@@ -565,14 +569,13 @@ def _recurse_fused_path(ex, sg: SubGraph, cgq, csr, depth: int,
                            reached=int(pb.unpack_words(union, nd).sum()))
             return masks_h, trav_h
 
-    masks_p, trav = ex.batched_recurse(
-        g, seeds_mask, depth, allow_loop, _solo_fused)
+    # ONE host round-trip for the whole traversal, bit-packed in DST-RANK
+    # space, fetched under the timer of whichever launch ran it (the solo
+    # closure, or the batch leader's runner: slices of its host arrays);
+    # the host maps ranks -> uids
+    masks_h, trav_h = ex.batched_recurse(
+        g, seeds[seeds < g.num_nodes], depth, allow_loop, _solo_fused)
     with costs.stage("dev.post"):
-        # ONE host round-trip for the whole traversal, bit-packed in
-        # DST-RANK space; host maps ranks -> uids.
-        # A no-op after the solo closure, which fetched under its timer;
-        # the batched runner hands back device slices.
-        masks_h, trav_h = jax.device_get((masks_p, trav))
         live, union = _fused_levels(masks_h)
         _count_levels(ex, live, depth - live)
         traversed = int(trav_h[:live].sum())

@@ -489,9 +489,12 @@ class Registry:
         # where a request's time goes (obs/costs.py StageClock): integer
         # microseconds per named stage, summed over closed requests; and
         # the cost ledger's per-kernel device windows (lg.kernels), which
-        # otherwise reach only /debug/top's ring
+        # otherwise reach only /debug/top's ring. The two waits of a
+        # request that shares the device show from start-up, at 0: one
+        # client never enters them, and a reader has to tell that from a
+        # program without the stages
         self.keyed_gauges["dgraph_stage_us_total"] = KeyedGauge(
-            labels=("stage",))
+            labels=("stage",), keep=("batch.wait", "gate.wait"))
         self.keyed_gauges["dgraph_kernel_us_total"] = KeyedGauge(
             labels=("kernel",))
         self.keyed_gauges["dgraph_kernel_calls_total"] = KeyedGauge(

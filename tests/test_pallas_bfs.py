@@ -451,7 +451,9 @@ def test_row_end_items_hold_every_row_once(rng, shape):
 # sha256 of the lowered text (CPU: the kernels in interpret mode, inlined)
 # of the programs a request reaches, for rmat_csr(12, 8, seed=5): the
 # recurse programs as PR 35 left them (one emit, row_end_prefix*, for
-# every program; edge dedup on vertices), bfs_dist as it was at commit
+# every program; edge dedup on vertices; recurse_fused_multi as PR 36
+# left it: rows of seed uids in, the masks built inside, a row of pads
+# skipped), bfs_dist as it was at commit
 # ab2061a (PR 34), before that PR — the search shares its kernels, its
 # membership tests and _hop_for with them. A PR that means to change a
 # program replaces its line
@@ -459,7 +461,7 @@ PROGRAM_LOWERINGS = {
     "recurse_fused":
         "f949fee3a0c0057b01a81127a9c41e559a3f8b58ce76df9b125dad9157d10a65",
     "recurse_fused_multi":
-        "e939cc21f4bd62d393c6fb5092346a3a978841baddfe9eabd1a464b2e2a7005e",
+        "1622c9e149b82305e609b5e4706b77f2e113ce906fffc5b395fe61c7e9c59391",
     "recurse_step":
         "4466867cced45d75ff48c0a2ad6f03625b1e8310091440167409d66f2b607386",
     "bfs_dist":
@@ -477,7 +479,8 @@ def lower_program(program, subjects, indptr, indices):
         "recurse_fused": lambda: pb.recurse_fused.lower(
             *fused_layout(g), jnp.zeros((n,), bool), **fused),
         "recurse_fused_multi": lambda: pb.recurse_fused_multi.lower(
-            *fused_layout(g), jnp.zeros((2, n), bool), **fused),
+            *fused_layout(g), np.zeros((2, 1), np.int32), num_nodes=n,
+            **fused),
         "recurse_step": lambda: pb.recurse_step.lower(
             g.in_src_pad, g.in_iptr_rank, g.row_ends, g.subjects,
             g.in_subjects, g.fwd_indptr, jnp.zeros((n,), bool),
@@ -740,7 +743,7 @@ def test_recurse_programs_hold_nothing_edge_sized(one_v5e, monkeypatch,
         "recurse_fused": lambda: pb.recurse_fused.lower(
             *layout, arg((n,), bool), **fused),
         "recurse_fused_multi": lambda: pb.recurse_fused_multi.lower(
-            *layout, arg((2, n), bool), **fused),
+            *layout, arg((2, 1)), num_nodes=n, **fused),
         "recurse_step": lambda: pb.recurse_step.lower(
             arg((e_pad,)), arg((nd + 1,)), ends, arg((ns,)), arg((nd,)),
             arg((ns + 1,)), arg((n,), bool), arg((ns,), bool),

@@ -617,6 +617,9 @@ def test_metrics_carry_stage_kernel_and_startup_series(monkeypatch):
     assert {"http.read", "parse", "plan", "exec", "exec.prep",
             "dev.dispatch", "dev.wait", "dev.post", "dev.window", "encode",
             "http.write"} <= set(stage_us)
+    # the two waits of a request that shares the device show at 0: one
+    # request at a time never queues for the gate or rides a batch
+    assert stage_us.pop("batch.wait") == stage_us.pop("gate.wait") == 0
     assert all(v > 0 and v == int(v) for v in stage_us.values())
     assert series["dgraph_stage_requests_total"][0][1] == 2
     kernel_us = labelled("dgraph_kernel_us_total", "kernel")
