@@ -349,8 +349,8 @@ class PullGraph(NamedTuple):
     bitmap chunk loop (the kernel's per-edge cost), the frontier pack, and
     the node phase (the row-end slots).
     The device arrays are the programs' arguments; the host arrays are
-    what the engine reads between launches (a search's first level and its
-    backtrack)."""
+    what the engine reads between launches (a traversal's seeds as ranks,
+    its first level, a search's backtrack)."""
 
     in_src_pad: jax.Array       # int32[E_pad] source SRC-RANKS, dst-sorted
     in_src_pad_d: jax.Array     # int32[E_pad] source DST-RANKS, dst-sorted
@@ -360,8 +360,13 @@ class PullGraph(NamedTuple):
     # kernels walk
     subjects: jax.Array         # int32[Ns] sorted uids with out-edges
     in_subjects: jax.Array      # int32[Nd] sorted uids with in-edges
-    fwd_indptr: jax.Array       # int32[Ns+1] forward CSR (a search's level 1)
+    fwd_indptr: jax.Array       # int32[Ns+1] forward CSR: the rows level 1
+    # of a search or of a fused recurse reads
     fwd_dst_rank: jax.Array     # int32[E] dst RANKS in forward edge order
+    fwd_dst_pad: jax.Array      # int32[E_pad] the same, padded to the edge
+    # stream's block class: what the fused recurse programs read their
+    # seeds' rows from, so that an edge written inside the pad block moves
+    # no shape of theirs (bfs_dist takes the exact array)
     out_degree_d: jax.Array     # int32[Nd] out-degree by DST rank (0: the
     # destination has no out-edge): what a recurse level >= 2 charges; by
     # src rank it is diff(fwd_indptr)
@@ -376,7 +381,7 @@ class PullGraph(NamedTuple):
     host_in_subjects: np.ndarray  # HOST int64[Nd]
     host_subjects: np.ndarray   # HOST int64[Ns]
     host_fwd_indptr: np.ndarray  # HOST int[Ns+1]: a source's out-degree,
-    # which picks the first level of a search
+    # which picks the first level of a search and of a fused recurse
 
 
 def prep_pull(subjects: np.ndarray, indptr: np.ndarray,
@@ -438,6 +443,8 @@ def prep_pull(subjects: np.ndarray, indptr: np.ndarray,
     # forward layout: the row a search's first level reads
     fwd_dst_rank = np.searchsorted(in_subjects, np.asarray(indices)).astype(
         np.int32)                    # every dst IS in in_subjects
+    fwd_dst_pad = np.zeros(e_pad, dtype=np.int32)
+    fwd_dst_pad[:E] = fwd_dst_rank
     out_degree_d = np.zeros(nd + 1, dtype=np.int32)    # slot Nd: dropped
     out_degree_d[map_s2d] = np.diff(indptr)
     return PullGraph(jnp.asarray(src_pad), jnp.asarray(src_pad_d),
@@ -445,7 +452,8 @@ def prep_pull(subjects: np.ndarray, indptr: np.ndarray,
                      jnp.asarray(subjects.astype(np.int32)),
                      jnp.asarray(in_subjects.astype(np.int32)),
                      jnp.asarray(np.asarray(indptr).astype(np.int32)),
-                     jnp.asarray(fwd_dst_rank), jnp.asarray(out_degree_d[:nd]),
+                     jnp.asarray(fwd_dst_rank), jnp.asarray(fwd_dst_pad),
+                     jnp.asarray(out_degree_d[:nd]),
                      int(num_nodes), int(E), int(chunks), int(chunks_d),
                      iptr, src_sorted, map_s2d,
                      in_subjects.astype(np.int64), subjects.astype(np.int64),
@@ -547,7 +555,8 @@ def _reached_for(frontier_bits, stream, n_chunks: int, ends: RowEnds, last):
 
 
 def _recurse_tail(frontier, expanded, out_degree, stream, n_chunks: int,
-                  ends: RowEnds, last, nd: int, allow_loop: bool):
+                  ends: RowEnds, last, nd: int, allow_loop: bool,
+                  flist=None):
     """One recurse level in the stream's rank space: (frontier bits,
     the vertices that were in an earlier frontier) → (reached [Nd],
     traversed, expanded'). Edge dedup, exactly, on vertices: an edge is
@@ -558,7 +567,12 @@ def _recurse_tail(frontier, expanded, out_degree, stream, n_chunks: int,
     re-entered ones included (the budget the reference charges,
     recurse.go:167); reached = dst ranks with >= 1 fresh in-edge. The
     exactness-critical piece, kept in ONE place for the fused and stepped
-    paths alike."""
+    paths alike. `flist`, where the caller holds one (the rows a pushed
+    level 1 read), lists the frontier's ranks — at most FRONTIER_CAP, in
+    any order, _INT32_MAX for an empty lane: the listed ranks that are
+    live, sorted, ARE the sparse kernel's table, with no nonzero over the
+    mask (a rank listed twice changes the table and not what it
+    answers)."""
     with jax.named_scope("visit"):
         traversed = jnp.sum(jnp.where(frontier, out_degree, 0),
                             dtype=jnp.int32)
@@ -568,7 +582,15 @@ def _recurse_tail(frontier, expanded, out_degree, stream, n_chunks: int,
             live = frontier & ~expanded
             expanded2 = expanded | frontier
     with jax.named_scope("prefix"):
-        bounds = _reached_for(live, stream, n_chunks, ends, last)
+        if flist is None:
+            bounds = _reached_for(live, stream, n_chunks, ends, last)
+        else:
+            is_live = jnp.take(live, flist, mode="fill", fill_value=False)
+            flist = jnp.sort(jnp.where(is_live, flist, _INT32_MAX))
+            flist = jnp.pad(flist, (0, FRONTIER_CAP - flist.shape[0]),
+                            constant_values=_INT32_MAX)
+            bounds = row_end_prefix_sparse(_table_of_list(flist), stream,
+                                           ends, last)
     return _reached_from(bounds, nd), traversed, expanded2
 
 
@@ -802,59 +824,169 @@ def _walk_back(g: PullGraph, dist: np.ndarray, dr: int, src: int, dst: int):
     return path[::-1]
 
 
-def _recurse_fused_levels(in_src_pad, in_src_pad_d, in_iptr_rank, row_ends,
-                          subjects, in_subjects, fwd_indptr, out_degree_d,
-                          seeds_mask, *, depth: int, chunks: int,
-                          chunks_d: int, allow_loop: bool):
-    """Traced body shared by recurse_fused (one seed mask) and
-    recurse_fused_multi (a stacked batch of seed masks): all `depth`
-    levels as one lax.scan over the SAME per-level tail."""
-    nd = in_subjects.shape[0]
-    last = _last_edges(in_iptr_rank)           # what every level's pick reads
+def fused_graph_args(g: PullGraph) -> tuple:
+    """The graph arguments of recurse_fused / recurse_fused_multi, in
+    order; the seeds follow them."""
+    return (g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank, g.row_ends,
+            g.fwd_indptr, g.fwd_dst_pad, g.out_degree_d)
 
-    def body(carry, i):
+
+def seed_ranks(g: PullGraph, uids) -> np.ndarray:
+    """int32[2, n]: the distinct seed uids of a recurse as ranks, found on
+    the host — row 0 in the source space (Ns: no out-edge), row 1 in the
+    destination space (Nd: no in-edge). What a fused program takes in
+    place of a uid-space mask (stack_seeds pads it)."""
+    from dgraph_tpu.ops.uidset import host_rank_of
+
+    uids = np.unique(np.asarray(uids, dtype=np.int64))
+    return np.stack([
+        host_rank_of(g.host_subjects, uids, len(g.host_subjects)),
+        host_rank_of(g.host_in_subjects, uids, len(g.host_in_subjects)),
+    ]).astype(np.int32)
+
+
+def stack_seeds(g: PullGraph, members: list[np.ndarray],
+                rows: int) -> np.ndarray:
+    """int32[rows, 2, S]: seed_ranks of each member of one launch, a row a
+    member, padded with (Ns, Nd) to a pow2 class S of the longest list;
+    rows no member holds are pads only. ONE host array: the only thing
+    that crosses to the device for the launch."""
+    width = max(1, *(m.shape[1] for m in members))
+    out = np.empty((rows, 2, 1 << (width - 1).bit_length()), dtype=np.int32)
+    out[:, 0] = len(g.host_subjects)
+    out[:, 1] = len(g.host_in_subjects)
+    for i, m in enumerate(members):
+        out[i, :, : m.shape[1]] = m
+    return out
+
+
+def recurse_first_hop_mode(g: PullGraph, ranks: np.ndarray) -> str:
+    """"push" or "stream": the branch a fused recurse takes for level 1
+    from seed_ranks `ranks` (the label of dgraph_recurse_first_hop_total),
+    by the predicate and the degree sum the program uses."""
+    at = g.host_fwd_indptr
+    total = int((np.take(at, ranks[0] + 1, mode="clip")
+                 - np.take(at, ranks[0], mode="clip")).sum())
+    return "push" if first_hop_pushes(total, FIRST_HOP_CAP) else "stream"
+
+
+def _seed_rows(fwd_dst_pad, starts, degrees, width: int):
+    """The seeds' forward rows (seed j: `degrees[j]` edges from
+    `starts[j]`, at most `width` in all) laid side by side in `width`
+    lanes of dst ranks, _INT32_MAX past their sum — level 1 of a recurse
+    as a push. One seed: its row is one contiguous slice (bfs_dist's
+    push_hop); several: each lane finds its (seed, offset) in the
+    cumulative degrees and the rows are one `width`-wide gather."""
+    lanes = jnp.arange(width, dtype=jnp.int32)
+    if starts.shape[0] == 1:
+        # dynamic_slice clamps a window that would pass the end of the
+        # array: clamp first, and mask by edge position
+        at = jnp.clip(starts[0], 0, fwd_dst_pad.shape[0] - width) + lanes
+        return jnp.where(
+            (at >= starts[0]) & (at < starts[0] + degrees[0]),
+            lax.dynamic_slice(fwd_dst_pad, (at[0],), (width,)), _INT32_MAX)
+    ends = jnp.cumsum(degrees)
+    # the seed a lane falls in: the first whose rows end past it
+    k = jnp.minimum(jnp.searchsorted(ends, lanes, side="right"),
+                    starts.shape[0] - 1)
+    at = starts[k] + lanes - (ends[k] - degrees[k])
+    return jnp.where(lanes < ends[-1],
+                     jnp.take(fwd_dst_pad, at, mode="clip"), _INT32_MAX)
+
+
+def _recurse_fused_levels(in_src_pad, in_src_pad_d, in_iptr_rank, row_ends,
+                          fwd_indptr, fwd_dst_pad, out_degree_d, seeds, *,
+                          depth: int, chunks: int, chunks_d: int,
+                          allow_loop: bool, first_hop_cap: int):
+    """Traced body shared by recurse_fused (one seed list) and
+    recurse_fused_multi (a stacked batch of them): level 1 by the seeds'
+    degree sum, the levels from the second on as one lax.scan over the
+    SAME per-level tail."""
+    ns, nd = fwd_indptr.shape[0] - 1, out_degree_d.shape[0]
+    width = max(first_hop_cap, 1)          # static: the lanes of the rows
+    if width > FRONTIER_CAP:               # which have to fit the table
+        raise ValueError(f"recurse_fused: first_hop_cap={first_hop_cap}; "
+                         f"want at most {FRONTIER_CAP} lanes")
+    last = _last_edges(in_iptr_rank)           # what every level's pick reads
+    with jax.named_scope("seed"):
+        # `expanded` lives in dst-rank space and starts from the seeds that
+        # are destinations: a seed with no in-edge can never come back
+        expanded = jnp.zeros((nd,), bool).at[seeds[1]].set(True, mode="drop")
+        # indptr[Ns] = indptr[Ns + 1 (clipped)] = E: the pad rank's row is
+        # empty. Level 1 charges every out-edge of every seed, whichever
+        # way it goes
+        starts = jnp.take(fwd_indptr, seeds[0], mode="clip")
+        degrees = jnp.take(fwd_indptr, seeds[0] + 1, mode="clip") - starts
+        total = jnp.sum(degrees, dtype=jnp.int32)
+
+    def push_hop(_):
+        with jax.named_scope("push"):
+            row = _seed_rows(fwd_dst_pad, starts, degrees, width)
+            return jnp.zeros((nd,), bool).at[row].set(True, mode="drop"), row
+
+    def stream_hop(_):
+        with jax.named_scope("prefix"):
+            # src-rank space: a seed with out-edges and no in-edge exists
+            # only here
+            seeds_s = jnp.zeros((ns,), bool).at[seeds[0]].set(
+                True, mode="drop")
+            bounds = _reached_for(seeds_s, in_src_pad, chunks, row_ends, last)
+        return (_reached_from(bounds, nd),
+                jnp.full((width,), _INT32_MAX, jnp.int32))
+
+    pushes = first_hop_pushes(total, first_hop_cap)
+    reached, row = lax.cond(pushes, push_hop, stream_hop, None)
+    masks_p = pack_words(reached, pack_chunks(nd))[None]
+    trav = total[None]
+    if depth == 1:
+        return masks_p, trav
+
+    def body(carry, second):
+        # a level >= 2 frontier is the previous level's destinations: bits
+        # straight from the dst-rank mask (no remap gather); after a push
+        # level 2 has them as a list too, the rows level 1 read
         frontier_d, expanded = carry
 
-        def from_seeds(_):
-            # src-rank space: a seed with out-edges and no in-edge exists
-            # only here. Nothing is expanded yet
-            seeds_s = jnp.take(seeds_mask, subjects)
-            reached, traversed, _ = _recurse_tail(
-                seeds_s, jnp.zeros_like(seeds_s), jnp.diff(fwd_indptr),
-                in_src_pad, chunks, row_ends, last, nd, allow_loop)
-            return reached, traversed, expanded
-
-        def from_reached(_):
-            # a level >= 2 frontier is the previous level's destinations:
-            # bits straight from the dst-rank mask (no remap gather)
+        def tail(flist):
             return _recurse_tail(frontier_d, expanded, out_degree_d,
                                  in_src_pad_d, chunks_d, row_ends, last, nd,
-                                 allow_loop)
+                                 allow_loop, flist)
 
         reached, traversed, expanded2 = lax.cond(
-            i == 0, from_seeds, from_reached, None)
+            second & pushes, lambda: tail(row), lambda: tail(None))
         return (reached, expanded2), (pack_words(reached, pack_chunks(nd)),
                                       traversed)
 
-    # `expanded` lives in dst-rank space and starts from the seeds that are
-    # destinations: a seed with no in-edge can never come back
-    carry0 = (jnp.zeros((nd,), dtype=bool), jnp.take(seeds_mask, in_subjects))
-    _carry, (masks_p, trav) = lax.scan(body, carry0, jnp.arange(depth),
-                                       length=depth)
-    return masks_p, trav
+    _carry, (masks_rest, trav_rest) = lax.scan(
+        body, (reached, expanded), jnp.arange(depth - 1) == 0)
+    return (jnp.concatenate([masks_p, masks_rest]),
+            jnp.concatenate([trav, trav_rest]))
 
 
 @partial(jax.jit, static_argnames=("depth", "chunks", "chunks_d",
-                                   "allow_loop"))
-def recurse_fused(in_src_pad, in_src_pad_d, in_iptr_rank, row_ends, subjects,
-                  in_subjects, fwd_indptr, out_degree_d, seeds_mask, *,
-                  depth: int, chunks: int, chunks_d: int, allow_loop: bool):
-    """All `depth` levels in ONE dispatch (lax.scan): no host round-trip
-    between levels. Single-predicate shape, so levels
-    >= 2 stay entirely in DST-RANK space (a recurse frontier is the
-    previous level's fresh destinations): no full-uid scatter, no src-rank
-    remap gather, and the bitmap pack runs over the compressed rank space
-    (the same dual-space trick as bfs_dist's levels >= 2).
+                                   "allow_loop", "first_hop_cap"))
+def recurse_fused(in_src_pad, in_src_pad_d, in_iptr_rank, row_ends,
+                  fwd_indptr, fwd_dst_pad, out_degree_d, seeds, *,
+                  depth: int, chunks: int, chunks_d: int, allow_loop: bool,
+                  first_hop_cap: int = FIRST_HOP_CAP):
+    """All `depth` levels in ONE dispatch: no host round-trip between
+    levels. Single-predicate shape, so levels >= 2 stay entirely in
+    DST-RANK space (a recurse frontier is the previous level's fresh
+    destinations): no full-uid scatter, no src-rank remap gather, and the
+    bitmap pack runs over the compressed rank space (the same dual-space
+    trick as bfs_dist's levels >= 2).
+
+    `seeds` is int32[2, S], the seed set in rank space (seed_ranks /
+    stack_seeds: row 0 source ranks, pad Ns; row 1 destination ranks, pad
+    Nd) — one host array, the only transfer of a request; nothing
+    uid-sized exists in the program. Level 1 reads the seeds' degrees in
+    `fwd_indptr`: at or under `first_hop_cap` out-edges in all
+    (first_hop_pushes, bfs_dist's predicate) it reads the seeds' forward
+    rows and scatters them, and level 2 takes those rows, less the
+    vertices already expanded, as the sparse kernel's list (no nonzero
+    over a mask); above it level 1 streams every in-edge from the seeds'
+    src-rank bits and level 2 reaches from the mask, as every later level
+    does. Same outputs either way, bit for bit.
 
     Returns stacked per-level (dest_words [D,Cd*8,128] BIT-PACKED
     DST-RANK masks — the host fetches these every query, so
@@ -865,48 +997,48 @@ def recurse_fused(in_src_pad, in_src_pad_d, in_iptr_rank, row_ends, subjects,
     single-uid-child no-filter recurse shape (the common + benchmarked
     one); anything needing host logic between levels uses recurse_step."""
     return _recurse_fused_levels(
-        in_src_pad, in_src_pad_d, in_iptr_rank, row_ends, subjects,
-        in_subjects, fwd_indptr, out_degree_d, seeds_mask, depth=depth,
-        chunks=chunks, chunks_d=chunks_d, allow_loop=allow_loop)
+        in_src_pad, in_src_pad_d, in_iptr_rank, row_ends, fwd_indptr,
+        fwd_dst_pad, out_degree_d, seeds, depth=depth, chunks=chunks,
+        chunks_d=chunks_d, allow_loop=allow_loop, first_hop_cap=first_hop_cap)
 
 
-@partial(jax.jit, static_argnames=("num_nodes", "depth", "chunks",
-                                   "chunks_d", "allow_loop"))
+@partial(jax.jit, static_argnames=("depth", "chunks", "chunks_d",
+                                   "allow_loop", "first_hop_cap"))
 def recurse_fused_multi(in_src_pad, in_src_pad_d, in_iptr_rank, row_ends,
-                        subjects, in_subjects, fwd_indptr, out_degree_d,
-                        seeds, *, num_nodes: int, depth: int, chunks: int,
-                        chunks_d: int, allow_loop: bool):
-    """Multi-source batched recurse: seeds int32[B, S] lists the seed uids
-    of B concurrent queries, one row a query, each row padded with
-    `num_nodes` (past the uid space: the scatter drops it) and a row of
-    pads only for a slot no query holds. The whole batch runs as ONE
-    device dispatch — the one-extra-dimension extension of recurse_fused
-    the batched-dispatch tier launches (query/batch.py), which hands it
-    one host array: the seed masks are built here, inside the program, so
-    no occupancy has eager programs of its own and B is the batcher's
+                        fwd_indptr, fwd_dst_pad, out_degree_d, seeds, *,
+                        depth: int, chunks: int, chunks_d: int,
+                        allow_loop: bool,
+                        first_hop_cap: int = FIRST_HOP_CAP):
+    """Multi-source batched recurse: seeds int32[B, 2, S] holds the seed
+    lists of B concurrent queries (stack_seeds), one row a query as
+    recurse_fused takes it, and a row of pads only for a slot no query
+    holds. The whole batch runs as ONE device dispatch — the
+    one-extra-dimension extension of recurse_fused the batched-dispatch
+    tier launches (query/batch.py), which hands it one host array, so no
+    occupancy has eager programs of its own and B is the batcher's
     capacity whatever the occupancy. lax.map over the exact recurse_fused
     body, so slice b of the stacked outputs is bit-identical to a solo
-    recurse_fused call with row b's seed mask (the per-level ops are
-    integer/boolean — no float reassociation); a row without a seed skips
-    the body and hands back zeros, which is what the body gives an empty
-    frontier. Each query keeps its own expanded set: batching never
-    entangles traversals. Returns (masks_p [B, depth, ...], traversed
-    [B, depth])."""
-    nd = in_subjects.shape[0]
+    recurse_fused call with row b (the per-level ops are integer/boolean —
+    no float reassociation), each member's level 1 pushing or streaming
+    by its own degree sum; a row without a seed that has an out-edge
+    skips the body and hands back zeros, which is what the body gives
+    it. Each query keeps its own expanded set: batching never entangles
+    traversals. Returns (masks_p [B, depth, ...], traversed [B, depth])."""
+    ns, nd = fwd_indptr.shape[0] - 1, out_degree_d.shape[0]
 
     def levels(row):
-        mask = jnp.zeros((num_nodes,), bool).at[row].set(True, mode="drop")
         return _recurse_fused_levels(
-            in_src_pad, in_src_pad_d, in_iptr_rank, row_ends, subjects,
-            in_subjects, fwd_indptr, out_degree_d, mask, depth=depth,
-            chunks=chunks, chunks_d=chunks_d, allow_loop=allow_loop)
+            in_src_pad, in_src_pad_d, in_iptr_rank, row_ends, fwd_indptr,
+            fwd_dst_pad, out_degree_d, row, depth=depth, chunks=chunks,
+            chunks_d=chunks_d, allow_loop=allow_loop,
+            first_hop_cap=first_hop_cap)
 
     def nothing(_row):
         return (jnp.zeros((depth, pack_chunks(nd) * 8, _LANES), jnp.int32),
                 jnp.zeros((depth,), jnp.int32))
 
     return lax.map(
-        lambda row: lax.cond(jnp.any(row < num_nodes), levels, nothing, row),
+        lambda row: lax.cond(jnp.any(row[0] < ns), levels, nothing, row),
         seeds)
 
 

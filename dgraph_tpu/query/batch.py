@@ -26,9 +26,9 @@ into one kernel launch):
                 target stream back per source slot);
       vector  — stacked [B, D] query matrices through the tiled top-k
                 matmul (ops/vector.topk_candidates_batch);
-      recurse — stacked seed masks through the one-extra-dimension
-                multi-source fused recurse (ops/pallas_bfs.
-                recurse_fused_multi).
+      recurse — stacked rank-space seed lists through the
+                one-extra-dimension multi-source fused recurse
+                (ops/pallas_bfs.recurse_fused_multi).
   * Composition with the cache tiers: singleflight (qcache) dedupes
     IDENTICAL in-flight tasks — only the flight leader reaches the
     batcher; the batcher packs DISTINCT compatible ones. Tasks that miss
@@ -93,8 +93,8 @@ class _VectorWork:
 
 
 class _RecurseWork:
-    """One fused recurse: its PullGraph and its seed uids (a host array,
-    every one below g.num_nodes)."""
+    """One fused recurse: its PullGraph and its seeds as ranks (a host
+    array, pb.seed_ranks)."""
 
     __slots__ = ("g", "seeds")
 
@@ -216,12 +216,15 @@ def _classify_vector(snap, schema, q):
 # ---------------------------------------------------------------------------
 
 class _Entry:
-    __slots__ = ("work", "solo", "dl", "lg", "event", "result", "error",
-                 "batch_size")
+    __slots__ = ("work", "solo", "span_attrs", "dl", "lg", "event",
+                 "result", "error", "batch_size")
 
-    def __init__(self, work, solo=None) -> None:
+    def __init__(self, work, solo=None, span_attrs=None) -> None:
         self.work = work
         self.solo = solo        # zero-arg solo execution (1-entry batches)
+        # what this member alone knows of its share of the launch, for
+        # its device_kernel span of it
+        self.span_attrs = span_attrs or {}
         self.dl = dl.current()  # the submitting caller's deadline
         # the submitting caller's cost ledger: a batched kernel acts for
         # SEVERAL requests, so its cost is apportioned to the members'
@@ -393,7 +396,8 @@ class DeviceBatcher:
             b.full.wait(self.window_s)
 
     def _submit(self, key: tuple, kind: str, work,
-                runner: Callable[[list[_Entry]], None], solo=None):
+                runner: Callable[[list[_Entry]], None], solo=None,
+                **span_attrs):
         """Join an open compatible batch or lead a new one. The leader
         waits the window (unless the device is idle or the batch fills),
         freezes the batch, runs `runner` (which must fill every entry's
@@ -404,9 +408,10 @@ class DeviceBatcher:
         On the request's stage clock (obs/costs.py) a follower's wait for
         its leader and a leader's wait for companions are `batch.wait`;
         the follower's wait is also a device_kernel span of the batch's
-        family (role="follower"), so its trace says which launch answered
-        it as the leader's (role="leader", opened by the runner) does."""
-        entry = _Entry(work, solo)
+        family (role="follower", and the member's own `span_attrs`), so
+        its trace says which launch answered it as the leader's
+        (role="leader", opened by the runner) does."""
+        entry = _Entry(work, solo, span_attrs)
         with self._lock:
             b = self._open.get(key)
             if b is not None and not b.closed and \
@@ -424,7 +429,7 @@ class DeviceBatcher:
             wait_s = _FOLLOWER_WAIT_S if rem is None else \
                 min(_FOLLOWER_WAIT_S, max(rem, 0.0) + 0.1)
             with otrace.span("device_kernel", kernel=_FAMILY[kind],
-                             role="follower") as sp, \
+                             role="follower", **entry.span_attrs) as sp, \
                     costs.stage("batch.wait"):
                 if not entry.event.wait(wait_s):
                     # own budget gone while the batch still runs: typed
@@ -517,24 +522,26 @@ class DeviceBatcher:
         return self._submit(key, kind, work, runner,
                             solo=lambda: solo(q, klass=kind))
 
-    def dispatch_recurse(self, g, seeds: np.ndarray, depth: int,
-                         allow_loop: bool, solo: Callable):
+    def dispatch_recurse(self, g, ranks: np.ndarray, first_hop: str,
+                         depth: int, allow_loop: bool, solo: Callable):
         """The fused-recurse seam (query/recurse.py): compatible concurrent
         traversals (same PullGraph — which pins tablet + snapshot — same
-        depth, same loop rule) stack their seed uids into ONE multi-source
-        recurse_fused_multi dispatch. `seeds` is a host array of uids
-        below g.num_nodes; `solo` is the ungated single-query
-        recurse_fused closure."""
+        depth, same loop rule) stack their seeds into ONE multi-source
+        recurse_fused_multi dispatch. `ranks` is the member's seed set in
+        rank space (pb.seed_ranks, a host array), `first_hop` the branch
+        its level 1 takes (on its span of the launch); `solo` is the
+        ungated single-query recurse_fused closure."""
         key = ("recurse", id(g), depth, allow_loop)
         if self._deadline_bypasses("recurse"):
             return self._gate_run(solo, "recurse")
-        work = _RecurseWork(g, seeds)
+        work = _RecurseWork(g, ranks)
 
         def runner(entries: list[_Entry]) -> None:
             self._run_recurse(entries, depth, allow_loop)
 
         return self._submit(key, "recurse", work, runner,
-                            solo=lambda: self._gate_run(solo, "recurse"))
+                            solo=lambda: self._gate_run(solo, "recurse"),
+                            first_hop=first_hop)
 
     # --------------------------------------------------------------- runners
 
@@ -706,44 +713,42 @@ class DeviceBatcher:
 
     def _run_recurse(self, entries: list[_Entry], depth: int,
                      allow_loop: bool) -> None:
-        """The members' seed uids, one row each of ONE host array, through
-        recurse_fused_multi; slice b of the stacked outputs is
-        bit-identical to a solo recurse_fused call (the per-level ops are
-        integer/boolean). Host arrays in, one jitted call, one fetch: the
-        array always has max_batch rows (a row of pads skips the
-        traversal on the device), so every occupancy runs one program and
-        none has eager programs of its own; its width is a pow2 class of
-        the longest seed list. Each entry receives its (masks, traversed)
-        pair as slices of the fetched host arrays. The leader's clock is
-        in dev.dispatch until the call returned its futures and in
-        dev.wait in the fetch, as the solo closure's."""
+        """The members' seeds, a row each of ONE host array of ranks
+        (pb.stack_seeds), through recurse_fused_multi; slice b of the
+        stacked outputs is bit-identical to a solo recurse_fused call
+        (the per-level ops are integer/boolean). Host arrays in, one
+        jitted call, one fetch: the array always has max_batch rows (a
+        row of pads skips the traversal on the device), so every
+        occupancy runs one program and none has eager programs of its
+        own; its width is a pow2 class of the longest seed list. Each
+        entry receives its (masks, traversed) pair as slices of the
+        fetched host arrays. The leader's clock is in dev.dispatch until
+        the call returned its futures and in dev.wait in the fetch, as
+        the solo closure's; its span carries its own first_hop, as each
+        follower's does."""
         import jax
 
         from dgraph_tpu.ops import pallas_bfs as pb
 
         g = entries[0].work.g
         nbatch = len(entries)
-        width = max(1, *(len(e.work.seeds) for e in entries))
-        seeds = np.full((self.max_batch, 1 << (width - 1).bit_length()),
-                        g.num_nodes, dtype=np.int32)
-        for i, e in enumerate(entries):
-            seeds[i, : len(e.work.seeds)] = e.work.seeds
+        seeds = pb.stack_seeds(g, [e.work.seeds for e in entries],
+                               self.max_batch)
 
         def kernel():
             with costs.stage("dev.dispatch"):
                 out = pb.recurse_fused_multi(
-                    g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank,
-                    g.row_ends, g.subjects, g.in_subjects, g.fwd_indptr,
-                    g.out_degree_d, seeds, num_nodes=g.num_nodes,
-                    depth=depth, chunks=g.chunks, chunks_d=g.chunks_d,
-                    allow_loop=allow_loop)
+                    *pb.fused_graph_args(g), seeds, depth=depth,
+                    chunks=g.chunks, chunks_d=g.chunks_d,
+                    allow_loop=allow_loop, first_hop_cap=pb.FIRST_HOP_CAP)
             # the fetch is the fence, as in the solo closure: the timer
             # and the gate slot cover the device run, not its enqueue
             with costs.stage("dev.wait"):
                 return jax.device_get(out)
 
         with otrace.span("device_kernel", kernel="batch.recurse",
-                         depth=depth, batch=nbatch, role="leader") as sp:
+                         depth=depth, batch=nbatch, role="leader",
+                         **entries[0].span_attrs) as sp:
             (masks_h, trav_h), dt_ms = self._timed_gate_run(
                 kernel, "recurse")
             d2h = int(masks_h.nbytes + trav_h.nbytes)

@@ -242,18 +242,19 @@ class Executor:
         return self.gate.run(fn, klass=klass) if self.gate is not None \
             else fn()
 
-    def batched_recurse(self, g, seeds, depth: int, allow_loop: bool,
-                        solo):
+    def batched_recurse(self, g, ranks, first_hop: str, depth: int,
+                        allow_loop: bool, solo):
         """Fused-recurse seam of the dispatch batcher: compatible
         concurrent traversals (same PullGraph object — which pins tablet
-        and snapshot — same depth and loop rule) stack their seed uids
-        (`seeds`: a host array, each below g.num_nodes) into ONE
+        and snapshot — same depth and loop rule) stack their seeds
+        (`ranks`: pb.seed_ranks, a host array; `first_hop`: the branch
+        their level 1 takes, for the launch's span) into ONE
         multi-source dispatch (ops/pallas_bfs.recurse_fused_multi)
         instead of serializing through the gate one fused scan each.
         Either way the caller gets host arrays: (packed level masks,
         traversed per level)."""
         if self.batcher is not None:
-            return self.batcher.dispatch_recurse(g, seeds, depth,
+            return self.batcher.dispatch_recurse(g, ranks, first_hop, depth,
                                                  allow_loop, solo)
         return self.gated(solo, klass="recurse")
 

@@ -16,7 +16,8 @@ TPU shape — one hot path, benched and served alike (worker/task.go:605):
     is a device-resident bool vector over the vertices (`expanded`) and a
     level reaches from `frontier & ~expanded`.
     The common single-child no-filter shape runs ALL levels in one
-    dispatch (recurse_fused lax.scan) — no host sync between levels.
+    dispatch (recurse_fused) — no host sync between levels — from seeds
+    handed over as ranks, its first level reading the seeds' own rows.
     Per-source target lists (uidMatrix) stay CSR-shaped and deferred
     (LazyRecurseMatrix): output encoders materialize on demand, a
     frontier vertex's whole row if it was in no earlier frontier, else
@@ -526,45 +527,57 @@ def _recurse_fused_path(ex, sg: SubGraph, cgq, csr, depth: int,
     stacked per-level masks. Matches build_level's output for the
     single-uid-child no-filter shape exactly (tests equality-gate it).
 
+    The seeds go in as ranks, found here on the host (pb.seed_ranks): one
+    numpy array is all that crosses to the device, and nothing runs there
+    for a request but the jitted program. Level 1 of it reads the seeds'
+    forward rows ("push") or streams every in-edge ("stream") by their
+    out-degree sum: dgraph_recurse_first_hop_total counts the traversal
+    under the mode the same predicate gives the host's degrees.
+
     Stages of the request's clock (obs/costs.py): pull_graph_for is
-    exec.prep; the seed mask's eager programs and the jitted call are
-    dev.dispatch; blocked in the fetch is dev.wait; everything the host
-    does with the fetched masks is dev.post. In a stacked launch
-    (query/batch.py) only the leader has dev.dispatch and dev.wait: a
-    follower is in batch.wait until its slices are there."""
+    exec.prep; the seed array and the jitted call are dev.dispatch;
+    blocked in the fetch is dev.wait; everything the host does with the
+    fetched masks is dev.post. In a stacked launch (query/batch.py) only
+    the leader has dev.dispatch and dev.wait: a follower is in batch.wait
+    until its slices are there."""
     from dgraph_tpu.ops import pallas_bfs as pb
 
     with costs.stage("exec.prep"):
         g = pb.pull_graph_for(csr)
     nd = len(g.host_in_subjects)
     seeds = np.sort(np.asarray(sg.dest_uids, dtype=np.int64))
+    ranks = pb.seed_ranks(g, seeds)
+    first_hop = pb.recurse_first_hop_mode(g, ranks)
+    metrics = getattr(ex.snap, "metrics", None)
+    if metrics is not None:
+        metrics.keyed("dgraph_recurse_first_hop_total",
+                      labels=("mode",)).inc(first_hop)
+
     # batched-dispatch seam (query/batch.py): compatible concurrent
-    # traversals hand their seed uids to one multi-source dispatch, which
-    # builds the masks inside its program; a traversal that runs alone
-    # builds its own here (eager programs, S5 (a)), inside its gate slot.
-    # Without a batcher this is exactly the old gated solo call
+    # traversals hand their seed ranks to one multi-source dispatch; a
+    # traversal that runs alone runs this, inside its gate slot. Without
+    # a batcher this is exactly the old gated solo call
     def _solo_fused():
-        with costs.stage("dev.dispatch"):
-            seeds_mask = _seeds_mask(seeds, g.num_nodes)
         with otrace.span("device_kernel", kernel="pb.recurse_fused",
-                         depth=depth, edges=g.num_edges) as sp, \
+                         depth=depth, edges=g.num_edges,
+                         first_hop=first_hop) as sp, \
                 costs.kernel("pb.recurse_fused", attr=cgq.attr,
                              stage="dev.dispatch") as ck:
+            seeds_h = pb.stack_seeds(g, [ranks], 1)[0]
             masks_p, trav = pb.recurse_fused(
-                g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank, g.row_ends,
-                g.subjects, g.in_subjects, g.fwd_indptr, g.out_degree_d,
-                seeds_mask, depth=depth, chunks=g.chunks,
-                chunks_d=g.chunks_d, allow_loop=allow_loop)
+                *pb.fused_graph_args(g), seeds_h, depth=depth,
+                chunks=g.chunks, chunks_d=g.chunks_d, allow_loop=allow_loop,
+                first_hop_cap=pb.FIRST_HOP_CAP)
             # the fetch is the fence: dispatch is asynchronous, so the
             # timer (and the gate slot) must cover it to book device time
             with costs.stage("dev.wait"):
                 masks_h, trav_h = jax.device_get((masks_p, trav))
             with costs.stage("dev.post"):
                 d2h = int(masks_h.nbytes + trav_h.nbytes)
-                ck.set(h2d=int(seeds_mask.nbytes), d2h=d2h)
+                ck.set(h2d=int(seeds_h.nbytes), d2h=d2h)
                 if sp:
                     live, union = _fused_levels(masks_h)
-                    sp.set(transfer_h2d_bytes=int(seeds_mask.nbytes),
+                    sp.set(transfer_h2d_bytes=int(seeds_h.nbytes),
                            transfer_d2h_bytes=d2h, levels_live=live,
                            reached=int(pb.unpack_words(union, nd).sum()))
             return masks_h, trav_h
@@ -573,8 +586,8 @@ def _recurse_fused_path(ex, sg: SubGraph, cgq, csr, depth: int,
     # space, fetched under the timer of whichever launch ran it (the solo
     # closure, or the batch leader's runner: slices of its host arrays);
     # the host maps ranks -> uids
-    masks_h, trav_h = ex.batched_recurse(
-        g, seeds[seeds < g.num_nodes], depth, allow_loop, _solo_fused)
+    masks_h, trav_h = ex.batched_recurse(g, ranks, first_hop, depth,
+                                         allow_loop, _solo_fused)
     with costs.stage("dev.post"):
         live, union = _fused_levels(masks_h)
         _count_levels(ex, live, depth - live)
@@ -592,7 +605,6 @@ def _recurse_fused_path(ex, sg: SubGraph, cgq, csr, depth: int,
             if id(cgq) in level_dests:
                 level_dests[id(cgq)].append(uids_of(union))
             return
-        metrics = getattr(ex.snap, "metrics", None)
         expanded = None if allow_loop else np.zeros(g.num_nodes, dtype=bool)
         frontier = seeds
         attach = sg.children = []

@@ -503,6 +503,11 @@ class Registry:
         # mode="push" reads the root's forward row, "stream" every in-edge
         self.keyed_gauges["dgraph_bfs_first_hop_total"] = KeyedGauge(
             labels=("mode",), keep=("push", "stream"))
+        # level 1 of a fused @recurse (ops/pallas_bfs.recurse_first_hop_mode),
+        # once a traversal: "push" reads the seeds' forward rows, "stream"
+        # every in-edge
+        self.keyed_gauges["dgraph_recurse_first_hop_total"] = KeyedGauge(
+            labels=("mode",), keep=("push", "stream"))
         # levels the device @recurse programs ran (query/recurse.py): a
         # fused scan runs all `depth` of them, "empty" are those whose
         # frontier held no vertex — a whole stream of the graph for nothing

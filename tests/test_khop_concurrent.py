@@ -20,7 +20,6 @@ import time
 import urllib.request
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -155,8 +154,8 @@ def test_every_client_gets_the_count_of_its_own_root(world, mode):
                 prom["dgraph_batch_tasks_total"],
                 prom['dgraph_batch_occupancy_bucket{le="1"}'])
 
-    # a request alone first, as a warm-up does: the solo program and its
-    # seed mask's eager programs are the stacked launch's only companions
+    # a request alone first, as a warm-up does: the solo program is the
+    # stacked launch's only companion
     assert post(base, "/query?edgeLimit=1000000",
                 query_text(roots[1]))["data"] == want[1]
     before = launches()
@@ -183,18 +182,15 @@ def layout(world):
     """The node's PullGraph and each root's solo arrays, by root."""
     csr = world["node"].snapshot().pred("follows").csr
     g = pb.pull_graph_for(csr)
-    args = (g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank, g.row_ends,
-            g.subjects, g.in_subjects, g.fwd_indptr, g.out_degree_d)
     cache: dict = {}
 
     def solo(roots: tuple, depth: int):
         key = (roots, depth)
         if key not in cache:
-            mask = np.zeros(g.num_nodes, dtype=bool)
-            mask[[r for r in roots if r < g.num_nodes]] = True
+            seeds = pb.stack_seeds(g, [pb.seed_ranks(g, roots)], 1)[0]
             cache[key] = jax.device_get(pb.recurse_fused(
-                *args, jnp.asarray(mask), depth=depth, chunks=g.chunks,
-                chunks_d=g.chunks_d, allow_loop=False))
+                *pb.fused_graph_args(g), seeds, depth=depth,
+                chunks=g.chunks, chunks_d=g.chunks_d, allow_loop=False))
         return cache[key]
 
     return g, solo
@@ -218,9 +214,10 @@ def test_each_occupancy_hands_back_the_solo_arrays(world, layout, n):
     depth = 2
 
     def member(batcher, roots):
-        seeds = np.asarray([r for r in roots if r < g.num_nodes], np.int64)
+        ranks = pb.seed_ranks(g, roots)
         return lambda: batcher.dispatch_recurse(
-            g, seeds, depth, False, solo=lambda: solo(roots, depth))
+            g, ranks, pb.recurse_first_hop_mode(g, ranks), depth, False,
+            solo=lambda: solo(roots, depth))
 
     for _attempt in range(4):      # a straggler may miss the window
         batcher = DeviceBatcher(None, Registry(), window_ms=250,

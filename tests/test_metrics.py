@@ -116,6 +116,8 @@ def test_keyed_gauge_keeps_its_closed_keys_at_zero(op):
     text = prom.render(metrics.Registry())
     assert 'dgraph_bfs_first_hop_total{mode="push"} 0' in text
     assert 'dgraph_bfs_first_hop_total{mode="stream"} 0' in text
+    assert 'dgraph_recurse_first_hop_total{mode="push"} 0' in text
+    assert 'dgraph_recurse_first_hop_total{mode="stream"} 0' in text
 
 
 def test_keyed_gauge_get_is_locked_and_consistent():

@@ -94,10 +94,15 @@ def host_recurse(subjects, indptr, indices, seed_uids, depth,
     return levels
 
 
-def fused_layout(g):
-    """The graph arguments of recurse_fused / recurse_fused_multi."""
-    return (g.in_src_pad, g.in_src_pad_d, g.in_iptr_rank, g.row_ends,
-            g.subjects, g.in_subjects, g.fwd_indptr, g.out_degree_d)
+def fused(g, seed_uids, depth, allow_loop=False,
+          first_hop_cap=pb.FIRST_HOP_CAP):
+    """One recurse_fused call as the executor makes it: the seeds in as
+    one host array of ranks. Returns host (masks_p, traversed)."""
+    seeds = pb.stack_seeds(g, [pb.seed_ranks(g, seed_uids)], 1)[0]
+    return jax.device_get(pb.recurse_fused(
+        *pb.fused_graph_args(g), seeds, depth=depth, chunks=g.chunks,
+        chunks_d=g.chunks_d, allow_loop=allow_loop,
+        first_hop_cap=first_hop_cap))
 
 
 def check_search(g, csr, root, hops, first_hop_cap):
@@ -125,14 +130,10 @@ def check_recurse(g, csr, seed_uids, hops):
     """recurse_fused's per-level reached sets and traversed counts against
     the host recurse; their union with the seeds is the BFS's visited set.
     Returns the per-level traversed counts."""
-    seeds_mask = np.zeros(g.num_nodes, dtype=bool)
-    seeds_mask[seed_uids] = True
-    masks_p, trav = pb.recurse_fused(
-        *fused_layout(g), jnp.asarray(seeds_mask), depth=hops,
-        chunks=g.chunks, chunks_d=g.chunks_d, allow_loop=False)
-    masks_h, trav = np.asarray(masks_p), np.asarray(trav)
+    masks_h, trav = fused(g, seed_uids, hops)
     nd = len(g.host_in_subjects)
-    union = seeds_mask.copy()
+    union = np.zeros(g.num_nodes, dtype=bool)
+    union[seed_uids] = True
     levels = host_recurse(*csr, seed_uids, hops)
     for lvl, (want_reached, want_traversed, _matrix) in enumerate(levels):
         reached = g.host_in_subjects[pb.unpack_words(masks_h[lvl], nd)]
@@ -449,19 +450,23 @@ def test_row_end_items_hold_every_row_once(rng, shape):
 
 
 # sha256 of the lowered text (CPU: the kernels in interpret mode, inlined)
-# of the programs a request reaches, for rmat_csr(12, 8, seed=5): the
-# recurse programs as PR 35 left them (one emit, row_end_prefix*, for
-# every program; edge dedup on vertices; recurse_fused_multi as PR 36
-# left it: rows of seed uids in, the masks built inside, a row of pads
-# skipped), bfs_dist as it was at commit
+# of the programs a request reaches, for rmat_csr(12, 8, seed=5).
+# recurse_fused and recurse_fused_multi as PR 37 left them: seeds in as
+# ranks (int32[2, S]; a stacked int32[B, 2, S], a row of pads skipped),
+# level 1 a push over the seeds' forward rows or a stream by their degree
+# sum, level 2 from the pushed rows as a list, nothing uid-sized inside —
+# recurse_fused pinned at S = 1 (the row as one dynamic_slice),
+# recurse_fused_multi at S = 4 (the rows as one gather). recurse_step as PR
+# 35 left it (one emit, row_end_prefix*; edge dedup on vertices: it shares
+# _recurse_tail with the fused programs), bfs_dist as it was at commit
 # ab2061a (PR 34), before that PR — the search shares its kernels, its
 # membership tests and _hop_for with them. A PR that means to change a
 # program replaces its line
 PROGRAM_LOWERINGS = {
     "recurse_fused":
-        "f949fee3a0c0057b01a81127a9c41e559a3f8b58ce76df9b125dad9157d10a65",
+        "104fb9fae5dfc2fc48c9df66a396b649bf3f797ac40e2a38bbdf835129c646ae",
     "recurse_fused_multi":
-        "1622c9e149b82305e609b5e4706b77f2e113ce906fffc5b395fe61c7e9c59391",
+        "f04ca67ed1d1c3facf17718451cf94664be06605cf62608dabcbac45e364c0e9",
     "recurse_step":
         "4466867cced45d75ff48c0a2ad6f03625b1e8310091440167409d66f2b607386",
     "bfs_dist":
@@ -473,14 +478,14 @@ def lower_program(program, subjects, indptr, indices):
     """`program` of PROGRAM_LOWERINGS, lowered for a CSR."""
     n = int(max(subjects.max(), indices.max())) + 1
     g = pb.prep_pull(subjects, indptr, indices, n)
-    fused = dict(depth=3, chunks=g.chunks, chunks_d=g.chunks_d,
-                 allow_loop=False)
+    statics = dict(depth=3, chunks=g.chunks, chunks_d=g.chunks_d,
+                   allow_loop=False, first_hop_cap=pb.FIRST_HOP_CAP)
     return {
         "recurse_fused": lambda: pb.recurse_fused.lower(
-            *fused_layout(g), jnp.zeros((n,), bool), **fused),
+            *pb.fused_graph_args(g), np.zeros((2, 1), np.int32), **statics),
         "recurse_fused_multi": lambda: pb.recurse_fused_multi.lower(
-            *fused_layout(g), np.zeros((2, 1), np.int32), num_nodes=n,
-            **fused),
+            *pb.fused_graph_args(g), np.zeros((2, 2, 4), np.int32),
+            **statics),
         "recurse_step": lambda: pb.recurse_step.lower(
             g.in_src_pad, g.in_iptr_rank, g.row_ends, g.subjects,
             g.in_subjects, g.fwd_indptr, jnp.zeros((n,), bool),
@@ -624,19 +629,20 @@ REENTRY_GRAPHS = {
 }
 
 
-def fused_levels(g, seeds_mask, depth, allow_loop):
+def fused_levels(g, seeds, depth, allow_loop,
+                 first_hop_cap=pb.FIRST_HOP_CAP):
     """Per level (reached uids, traversed) of one recurse_fused call."""
-    masks_p, trav = pb.recurse_fused(
-        *fused_layout(g), jnp.asarray(seeds_mask), depth=depth,
-        chunks=g.chunks, chunks_d=g.chunks_d, allow_loop=allow_loop)
-    masks_h, nd = np.asarray(masks_p), len(g.host_in_subjects)
+    masks_h, trav = fused(g, seeds, depth, allow_loop, first_hop_cap)
+    nd = len(g.host_in_subjects)
     return [(g.host_in_subjects[pb.unpack_words(masks_h[lvl], nd)],
-             int(t)) for lvl, t in enumerate(np.asarray(trav))]
+             int(t)) for lvl, t in enumerate(trav)]
 
 
-def stepped_levels(g, seeds_mask, depth, allow_loop):
+def stepped_levels(g, seeds, depth, allow_loop):
     """The same from a chain of recurse_step calls, each fed the one
     before's destinations and its `expanded`."""
+    seeds_mask = np.zeros(g.num_nodes, dtype=bool)
+    seeds_mask[seeds] = True
     frontier = jnp.asarray(seeds_mask)
     expanded = jnp.zeros(g.subjects.shape, dtype=bool)
     levels = []
@@ -664,10 +670,8 @@ def test_recurse_dedups_edges(rng, graph, levels_of, allow_loop):
     csr, seeds, depth = REENTRY_GRAPHS[graph](rng)
     num_nodes = int(max(csr[0].max(), csr[2].max())) + 2
     g = pb.prep_pull(*csr, num_nodes)
-    seeds_mask = np.zeros(num_nodes, dtype=bool)
-    seeds_mask[seeds] = True
     want = host_recurse(*csr, seeds, depth, allow_loop)
-    got = levels_of(g, seeds_mask, depth, allow_loop)
+    got = levels_of(g, seeds, depth, allow_loop)
     store = SimpleNamespace(host_arrays=lambda: csr)
     expanded = np.zeros(num_nodes, dtype=bool)
     frontier = np.sort(seeds)
@@ -720,9 +724,10 @@ def one_v5e():
                                      "recurse_step"])
 def test_recurse_programs_hold_nothing_edge_sized(one_v5e, monkeypatch,
                                                   program):
-    """A recurse level dedups vertices: beside the two edge streams it is
-    given, a recurse program compiled for the chip holds nothing with an
-    element an edge — no output (the bool[depth, E_pad] fresh flags are
+    """A recurse level dedups vertices: beside the edge arrays it is
+    given (the two streams; the fused programs read the seeds' rows of
+    fwd_dst_pad too), a recurse program compiled for the chip holds
+    nothing with an element an edge — no output (the bool[depth, E_pad] fresh flags are
     gone) and no temporary (the per-edge prefix, `seen`, `fresh` and their
     cumsum were 14 bytes an edge and more): outputs and temporaries
     together stay under one BYTE an edge. 2.1M edges over 40k + 40k
@@ -736,14 +741,15 @@ def test_recurse_programs_hold_nothing_edge_sized(one_v5e, monkeypatch,
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e)
 
     ends = pb.RowEnds(arg((n_items,)), arg((n_items,)))
-    fused = dict(depth=3, chunks=2, chunks_d=2, allow_loop=False)
-    layout = (arg((e_pad,)), arg((e_pad,)), arg((nd + 1,)), ends, arg((ns,)),
-              arg((nd,)), arg((ns + 1,)), arg((nd,)))
+    statics = dict(depth=3, chunks=2, chunks_d=2, allow_loop=False)
+    # the graph arguments, the padded forward array among them
+    layout = (arg((e_pad,)), arg((e_pad,)), arg((nd + 1,)), ends,
+              arg((ns + 1,)), arg((e_pad,)), arg((nd,)))
     compiled = {
         "recurse_fused": lambda: pb.recurse_fused.lower(
-            *layout, arg((n,), bool), **fused),
+            *layout, arg((2, 1)), **statics),
         "recurse_fused_multi": lambda: pb.recurse_fused_multi.lower(
-            *layout, arg((2, 1)), num_nodes=n, **fused),
+            *layout, arg((2, 2, 4)), **statics),
         "recurse_step": lambda: pb.recurse_step.lower(
             arg((e_pad,)), arg((nd + 1,)), ends, arg((ns,)), arg((nd,)),
             arg((ns + 1,)), arg((n,), bool), arg((ns,), bool),

@@ -6,6 +6,11 @@ mesh — the same program Mosaic compiles on TPU) and the full JSON output
 must be identical to the host-mirror path for every query shape: fused
 single-child, multi-child stepped, value children, filters, loops, reverse
 edges, depth exhaustion, and the edge budget error.
+
+The fused program takes its seeds as ranks and picks level 1 by their
+out-degree sum — the seeds' own forward rows ("push") or every in-edge
+("stream"): test_push_and_stream_are_one_traversal holds both branches to
+each other, bit for bit, and to a plain host recurse.
 """
 
 import json
@@ -14,7 +19,9 @@ import numpy as np
 import pytest
 
 from dgraph_tpu.api.server import Node
+from dgraph_tpu.ops import pallas_bfs as pb
 from dgraph_tpu.query import recurse as recmod
+from test_pallas_bfs import csr_of, fused, host_recurse
 
 
 def _graph_node(rng, n=48):
@@ -218,3 +225,91 @@ def test_set_query_edge_limit_bounds_shortest(rng):
                        "{ follow } r(func: uid(p)) { uid } }")
     finally:
         eng.set_query_edge_limit(old)
+
+
+SEED_CAP = 8       # a first_hop_cap that splits this graph's seed sets
+
+
+def _seed_graph():
+    """Uids 1..40 in a ring. Hub 50 -> every ring uid and itself (41
+    out-edges, a self-loop in its row), 40 -> 50. 45, 46, 47 -> two ring
+    uids each and have no in-edge; 80 has an in-edge (3 -> 80) and no
+    out-edge; 55 has SEED_CAP out-edges and 56 one more; 60 -> 7, 3 -> 60.
+    Uid 70, the LAST subject, -> 1, 2, 3: its row ends where the edges
+    end — two lanes before the padded forward array does: 61..69, which
+    nothing reaches, point at as many of 200, 201, ... as bring the edge
+    count to two under a block. Uid 90 is in neither rank space."""
+    e = [(u, u % 40 + 1) for u in range(1, 41)]
+    e += [(50, u) for u in range(1, 41)] + [(50, 50), (40, 50)]
+    e += [(45, 9), (45, 10), (46, 11), (46, 12), (47, 13), (47, 14)]
+    e += [(3, 80), (60, 7), (3, 60), (70, 1), (70, 2), (70, 3)]
+    e += [(55, u) for u in range(1, SEED_CAP + 1)] + [(5, 55)]
+    e += [(56, u) for u in range(1, SEED_CAP + 2)] + [(6, 56)]
+    fill = pb.EDGE_BLOCK - 2 - len(set(e))
+    e += [(61 + i % 9, 200 + i // 9) for i in range(fill)]
+    e = np.asarray(sorted(set(e)))
+    return csr_of(e[:, 0], e[:, 1])
+
+
+# name: (seed uids, whether level 1 pushes at first_hop_cap=SEED_CAP)
+SEED_SETS = {
+    "one_seed": ([5], True),
+    "self_loop": ([50], False),
+    "out_edges_and_no_in_edge": ([45], True),
+    "in_edges_and_no_out_edge": ([80], True),
+    "uid_in_neither_space": ([90], True),
+    "uid_past_the_uid_space": ([5000], True),
+    # 70 -> 1, 2, 3; 1 -> 2; 2 -> 3: the rows hold 2 and 3 twice, and
+    # seeds 1 and 2 are other seeds' neighbours — level 2's list has
+    # duplicates and entries that were expanded already
+    "rows_overlap_and_a_seed_is_a_neighbour": ([1, 2, 70], True),
+    "degree_sum_at_the_cap": ([55], True),
+    "degree_sum_at_the_cap_over_four_seeds": ([45, 46, 60, 70], True),
+    "degree_sum_one_over_the_cap": ([56], False),
+    "degree_sum_one_over_the_cap_over_four_seeds": ([45, 46, 47, 70], False),
+    # the row starts 5 lanes before the end of the padded forward array:
+    # a dynamic_slice of SEED_CAP lanes from there is clamped back
+    "row_at_the_end_of_the_edges": ([70], True),
+    "rows_to_the_end_of_the_edges": ([60, 70, 80], True),
+}
+
+
+@pytest.fixture(scope="module")
+def seed_graph():
+    csr = _seed_graph()
+    g = pb.prep_pull(*csr, int(csr[2].max()) + 1)
+    assert g.fwd_dst_pad.shape[0] - g.num_edges == 2 < SEED_CAP
+    assert g.host_subjects[-1] == 70
+    return csr, g
+
+
+@pytest.mark.parametrize("allow_loop", [False, True], ids=["dedup", "loop"])
+@pytest.mark.parametrize("depth", [1, 2, 3, 6])
+@pytest.mark.parametrize("name", sorted(SEED_SETS))
+def test_push_and_stream_are_one_traversal(seed_graph, name, depth,
+                                           allow_loop):
+    """Level 1 as a push over the seeds' forward rows (the default cap:
+    every seed set of this graph fits it), as the cap splits them
+    (SEED_CAP), and as a forced stream (a cap of 0: any seed with an
+    out-edge is over it) give the same (masks_p, traversed), bit for bit,
+    at every depth — level 2 takes the pushed rows as its list — and what
+    they give is the plain host recurse over a per-edge `seen`."""
+    csr, g = seed_graph
+    seeds, pushes_at_cap = SEED_SETS[name]
+    degrees = dict(zip(csr[0].tolist(), np.diff(csr[1]).tolist()))
+    total = sum(degrees.get(u, 0) for u in seeds)
+    assert pb.first_hop_pushes(total, SEED_CAP) == pushes_at_cap
+    assert pb.first_hop_pushes(total, pb.FIRST_HOP_CAP)
+    assert total == 0 or not pb.first_hop_pushes(total, 0)
+    masks, trav = fused(g, seeds, depth, allow_loop)
+    for cap in (SEED_CAP, 0):
+        masks_c, trav_c = fused(g, seeds, depth, allow_loop, cap)
+        assert masks_c.dtype == masks.dtype and trav_c.dtype == trav.dtype
+        np.testing.assert_array_equal(masks_c, masks, err_msg=f"{cap=}")
+        np.testing.assert_array_equal(trav_c, trav, err_msg=f"{cap=}")
+    want = host_recurse(*csr, seeds, depth, allow_loop)
+    nd = len(g.host_in_subjects)
+    for lvl, (want_reached, want_traversed, _matrix) in enumerate(want):
+        np.testing.assert_array_equal(
+            g.host_in_subjects[pb.unpack_words(masks[lvl], nd)], want_reached)
+        assert int(trav[lvl]) == want_traversed
