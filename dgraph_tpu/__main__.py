@@ -59,8 +59,12 @@ def cmd_serve(args) -> int:
     # backend_init, store_open (the Node), listen (-> banner)
     import time
 
+    from dgraph_tpu.obs import costs
+
     age = runtime.process_age_s()
     marks = [time.perf_counter()]
+    # the collector's pauses, timed from here on (dgraph_gc_pause_us_total)
+    costs.GC_PAUSES.install()
     where = _init_backend(lg)
     marks.append(time.perf_counter())
     node = Node(dirpath=args.postings,

@@ -443,6 +443,42 @@ class _Handler(BaseHTTPRequestHandler):
     def log_message(self, *a):  # quiet
         pass
 
+    # The request's clock starts before do_POST can open it (obs/costs.py
+    # StageClock `before`): stage `http.accept` runs from the accept
+    # (_Server's stamp) to this thread's first instruction, `http.head`
+    # from there — on a kept-alive connection's later request from its
+    # request line being read — to do_POST.
+
+    def setup(self):
+        self._stamp_head()
+        self._accept_ns = self.server.accepted.pop(self.request, 0)
+        self._first = True
+        super().setup()
+
+    def parse_request(self):
+        if self._first:
+            self._first = False
+        else:                   # no accept; the head starts here
+            self._accept_ns = 0
+            self._stamp_head()
+        return super().parse_request()
+
+    def _stamp_head(self) -> None:
+        """`http.head` starts now; the handler takes the request's turn at
+        the CPU clock here, where its first CPU reading is due."""
+        self._head_ns = time.perf_counter_ns()
+        self._cpu_turn = costs.cpu_turn()
+        self._head_cpu = time.thread_time_ns() if self._cpu_turn else None
+
+    def _clocked(self):
+        """This request's clock, the stages before its entry point filled
+        in (Node.clocked)."""
+        before = (("http.head", self._head_ns, self._head_cpu),)
+        if self._accept_ns:
+            before = (("http.accept", self._accept_ns, None),) + before
+        return self.node.clocked("query", "http.read", before,
+                                 self._cpu_turn)
+
     def _read_body(self) -> str:
         n = int(self.headers.get("Content-Length", 0))
         return self.rfile.read(n).decode("utf-8") if n else ""
@@ -480,9 +516,10 @@ class _Handler(BaseHTTPRequestHandler):
         "/debug/metrics": "serving-layer readout: caches, overlay, folds, "
                           "planner, mesh, residency",
         "/debug/traces": "distributed span traces index (?n=32); a "
-                         "sampled /query holds its stage segments (parse, "
-                         "plan, exec, dev.dispatch, dev.wait, ...) as "
-                         "child spans with real durations",
+                         "sampled /query holds its stage segments "
+                         "(http.accept, http.head, parse, plan, exec, "
+                         "dev.dispatch, dev.wait, ...) as child spans with "
+                         "real durations and cpu_us",
         "/debug/traces/<trace_id>": "one trace as Chrome trace-event JSON "
                                     "(load in Perfetto / chrome://tracing)",
         "/debug/slow": "slow-query log ring (?n=32; cost regressions "
@@ -507,7 +544,13 @@ class _Handler(BaseHTTPRequestHandler):
         "/metrics": "Prometheus text exposition of the metrics registry; "
                     "dgraph_stage_us_total{stage=} / "
                     "dgraph_stage_requests_total say where a request's "
-                    "time goes, dgraph_kernel_us_total{kernel=} the device "
+                    "time goes from the accept on and "
+                    "dgraph_stage_cpu_us_total{stage=} how much of it its "
+                    "thread worked, dgraph_http_accept_loop_us_total, "
+                    "dgraph_gc_pause_us_total{generation=} and "
+                    "dgraph_process_cpu_seconds_total what the accept loop, "
+                    "the collector and the whole process took, "
+                    "dgraph_kernel_us_total{kernel=} the device "
                     "windows by kernel, dgraph_startup_ms{phase=} serve's "
                     "start-up phases",
     }
@@ -527,6 +570,13 @@ class _Handler(BaseHTTPRequestHandler):
             # exemplar scraping is on; so do Grafana agents)
             from dgraph_tpu.obs import prom
 
+            # what the hot side keeps as plain ints, copied in now: the
+            # accept loop's, the collector's pauses, the process's CPU
+            m = self.node.metrics
+            self.server.publish(m)
+            costs.GC_PAUSES.publish(m)
+            m.counter("dgraph_process_cpu_seconds_total").set(
+                time.process_time())
             body, ctype = prom.negotiated(
                 self.headers.get("Accept"),
                 lambda ex: prom.render(self.node.metrics, exemplars=ex))
@@ -613,7 +663,7 @@ class _Handler(BaseHTTPRequestHandler):
         # clock and mints the root span `query` here, so reading the body
         # and writing the answer are stages like parse and exec
         # (obs/costs.py StageClock; Node.query joins the open clock)
-        with self.node.clocked("query", "http.read") if path == "/query" \
+        with self._clocked() if path == "/query" \
                 else contextlib.nullcontext():
             self._do_post(path)
 
@@ -970,9 +1020,48 @@ class _Server(ThreadingHTTPServer):
     clients connecting at once: http.server's own 5 resets the sixth
     connection that arrives while the accept loop waits for the
     interpreter (or leaves its SYN to a retry a second later) — 22 arrive
-    together in RedisGraph's parallel-requests test."""
+    together in RedisGraph's parallel-requests test.
+
+    The accept loop (one thread) stamps each connection when accept()
+    returns, for the handler's stage `http.accept`, and counts in plain
+    ints — no lock — the connections and the time from there until
+    process_request returned (Thread creation and start(), which waits
+    for the new thread's first turn at the interpreter): the time it could
+    not be accepting. What a connection waited in the kernel's backlog
+    before accept() returned the server cannot see; a loop busy most of
+    the time says the backlog is where requests queue."""
 
     request_queue_size = 128
+
+    def __init__(self, *a, **kw) -> None:
+        super().__init__(*a, **kw)
+        self.accepted: dict = {}     # connection -> perf_counter_ns of accept
+        self._t_accept = 0           # the newest one: the loop's own
+        self.connections = 0
+        self.accept_loop_ns = 0
+
+    def get_request(self):
+        pair = super().get_request()
+        self._t_accept = self.accepted[pair[0]] = time.perf_counter_ns()
+        self.connections += 1
+        return pair
+
+    def process_request(self, request, client_address):
+        try:
+            super().process_request(request, client_address)
+        finally:
+            self.accept_loop_ns += time.perf_counter_ns() - self._t_accept
+
+    def shutdown_request(self, request):
+        self.accepted.pop(request, None)    # a handler that never set up
+        super().shutdown_request(request)
+
+    def publish(self, metrics) -> None:
+        """Copy the loop's counters into the registry (for /metrics)."""
+        metrics.counter("dgraph_http_connections_total").set(
+            self.connections)
+        metrics.counter("dgraph_http_accept_loop_us_total").set(
+            self.accept_loop_ns // 1000)
 
 
 def make_server(node: Node, host: str = "127.0.0.1", port: int = 8080,

@@ -731,17 +731,22 @@ class Node:
         return self.tracer.root(name, attrs=attrs,
                                 force=self.slow_log.enabled)
 
-    def clocked(self, name: str, first: str):
+    def clocked(self, name: str, first: str, before: tuple = (),
+                cpu: bool | None = None):
         """Own one request: mint its root span `name` (the sampling
         decision) and open its stage clock in stage `first` (obs/costs.py
-        StageClock). The entry point that owns a request calls this —
-        HTTP do_POST, the gRPC handler, or query() itself when called
-        in-process; where a clock is already open the request is joined,
-        not owned: the open clock comes back and nothing closes here."""
+        StageClock; `before` are the stages the request was in before its
+        owner could open a clock, `cpu` its turn at the CPU clock where
+        the owner took it already: the HTTP handler's). The entry point
+        that owns a request calls this — HTTP do_POST, the gRPC handler,
+        or query() itself when called in-process; where a clock is
+        already open the request is joined, not owned: the open clock
+        comes back and nothing closes here."""
         clk = costs.clock()
         if clk is not None:
             return contextlib.nullcontext(clk)
-        return costs.StageClock(first, self._span(name), self.metrics)
+        return costs.StageClock(first, self._span(name), self.metrics,
+                                before, cpu)
 
     def _parse(self, q: str, variables: dict | None = None) -> dql.ParsedRequest:
         """Parse through the plan cache: hot query shapes skip the lexer +

@@ -31,8 +31,13 @@ splits its window), and so are the two waits of a request that shares
 the device: `gate.wait` (queued for a slot of the dispatch gate) and
 `batch.wait` (a batch leader's wait for companions, a follower's wait
 for its leader's launch). It is read three ways off one clock: always-on
-/metrics counters (dgraph_stage_us_total{stage=}), and for a sampled
-request child spans in /debug/traces and jax.profiler annotations.
+/metrics counters (dgraph_stage_us_total{stage=} for the wall time,
+dgraph_stage_cpu_us_total{stage=} for the opening thread's CPU time in
+it), and for a sampled request child spans in /debug/traces and
+jax.profiler annotations. An HTTP request's clock reaches back to the
+accept (`http.accept`, `http.head`: StageClock's `before`), and a full
+collection of the interpreter's garbage is a stage of the request that
+triggered it (`gc`: GcPauses, below the clock).
 
 Completed records land in a CostBook: a bounded sliding window that
 powers GET /debug/top (rank plan shapes / predicates / endpoints by
@@ -46,6 +51,8 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import gc
+import itertools
 import json
 import threading
 import time
@@ -515,6 +522,23 @@ _clock: contextvars.ContextVar["StageClock | None"] = \
 
 _get_ident = threading.get_ident
 _now_ns = time.perf_counter_ns
+_cpu_ns = time.thread_time_ns
+
+# A thread's CPU clock is a system call a read (CLOCK_THREAD_CPUTIME_ID has
+# no vDSO path): 0.3 us on a plain kernel, 6 us where a sandbox handles
+# system calls (gVisor) — read at all 23 switches of every request it took
+# 13.6% of `khop-par22`'s requests a second there (PERF.md §6, PR 38). So
+# one request in CPU_EVERY reads it, and counts itself in
+# dgraph_stage_cpu_requests_total; the wall clock (vDSO, 0.07 us) stays on
+# every request.
+CPU_EVERY = 32
+_cpu_turns = itertools.count()
+
+
+def cpu_turn() -> bool:
+    """Takes the next request's turn: True for the one request in
+    CPU_EVERY whose stages read the CPU clock."""
+    return next(_cpu_turns) % CPU_EVERY == 0
 
 
 def clock() -> "StageClock | None":
@@ -545,30 +569,58 @@ class StageClock:
     dict add, no lock (only the opening thread switches: to a pool thread
     that runs in a copy of the request's context, clock() is None).
 
+    For one request in CPU_EVERY (`cpu`: the owner's turn, or cpu_turn()
+    here) `cpu` holds, beside `ns`, the CPU time the opening thread spent
+    in each stage (time.thread_time_ns, one more read a switch); for the
+    others it is None. Wall less CPU of a stage that does not block by
+    design is time the thread was runnable and not running — queued for
+    the interpreter, or for a core. CPU burnt in C with the interpreter
+    released (numpy, the jit call's C++) counts as the thread's work, so
+    the CPU of all threads' stages together can pass one core.
+
+    `before` holds what happened to the request before its owner could
+    open a clock, as (stage, start perf_counter_ns, start thread_time_ns
+    or None) in order: each segment runs to the next one's start, the
+    last to the clock's opening; None says the segment crossed threads
+    and has no CPU time by definition. The HTTP handler passes
+    `http.accept` and `http.head` (api/http.py); the root span is moved
+    back to the first one's start, so the segments are its children like
+    every other.
+
     `root` is the request's root span — the ONE sampling decision. When
     it is a real span, every segment is also a child span of kind
-    "stage" (parent = the span current when the segment opened; handed to
-    the tracer in one piece when the clock closes) and a
+    "stage" (`cpu_us` in its attrs where the CPU clock was read; parent =
+    the span current when the segment opened; handed to the tracer in one
+    piece when the clock closes) and a
     jax.profiler.TraceAnnotation("dgraph.<stage>"), so /debug/traces and
     the profiler's host plane read this same clock. Only segments are
-    annotated, never the spans that enclose them.
+    annotated, never the spans that enclose them, and not the `before`
+    segments: they were over when the clock opened.
 
     Closing flushes integer microseconds to
     dgraph_stage_us_total{stage=} and counts the request in
-    dgraph_stage_requests_total."""
+    dgraph_stage_requests_total; a request that read the CPU clock also
+    flushes dgraph_stage_cpu_us_total{stage=} and counts itself in
+    dgraph_stage_cpu_requests_total."""
 
-    __slots__ = ("ns", "root", "claimed", "_metrics", "_tid", "_cur", "_t",
-                 "_segs", "_ann", "_parent", "_wall0", "_t0", "_token")
+    __slots__ = ("ns", "cpu", "root", "claimed", "_metrics", "_tid", "_cur",
+                 "_t", "_c", "_segs", "_ann", "_parent", "_wall0", "_t0",
+                 "_before", "_token")
 
-    def __init__(self, first: str, root, metrics=None) -> None:
+    def __init__(self, first: str, root, metrics=None, before=(),
+                 cpu: bool | None = None) -> None:
         self.ns: dict[str, int] = {}
+        self.cpu: dict[str, int] | None = \
+            {} if (cpu_turn() if cpu is None else cpu) else None
         self.root = root
         self.claimed = False      # Node.query took `root` as its own span
         self._metrics = metrics
         self._tid = _get_ident()
         self._cur = first
+        self._before = before
         # sampled only: closed segments (name, parent span id, start ns,
-        # end ns), the running segment's annotation and parent span id
+        # end ns, cpu ns), the running segment's annotation and parent
+        # span id
         self._segs: list | None = [] if root else None
         self._ann = None
         self._parent = ""
@@ -577,10 +629,31 @@ class StageClock:
         self._token = _clock.set(self)
         self.root.__enter__()
         self._wall0 = time.time()
-        self._t = self._t0 = time.perf_counter_ns()
+        self._t = self._t0 = _now_ns()
+        self._c = _cpu_ns() if self.cpu is not None else 0
+        if self._before:
+            self._backfill()
         if self._segs is not None:
             self._open_segment(self._cur)
         return self
+
+    def _backfill(self) -> None:
+        """Charge the `before` segments, and start the root span where the
+        first of them did."""
+        before, segs = self._before, self._segs
+        last = len(before) - 1
+        for i, (name, a, c0) in enumerate(before):
+            b = self._t0 if i == last else before[i + 1][1]
+            self.ns[name] = self.ns.get(name, 0) + b - a
+            dc = None
+            if self.cpu is not None:
+                # only the last segment ends on this thread's CPU clock
+                dc = self._c - c0 if i == last and c0 is not None else 0
+                self.cpu[name] = self.cpu.get(name, 0) + dc
+            if segs is not None:
+                segs.append((name, self.root.span_id, a, b, dc))
+        if segs is not None:
+            self.root.backdate((self._t0 - before[0][1]) * 1e-9)
 
     def __exit__(self, et, ev, tb):
         self.switch("")
@@ -593,22 +666,41 @@ class StageClock:
             m.keyed("dgraph_stage_us_total", labels=("stage",)).inc_many(
                 {k: v // 1000 for k, v in self.ns.items()})
             m.counter("dgraph_stage_requests_total").inc()
+            if self.cpu is not None:
+                m.keyed("dgraph_stage_cpu_us_total",
+                        labels=("stage",)).inc_many(
+                    {k: v // 1000 for k, v in self.cpu.items()})
+                m.counter("dgraph_stage_cpu_requests_total").inc()
         return False
 
     def switch(self, name: str) -> str:
         """Close the running segment, start `name`; returns the stage that
-        was running. For the opening thread only (clock() sees to it)."""
+        was running. For the opening thread only (clock() sees to it).
+        A full collection that starts in here switches the clock from
+        inside this call (GcPauses): the clock's own fields are set before
+        the sampled part so that it finds a clock it can switch, and what
+        it charges to the wrong stage is less than its own pause."""
         now = _now_ns()
         prev = self._cur
+        t0 = self._t
         ns = self.ns
-        ns[prev] = ns.get(prev, 0) + now - self._t
+        ns[prev] = ns.get(prev, 0) + now - t0
+        dc = None
+        cpu = self.cpu
+        if cpu is not None:
+            c = _cpu_ns()
+            dc = c - self._c
+            cpu[prev] = cpu.get(prev, 0) + dc
+            self._c = c
         self._cur = name
+        self._t = now
         if self._segs is not None:
-            self._ann.__exit__(None, None, None)
-            self._segs.append((prev, self._parent, self._t, now))
+            ann, self._ann = self._ann, None
+            if ann is not None:
+                ann.__exit__(None, None, None)
+            self._segs.append((prev, self._parent, t0, now, dc))
             if name:
                 self._open_segment(name)
-        self._t = now
         return prev
 
     def _open_segment(self, name: str) -> None:
@@ -624,8 +716,9 @@ class StageClock:
             {"trace_id": root.trace_id, "span_id": tracer._new_id(),
              "parent_id": parent, "name": name, "kind": "stage",
              "proc": root.proc, "start": wall0 + (a - t0) * 1e-9,
-             "dur": round((b - a) * 1e-9, 9), "attrs": {}}
-            for name, parent, a, b in self._segs])
+             "dur": round((b - a) * 1e-9, 9),
+             "attrs": {} if dc is None else {"cpu_us": dc // 1000}}
+            for name, parent, a, b, dc in self._segs])
 
     def server_latency(self) -> dict:
         """The reference's Latency split, from the stages closed so far:
@@ -663,6 +756,78 @@ class _StageScope:
 
 
 stage = _StageScope
+
+
+class GcPauses:
+    """The pauses of the interpreter's collector, by generation: one
+    gc.callbacks hook (the interpreter calls it with "start" and "stop"
+    around every collection, on the thread whose allocation set it off;
+    collections never nest, so one start stamp is enough).
+
+    The hook takes no lock and calls nothing that does — a collection
+    can start at any allocation, also inside Metrics._lock on the same
+    thread: it adds to plain ints, which publish() copies into
+    dgraph_gc_pause_us_total{generation=} and
+    dgraph_gc_collections_total{generation=} when /metrics is rendered.
+
+    A full collection (generation 2) is also a stage of the request whose
+    thread it ran on: that thread's open clock, if any, is in stage `gc`
+    for the pause (on a sampled request with its TraceAnnotation
+    "dgraph.gc", so an idle gap of the device in a profile can carry its
+    cause, and an event `gc` on the root span). Every other thread stands
+    still meanwhile, and shows the pause as wall time without CPU in
+    whatever stage it was in."""
+
+    __slots__ = ("pause_ns", "collections", "_t0", "_clk", "_prev")
+
+    def __init__(self) -> None:
+        self.pause_ns = [0, 0, 0]
+        self.collections = [0, 0, 0]
+        self._t0 = 0
+        self._clk: StageClock | None = None
+        self._prev = ""
+
+    def __call__(self, phase: str, info: dict) -> None:
+        gen = info["generation"]
+        if phase == "start":
+            if gen == 2:
+                clk = self._clk = clock()
+                if clk is not None:
+                    self._prev = clk.switch("gc")
+            self._t0 = _now_ns()
+            return
+        dt = _now_ns() - self._t0
+        self.pause_ns[gen] += dt
+        self.collections[gen] += 1
+        clk = self._clk
+        if clk is not None:
+            self._clk = None
+            clk.switch(self._prev)
+            clk.root.event("gc", generation=gen,
+                           collected=info.get("collected", 0),
+                           ms=round(dt * 1e-6, 3))
+
+    def install(self) -> None:
+        if self not in gc.callbacks:
+            gc.callbacks.append(self)
+
+    def remove(self) -> None:
+        if self in gc.callbacks:
+            gc.callbacks.remove(self)
+
+    def publish(self, metrics) -> None:
+        pause = metrics.keyed("dgraph_gc_pause_us_total",
+                              labels=("generation",))
+        count = metrics.keyed("dgraph_gc_collections_total",
+                              labels=("generation",))
+        for gen in range(3):
+            pause.set(str(gen), self.pause_ns[gen] // 1000)
+            count.set(str(gen), self.collections[gen])
+
+
+# the process has one collector, so one record of its pauses: `serve`
+# installs the hook at start-up; every node of the process publishes it
+GC_PAUSES = GcPauses()
 
 
 # ---------------------------------------------------------------------------

@@ -131,6 +131,13 @@ class Span:
     def event(self, name: str, **attrs) -> None:
         self.events_.append((time.perf_counter() - self.t0, name, attrs))
 
+    def backdate(self, seconds: float) -> None:
+        """The span began `seconds` before it was built: the owner of a
+        request learns of it after its first stages are over (obs/costs.py
+        StageClock `before`)."""
+        self.wall0 -= seconds
+        self.t0 -= seconds
+
     def __enter__(self) -> "Span":
         self._token = _current.set(self)
         return self

@@ -413,6 +413,16 @@ class Registry:
                      # requests whose stage clock closed (obs/costs.py
                      # StageClock): the divisor of dgraph_stage_us_total
                      "dgraph_stage_requests_total",
+                     # those of them that read the CPU clock too (one in
+                     # costs.CPU_EVERY): dgraph_stage_cpu_us_total's
+                     "dgraph_stage_cpu_requests_total",
+                     # the accept loop of the HTTP server (api/http.py
+                     # _Server: plain ints of its one thread), and the
+                     # process's CPU seconds (time.process_time) — all
+                     # three set when /metrics is rendered
+                     "dgraph_http_connections_total",
+                     "dgraph_http_accept_loop_us_total",
+                     "dgraph_process_cpu_seconds_total",
                      # device aggregation + whole-graph analytics
                      # (ops/segments.py, query/groupby.py,
                      # query/analytics.py; ISSUE 17)
@@ -492,9 +502,23 @@ class Registry:
         # otherwise reach only /debug/top's ring. The two waits of a
         # request that shares the device show from start-up, at 0: one
         # client never enters them, and a reader has to tell that from a
-        # program without the stages
+        # program without the stages. So do the two stages before
+        # do_POST, which a gRPC or in-process request has not, and `gc`,
+        # which most requests never enter
+        kept = ("batch.wait", "gate.wait", "http.accept", "http.head", "gc")
         self.keyed_gauges["dgraph_stage_us_total"] = KeyedGauge(
-            labels=("stage",), keep=("batch.wait", "gate.wait"))
+            labels=("stage",), keep=kept)
+        # the CPU time of the request's own thread in each stage, beside
+        # the wall time above: same stages, over the requests counted in
+        # dgraph_stage_cpu_requests_total
+        self.keyed_gauges["dgraph_stage_cpu_us_total"] = KeyedGauge(
+            labels=("stage",), keep=kept)
+        # the collector's pauses and collections by generation
+        # (obs/costs.py GcPauses), set when /metrics is rendered
+        self.keyed_gauges["dgraph_gc_pause_us_total"] = KeyedGauge(
+            labels=("generation",), keep=("0", "1", "2"))
+        self.keyed_gauges["dgraph_gc_collections_total"] = KeyedGauge(
+            labels=("generation",), keep=("0", "1", "2"))
         self.keyed_gauges["dgraph_kernel_us_total"] = KeyedGauge(
             labels=("kernel",))
         self.keyed_gauges["dgraph_kernel_calls_total"] = KeyedGauge(

@@ -543,15 +543,18 @@ def test_unsampled_request_builds_no_span_and_no_annotation(monkeypatch):
         assert bool(spans.n) == some and bool(anns.n) == some, \
             (sample, spans.n, anns.n)
         if some:        # one annotation a segment, and only segments:
-            #             never the root, task:* or device_kernel spans
+            #             never the root, task:* or device_kernel spans —
+            #             nor http.accept and http.head, which were over
+            #             when the clock opened: spans only
             rec = node.tracer.sink.get(traces[0]["trace_id"])
             kinds = [s["kind"] for s in rec["spans"]]
-            assert anns.n == kinds.count("stage") > 0
-            assert spans.n == len(kinds) - anns.n >= 2
+            assert anns.n == kinds.count("stage") - 2 > 0
+            assert spans.n == len(kinds) - kinds.count("stage") >= 2
 
 
 def test_sampled_request_holds_its_stage_spans(monkeypatch):
     _force_tier(monkeypatch, "kernel")
+    monkeypatch.setattr(costs, "CPU_EVERY", 1)     # every request's CPU
     node = _chain_node(span_sample=1.0, trace_rng=random.Random(5))
     srv, base = _serve(node)
     try:
@@ -571,10 +574,18 @@ def test_sampled_request_holds_its_stage_spans(monkeypatch):
     stages = sorted((s for s in spans if s["kind"] == "stage"),
                     key=lambda s: s["start"])
     names = [s["name"] for s in stages]
-    # the handler's own time is http.read: the body before the query, and
-    # its latency histogram after the answer is written
-    assert names[0] == "http.read" and names[-2:] == ["http.write",
-                                                      "http.read"]
+    # the clock reaches back to the accept; then the handler's own time is
+    # http.read: the body before the query, and its latency histogram
+    # after the answer is written
+    assert names[:3] == ["http.accept", "http.head", "http.read"] \
+        and names[-2:] == ["http.write", "http.read"]
+    assert [s["parent_id"] for s in stages[:2]] == [root["span_id"]] * 2
+    # every segment says what its thread worked of it: none more than it
+    # lasted (but for a clock's tick), the hand-over between two threads
+    # nothing by definition
+    assert stages[0]["attrs"] == {"cpu_us": 0}
+    for s in stages:
+        assert 0 <= s["attrs"]["cpu_us"] <= s["dur"] * 1e6 + 1000, s
     order = [names.index(n) for n in ("parse", "exec", "exec.prep",
                                       "dev.dispatch", "dev.wait",
                                       "dev.post", "encode")]
@@ -598,6 +609,7 @@ def test_metrics_carry_stage_kernel_and_startup_series(monkeypatch):
 
     _force_tier(monkeypatch, "kernel")
     monkeypatch.setattr(taskmod, "HOST_EXPAND_MAX", 0)   # csr.expand window
+    monkeypatch.setattr(costs, "CPU_EVERY", 1)     # every request's CPU
     node = _chain_node(span_sample=0.0, planner=False)
     srv, base = _serve(node)
     try:
@@ -617,11 +629,22 @@ def test_metrics_carry_stage_kernel_and_startup_series(monkeypatch):
     assert {"http.read", "parse", "plan", "exec", "exec.prep",
             "dev.dispatch", "dev.wait", "dev.post", "dev.window", "encode",
             "http.write"} <= set(stage_us)
+    assert {"http.accept", "http.head"} <= set(stage_us)
     # the two waits of a request that shares the device show at 0: one
-    # request at a time never queues for the gate or rides a batch
-    assert stage_us.pop("batch.wait") == stage_us.pop("gate.wait") == 0
+    # request at a time never queues for the gate or rides a batch; nor
+    # did a full collection fall into these two requests
+    assert stage_us.pop("batch.wait") == stage_us.pop("gate.wait") == \
+        stage_us.pop("gc") == 0
     assert all(v > 0 and v == int(v) for v in stage_us.values())
-    assert series["dgraph_stage_requests_total"][0][1] == 2
+    # the CPU series: the same stages (one whose CPU rounds to no whole
+    # microsecond shows nothing), never more than the wall but for a tick
+    cpu_us = labelled("dgraph_stage_cpu_us_total", "stage")
+    assert cpu_us["http.accept"] == cpu_us["gc"] == 0 < cpu_us["http.head"]
+    assert set(cpu_us) <= set(stage_us) | {"batch.wait", "gate.wait", "gc"}
+    assert cpu_us["exec"] > 0 and cpu_us["parse"] > 0
+    assert sum(cpu_us.values()) <= sum(stage_us.values()) + 2000
+    assert series["dgraph_stage_requests_total"][0][1] == \
+        series["dgraph_stage_cpu_requests_total"][0][1] == 2
     kernel_us = labelled("dgraph_kernel_us_total", "kernel")
     calls = labelled("dgraph_kernel_calls_total", "kernel")
     assert calls["pb.bfs_dist"] == 1 and kernel_us["pb.bfs_dist"] > 0
@@ -853,3 +876,293 @@ def test_owner_takes_the_one_sampling_decision_from_an_injected_rng():
         node.close()
     assert picks == [1, 0, 1, 0]
     assert rng.asked == 4
+
+
+# ---------------------------------------------------------------------------
+# work beside waiting, the stages before do_POST, the collector (PR 38)
+# ---------------------------------------------------------------------------
+
+import gc
+import http.client
+
+from dgraph_tpu.utils.metrics import Registry
+
+
+def _labelled(series, name, label):
+    return {lb[label]: v for lb, v in series[name]}
+
+
+def test_a_stage_that_sleeps_has_no_cpu_and_one_that_spins_has_its_wall():
+    reg = Registry()
+    clk = costs.StageClock("owner", otrace.NULL_SPAN, reg, cpu=True)
+    with clk:
+        with costs.stage("sleeps"):
+            time.sleep(0.05)
+        with costs.stage("spins"):
+            end = time.perf_counter() + 0.05
+            while time.perf_counter() < end:
+                pass
+    ns, cpu = clk.ns, clk.cpu
+    assert set(cpu) == set(ns) == {"owner", "sleeps", "spins"}
+    assert ns["sleeps"] >= 50e6 and cpu["sleeps"] < 5e6
+    # a spinning thread is on a core for its wall time, less what a loaded
+    # box takes away from it
+    assert ns["spins"] >= 50e6 and cpu["spins"] > 0.5 * ns["spins"]
+    tick = 1_000_000
+    for s in ns:
+        assert 0 <= cpu[s] <= ns[s] + tick, (s, cpu[s], ns[s])
+    assert sum(cpu.values()) <= sum(ns.values()) + tick
+    series = prom.parse(prom.render(reg))
+    wall_us = _labelled(series, "dgraph_stage_us_total", "stage")
+    cpu_us = _labelled(series, "dgraph_stage_cpu_us_total", "stage")
+    assert wall_us["sleeps"] == ns["sleeps"] // 1000
+    assert cpu_us["spins"] == cpu["spins"] // 1000
+    assert cpu_us.get("sleeps", 0) == cpu["sleeps"] // 1000 < 5000
+    assert series["dgraph_stage_requests_total"][0][1] == \
+        series["dgraph_stage_cpu_requests_total"][0][1] == 1
+
+
+def test_one_request_in_cpu_every_reads_the_cpu_clock(monkeypatch):
+    """The CPU clock is a system call a read: one request in CPU_EVERY
+    pays it, and says so in its own count; the wall clock is every
+    request's."""
+    import itertools
+
+    monkeypatch.setattr(costs, "CPU_EVERY", 4)
+    monkeypatch.setattr(costs, "_cpu_turns", itertools.count())
+    node = _chain_node(span_sample=1.0, trace_rng=random.Random(23))
+    try:
+        for _ in range(8):
+            node.query('{ q(func: has(name)) { name } }')
+        series = prom.parse(prom.render(node.metrics))
+        recs = [node.tracer.sink.get(t["trace_id"])
+                for t in node.tracer.sink.index(8)]
+    finally:
+        node.close()
+    assert series["dgraph_stage_requests_total"][0][1] == 8
+    assert series["dgraph_stage_cpu_requests_total"][0][1] == 2
+    wall_us = _labelled(series, "dgraph_stage_us_total", "stage")
+    cpu_us = _labelled(series, "dgraph_stage_cpu_us_total", "stage")
+    # two requests' CPU against eight requests' wall
+    assert 0 < cpu_us["plan"] < wall_us["plan"]
+    with_cpu = [all("cpu_us" in s["attrs"] for s in r["spans"]
+                    if s["kind"] == "stage") for r in recs]
+    without = [all(s["attrs"] == {} for s in r["spans"]
+                   if s["kind"] == "stage") for r in recs]
+    assert with_cpu.count(True) == 2 and without.count(True) == 6
+    # a clock told its turn does not take one
+    assert costs.StageClock("a", otrace.NULL_SPAN, cpu=False).cpu is None
+    assert costs.StageClock("a", otrace.NULL_SPAN, cpu=True).cpu == {}
+    assert next(costs._cpu_turns) == 8
+
+
+def test_clock_is_backfilled_with_the_stages_before_its_owner():
+    """`before`: each segment runs to the next one's start, the last to
+    the clock's opening; only the last can have CPU time."""
+    t0, c0 = time.perf_counter_ns(), time.thread_time_ns()
+    end = time.perf_counter() + 0.01
+    while time.perf_counter() < end:
+        pass
+    node = _chain_node(span_sample=1.0, trace_rng=random.Random(11))
+    try:
+        before = (("http.accept", t0 - 3_000_000, None),
+                  ("http.head", t0, c0))
+        with node.clocked("query", "http.read", before, True) as clk:
+            opened = clk._t0
+        rec = node.tracer.sink.get(node.tracer.sink.index(1)[0]["trace_id"])
+    finally:
+        node.close()
+    assert clk.ns["http.accept"] == 3_000_000 and clk.cpu["http.accept"] == 0
+    assert clk.ns["http.head"] == opened - t0 >= 10e6
+    assert 5e6 < clk.cpu["http.head"] <= clk.ns["http.head"] + 1e6
+    root = _links_intact(rec["spans"])
+    stages = sorted((s for s in rec["spans"] if s["kind"] == "stage"),
+                    key=lambda s: s["start"])
+    assert [s["name"] for s in stages] == ["http.accept", "http.head",
+                                           "http.read"]
+    eps = 200e-6
+    assert abs(root["start"] - stages[0]["start"]) < eps
+    assert root["dur"] >= 0.013
+    assert stages[-1]["start"] + stages[-1]["dur"] <= \
+        root["start"] + root["dur"] + eps
+
+
+def test_http_round_trip_grows_accept_and_head_inside_the_root_span(
+        monkeypatch):
+    monkeypatch.setattr(costs, "CPU_EVERY", 1)     # every request's CPU
+    node = _chain_node(span_sample=1.0, trace_rng=random.Random(13))
+    srv, base = _serve(node)
+    try:
+        _post(base, "/query", '{ q(func: has(name)) { name } }')
+        _closed(node, 1)
+        series = prom.parse(_get(base, "/metrics")[1].decode())
+        rec = node.tracer.sink.get(node.tracer.sink.index(1)[0]["trace_id"])
+    finally:
+        srv.shutdown()
+        node.close()
+    wall_us = _labelled(series, "dgraph_stage_us_total", "stage")
+    cpu_us = _labelled(series, "dgraph_stage_cpu_us_total", "stage")
+    assert wall_us["http.accept"] > 0 and wall_us["http.head"] > 0
+    assert cpu_us["http.accept"] == 0 < cpu_us["http.head"] <= \
+        wall_us["http.head"] + 1000
+    # the loop's own counters: the query's connection and this scrape's
+    assert series["dgraph_http_connections_total"][0][1] == 2
+    assert series["dgraph_http_accept_loop_us_total"][0][1] > 0
+    assert series["dgraph_process_cpu_seconds_total"][0][1] > 0
+    root = _links_intact(rec["spans"])
+    eps = 200e-6
+    stages = [s for s in rec["spans"] if s["kind"] == "stage"]
+    first = min(stages, key=lambda s: s["start"])
+    assert first["name"] == "http.accept"
+    assert root["start"] <= first["start"] + eps
+    for s in stages:
+        assert root["start"] - eps <= s["start"] and \
+            s["start"] + s["dur"] <= root["start"] + root["dur"] + eps, s
+    # the envelope's split is the clock's as before: no new field
+    tree = otrace.span_tree(rec)["tree"]
+    assert len(tree) == 1 and tree[0]["name"] == "query"
+    assert {"http.accept", "http.head"} <= \
+        {c["name"] for c in tree[0]["children"]}
+    assert otrace.chrome_trace(rec)["traceEvents"]
+
+
+def test_second_request_of_a_kept_alive_connection_has_no_accept():
+    node = _chain_node(span_sample=1.0, trace_rng=random.Random(17))
+    srv, base = _serve(node)
+    # http.server speaks HTTP/1.0 and closes after every answer unless its
+    # handler class says 1.1 (every answer here has a Content-Length)
+    srv.RequestHandlerClass.protocol_version = "HTTP/1.1"
+    conn = http.client.HTTPConnection("127.0.0.1", srv.server_address[1])
+    accept_us = node.metrics.keyed("dgraph_stage_us_total")
+    try:
+        seen = []
+        for i in (1, 2):
+            conn.request("POST", "/query", body='{ q(func: has(name)) '
+                                                '{ name } }')
+            assert conn.getresponse().read()
+            _closed(node, i)
+            seen.append((accept_us.get("http.accept"),
+                         accept_us.get("http.head")))
+        recs = [node.tracer.sink.get(t["trace_id"])
+                for t in node.tracer.sink.index(2)]      # newest first
+    finally:
+        conn.close()
+        srv.shutdown()
+        node.close()
+    assert srv.connections == 1
+    (a1, h1), (a2, h2) = seen
+    assert a1 > 0 and a2 == a1            # one accept, charged once
+    assert h2 > h1 > 0                    # a head a request
+    names = [[s["name"] for s in sorted(r["spans"],
+                                        key=lambda s: s["start"])
+              if s["kind"] == "stage"] for r in recs]
+    assert names[1][:3] == ["http.accept", "http.head", "http.read"]
+    assert names[0][:2] == ["http.head", "http.read"]
+    # the later request's head starts at its request line, not at the
+    # connection's set-up: it cannot hold the first request
+    heads = [next(s for s in r["spans"] if s["name"] == "http.head")
+             for r in recs]
+    first_root = _links_intact(recs[1]["spans"])
+    assert heads[0]["start"] >= first_root["start"] + first_root["dur"] - 1e-3
+
+
+def test_full_collection_is_counted_timed_and_a_stage_of_its_request():
+    pauses = costs.GcPauses()
+    reg = Registry()
+    node = _chain_node(span_sample=1.0, trace_rng=random.Random(19))
+    pauses.install()
+    try:
+        assert pauses in gc.callbacks
+        pauses.install()                          # once only
+        assert gc.callbacks.count(pauses) == 1
+        before = list(pauses.collections)
+        gc.collect()                              # no clock open: counted
+        assert pauses.collections[2] == before[2] + 1
+        assert pauses.pause_ns[2] > 0
+        with node.clocked("query", "owner", cpu=True) as clk:
+            with costs.stage("exec"):
+                gc.collect()
+                assert clk._cur == "exec"         # back where it was
+            gc.collect(0)                         # a young one: no stage
+        rec = node.tracer.sink.get(node.tracer.sink.index(1)[0]["trace_id"])
+    finally:
+        pauses.remove()
+        node.close()
+    assert pauses not in gc.callbacks
+    assert pauses.collections[2] == before[2] + 2
+    assert pauses.collections[0] >= before[0] + 1
+    assert 0 < clk.ns["gc"] <= pauses.pause_ns[2]
+    assert clk.cpu["gc"] > 0
+    stages = [s["name"] for s in sorted(rec["spans"],
+                                        key=lambda s: s["start"])
+              if s["kind"] == "stage"]
+    assert stages == ["owner", "exec", "gc", "exec", "owner"]
+    root = _links_intact(rec["spans"])
+    ev = [e for e in root["events"] if e["name"] == "gc"]
+    assert len(ev) == 1 and ev[0]["attrs"]["generation"] == 2
+    assert ev[0]["attrs"]["ms"] > 0 and "collected" in ev[0]["attrs"]
+    pauses.publish(reg)
+    series = prom.parse(prom.render(reg))
+    count = _labelled(series, "dgraph_gc_collections_total", "generation")
+    pause = _labelled(series, "dgraph_gc_pause_us_total", "generation")
+    assert set(count) == set(pause) == {"0", "1", "2"}
+    assert count["2"] == pauses.collections[2]
+    assert pause["2"] == pauses.pause_ns[2] // 1000 > 0
+
+
+def test_collector_hook_takes_no_lock_its_thread_may_hold():
+    """A collection starts at any allocation, also one made under the
+    metrics registry's lock (not re-entrant): the hook must come back."""
+    pauses = costs.GcPauses()
+    reg = Registry()
+    done = []
+
+    locks = [reg._lock, reg.counter("dgraph_stage_requests_total")._lock,
+             reg.keyed_gauges["dgraph_stage_us_total"]._lock,
+             reg.keyed_gauges["dgraph_gc_pause_us_total"]._lock]
+
+    def collect_under_the_locks():
+        clk = costs.StageClock("owner", otrace.NULL_SPAN)
+        with clk, locks[0], locks[1], locks[2], locks[3]:
+            gc.collect()
+            done.append(dict(clk.ns))
+
+    pauses.install()
+    try:
+        t = threading.Thread(target=collect_under_the_locks, daemon=True)
+        t.start()
+        t.join(timeout=20)
+        assert not t.is_alive(), "gc.collect() under the registry's lock hung"
+    finally:
+        pauses.remove()
+    assert done and done[0]["gc"] > 0
+    assert pauses.collections[2] >= 1
+
+
+NEW_AT_ZERO = {
+    "dgraph_stage_cpu_us_total": ("stage", {"batch.wait", "gate.wait",
+                                            "http.accept", "http.head",
+                                            "gc"}),
+    "dgraph_stage_us_total": ("stage", {"batch.wait", "gate.wait",
+                                        "http.accept", "http.head", "gc"}),
+    "dgraph_gc_pause_us_total": ("generation", {"0", "1", "2"}),
+    "dgraph_gc_collections_total": ("generation", {"0", "1", "2"}),
+    "dgraph_stage_cpu_requests_total": None,
+    "dgraph_http_connections_total": None,
+    "dgraph_http_accept_loop_us_total": None,
+    "dgraph_process_cpu_seconds_total": None,
+}
+
+
+@pytest.mark.parametrize("name", sorted(NEW_AT_ZERO))
+def test_new_series_show_at_zero_on_a_fresh_node(name):
+    node = Node()
+    try:
+        series = prom.parse(prom.render(node.metrics))
+    finally:
+        node.close()
+    if NEW_AT_ZERO[name] is None:
+        assert series[name] == [({}, 0.0)]
+    else:
+        label, keys = NEW_AT_ZERO[name]
+        assert _labelled(series, name, label) == dict.fromkeys(keys, 0.0)
