@@ -342,8 +342,10 @@ class Executor:
                 # against the cluster, not one tablet server)
                 uids.append(want)
             else:
+                # runs on every request: a binary search of the sorted
+                # known uids, O(roots * log N), never a store-sized pass
                 present = _known_uids(self.snap)
-                uids.append(want[np.isin(want, present)]
+                uids.append(want[us.host_rank_of(present, want, -1) >= 0]
                             if len(present) else want)
         for v in gq.root_uid_vars:
             vv = self.vars.get(v)
@@ -1407,7 +1409,8 @@ def _block_defines(gq: dql.GraphQuery) -> set[str]:
 
 
 def _known_uids(snap: GraphSnapshot) -> np.ndarray:
-    """All uids present anywhere in the snapshot (subjects or objects).
+    """All uids present anywhere in the snapshot (subjects or objects),
+    sorted and distinct (np.unique output) — _root_uids binary-searches it.
     Computed once per snapshot and cached — uid(...) validation runs per query."""
     cached = getattr(snap, "_known_uids_cache", None)
     if cached is not None:

@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 from dgraph_tpu.query import dql
-from dgraph_tpu.query.engine import Executor, QueryError
+from dgraph_tpu.query.engine import Executor, QueryError, _known_uids
 from dgraph_tpu.storage import index as idx
 from dgraph_tpu.storage.csr_build import build_snapshot
 from dgraph_tpu.storage.postings import DirectedEdge, Op
@@ -589,3 +589,77 @@ def test_groupby_fast_path_exactness_guards():
     # same instant, different tz offsets: distinct display keys
     out, _ = n.query('{ q(func: has(when)) @groupby(when) { count(uid) } }')
     assert len(out["q"][0]["@groupby"]) == 2
+
+
+# ---- uid(0x…) roots: membership in the snapshot is a binary search (PR 39)
+
+@pytest.fixture(scope="module")
+def sparse_env():
+    """Known uids with gaps: 10 20 30 40 through `follows` (30 and 40 as
+    objects only), 25 through the value-only predicate `name`."""
+    s = Store()
+    for e in parse_schema("name: string .\nfollows: uid ."):
+        s.set_schema(e)
+    for a, b in [(10, 20), (20, 30), (10, 40)]:
+        idx.add_mutation_with_index(s, DirectedEdge(a, "follows", object_uid=b), 1)
+    idx.add_mutation_with_index(
+        s, DirectedEdge(25, "name", value=Val(TypeID.STRING, "value-only")), 1)
+    s.commit(1, 2, list(s.lists.keys()))
+    return s, build_snapshot(s, read_ts=3)
+
+
+@pytest.fixture(scope="module")
+def empty_env():
+    s = Store()
+    for e in parse_schema("follows: uid ."):
+        s.set_schema(e)
+    return s, build_snapshot(s, read_ts=3)
+
+
+ROOT_CASES = {
+    "known": ("sparse", [20]),
+    "unknown-below-smallest": ("sparse", [3]),
+    "unknown-between-two": ("sparse", [27]),
+    "unknown-above-largest": ("sparse", [41]),
+    "duplicates-unsorted": ("sparse", [40, 10, 40, 27, 10, 30]),
+    "several-some-unknown": ("sparse", [10, 11, 20, 39, 40, 1000]),
+    "more-roots-than-known": ("sparse", list(range(1, 64))),
+    "empty-store": ("empty", [7, 3, 7]),
+    "value-only-predicate": ("sparse", [25, 26]),
+}
+
+
+@pytest.mark.parametrize("case", list(ROOT_CASES))
+def test_root_uids_match_isin_reference(case, sparse_env, empty_env):
+    which, roots = ROOT_CASES[case]
+    s, snap = sparse_env if which == "sparse" else empty_env
+    present = _known_uids(snap)
+    assert (len(present) == 0) == (which == "empty")
+    want = np.unique(np.asarray(roots, np.int64))
+    ref = want[np.isin(want, present)] if len(present) else want
+    gq = dql.parse("{ q(func: uid(%s)) { uid } }"
+                   % ", ".join(map(str, roots))).queries[0]
+    got = Executor(snap, s.schema)._root_uids(gq)
+    assert got.dtype == np.int64
+    np.testing.assert_array_equal(got, ref)
+    if which == "sparse":
+        assert set(got.tolist()) == set(roots) & {10, 20, 25, 30, 40}
+
+
+def test_root_uids_never_scan_the_store(sparse_env, monkeypatch):
+    """The store-sized pass cannot come back unnoticed: np.isin / np.in1d
+    raise for the length of the call."""
+    s, snap = sparse_env
+    _known_uids(snap)           # the snapshot's cache is built outside
+    ex = Executor(snap, s.schema)
+    gq = dql.parse("{ q(func: uid(40, 27, 10)) { uid } }").queries[0]
+
+    def scan(*a, **k):
+        raise AssertionError("_root_uids scanned the store")
+
+    monkeypatch.setattr(np, "isin", scan)
+    if hasattr(np, "in1d"):
+        monkeypatch.setattr(np, "in1d", scan)
+    got = ex._root_uids(gq)
+    monkeypatch.undo()
+    assert got.tolist() == [10, 40]
