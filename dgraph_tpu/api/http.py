@@ -470,14 +470,13 @@ class _Handler(BaseHTTPRequestHandler):
         self._cpu_turn = costs.cpu_turn()
         self._head_cpu = time.thread_time_ns() if self._cpu_turn else None
 
-    def _clocked(self):
-        """This request's clock, the stages before its entry point filled
-        in (Node.clocked)."""
+    def _clocked(self, name: str):
+        """This request's clock, its root span `name`, the stages before
+        its entry point filled in (Node.clocked)."""
         before = (("http.head", self._head_ns, self._head_cpu),)
         if self._accept_ns:
             before = (("http.accept", self._accept_ns, None),) + before
-        return self.node.clocked("query", "http.read", before,
-                                 self._cpu_turn)
+        return self.node.clocked(name, "http.read", before, self._cpu_turn)
 
     def _read_body(self) -> str:
         n = int(self.headers.get("Content-Length", 0))
@@ -657,14 +656,18 @@ class _Handler(BaseHTTPRequestHandler):
                  "/abort": "abort", "/alter": "alter",
                  "/analytics": "analytics"}
 
+    # endpoints whose requests run on the stage clock, by root span name
+    _CLOCKED = {"/query": "query", "/analytics": "analytics"}
+
     def do_POST(self):
         path = urlparse(self.path).path.rstrip("/")
-        # this handler owns a /query request: it opens the request's stage
-        # clock and mints the root span `query` here, so reading the body
-        # and writing the answer are stages like parse and exec
-        # (obs/costs.py StageClock; Node.query joins the open clock)
-        with self._clocked() if path == "/query" \
-                else contextlib.nullcontext():
+        # this handler owns a /query or /analytics request: it opens the
+        # request's stage clock and mints the root span (`query`,
+        # `analytics`) here, so reading the body and writing the answer are
+        # stages like parse and exec (obs/costs.py StageClock; Node.query
+        # and Node.analytics join the open clock)
+        name = self._CLOCKED.get(path)
+        with self._clocked(name) if name else contextlib.nullcontext():
             self._do_post(path)
 
     def _do_post(self, path: str):
@@ -818,8 +821,10 @@ class _Handler(BaseHTTPRequestHandler):
     def _analytics(self):
         """POST /analytics — whole-graph OLAP over one predicate's tablet
         (docs/ops.md "Analytics"). Body: {"kind": "pagerank"|"cc"|
-        "triangles", "pred": "<predicate>", ...knobs}; ?timeoutMs= rides
-        the query string like every other endpoint."""
+        "triangles"|"pr"|"wcc", "pred": "<predicate>", ...knobs}; `pr` and
+        `wcc` (LDBC Graphalytics) take "iterations" and "uids", the probe
+        vertices they report; ?timeoutMs= rides the query string like
+        every other endpoint."""
         j = json.loads(self._read_body() or "{}")
         kind = str(j.get("kind", ""))
         pred = str(j.get("pred", ""))
@@ -834,10 +839,14 @@ class _Handler(BaseHTTPRequestHandler):
             tol=float(j.get("tol", 1e-6)),
             max_iters=int(j.get("maxIters", j.get("max_iters", 100))),
             top=int(j.get("top", 20)),
+            iterations=int(j.get("iterations", 10)),
+            uids=j.get("uids") or (),
             timeout_ms=float(timeout_ms) if timeout_ms else None,
             start_ts=int(j["startTs"]) if j.get("startTs") else None)
         ext = {"server_latency": {"total_ns": time.perf_counter_ns() - t0}}
-        self._send(200, _envelope_ok({"analytics": out}, ext))
+        with costs.stage("encode"):
+            body = _envelope_ok({"analytics": out}, ext)
+        self._send(200, body)
 
     def _query(self):
         body = self._read_body()

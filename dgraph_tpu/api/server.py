@@ -1108,17 +1108,37 @@ class Node:
 
     def analytics(self, kind: str, pred: str, *, damping: float = 0.85,
                   tol: float = 1e-6, max_iters: int = 100, top: int = 20,
+                  iterations: int = 10, uids=(),
                   timeout_ms: float | None = None,
                   start_ts: int | None = None) -> dict:
         """Whole-graph analytics over one uid predicate's tablet
         (query/analytics.py): PageRank / connected components / triangle
         count as device-resident while_loop programs on the mesh, host
         oracle fallback when the tablet is overlay/residency-deferred or
-        the node runs without a mesh. Same request discipline as query():
-        span + deadline scope + cost ledger + DispatchGate."""
+        the node runs without a mesh; Graphalytics' `pr` (`iterations`
+        steps) and `wcc` on one chip over the resident PullGraph, for the
+        probe vertices `uids`. Same request discipline as query(): stage
+        clock + span + deadline scope + cost ledger + DispatchGate."""
+        # the stage clock, as query() joins or owns it: what is in no
+        # narrower stage is `plan` (read view, snapshot, layout lookup)
+        with self.clocked("analytics", "plan") as clk, costs.stage("plan"):
+            if not clk.claimed and (not clk.root
+                                    or otrace.current() is clk.root):
+                clk.claimed = True
+                sp, in_span = clk.root, contextlib.nullcontext()
+                sp.set(kind=kind, pred=pred)
+            else:
+                sp = in_span = self._span("analytics", kind=kind,
+                                          pred=pred)
+            with in_span:
+                return self._analytics(sp, kind, pred, damping, tol,
+                                       max_iters, top, iterations, uids,
+                                       timeout_ms, start_ts)
+
+    def _analytics(self, sp, kind, pred, damping, tol, max_iters, top,
+                   iterations, uids, timeout_ms, start_ts) -> dict:
         from dgraph_tpu.query import analytics as an
 
-        sp = self._span("analytics", kind=kind, pred=pred)
         m = self.metrics
         m.meter("analytics").mark()
         t0 = time.perf_counter()
@@ -1128,7 +1148,7 @@ class Node:
                               tenant=tenant) \
             if self.cost_ledger else None
         try:
-            with sp, self._deadline_scope(timeout_ms), costs.scope(lg):
+            with self._deadline_scope(timeout_ms), costs.scope(lg):
                 self._admit_tenant(tenant)
                 read_ts, snap = self._read_view(start_ts)
                 if tenant:
@@ -1151,7 +1171,8 @@ class Node:
                 out = an.run(kind, csr, mesh=self.mesh_exec,
                              gate=self.dispatch_gate, metrics=m,
                              damping=damping, tol=tol,
-                             max_iters=max_iters, top=top)
+                             max_iters=max_iters, top=top,
+                             iterations=iterations, uids=uids)
                 out["pred"] = pred
                 sp.set(device=out["device"], nodes=out["nodes"],
                        edges=out["edges"])

@@ -1042,6 +1042,100 @@ def recurse_fused_multi(in_src_pad, in_src_pad_d, in_iptr_rank, row_ends,
         seeds)
 
 
+# ---------------------------------------------------------------------------
+# whole-graph analytics over the same layout (LDBC Graphalytics PR and WCC,
+# query/analytics.py): every step reads every in-edge of the dst-sorted
+# stream once, a segmented reduction by destination rank. The vertex set
+# is the DST-RANK space, which holds every vertex with an edge when every
+# source is also a destination (the caller checks: analytics.pull_layout).
+# XLA segment ops, not a row-end kernel: a rank's value is a float sum or
+# an int min over its whole row, not a prefix pick, and the plain form is
+# the baseline a kernel has to beat.
+# ---------------------------------------------------------------------------
+
+
+def _dst_segments(in_iptr_rank: jax.Array, e_pad: int) -> jax.Array:
+    """int32[e_pad]: the destination rank of each edge of the stream, Nd
+    for the pad edges — the count of row starts iptr[1..Nd] at or below
+    the edge's position."""
+    starts = jnp.zeros(e_pad + 1, jnp.int32).at[in_iptr_rank[1:]].add(1)
+    return jnp.cumsum(starts[:e_pad])
+
+
+@partial(jax.jit, static_argnames=("top",))
+def analytics_pr(in_src_pad_d, in_iptr_rank, out_degree_d, probes,
+                 iterations, damping, *, top: int):
+    """Graphalytics PageRank: `iterations` steps from 1/N, no tolerance
+    stop; a step gives (1 - d) / N + d * (the in-neighbours' rank over
+    their out-degree + the dangling vertices' rank / N). Ranks are held in
+    `damping`'s dtype (float32 served). Returns (ranks at the probe
+    ranks, the `top` highest ranks and their dst ranks, the sum of all
+    ranks): nothing vertex-sized leaves the device."""
+    nd = out_degree_d.shape[0]
+    dt = damping.dtype
+    seg = _dst_segments(in_iptr_rank, in_src_pad_d.shape[0])
+    src = jnp.minimum(in_src_pad_d, nd)       # pad edges: the zero slot Nd
+    dangling = out_degree_d == 0
+    inv = jnp.where(dangling, 0, 1 / jnp.maximum(out_degree_d, 1)).astype(dt)
+    n = jnp.asarray(nd, dt)
+
+    def step(_, r):
+        w = jnp.concatenate([r * inv, jnp.zeros(1, dt)])[src]
+        pulled = jax.ops.segment_sum(w, seg, num_segments=nd + 1,
+                                     indices_are_sorted=True)[:nd]
+        lost = jnp.sum(jnp.where(dangling, r, 0))
+        return (1 - damping) / n + damping * (pulled + lost / n)
+
+    r = lax.fori_loop(0, iterations, step, jnp.full(nd, 1, dt) / n)
+    top_v, top_i = lax.top_k(r, top)
+    return r[probes], top_v, top_i, jnp.sum(r)
+
+
+@partial(jax.jit, static_argnames=("push",))
+def analytics_wcc(in_src_pad_d, in_iptr_rank, probes, *, push: bool):
+    """Weakly connected components by FastSV (Zhang, Azad and Hu, 2020):
+    every vertex has a parent, itself at first. A round finds for each
+    vertex the least grandparent among its in-neighbours (and, `push`, its
+    out-neighbours: a graph not stored in both directions), hooks the
+    vertex's parent and the vertex itself to it, and shortcuts the vertex
+    to its grandparent; rounds run until one changes no grandparent — 4 on
+    every Graph500 scale-18 seed tried, where one min-label pass and one
+    jump a round took 4 or 5. Parents are dst ranks and only ever fall, so
+    jumping them to a fixpoint leaves each vertex the least rank of its
+    component. Returns (labels at the probe ranks, components, the
+    largest's size, rounds)."""
+    nd = in_iptr_rank.shape[0] - 1
+    seg = _dst_segments(in_iptr_rank, in_src_pad_d.shape[0])
+    src = jnp.minimum(in_src_pad_d, nd)
+    sentinel = jnp.full(1, nd, jnp.int32)     # above every rank
+
+    def least_near(gf):
+        ext = jnp.concatenate([gf, sentinel])
+        m = jax.ops.segment_min(ext[src], seg, num_segments=nd + 1,
+                                indices_are_sorted=True)[:nd]
+        if push:
+            m = jnp.minimum(m, jnp.full(nd + 1, nd, jnp.int32)
+                            .at[src].min(ext[seg])[:nd])
+        return m
+
+    def body(carry):
+        f, gf, _, rounds = carry
+        m = least_near(gf)
+        f = jnp.minimum(jnp.minimum(f.at[f].min(m), m), gf)
+        new_gf = f[f]
+        return f, new_gf, jnp.any(new_gf != gf), rounds + 1
+
+    ranks = jnp.arange(nd, dtype=jnp.int32)
+    f, _, _, rounds = lax.while_loop(
+        lambda c: c[2], body, (ranks, ranks, jnp.bool_(True), jnp.int32(0)))
+    lab = lax.while_loop(
+        lambda c: c[1], lambda c: (c[0][c[0]], jnp.any(c[0][c[0]] != c[0])),
+        (f, jnp.bool_(True)))[0]
+    components = jnp.sum(lab == ranks)
+    largest = jnp.max(jnp.zeros(nd, jnp.int32).at[lab].add(1))
+    return lab[probes], components, largest, rounds
+
+
 # device-runtime observatory (obs/devprof.py, ISSUE 19): jitted entry
 # points by program family, probed for live jit-cache size on
 # /debug/compiles (see ops/segments.py).
@@ -1052,4 +1146,6 @@ JIT_PROGRAMS = {
     "pb.bfs_dist": bfs_dist,
     "pb.recurse_fused": recurse_fused,
     "pb.recurse_fused_multi": recurse_fused_multi,
+    "pb.analytics_pr": analytics_pr,
+    "pb.analytics_wcc": analytics_wcc,
 }

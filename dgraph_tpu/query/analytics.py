@@ -1,4 +1,5 @@
-"""Whole-graph analytics: PageRank / connected components / triangles.
+"""Whole-graph analytics: PageRank / connected components / triangles, and
+LDBC Graphalytics' PR and WCC.
 
 The OLAP workload class beyond the reference (ROADMAP item 3): iterative
 SpMSpV programs that the per-query traversal engine cannot express run as
@@ -14,13 +15,30 @@ fallbacks below. CC labels and triangle counts are EXACT either way (CC
 converges to the minimum member rank per component on both paths);
 PageRank device f32 vs host f64 agree to oracle tolerance, not bitwise —
 the result carries a ``device`` flag so callers know which path ran.
+
+Graphalytics' kinds (``pr``, ``wcc``; specification v1.0) have the
+specification's semantics — PR runs exactly ``iterations`` steps, WCC
+labels every vertex with its component's least member — and answer for the
+probe vertices a request names. Their device path needs no mesh: one
+jitted program each (ops/pallas_bfs.analytics_pr / analytics_wcc) over the
+PullGraph that ``pb.pull_graph_for`` keeps resident per snapshot for the
+traversal programs, so a request builds no edge list. Overlay or
+residency-deferred tablets, and a predicate whose sources are not all
+destinations, run the host oracles under the same kinds, counted by reason.
 """
 
 from __future__ import annotations
 
+import contextlib
+
 import numpy as np
 
-KINDS = ("pagerank", "cc", "triangles")
+KINDS = ("pagerank", "cc", "triangles", "pr", "wcc")
+GX_KINDS = ("pr", "wcc")
+
+# a request's probe ranks go to the device padded to a multiple of this:
+# one program for every probe count up to it
+PROBE_CLASS = 64
 
 # dense trace(A^3) replicates an ncap x ncap f32 adjacency per device —
 # past this node count the exact host intersection counter wins
@@ -138,15 +156,19 @@ def _device_eligible(mesh, csr) -> bool:
 
 def run(kind: str, csr, mesh=None, gate=None, metrics=None, *,
         damping: float = 0.85, tol: float = 1e-6, max_iters: int = 100,
-        top: int = 20) -> dict:
+        top: int = 20, iterations: int = 10, uids=()) -> dict:
     """One analytics computation over one tablet's whole graph. mesh is a
     parallel/mesh_exec.MeshExecutor (or None → host oracles); gate the
-    DispatchGate (deadline/shed enforcement around the device program)."""
+    DispatchGate (deadline/shed enforcement around the device program).
+    `iterations` and `uids` (the probe vertices) are Graphalytics'."""
     from dgraph_tpu.obs import costs
 
     if kind not in KINDS:
         raise ValueError(f"unknown analytics kind {kind!r}; "
                          f"one of {', '.join(KINDS)}")
+    if kind in GX_KINDS:
+        return _run_gx(kind, csr, gate, metrics, damping=damping,
+                       iterations=iterations, top=top, uids=uids)
     nodes, esrc, edst = graph_arrays(csr)
     n = len(nodes)
     device = _device_eligible(mesh, csr)
@@ -198,4 +220,178 @@ def run(kind: str, csr, mesh=None, gate=None, metrics=None, *,
     if metrics is not None and "iterations" in out:
         metrics.counter("dgraph_analytics_iterations_total").inc(
             out["iterations"])
+    return out
+
+
+# ---------------------------------------------------------------------------
+# LDBC Graphalytics PR and WCC
+# ---------------------------------------------------------------------------
+
+def pull_layout(csr):
+    """(PullGraph, why its DST-RANK space is not the vertex set or None,
+    whether every edge is stored in both directions), cached on the tablet
+    beside the PullGraph: once a snapshot. The dst ranks hold every vertex
+    with an in-edge; they are the vertex set when every source is also a
+    destination. Stored both ways, a WCC round needs only the pull."""
+    got = getattr(csr, "_gx_layout", None)
+    if got is None:
+        from dgraph_tpu.ops import pallas_bfs as pb
+
+        g = pb.pull_graph_for(csr)
+        nd = len(g.host_in_subjects)
+        reason = None
+        if nd == 0:
+            reason = "empty"
+        elif len(g.host_map_s2d) and int(g.host_map_s2d.max()) >= nd:
+            reason = "rank_spaces"       # a source that is no destination
+        # an in-row holds its sources in rank order, a forward row its
+        # targets in uid order: equal rows, equal graphs
+        symmetric = reason is None and len(g.host_subjects) == nd \
+            and np.array_equal(g.host_fwd_indptr, g.host_in_iptr) \
+            and np.array_equal(g.host_subjects[g.host_in_src],
+                               csr.host_arrays()[2])
+        got = csr._gx_layout = (g, reason, bool(symmetric))
+    return got
+
+
+def _gx_reason(csr) -> str | None:
+    """Why a tablet cannot take the device path before its layout is
+    looked at: the residency gate of _device_eligible, without the mesh."""
+    from dgraph_tpu.storage.delta import OverlayCSR
+
+    if isinstance(csr, OverlayCSR):
+        return "overlay"
+    if getattr(csr, "_mesh_deferred", False):
+        return "deferred"
+    return None
+
+
+def _gx_device(kind: str, g, symmetric: bool, gate, probes: np.ndarray, *,
+               damping: float, iterations: int, top: int):
+    """One launch of the kind's program inside a gate slot; its fetched
+    host arrays. The probe ranks cross padded to PROBE_CLASS."""
+    import jax
+
+    from dgraph_tpu.obs import costs, otrace
+    from dgraph_tpu.ops import pallas_bfs as pb
+
+    nd = len(g.host_in_subjects)
+    pad = np.zeros(-(-max(len(probes), 1) // PROBE_CLASS) * PROBE_CLASS,
+                   dtype=np.int32)
+    pad[:len(probes)] = probes
+    family = f"pb.analytics_{kind}"
+    attrs = {"iterations": iterations} if kind == "pr" else {}
+
+    def launch():
+        with otrace.span("device_kernel", kernel=family, nodes=nd,
+                         edges=g.num_edges, **attrs) as sp, \
+                costs.kernel(family, stage="dev.dispatch") as ck:
+            if kind == "pr":
+                out = pb.analytics_pr(
+                    g.in_src_pad_d, g.in_iptr_rank, g.out_degree_d, pad,
+                    np.int32(iterations), np.float32(damping),
+                    top=max(1, min(int(top), nd)))
+            else:
+                out = pb.analytics_wcc(g.in_src_pad_d, g.in_iptr_rank, pad,
+                                       push=not symmetric)
+            with costs.stage("dev.wait"):
+                out = jax.device_get(out)
+            ck.set(h2d=int(pad.nbytes),
+                   d2h=int(sum(np.asarray(a).nbytes for a in out)))
+            if kind == "wcc":
+                sp.set(rounds=int(out[3]))
+        return out
+
+    return gate.run(launch, klass="analytics") if gate is not None \
+        else launch()
+
+
+def _pr_answer(nodes, want, at, ranks, top_v, top_i, total,
+               top: int) -> dict:
+    """`nodes` the vertex set's sorted uids, `at` each probe's rank in it
+    (-1: no vertex), `ranks` the probes' own ranks in `want`'s order."""
+    top = max(int(top), 0)
+    return {"values": {hex(int(u)): (float(v) if i >= 0 else None)
+                       for u, v, i in zip(want, ranks, at)},
+            "top": [{"uid": hex(int(nodes[i])), "score": float(v)}
+                    for v, i in zip(top_v[:top], top_i[:top])],
+            "sum": float(total)}
+
+
+def _wcc_answer(nodes, want, at, labels, components, largest) -> dict:
+    """As _pr_answer; `labels` the probes' components as vertex ranks."""
+    return {"labels": {hex(int(u)): (hex(int(nodes[lab])) if i >= 0
+                                     else None)
+                       for u, lab, i in zip(want, labels, at)},
+            "components": int(components), "largest": int(largest)}
+
+
+def _run_gx(kind: str, csr, gate, metrics, *, damping: float,
+            iterations: int, top: int, uids) -> dict:
+    """Graphalytics' PR / WCC: on the device over the resident PullGraph,
+    or by the host oracles over graph_arrays where the device path cannot
+    answer over the whole vertex set (the reason is counted)."""
+    from dgraph_tpu.obs import costs
+    from dgraph_tpu.ops.uidset import host_rank_of
+
+    iterations = int(iterations)
+    if kind == "pr" and iterations < 0:
+        raise ValueError("analytics: iterations must be >= 0")
+    want = np.asarray([int(u, 0) if isinstance(u, str) else int(u)
+                       for u in uids], dtype=np.int64)
+    reason = _gx_reason(csr)
+    if reason is None:
+        fresh = getattr(csr, "_gx_layout", None) is None
+        with costs.stage("exec.prep") if fresh else contextlib.nullcontext():
+            g, reason, symmetric = pull_layout(csr)
+    if reason is None:
+        nodes, edges = g.host_in_subjects, g.num_edges
+        at = host_rank_of(nodes, want, -1)
+        res = _gx_device(kind, g, symmetric, gate, np.maximum(at, 0),
+                         damping=damping, iterations=iterations, top=top)
+        with costs.stage("dev.post"):
+            head = res[0][:len(want)]
+            if kind == "pr":
+                steps = iterations
+                out = _pr_answer(nodes, want, at, head, res[1], res[2],
+                                 res[3], top)
+            else:
+                steps = int(res[3])
+                out = _wcc_answer(nodes, want, at, head, res[1], res[2])
+    else:
+        with costs.stage("exec"):
+            nodes, esrc, edst = graph_arrays(csr)
+            n, edges = len(nodes), len(esrc)
+            at = host_rank_of(nodes, want, -1)
+            if kind == "pr":
+                # tol -1: no delta stops it, every step runs
+                r, steps = pagerank_host(esrc, edst, n, damping=damping,
+                                         tol=-1.0, max_iters=iterations)
+                order = np.argsort(-r, kind="stable")
+                out = _pr_answer(nodes, want, at, r[at] if n else at,
+                                 r[order], order, r.sum(), top)
+            else:
+                lab = cc_host(esrc, edst, n)
+                sizes = np.unique(lab, return_counts=True)[1]
+                steps = 1                # union-find: one pass of the edges
+                out = _wcc_answer(nodes, want, at, lab[at] if n else at,
+                                  len(sizes), sizes.max(initial=0))
+    out = {"kind": kind, "nodes": int(len(nodes)), "edges": int(edges),
+           "device": reason is None, **out}
+    out["iterations" if kind == "pr" else "rounds"] = int(steps)
+    if metrics is not None:
+        metrics.counter("dgraph_analytics_runs_total").inc()
+        metrics.counter("dgraph_analytics_edges_total").inc(int(edges))
+        metrics.counter("dgraph_analytics_iterations_total").inc(int(steps))
+        if reason is None:
+            metrics.keyed("dgraph_analytics_device_runs_total",
+                          labels=("kind",)).inc(kind)
+        else:
+            metrics.counter("dgraph_analytics_host_fallbacks_total").inc()
+            metrics.keyed("dgraph_analytics_host_runs_total",
+                          labels=("kind", "reason")).inc(f"{kind}|{reason}")
+        metrics.keyed("dgraph_analytics_steps_total",
+                      labels=("kind",)).inc(kind, int(steps))
+        metrics.keyed("dgraph_analytics_edges_read_total",
+                      labels=("kind",)).inc(kind, int(steps) * int(edges))
     return out

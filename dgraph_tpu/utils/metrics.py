@@ -537,6 +537,19 @@ class Registry:
         # frontier held no vertex — a whole stream of the graph for nothing
         self.keyed_gauges["dgraph_recurse_levels_total"] = KeyedGauge(
             labels=("state",), keep=("live", "empty"))
+        # Graphalytics' analytics kinds (query/analytics.py): runs on the
+        # device over the PullGraph, runs on the host by reason (overlay,
+        # deferred, rank_spaces, empty), and their steps — PR iterations,
+        # WCC rounds (a host union-find is one) — each reading E edges
+        gx = ("pr", "wcc")
+        self.keyed_gauges["dgraph_analytics_device_runs_total"] = \
+            KeyedGauge(labels=("kind",), keep=gx)
+        self.keyed_gauges["dgraph_analytics_host_runs_total"] = KeyedGauge(
+            labels=("kind", "reason"))
+        self.keyed_gauges["dgraph_analytics_steps_total"] = KeyedGauge(
+            labels=("kind",), keep=gx)
+        self.keyed_gauges["dgraph_analytics_edges_read_total"] = KeyedGauge(
+            labels=("kind",), keep=gx)
         # serve's start-up phases, set once before the banner
         # (__main__.cmd_serve): import / backend_init / store_open / listen
         self.keyed_gauges["dgraph_startup_ms"] = KeyedGauge(
