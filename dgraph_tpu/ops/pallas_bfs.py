@@ -1048,9 +1048,10 @@ def recurse_fused_multi(in_src_pad, in_src_pad_d, in_iptr_rank, row_ends,
 # stream once, a segmented reduction by destination rank. The vertex set
 # is the DST-RANK space, which holds every vertex with an edge when every
 # source is also a destination (the caller checks: analytics.pull_layout).
-# XLA segment ops, not a row-end kernel: a rank's value is a float sum or
-# an int min over its whole row, not a prefix pick, and the plain form is
-# the baseline a kernel has to beat.
+# A step is an XLA gather of a value by source rank and row_reduce, a
+# block stream over the row-end kernels' grid that combines each row's
+# values — a float sum or an int min over the whole row, not a prefix
+# pick — and writes one value a destination rank.
 # ---------------------------------------------------------------------------
 
 
@@ -1062,18 +1063,132 @@ def _dst_segments(in_iptr_rank: jax.Array, e_pad: int) -> jax.Array:
     return jnp.cumsum(starts[:e_pad])
 
 
+_COMBINE = {"sum": jnp.add, "min": jnp.minimum}
+
+
+def _segmented_scan(x: jax.Array, seg: jax.Array, combine) -> jax.Array:
+    """Inclusive scan of a (R, 128) block in row-major order that restarts
+    wherever `seg` (non-decreasing: the edge's destination rank) changes.
+    Log steps along the lanes (pltpu.roll), then along the sublanes over
+    the rows' last lanes, then each row's lanes of the segment that ended
+    the row before take that row's total. A sum is added in a tree of
+    depth 14, never taken as a difference of prefixes, and nothing runs
+    on the MXU, which would round f32 to bf16."""
+    rows, lanes = x.shape
+    lane = lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    row = lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    k = 1
+    while k < lanes:
+        take = (lane >= k) & (pltpu.roll(seg, k, 1) == seg)
+        x = jnp.where(take, combine(x, pltpu.roll(x, k, 1)), x)
+        k *= 2
+    # a row's total so far is its last lane's; it runs into the next row
+    # where that row starts in the same segment
+    tail = jnp.broadcast_to(x[:, lanes - 1:], x.shape)
+    tseg = jnp.broadcast_to(seg[:, lanes - 1:], x.shape)
+    k = 1
+    while k < rows:
+        take = (row >= k) & (pltpu.roll(tseg, k, 0) == tseg)
+        tail = jnp.where(take, combine(tail, pltpu.roll(tail, k, 0)), tail)
+        k *= 2
+    take = (row >= 1) & (pltpu.roll(tseg, 1, 0) == seg)
+    return jnp.where(take, combine(x, pltpu.roll(tail, 1, 0)), x)
+
+
+def _row_reduce_kernel(block_ref, tile_ref, val_ref, seg_ref, last_ref,
+                       out_ref, scan_ref, cval_ref, cseg_ref, *, combine):
+    """One grid step of row_reduce, over the row-end kernels' items: item
+    i -> each destination rank of the item's tile whose row ends in the
+    item's edge block gets its row's values combined, written to the
+    rank's slot of the output tile as the value's 32 bits.
+
+    An item that opens an edge block scans it (_segmented_scan) and
+    finishes the row the block opens with from the SMEM carry —
+    (cval, cseg): the combined value and the destination of the row the
+    last block ended in, so a row crosses any number of blocks — then
+    keeps the scan in VMEM for the block's later items. The pick is the
+    row-end kernels': rank v wants the scan at its last in-edge."""
+    i = pl.program_id(0)
+    before = jnp.maximum(i - 1, 0)
+    opens_block = (i == 0) | (block_ref[i] != block_ref[before])
+    opens_tile = (i == 0) | (tile_ref[i] != tile_ref[before])
+
+    @pl.when(i == 0)
+    def _():
+        cval_ref[0] = jnp.zeros((), cval_ref.dtype)
+        cseg_ref[0] = -1                       # no row runs into block 0
+
+    @pl.when(opens_block)
+    def _():
+        seg = seg_ref[:]
+        x = _segmented_scan(val_ref[:], seg, combine)
+        x = jnp.where(seg == cseg_ref[0], combine(x, cval_ref[0]), x)
+        scan_ref[:] = lax.bitcast_convert_type(x, jnp.int32)
+        cval_ref[0] = x[x.shape[0] - 1, _LANES - 1]
+        cseg_ref[0] = seg[seg.shape[0] - 1, _LANES - 1]
+
+    @pl.when(opens_tile)
+    def _():
+        out_ref[:] = jnp.zeros_like(out_ref)
+
+    at = last_ref[:] - block_ref[i] * EDGE_BLOCK       # (8, 128) int32
+    row = lax.shift_right_arithmetic(at, 7)
+    lane = jnp.bitwise_and(at, _LANES - 1)
+    picked = jnp.zeros_like(at)
+    for r in range(EDGE_BLOCK // _LANES):
+        row_r = jnp.broadcast_to(scan_ref[r : r + 1, :], at.shape)
+        g = jnp.take_along_axis(row_r, lane, axis=1)       # in-vreg gather
+        picked = jnp.where(row == r, g, picked)
+    ends_here = (at >= 0) & (at < EDGE_BLOCK)
+    out_ref[:] = jnp.where(ends_here, picked, out_ref[:])
+
+
+@partial(jax.jit, static_argnames=("combine",))
+def row_reduce(values: jax.Array, seg: jax.Array, ends: RowEnds,
+               last: jax.Array, *, combine: str) -> jax.Array:
+    """Each destination rank's in-edge values combined — `combine` "sum"
+    (float32) or "min" (int32) — over a dst-sorted stream of E_pad values
+    whose destination ranks are `seg` (_dst_segments), in `last`'s layout
+    (_last_edges): rank v at [v // 128, v % 128], 0 past Nd. One block
+    stream over the row-end kernels' grid (`ends`, graph-static); nothing
+    edge-sized leaves it, and a pad edge's value reaches no rank."""
+    rblk = EDGE_BLOCK // _LANES
+    by_block = pl.BlockSpec((rblk, _LANES), lambda i, blk, tile: (blk[i], 0),
+                            memory_space=pltpu.VMEM)
+    by_tile = pl.BlockSpec((8, _LANES), lambda i, blk, tile: (tile[i], 0),
+                           memory_space=pltpu.VMEM)
+    words = pl.pallas_call(
+        partial(_row_reduce_kernel, combine=_COMBINE[combine]),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(ends.block.shape[0],),
+            in_specs=[by_block, by_block, by_tile],
+            out_specs=by_tile,
+            scratch_shapes=[pltpu.VMEM((rblk, _LANES), jnp.int32),
+                            pltpu.SMEM((1,), values.dtype),
+                            pltpu.SMEM((1,), jnp.int32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct(last.shape, jnp.int32),
+        interpret=interpret_mode(),
+    )(ends.block, ends.tile, values.reshape(-1, _LANES),
+      seg.reshape(-1, _LANES), last)
+    return lax.bitcast_convert_type(words, values.dtype)
+
+
 @partial(jax.jit, static_argnames=("top",))
-def analytics_pr(in_src_pad_d, in_iptr_rank, out_degree_d, probes,
+def analytics_pr(in_src_pad_d, in_iptr_rank, row_ends, out_degree_d, probes,
                  iterations, damping, *, top: int):
     """Graphalytics PageRank: `iterations` steps from 1/N, no tolerance
     stop; a step gives (1 - d) / N + d * (the in-neighbours' rank over
     their out-degree + the dangling vertices' rank / N). Ranks are held in
-    `damping`'s dtype (float32 served). Returns (ranks at the probe
-    ranks, the `top` highest ranks and their dst ranks, the sum of all
-    ranks): nothing vertex-sized leaves the device."""
+    `damping`'s dtype (float32 served; a row is summed in float32 by
+    row_reduce). Returns (ranks at the probe ranks, the `top` highest
+    ranks and their dst ranks, the sum of all ranks): nothing
+    vertex-sized leaves the device."""
     nd = out_degree_d.shape[0]
     dt = damping.dtype
     seg = _dst_segments(in_iptr_rank, in_src_pad_d.shape[0])
+    last = _last_edges(in_iptr_rank)
     src = jnp.minimum(in_src_pad_d, nd)       # pad edges: the zero slot Nd
     dangling = out_degree_d == 0
     inv = jnp.where(dangling, 0, 1 / jnp.maximum(out_degree_d, 1)).astype(dt)
@@ -1081,8 +1196,8 @@ def analytics_pr(in_src_pad_d, in_iptr_rank, out_degree_d, probes,
 
     def step(_, r):
         w = jnp.concatenate([r * inv, jnp.zeros(1, dt)])[src]
-        pulled = jax.ops.segment_sum(w, seg, num_segments=nd + 1,
-                                     indices_are_sorted=True)[:nd]
+        pulled = row_reduce(w.astype(jnp.float32), seg, row_ends, last,
+                            combine="sum").reshape(-1)[:nd].astype(dt)
         lost = jnp.sum(jnp.where(dangling, r, 0))
         return (1 - damping) / n + damping * (pulled + lost / n)
 
@@ -1092,7 +1207,8 @@ def analytics_pr(in_src_pad_d, in_iptr_rank, out_degree_d, probes,
 
 
 @partial(jax.jit, static_argnames=("push",))
-def analytics_wcc(in_src_pad_d, in_iptr_rank, probes, *, push: bool):
+def analytics_wcc(in_src_pad_d, in_iptr_rank, row_ends, probes, *,
+                  push: bool):
     """Weakly connected components by FastSV (Zhang, Azad and Hu, 2020):
     every vertex has a parent, itself at first. A round finds for each
     vertex the least grandparent among its in-neighbours (and, `push`, its
@@ -1106,13 +1222,14 @@ def analytics_wcc(in_src_pad_d, in_iptr_rank, probes, *, push: bool):
     largest's size, rounds)."""
     nd = in_iptr_rank.shape[0] - 1
     seg = _dst_segments(in_iptr_rank, in_src_pad_d.shape[0])
+    last = _last_edges(in_iptr_rank)
     src = jnp.minimum(in_src_pad_d, nd)
     sentinel = jnp.full(1, nd, jnp.int32)     # above every rank
 
     def least_near(gf):
         ext = jnp.concatenate([gf, sentinel])
-        m = jax.ops.segment_min(ext[src], seg, num_segments=nd + 1,
-                                indices_are_sorted=True)[:nd]
+        m = row_reduce(ext[src], seg, row_ends, last,
+                       combine="min").reshape(-1)[:nd]
         if push:
             m = jnp.minimum(m, jnp.full(nd + 1, nd, jnp.int32)
                             .at[src].min(ext[seg])[:nd])
@@ -1146,6 +1263,7 @@ JIT_PROGRAMS = {
     "pb.bfs_dist": bfs_dist,
     "pb.recurse_fused": recurse_fused,
     "pb.recurse_fused_multi": recurse_fused_multi,
+    "pb.row_reduce": row_reduce,
     "pb.analytics_pr": analytics_pr,
     "pb.analytics_wcc": analytics_wcc,
 }

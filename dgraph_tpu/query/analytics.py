@@ -266,6 +266,15 @@ def _gx_reason(csr) -> str | None:
     return None
 
 
+def _reduce_path() -> str:
+    """Where a device step's per-destination reduction runs: "pallas", the
+    row_reduce kernel compiled for the chip, or "interpret", the same
+    kernel in Pallas' interpreter off the chip (pb.interpret_mode)."""
+    from dgraph_tpu.ops import pallas_bfs as pb
+
+    return "interpret" if pb.interpret_mode() else "pallas"
+
+
 def _gx_device(kind: str, g, symmetric: bool, gate, probes: np.ndarray, *,
                damping: float, iterations: int, top: int):
     """One launch of the kind's program inside a gate slot; its fetched
@@ -280,7 +289,9 @@ def _gx_device(kind: str, g, symmetric: bool, gate, probes: np.ndarray, *,
                    dtype=np.int32)
     pad[:len(probes)] = probes
     family = f"pb.analytics_{kind}"
-    attrs = {"iterations": iterations} if kind == "pr" else {}
+    attrs = {"reduce": _reduce_path()}
+    if kind == "pr":
+        attrs["iterations"] = iterations
 
     def launch():
         with otrace.span("device_kernel", kernel=family, nodes=nd,
@@ -288,12 +299,12 @@ def _gx_device(kind: str, g, symmetric: bool, gate, probes: np.ndarray, *,
                 costs.kernel(family, stage="dev.dispatch") as ck:
             if kind == "pr":
                 out = pb.analytics_pr(
-                    g.in_src_pad_d, g.in_iptr_rank, g.out_degree_d, pad,
-                    np.int32(iterations), np.float32(damping),
-                    top=max(1, min(int(top), nd)))
+                    g.in_src_pad_d, g.in_iptr_rank, g.row_ends,
+                    g.out_degree_d, pad, np.int32(iterations),
+                    np.float32(damping), top=max(1, min(int(top), nd)))
             else:
-                out = pb.analytics_wcc(g.in_src_pad_d, g.in_iptr_rank, pad,
-                                       push=not symmetric)
+                out = pb.analytics_wcc(g.in_src_pad_d, g.in_iptr_rank,
+                                       g.row_ends, pad, push=not symmetric)
             with costs.stage("dev.wait"):
                 out = jax.device_get(out)
             ck.set(h2d=int(pad.nbytes),
@@ -386,6 +397,9 @@ def _run_gx(kind: str, csr, gate, metrics, *, damping: float,
         if reason is None:
             metrics.keyed("dgraph_analytics_device_runs_total",
                           labels=("kind",)).inc(kind)
+            if _reduce_path() == "pallas":
+                metrics.keyed("dgraph_analytics_kernel_steps_total",
+                              labels=("kind",)).inc(kind, int(steps))
         else:
             metrics.counter("dgraph_analytics_host_fallbacks_total").inc()
             metrics.keyed("dgraph_analytics_host_runs_total",

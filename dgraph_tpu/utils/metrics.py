@@ -548,6 +548,11 @@ class Registry:
             labels=("kind", "reason"))
         self.keyed_gauges["dgraph_analytics_steps_total"] = KeyedGauge(
             labels=("kind",), keep=gx)
+        # of those device steps, the ones whose per-destination reduction
+        # ran in the row_reduce kernel compiled for the chip (not in
+        # Pallas' interpreter): equal to the device steps on a chip
+        self.keyed_gauges["dgraph_analytics_kernel_steps_total"] = \
+            KeyedGauge(labels=("kind",), keep=gx)
         self.keyed_gauges["dgraph_analytics_edges_read_total"] = KeyedGauge(
             labels=("kind",), keep=gx)
         # serve's start-up phases, set once before the banner

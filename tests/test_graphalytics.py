@@ -87,9 +87,9 @@ def test_lower_precision_and_lost_dangling_mass_fail_the_check(kron):
     g = pb.pull_graph_for(node.snapshot().preds["follows"].csr)
     assert np.array_equal(g.host_in_subjects, nodes)
     probes = np.arange(len(nodes), dtype=np.int32)
-    vals = pb.analytics_pr(g.in_src_pad_d, g.in_iptr_rank, g.out_degree_d,
-                           probes, np.int32(10), jnp.bfloat16(0.85),
-                           top=1)[0]
+    vals = pb.analytics_pr(g.in_src_pad_d, g.in_iptr_rank, g.row_ends,
+                           g.out_degree_d, probes, np.int32(10),
+                           jnp.bfloat16(0.85), top=1)[0]
     assert ref.rel_error(np.asarray(vals, np.float64), want) > TOL
     s, t = _dangling_digraph(7)
     _, full = ref.pagerank(s, t)
@@ -241,8 +241,8 @@ def test_probes_outside_the_vertex_set_and_bad_requests(directed):
 
 def test_http_kinds_stages_span_and_counters():
     """POST /analytics `pr` / `wcc` through a served node: the answer, the
-    request's stages on /metrics, the device_kernel span in its trace and
-    the four counters."""
+    request's stages on /metrics, the device_kernel span in its trace
+    (where the reduction ran) and the five counters."""
     from dgraph_tpu.api.http import serve_forever
     from dgraph_tpu.obs import prom
 
@@ -282,9 +282,14 @@ def test_http_kinds_stages_span_and_counters():
         return next(v for lab, v in parsed[name]
                     if all(lab.get(k) == x for k, x in labels.items()))
 
+    # a step's reduction ran in the row_reduce kernel compiled for the chip,
+    # or (off the chip) in Pallas' interpreter, which the counter leaves out
+    reduce = "interpret" if pb.interpret_mode() else "pallas"
     for kind, steps in (("pr", 10), ("wcc", wcc["rounds"])):
         assert val("dgraph_analytics_device_runs_total", kind=kind) == 1
         assert val("dgraph_analytics_steps_total", kind=kind) == steps
+        assert val("dgraph_analytics_kernel_steps_total", kind=kind) == \
+            (steps if reduce == "pallas" else 0)
         assert val("dgraph_analytics_edges_read_total", kind=kind) == \
             steps * len(src)
     assert "dgraph_analytics_host_runs_total" not in parsed
@@ -304,3 +309,4 @@ def test_http_kinds_stages_span_and_counters():
     assert kernels["pb.analytics_pr"]["iterations"] == 10
     assert kernels["pb.analytics_wcc"]["rounds"] == wcc["rounds"]
     assert kernels["pb.analytics_wcc"]["edges"] == len(src)
+    assert {k["reduce"] for k in kernels.values()} == {reduce}

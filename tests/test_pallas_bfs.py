@@ -758,3 +758,32 @@ def test_recurse_programs_hold_nothing_edge_sized(one_v5e, monkeypatch,
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.output_size_in_bytes < e_pad
+
+
+@pytest.mark.parametrize("program", ["analytics_pr", "analytics_wcc"])
+def test_analytics_programs_reduce_rows_in_the_kernel(one_v5e, monkeypatch,
+                                                      program):
+    """A whole-graph step's per-destination sum / min runs in row_reduce
+    compiled for the chip: the program holds a Pallas custom call and no
+    scatter into Nd + 1 slots (the segment_sum / segment_min by
+    destination rank it replaced). What scatters are left build the
+    destination ids once a call (into E_pad + 1) or are FastSV's
+    vertex-sized hooking and the component sizes (into Nd)."""
+    monkeypatch.setattr(pb, "interpret_mode", lambda: False)
+    e_pad, n_items, nd = 16 * pb.EDGE_BLOCK, 32, 3_001
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e)
+
+    graph = (arg((e_pad,)), arg((nd + 1,)),
+             pb.RowEnds(arg((n_items,)), arg((n_items,))))
+    if program == "analytics_pr":
+        lowered = pb.analytics_pr.lower(
+            *graph, arg((nd,)), arg((64,)), arg(()), arg((), jnp.float32),
+            top=20)
+    else:
+        lowered = pb.analytics_wcc.lower(*graph, arg((64,)), push=False)
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text
+    scatters = [ln for ln in text.splitlines() if " scatter(" in ln]
+    assert scatters and not any(f"[{nd + 1}]" in ln for ln in scatters)
