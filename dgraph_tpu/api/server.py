@@ -362,7 +362,7 @@ class Node:
 
             out = {}
             for name in ("segments", "vector", "pallas_bfs",
-                         "traversal"):
+                         "traversal", "lcc"):
                 m = sys.modules.get(f"dgraph_tpu.ops.{name}")
                 for fam, fn in getattr(m, "JIT_PROGRAMS", {}).items():
                     size = getattr(fn, "_cache_size", None)
@@ -1116,8 +1116,8 @@ class Node:
         count as device-resident while_loop programs on the mesh, host
         oracle fallback when the tablet is overlay/residency-deferred or
         the node runs without a mesh; Graphalytics' `pr` (`iterations`
-        steps) and `wcc` on one chip over the resident PullGraph, for the
-        probe vertices `uids`. Same request discipline as query(): stage
+        steps), `wcc` and `lcc` on one chip over the resident PullGraph,
+        for the probe vertices `uids`. Same request discipline as query(): stage
         clock + span + deadline scope + cost ledger + DispatchGate."""
         # the stage clock, as query() joins or owns it: what is in no
         # narrower stage is `plan` (read view, snapshot, layout lookup)

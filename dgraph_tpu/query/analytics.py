@@ -1,5 +1,5 @@
 """Whole-graph analytics: PageRank / connected components / triangles, and
-LDBC Graphalytics' PR and WCC.
+LDBC Graphalytics' PR, WCC and LCC.
 
 The OLAP workload class beyond the reference (ROADMAP item 3): iterative
 SpMSpV programs that the per-query traversal engine cannot express run as
@@ -16,15 +16,19 @@ converges to the minimum member rank per component on both paths);
 PageRank device f32 vs host f64 agree to oracle tolerance, not bitwise —
 the result carries a ``device`` flag so callers know which path ran.
 
-Graphalytics' kinds (``pr``, ``wcc``; specification v1.0) have the
-specification's semantics — PR runs exactly ``iterations`` steps, WCC
-labels every vertex with its component's least member — and answer for the
+Graphalytics' kinds (``pr``, ``wcc``, ``lcc``; specification v1.0) have
+the specification's semantics — PR runs exactly ``iterations`` steps, WCC
+labels every vertex with its component's least member, LCC gives each
+vertex the share of its neighbour pairs that are edges — and answer for the
 probe vertices a request names. Their device path needs no mesh: one
-jitted program each (ops/pallas_bfs.analytics_pr / analytics_wcc) over the
+jitted program each (ops/pallas_bfs.analytics_pr / analytics_wcc over the
 PullGraph that ``pb.pull_graph_for`` keeps resident per snapshot for the
-traversal programs, so a request builds no edge list. Overlay or
-residency-deferred tablets, and a predicate whose sources are not all
-destinations, run the host oracles under the same kinds, counted by reason.
+traversal programs, so a request builds no edge list; ops/lcc.analytics_lcc
+over the degree-ordered rows ``lcc_layout`` builds from that PullGraph on
+the first ``lcc`` request of a snapshot). Overlay or residency-deferred
+tablets, a predicate whose sources are not all destinations, and for
+``lcc`` one not stored in both directions, run the host oracles under the
+same kinds, counted by reason.
 """
 
 from __future__ import annotations
@@ -33,8 +37,8 @@ import contextlib
 
 import numpy as np
 
-KINDS = ("pagerank", "cc", "triangles", "pr", "wcc")
-GX_KINDS = ("pr", "wcc")
+KINDS = ("pagerank", "cc", "triangles", "pr", "wcc", "lcc")
+GX_KINDS = ("pr", "wcc", "lcc")
 
 # a request's probe ranks go to the device padded to a multiple of this:
 # one program for every probe count up to it
@@ -114,27 +118,35 @@ def cc_host(esrc, edst, n: int):
                        n).astype(np.int32)
 
 
-def triangles_host(esrc, edst, n: int) -> int:
-    """Exact count via sorted-adjacency intersection over the symmetrized
-    simple graph: triangle (u<v<w) counted once at edge (u,v) as a common
-    neighbor w>v."""
-    if n == 0 or len(esrc) == 0:
-        return 0
+def lcc_host(esrc, edst, n: int) -> tuple[np.ndarray, np.ndarray]:
+    """(t, lcc) of every node over the symmetrized simple graph: t(v) the
+    edges among v's distinct neighbours, lcc(v) = t(v) / (d(v) (d(v) - 1)
+    / 2), 0 where d(v) < 2. Each edge oriented from its lower-degree end;
+    a triangle a < b < c is found once, at a -> b, as the common out-
+    neighbour c, and counts for all three."""
     a = np.concatenate([esrc, edst]).astype(np.int64)
     b = np.concatenate([edst, esrc]).astype(np.int64)
     keep = a != b
     key = np.unique(a[keep] * n + b[keep])
-    u = (key // n).astype(np.int64)
-    v = (key % n).astype(np.int64)
+    u, v = key // n, key % n
+    deg = np.bincount(u, minlength=n)
+    pos = np.empty(n, dtype=np.int64)
+    pos[np.lexsort((np.arange(n), deg))] = np.arange(n)
+    up = pos[u] < pos[v]
+    u, v = u[up], v[up]                  # sorted by u, then by v
     starts = np.searchsorted(u, np.arange(n + 1))
-    tri = 0
-    fwd = u < v
-    for uu, vv in zip(u[fwd].tolist(), v[fwd].tolist()):
-        nu = v[starts[uu]: starts[uu + 1]]
-        nv = v[starts[vv]: starts[vv + 1]]
-        common = np.intersect1d(nu, nv, assume_unique=True)
-        tri += int((common > vv).sum())
-    return tri
+    tri = np.zeros(n, dtype=np.int64)
+    for uu, vv in zip(u.tolist(), v.tolist()):
+        common = np.intersect1d(v[starts[uu]:starts[uu + 1]],
+                                v[starts[vv]:starts[vv + 1]],
+                                assume_unique=True)
+        tri[uu] += len(common)
+        tri[vv] += len(common)
+        tri[common] += 1
+    d = deg.astype(np.float64)
+    with np.errstate(divide="ignore", invalid="ignore"):
+        lcc = np.where(deg > 1, tri / (d * (d - 1) / 2), 0.0)
+    return tri, lcc
 
 
 # ---------------------------------------------------------------------------
@@ -215,7 +227,7 @@ def run(kind: str, csr, mesh=None, gate=None, metrics=None, *,
             if device:
                 tri = gated(lambda: mesh.run_triangles(esrc, edst, n))
             else:
-                tri = triangles_host(esrc, edst, n)
+                tri = lcc_host(esrc, edst, n)[0].sum() // 3
         out["triangles"] = int(tri)
     if metrics is not None and "iterations" in out:
         metrics.counter("dgraph_analytics_iterations_total").inc(
@@ -224,7 +236,7 @@ def run(kind: str, csr, mesh=None, gate=None, metrics=None, *,
 
 
 # ---------------------------------------------------------------------------
-# LDBC Graphalytics PR and WCC
+# LDBC Graphalytics PR, WCC and LCC
 # ---------------------------------------------------------------------------
 
 def pull_layout(csr):
@@ -254,6 +266,20 @@ def pull_layout(csr):
     return got
 
 
+def lcc_layout(csr, g):
+    """The degree-ordered rows LCC's program reads (ops/lcc.Layout), built
+    from the PullGraph `g` of a tablet stored both ways — each in-row is
+    then the vertex's neighbours in rank space — and cached on the tablet
+    beside _gx_layout: once a snapshot, on its first `lcc` request, so
+    that no other kind pays for it."""
+    got = getattr(csr, "_lcc_layout", None)
+    if got is None:
+        from dgraph_tpu.ops import lcc
+
+        got = csr._lcc_layout = lcc.build(g.host_in_iptr, g.host_in_src)
+    return got
+
+
 def _gx_reason(csr) -> str | None:
     """Why a tablet cannot take the device path before its layout is
     looked at: the residency gate of _device_eligible, without the mesh."""
@@ -276,12 +302,14 @@ def _reduce_path() -> str:
 
 
 def _gx_device(kind: str, g, symmetric: bool, gate, probes: np.ndarray, *,
-               damping: float, iterations: int, top: int):
+               damping: float, iterations: int, top: int, lay=None):
     """One launch of the kind's program inside a gate slot; its fetched
-    host arrays. The probe ranks cross padded to PROBE_CLASS."""
+    host arrays. The probe ranks cross padded to PROBE_CLASS; `lay` is
+    lcc_layout's, for `lcc`."""
     import jax
 
     from dgraph_tpu.obs import costs, otrace
+    from dgraph_tpu.ops import lcc
     from dgraph_tpu.ops import pallas_bfs as pb
 
     nd = len(g.host_in_subjects)
@@ -289,7 +317,11 @@ def _gx_device(kind: str, g, symmetric: bool, gate, probes: np.ndarray, *,
                    dtype=np.int32)
     pad[:len(probes)] = probes
     family = f"pb.analytics_{kind}"
-    attrs = {"reduce": _reduce_path()}
+    if kind == "lcc":
+        attrs = {"oriented_edges": lay.oriented_edges,
+                 "max_out": lay.max_out}
+    else:
+        attrs = {"reduce": _reduce_path()}
     if kind == "pr":
         attrs["iterations"] = iterations
 
@@ -302,15 +334,22 @@ def _gx_device(kind: str, g, symmetric: bool, gate, probes: np.ndarray, *,
                     g.in_src_pad_d, g.in_iptr_rank, g.row_ends,
                     g.out_degree_d, pad, np.int32(iterations),
                     np.float32(damping), top=max(1, min(int(top), nd)))
-            else:
+            elif kind == "wcc":
                 out = pb.analytics_wcc(g.in_src_pad_d, g.in_iptr_rank,
                                        g.row_ends, pad, push=not symmetric)
+            else:
+                out = lcc.analytics_lcc(
+                    lay.tables, lay.members, lay.tails, lay.heads,
+                    lay.head_ids, lay.order, lay.degree, pad,
+                    buckets=lay.buckets)
             with costs.stage("dev.wait"):
                 out = jax.device_get(out)
             ck.set(h2d=int(pad.nbytes),
                    d2h=int(sum(np.asarray(a).nbytes for a in out)))
             if kind == "wcc":
                 sp.set(rounds=int(out[3]))
+            elif kind == "lcc":
+                sp.set(total=int(out[2]))
         return out
 
     return gate.run(launch, klass="analytics") if gate is not None \
@@ -337,11 +376,22 @@ def _wcc_answer(nodes, want, at, labels, components, largest) -> dict:
             "components": int(components), "largest": int(largest)}
 
 
+def _lcc_answer(want, at, tri, values, total, sums) -> dict:
+    """As _pr_answer; `tri` and `values` the probes' t and lcc."""
+    return {"values": {hex(int(u)): (float(v) if i >= 0 else None)
+                       for u, v, i in zip(want, values, at)},
+            "triangles": {hex(int(u)): (int(c) if i >= 0 else None)
+                          for u, c, i in zip(want, tri, at)},
+            "total": int(total), "sum": float(sums)}
+
+
 def _run_gx(kind: str, csr, gate, metrics, *, damping: float,
             iterations: int, top: int, uids) -> dict:
-    """Graphalytics' PR / WCC: on the device over the resident PullGraph,
-    or by the host oracles over graph_arrays where the device path cannot
-    answer over the whole vertex set (the reason is counted)."""
+    """Graphalytics' PR / WCC / LCC: on the device over the resident
+    PullGraph (LCC over the rows lcc_layout builds from it), or by the
+    host oracles over graph_arrays where the device path cannot answer
+    over the whole vertex set, or for LCC over the whole undirected
+    graph (the reason is counted)."""
     from dgraph_tpu.obs import costs
     from dgraph_tpu.ops.uidset import host_rank_of
 
@@ -350,25 +400,38 @@ def _run_gx(kind: str, csr, gate, metrics, *, damping: float,
         raise ValueError("analytics: iterations must be >= 0")
     want = np.asarray([int(u, 0) if isinstance(u, str) else int(u)
                        for u in uids], dtype=np.int64)
-    reason = _gx_reason(csr)
+    reason, lay = _gx_reason(csr), None
     if reason is None:
-        fresh = getattr(csr, "_gx_layout", None) is None
+        fresh = getattr(csr, "_gx_layout", None) is None or (
+            kind == "lcc" and getattr(csr, "_lcc_layout", None) is None)
         with costs.stage("exec.prep") if fresh else contextlib.nullcontext():
             g, reason, symmetric = pull_layout(csr)
+            if reason is None and kind == "lcc":
+                # an in-row is the whole neighbourhood only when every
+                # edge is stored both ways
+                if symmetric:
+                    lay = lcc_layout(csr, g)
+                else:
+                    reason = "one_way"
     if reason is None:
         nodes, edges = g.host_in_subjects, g.num_edges
         at = host_rank_of(nodes, want, -1)
         res = _gx_device(kind, g, symmetric, gate, np.maximum(at, 0),
-                         damping=damping, iterations=iterations, top=top)
+                         damping=damping, iterations=iterations, top=top,
+                         lay=lay)
         with costs.stage("dev.post"):
             head = res[0][:len(want)]
             if kind == "pr":
                 steps = iterations
                 out = _pr_answer(nodes, want, at, head, res[1], res[2],
                                  res[3], top)
-            else:
+            elif kind == "wcc":
                 steps = int(res[3])
                 out = _wcc_answer(nodes, want, at, head, res[1], res[2])
+            else:
+                steps = 1
+                out = _lcc_answer(want, at, head, res[1][:len(want)],
+                                  res[2], res[3])
     else:
         with costs.stage("exec"):
             nodes, esrc, edst = graph_arrays(csr)
@@ -381,15 +444,22 @@ def _run_gx(kind: str, csr, gate, metrics, *, damping: float,
                 order = np.argsort(-r, kind="stable")
                 out = _pr_answer(nodes, want, at, r[at] if n else at,
                                  r[order], order, r.sum(), top)
-            else:
+            elif kind == "wcc":
                 lab = cc_host(esrc, edst, n)
                 sizes = np.unique(lab, return_counts=True)[1]
                 steps = 1                # union-find: one pass of the edges
                 out = _wcc_answer(nodes, want, at, lab[at] if n else at,
                                   len(sizes), sizes.max(initial=0))
+            else:
+                tri, lcc = lcc_host(esrc, edst, n)
+                steps = 1
+                out = _lcc_answer(want, at, tri[at] if n else at,
+                                  lcc[at] if n else at, tri.sum() // 3,
+                                  lcc.sum())
     out = {"kind": kind, "nodes": int(len(nodes)), "edges": int(edges),
            "device": reason is None, **out}
-    out["iterations" if kind == "pr" else "rounds"] = int(steps)
+    if kind != "lcc":
+        out["iterations" if kind == "pr" else "rounds"] = int(steps)
     if metrics is not None:
         metrics.counter("dgraph_analytics_runs_total").inc()
         metrics.counter("dgraph_analytics_edges_total").inc(int(edges))
@@ -397,7 +467,12 @@ def _run_gx(kind: str, csr, gate, metrics, *, damping: float,
         if reason is None:
             metrics.keyed("dgraph_analytics_device_runs_total",
                           labels=("kind",)).inc(kind)
-            if _reduce_path() == "pallas":
+            if kind == "lcc":
+                metrics.counter("dgraph_analytics_lcc_compares_total").inc(
+                    lay.compares)
+                metrics.counter("dgraph_analytics_lcc_merge_total").inc(
+                    lay.merge)
+            elif _reduce_path() == "pallas":
                 metrics.keyed("dgraph_analytics_kernel_steps_total",
                               labels=("kind",)).inc(kind, int(steps))
         else:

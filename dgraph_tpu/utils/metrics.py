@@ -433,6 +433,12 @@ class Registry:
                      "dgraph_analytics_host_fallbacks_total",
                      "dgraph_analytics_iterations_total",
                      "dgraph_analytics_edges_total",
+                     # Graphalytics LCC on the device (ops/lcc.py): the
+                     # element pairs its intersections compared, padding
+                     # included, and Σ |R(u)| + |R(v)| over the edges they
+                     # intersected — the least a merge would read
+                     "dgraph_analytics_lcc_compares_total",
+                     "dgraph_analytics_lcc_merge_total",
                      # delta-journal retention (storage/store.py; ISSUE 18):
                      # keys/pinned_floor are gauges refreshed on scrape
                      "dgraph_delta_journal_keys",
@@ -539,9 +545,10 @@ class Registry:
             labels=("state",), keep=("live", "empty"))
         # Graphalytics' analytics kinds (query/analytics.py): runs on the
         # device over the PullGraph, runs on the host by reason (overlay,
-        # deferred, rank_spaces, empty), and their steps — PR iterations,
-        # WCC rounds (a host union-find is one) — each reading E edges
-        gx = ("pr", "wcc")
+        # deferred, rank_spaces, empty, one_way), and their steps — PR
+        # iterations, WCC rounds (a host union-find is one), one LCC pass —
+        # each reading E edges
+        gx = ("pr", "wcc", "lcc")
         self.keyed_gauges["dgraph_analytics_device_runs_total"] = \
             KeyedGauge(labels=("kind",), keep=gx)
         self.keyed_gauges["dgraph_analytics_host_runs_total"] = KeyedGauge(
@@ -552,7 +559,7 @@ class Registry:
         # ran in the row_reduce kernel compiled for the chip (not in
         # Pallas' interpreter): equal to the device steps on a chip
         self.keyed_gauges["dgraph_analytics_kernel_steps_total"] = \
-            KeyedGauge(labels=("kind",), keep=gx)
+            KeyedGauge(labels=("kind",), keep=gx[:2])
         self.keyed_gauges["dgraph_analytics_edges_read_total"] = KeyedGauge(
             labels=("kind",), keep=gx)
         # serve's start-up phases, set once before the banner

@@ -1,13 +1,18 @@
-"""Plain reference for LDBC Graphalytics' PR and WCC (specification v1.0),
+"""Plain reference for LDBC Graphalytics' PR, WCC and LCC (specification
+v1.0),
 independent of the code under test: numpy / scipy in float64, nothing
 imported from dgraph_tpu. tests/test_graphalytics.py holds the served
-kinds `pr` and `wcc` to it for every vertex.
+kinds `pr` and `wcc` to it for every vertex, tests/test_lcc.py the kind
+`lcc`.
 
 The vertex set is every vertex with an edge. PR: `iterations` steps from
 1/N, each PR(v) = (1 - d) / N + d * (sum over in-neighbours u of
 PR(u) / outdeg(u) + sum over dangling w of PR(w) / N). WCC: the weakly
 connected components, each vertex labelled by its component's least
-member."""
+member. LCC: over the symmetrised simple graph (both directions of every
+edge, self-loops and repeats dropped), t(v) is the number of edges among
+v's distinct neighbours and lcc(v) = t(v) / (d(v) (d(v) - 1) / 2), 0 where
+the degree d(v) < 2."""
 
 from __future__ import annotations
 
@@ -70,6 +75,31 @@ def wcc(src, dst) -> tuple[np.ndarray, np.ndarray]:
     least = np.full(comp.max() + 1 if n else 0, n, dtype=np.int64)
     np.minimum.at(least, comp, np.arange(n))
     return nodes, nodes[least[comp]]
+
+
+def lcc(src, dst) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """(sorted vertex uids, t(v) as int64, lcc(v) as float64): one
+    np.intersect1d of the two ends' neighbour sets an edge."""
+    nodes = vertices(src, dst)
+    n = len(nodes)
+    s = np.searchsorted(nodes, src)
+    t = np.searchsorted(nodes, dst)
+    keep = s != t
+    pairs = np.unique(np.stack([np.concatenate([s[keep], t[keep]]),
+                                np.concatenate([t[keep], s[keep]])], 1),
+                      axis=0)
+    starts = np.searchsorted(pairs[:, 0], np.arange(n + 1))
+    nbrs = [pairs[starts[v]:starts[v + 1], 1] for v in range(n)]
+    tri = np.zeros(n, dtype=np.int64)
+    for a, b in pairs[pairs[:, 0] < pairs[:, 1]].tolist():
+        common = len(np.intersect1d(nbrs[a], nbrs[b], assume_unique=True))
+        tri[a] += common
+        tri[b] += common
+    tri //= 2                     # each triangle at v is seen from two edges
+    deg = np.diff(starts).astype(np.float64)
+    with np.errstate(divide="ignore", invalid="ignore"):
+        ratio = np.where(deg > 1, tri / (deg * (deg - 1) / 2), 0.0)
+    return nodes, tri, ratio
 
 
 def rel_error(got: np.ndarray, want: np.ndarray) -> float:

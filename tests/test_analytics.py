@@ -102,7 +102,7 @@ def test_host_fallbacks_match_device(mesh):
     assert np.array_equal(np.asarray(lab_d, np.int64),
                           np.asarray(lab_h, np.int64))
     assert mesh.run_triangles(esrc, edst, n) == \
-        an.triangles_host(esrc, edst, n)
+        an.lcc_host(esrc, edst, n)[0].sum() // 3
     r_d, _ = mesh.run_pagerank(esrc, edst, n, tol=1e-9, max_iters=200)
     r_h, _ = an.pagerank_host(esrc, edst, n, tol=1e-9, max_iters=200)
     assert np.abs(np.asarray(r_d, np.float64) - r_h).max() < 1e-6
@@ -116,8 +116,8 @@ def test_empty_and_single_node_graphs(mesh):
     assert np.array_equal(np.asarray(lab), [0, 1, 2])
     assert an.pagerank_host(np.zeros(0, np.int32),
                             np.zeros(0, np.int32), 0)[0].shape == (0,)
-    assert an.triangles_host(np.zeros(0, np.int32),
-                             np.zeros(0, np.int32), 0) == 0
+    assert an.lcc_host(np.zeros(0, np.int32),
+                       np.zeros(0, np.int32), 0)[0].sum() == 0
 
 
 # ---------------------------------------------------------------------------
