@@ -1048,10 +1048,12 @@ def recurse_fused_multi(in_src_pad, in_src_pad_d, in_iptr_rank, row_ends,
 # stream once, a segmented reduction by destination rank. The vertex set
 # is the DST-RANK space, which holds every vertex with an edge when every
 # source is also a destination (the caller checks: analytics.pull_layout).
-# A step is an XLA gather of a value by source rank and row_reduce, a
-# block stream over the row-end kernels' grid that combines each row's
-# values — a float sum or an int min over the whole row, not a prefix
-# pick — and writes one value a destination rank.
+# A step is a gather of a value by source rank (gather_sorted, a block
+# stream over a VMEM-resident table; XLA's element gather for a table too
+# large for VMEM) and row_reduce, a block stream over the row-end
+# kernels' grid that combines each row's values — a float sum or an int
+# min over the whole row, not a prefix pick — and writes one value a
+# destination rank.
 # ---------------------------------------------------------------------------
 
 
@@ -1175,15 +1177,176 @@ def row_reduce(values: jax.Array, seg: jax.Array, ends: RowEnds,
     return lax.bitcast_convert_type(words, values.dtype)
 
 
+# The gather by source rank of a whole-graph step (the value of every
+# in-edge's source, in stream order) as a block stream over a table held
+# whole in VMEM. In stream order a tile of 1,024 edges meets nearly every
+# (8, 128) chunk of the table, so the lookup would cost a pass over the
+# whole table per tile (_active_dense's loop). gather_layout sorts each
+# 8,192-edge block by source once a snapshot: a sublane row of 128 sorted
+# edges then spans a few table rows of 128 values (22 on average at scale
+# 18), and a window step hands each sublane row its next table row — one
+# in-vreg lane gather and one select for 1,024 edges. The block's values
+# go back to stream order in VMEM before they are written.
+
+GATHER_TABLE_MAX = 4 << 20     # bytes of the largest table held in VMEM
+_GATHER_UNROLL = 16            # window steps a loop turn
+
+
+class GatherLayout(NamedTuple):
+    """gather_sorted's layout of one edge stream (gather_layout, once a
+    snapshot)."""
+
+    src: jax.Array     # int32[E_pad] each block's source ranks, ascending
+    back: jax.Array    # int32[E_pad] each edge's position in its block's
+    # sorted order, in stream order
+    meta: jax.Array    # int32[n_blocks, 1, 128] per block: [:64] the first
+    # table row of each sublane row's window, [64:72] each tile's window
+    # steps, a multiple of _GATHER_UNROLL
+
+
+def _table_rows(n: int) -> int:
+    """Rows of 128 values of a gather table of n values, a multiple of
+    _GATHER_UNROLL (itself of 8): a tile's window steps, rounded up to
+    it, never outnumber the table's rows."""
+    return -(-n // (_GATHER_UNROLL * _LANES)) * _GATHER_UNROLL
+
+
+def gather_fits(n: int) -> bool:
+    """Whether a table of n 32-bit values is held whole in VMEM: the
+    static shape test between gather_sorted and XLA's element gather."""
+    return _table_rows(n) * _LANES * 4 <= GATHER_TABLE_MAX
+
+
+def gather_layout(src: np.ndarray, n: int) -> tuple[GatherLayout | None,
+                                                      int]:
+    """(the GatherLayout of a stream whose edges read table slots `src`
+    (int32[E_pad], every value below n, pad edges on a slot of their own),
+    the window steps of one pass) — or (None, 0) where a table of n values
+    does not gather_fits."""
+    if not gather_fits(n):
+        return None, 0
+    rblk = EDGE_BLOCK // _LANES
+    blocks = np.asarray(src, np.int32).reshape(-1, EDGE_BLOCK)
+    order = np.argsort(blocks, axis=1, kind="stable")
+    ranks = np.take_along_axis(blocks, order, axis=1)
+    back = np.empty_like(order)
+    np.put_along_axis(back, order, np.arange(EDGE_BLOCK), axis=1)
+    rows = ranks.reshape(len(blocks), rblk, _LANES) >> 7
+    span = rows[:, :, -1] - rows[:, :, 0] + 1
+    steps = span.reshape(len(blocks), 8, 8).max(axis=2)
+    steps = -(-steps // _GATHER_UNROLL) * _GATHER_UNROLL
+    # a window starts at its sublane row's least table row, or early
+    # enough that its last step is still a row of the table
+    first = np.minimum(rows[:, :, 0],
+                       _table_rows(n) - np.repeat(steps, 8, axis=1))
+    meta = np.zeros((len(blocks), 1, _LANES), np.int32)
+    meta[:, 0, :rblk] = first
+    meta[:, 0, rblk:rblk + 8] = steps
+    return (GatherLayout(jnp.asarray(ranks.reshape(-1)),
+                         jnp.asarray(back.reshape(-1).astype(np.int32)),
+                         jnp.asarray(meta)), int(steps.sum()))
+
+
+def _gather_kernel(meta_ref, table_ref, src_ref, back_ref, out_ref,
+                   vals_ref):
+    """One edge block of gather_sorted. Each tile of 8 sorted sublane rows
+    takes `steps` window steps: step j loads table row first_k + j for
+    sublane row k, and each edge whose source sits in that row picks its
+    lane. Then each stream tile picks its values from the 64 sorted rows
+    by its `back` positions (row_reduce's pick)."""
+    rblk = EDGE_BLOCK // _LANES
+    sub = lax.broadcasted_iota(jnp.int32, (8, _LANES), 0)
+
+    def window(t, carry):
+        at = pl.multiple_of(t * 8, 8)
+        src = src_ref[pl.ds(at, 8), :]
+        first = [meta_ref[0, t * 8 + k] for k in range(8)]
+        base = jnp.zeros_like(src)
+        for k in range(8):
+            base = jnp.where(sub == k, first[k], base)
+        rel = lax.shift_right_logical(src, 7) - base   # the step that has it
+        lane = jnp.bitwise_and(src, _LANES - 1)
+
+        def turn(i, acc):
+            for u in range(_GATHER_UNROLL):
+                j = i * _GATHER_UNROLL + u
+                rows = jnp.concatenate(
+                    [table_ref[pl.ds(first[k] + j, 1), :] for k in range(8)])
+                g = jnp.take_along_axis(rows, lane, axis=1)  # in-vreg gather
+                acc = jnp.where(rel == j, g, acc)
+            return acc
+
+        vals_ref[pl.ds(at, 8), :] = lax.fori_loop(
+            0, meta_ref[0, rblk + t] // _GATHER_UNROLL, turn,
+            jnp.zeros_like(src))
+        return carry
+
+    def restore(t, carry):
+        at = pl.multiple_of(t * 8, 8)
+        back = back_ref[pl.ds(at, 8), :]
+        row = lax.shift_right_logical(back, 7)
+        lane = jnp.bitwise_and(back, _LANES - 1)
+        picked = jnp.zeros_like(back)
+        for r in range(rblk):
+            row_r = jnp.broadcast_to(vals_ref[r : r + 1, :], back.shape)
+            g = jnp.take_along_axis(row_r, lane, axis=1)
+            picked = jnp.where(row == r, g, picked)
+        out_ref[pl.ds(at, 8), :] = picked
+        return carry
+
+    lax.fori_loop(0, 8, window, 0)
+    lax.fori_loop(0, 8, restore, 0)
+
+
+@jax.jit
+def gather_sorted(table: jax.Array, lay: GatherLayout) -> jax.Array:
+    """table[src] in stream order for the stream `lay` (gather_layout)
+    sorts, `table` a vector of 32-bit values (float32 or int32; moved as
+    their bits, so every value equals XLA's gather) that gather_fits: one
+    block stream, the table whole in VMEM."""
+    n_rows, rblk = _table_rows(table.shape[0]), EDGE_BLOCK // _LANES
+    words = jnp.pad(lax.bitcast_convert_type(table, jnp.int32),
+                    (0, n_rows * _LANES - table.shape[0]))
+    by_block = pl.BlockSpec((rblk, _LANES), lambda b: (b, 0),
+                            memory_space=pltpu.VMEM)
+    out = pl.pallas_call(
+        _gather_kernel,
+        grid=(lay.meta.shape[0],),
+        in_specs=[pl.BlockSpec((None, 1, _LANES), lambda b: (b, 0, 0),
+                               memory_space=pltpu.SMEM),
+                  pl.BlockSpec((n_rows, _LANES), lambda b: (0, 0),
+                               memory_space=pltpu.VMEM),
+                  by_block, by_block],
+        out_specs=by_block,
+        scratch_shapes=[pltpu.VMEM((rblk, _LANES), jnp.int32)],
+        out_shape=jax.ShapeDtypeStruct((lay.src.shape[0] // _LANES, _LANES),
+                                       jnp.int32),
+        interpret=interpret_mode(),
+        name="gather_sorted",
+    )(lay.meta, words.reshape(n_rows, _LANES),
+      lay.src.reshape(-1, _LANES), lay.back.reshape(-1, _LANES))
+    return lax.bitcast_convert_type(out.reshape(-1), table.dtype)
+
+
+def _by_source(table: jax.Array, src: jax.Array, gather) -> jax.Array:
+    """Each edge's value table[src] (src clipped to the table's last slot
+    at pad edges): gather_sorted over the layout `gather` where there is
+    one and the table gather_fits, else XLA's element gather."""
+    if gather is None or not gather_fits(table.shape[0]):
+        return table[src]
+    return gather_sorted(table, gather)
+
+
 @partial(jax.jit, static_argnames=("top",))
 def analytics_pr(in_src_pad_d, in_iptr_rank, row_ends, out_degree_d, probes,
-                 iterations, damping, *, top: int):
+                 iterations, damping, gather=None, *, top: int):
     """Graphalytics PageRank: `iterations` steps from 1/N, no tolerance
     stop; a step gives (1 - d) / N + d * (the in-neighbours' rank over
     their out-degree + the dangling vertices' rank / N). Ranks are held in
     `damping`'s dtype (float32 served; a row is summed in float32 by
-    row_reduce). Returns (ranks at the probe ranks, the `top` highest
-    ranks and their dst ranks, the sum of all ranks): nothing
+    row_reduce). `gather` is the stream's GatherLayout over Nd + 1 slots
+    (None: XLA's gather). Returns (ranks at the probe ranks, the `top`
+    highest ranks and their dst ranks, the sum of all ranks): nothing
     vertex-sized leaves the device."""
     nd = out_degree_d.shape[0]
     dt = damping.dtype
@@ -1195,8 +1358,9 @@ def analytics_pr(in_src_pad_d, in_iptr_rank, row_ends, out_degree_d, probes,
     n = jnp.asarray(nd, dt)
 
     def step(_, r):
-        w = jnp.concatenate([r * inv, jnp.zeros(1, dt)])[src]
-        pulled = row_reduce(w.astype(jnp.float32), seg, row_ends, last,
+        w = _by_source(jnp.concatenate([r * inv, jnp.zeros(1, dt)])
+                       .astype(jnp.float32), src, gather)
+        pulled = row_reduce(w, seg, row_ends, last,
                             combine="sum").reshape(-1)[:nd].astype(dt)
         lost = jnp.sum(jnp.where(dangling, r, 0))
         return (1 - damping) / n + damping * (pulled + lost / n)
@@ -1207,8 +1371,8 @@ def analytics_pr(in_src_pad_d, in_iptr_rank, row_ends, out_degree_d, probes,
 
 
 @partial(jax.jit, static_argnames=("push",))
-def analytics_wcc(in_src_pad_d, in_iptr_rank, row_ends, probes, *,
-                  push: bool):
+def analytics_wcc(in_src_pad_d, in_iptr_rank, row_ends, probes, gather=None,
+                  *, push: bool):
     """Weakly connected components by FastSV (Zhang, Azad and Hu, 2020):
     every vertex has a parent, itself at first. A round finds for each
     vertex the least grandparent among its in-neighbours (and, `push`, its
@@ -1218,8 +1382,8 @@ def analytics_wcc(in_src_pad_d, in_iptr_rank, row_ends, probes, *,
     every Graph500 scale-18 seed tried, where one min-label pass and one
     jump a round took 4 or 5. Parents are dst ranks and only ever fall, so
     jumping them to a fixpoint leaves each vertex the least rank of its
-    component. Returns (labels at the probe ranks, components, the
-    largest's size, rounds)."""
+    component. `gather` as analytics_pr's. Returns (labels at the probe
+    ranks, components, the largest's size, rounds)."""
     nd = in_iptr_rank.shape[0] - 1
     seg = _dst_segments(in_iptr_rank, in_src_pad_d.shape[0])
     last = _last_edges(in_iptr_rank)
@@ -1228,7 +1392,7 @@ def analytics_wcc(in_src_pad_d, in_iptr_rank, row_ends, probes, *,
 
     def least_near(gf):
         ext = jnp.concatenate([gf, sentinel])
-        m = row_reduce(ext[src], seg, row_ends, last,
+        m = row_reduce(_by_source(ext, src, gather), seg, row_ends, last,
                        combine="min").reshape(-1)[:nd]
         if push:
             m = jnp.minimum(m, jnp.full(nd + 1, nd, jnp.int32)
@@ -1264,6 +1428,7 @@ JIT_PROGRAMS = {
     "pb.recurse_fused": recurse_fused,
     "pb.recurse_fused_multi": recurse_fused_multi,
     "pb.row_reduce": row_reduce,
+    "pb.gather_sorted": gather_sorted,
     "pb.analytics_pr": analytics_pr,
     "pb.analytics_wcc": analytics_wcc,
 }

@@ -280,6 +280,24 @@ def lcc_layout(csr, g):
     return got
 
 
+def gather_layout(csr, g):
+    """(GatherLayout, window steps a pass) of the gather by source rank
+    that every `pr` / `wcc` step makes (pb.gather_sorted over Nd + 1
+    slots, slot Nd the pad edges'), or (None, 0) where the table is too
+    large for VMEM and XLA's gather runs. Cached on the tablet beside
+    _gx_layout: once a snapshot, on its first `pr` or `wcc` request, so
+    that no other kind pays for it."""
+    got = getattr(csr, "_gx_gather", None)
+    if got is None:
+        from dgraph_tpu.ops import pallas_bfs as pb
+
+        nd = len(g.host_in_subjects)
+        src = np.full(g.in_src_pad_d.shape[0], nd, dtype=np.int32)
+        src[:g.num_edges] = g.host_map_s2d[g.host_in_src]
+        got = csr._gx_gather = pb.gather_layout(src, nd + 1)
+    return got
+
+
 def _gx_reason(csr) -> str | None:
     """Why a tablet cannot take the device path before its layout is
     looked at: the residency gate of _device_eligible, without the mesh."""
@@ -301,11 +319,21 @@ def _reduce_path() -> str:
     return "interpret" if pb.interpret_mode() else "pallas"
 
 
+def _gather_path(gather) -> str:
+    """Where a device step's gather by source rank runs: "vmem", the
+    gather_sorted kernel compiled for the chip, "interpret", the same
+    kernel in Pallas' interpreter, or "xla", XLA's element gather (no
+    layout: a table too large for VMEM)."""
+    if gather is None:
+        return "xla"
+    return "vmem" if _reduce_path() == "pallas" else "interpret"
+
+
 def _gx_device(kind: str, g, symmetric: bool, gate, probes: np.ndarray, *,
                damping: float, iterations: int, top: int, lay=None):
     """One launch of the kind's program inside a gate slot; its fetched
     host arrays. The probe ranks cross padded to PROBE_CLASS; `lay` is
-    lcc_layout's, for `lcc`."""
+    lcc_layout's for `lcc`, gather_layout's for `pr` / `wcc`."""
     import jax
 
     from dgraph_tpu.obs import costs, otrace
@@ -321,7 +349,9 @@ def _gx_device(kind: str, g, symmetric: bool, gate, probes: np.ndarray, *,
         attrs = {"oriented_edges": lay.oriented_edges,
                  "max_out": lay.max_out}
     else:
-        attrs = {"reduce": _reduce_path()}
+        gather, windows = lay
+        attrs = {"reduce": _reduce_path(), "gather": _gather_path(gather),
+                 "windows": windows}
     if kind == "pr":
         attrs["iterations"] = iterations
 
@@ -333,10 +363,12 @@ def _gx_device(kind: str, g, symmetric: bool, gate, probes: np.ndarray, *,
                 out = pb.analytics_pr(
                     g.in_src_pad_d, g.in_iptr_rank, g.row_ends,
                     g.out_degree_d, pad, np.int32(iterations),
-                    np.float32(damping), top=max(1, min(int(top), nd)))
+                    np.float32(damping), gather,
+                    top=max(1, min(int(top), nd)))
             elif kind == "wcc":
                 out = pb.analytics_wcc(g.in_src_pad_d, g.in_iptr_rank,
-                                       g.row_ends, pad, push=not symmetric)
+                                       g.row_ends, pad, gather,
+                                       push=not symmetric)
             else:
                 out = lcc.analytics_lcc(
                     lay.tables, lay.members, lay.tails, lay.heads,
@@ -402,8 +434,9 @@ def _run_gx(kind: str, csr, gate, metrics, *, damping: float,
                        for u in uids], dtype=np.int64)
     reason, lay = _gx_reason(csr), None
     if reason is None:
-        fresh = getattr(csr, "_gx_layout", None) is None or (
-            kind == "lcc" and getattr(csr, "_lcc_layout", None) is None)
+        fresh = getattr(csr, "_gx_layout", None) is None or getattr(
+            csr, "_lcc_layout" if kind == "lcc" else "_gx_gather",
+            None) is None
         with costs.stage("exec.prep") if fresh else contextlib.nullcontext():
             g, reason, symmetric = pull_layout(csr)
             if reason is None and kind == "lcc":
@@ -413,6 +446,8 @@ def _run_gx(kind: str, csr, gate, metrics, *, damping: float,
                     lay = lcc_layout(csr, g)
                 else:
                     reason = "one_way"
+            elif reason is None:
+                lay = gather_layout(csr, g)
     if reason is None:
         nodes, edges = g.host_in_subjects, g.num_edges
         at = host_rank_of(nodes, want, -1)
@@ -472,9 +507,13 @@ def _run_gx(kind: str, csr, gate, metrics, *, damping: float,
                     lay.compares)
                 metrics.counter("dgraph_analytics_lcc_merge_total").inc(
                     lay.merge)
-            elif _reduce_path() == "pallas":
-                metrics.keyed("dgraph_analytics_kernel_steps_total",
-                              labels=("kind",)).inc(kind, int(steps))
+            else:
+                if _reduce_path() == "pallas":
+                    metrics.keyed("dgraph_analytics_kernel_steps_total",
+                                  labels=("kind",)).inc(kind, int(steps))
+                metrics.keyed("dgraph_analytics_gather_steps_total",
+                              labels=("kind", "path")).inc(
+                    f"{kind}|{_gather_path(lay[0])}", int(steps))
         else:
             metrics.counter("dgraph_analytics_host_fallbacks_total").inc()
             metrics.keyed("dgraph_analytics_host_runs_total",

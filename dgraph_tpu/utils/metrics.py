@@ -560,6 +560,13 @@ class Registry:
         # Pallas' interpreter): equal to the device steps on a chip
         self.keyed_gauges["dgraph_analytics_kernel_steps_total"] = \
             KeyedGauge(labels=("kind",), keep=gx[:2])
+        # every device pr / wcc step by where its gather by source rank
+        # ran: path "vmem" (pb.gather_sorted compiled for the chip), "xla"
+        # (a table too large for VMEM) or "interpret" (off the chip)
+        self.keyed_gauges["dgraph_analytics_gather_steps_total"] = \
+            KeyedGauge(labels=("kind", "path"),
+                       keep=tuple(f"{k}|{p}" for k in gx[:2]
+                                  for p in ("vmem", "xla")))
         self.keyed_gauges["dgraph_analytics_edges_read_total"] = KeyedGauge(
             labels=("kind",), keep=gx)
         # serve's start-up phases, set once before the banner

@@ -78,6 +78,29 @@ def test_wcc_is_the_reference_partition_labelled_by_least_member(kron):
     assert out["largest"] == sizes.max()
 
 
+def test_vmem_gather_leaves_every_rank_and_label_bit_identical(kron):
+    """The served programs, handed the tablet's GatherLayout, answer
+    every vertex's PR rank and WCC label to the bit as the same programs
+    over XLA's gather: the kernel moves the values' bits and row_reduce
+    sums each row in the same order."""
+    _, _, _, node = kron
+    csr = node.snapshot().preds["follows"].csr
+    g = an.pull_layout(csr)[0]
+    lay, windows = an.gather_layout(csr, g)
+    assert lay is not None and windows > 0
+    probes = np.arange(len(g.host_in_subjects), dtype=np.int32)
+    for gather in (None, lay):
+        pr = pb.analytics_pr(g.in_src_pad_d, g.in_iptr_rank, g.row_ends,
+                             g.out_degree_d, probes, np.int32(10),
+                             np.float32(0.85), gather, top=20)
+        wcc = pb.analytics_wcc(g.in_src_pad_d, g.in_iptr_rank, g.row_ends,
+                               probes, gather, push=False)
+        got = [np.asarray(a).tobytes() for a in (*pr, *wcc)]
+        if gather is None:
+            want = got
+    assert got == want
+
+
 def test_lower_precision_and_lost_dangling_mass_fail_the_check(kron):
     """The same program with its ranks in bfloat16 fails the 1e-4 check;
     so does the reference without the dangling mass, on a graph that has
@@ -239,6 +262,25 @@ def test_probes_outside_the_vertex_set_and_bad_requests(directed):
         node.analytics("pagerankish", "follows")
 
 
+@pytest.mark.parametrize("kind", ["pr", "wcc"])
+def test_gather_layout_is_built_by_the_first_pr_or_wcc_only(kind):
+    """A /query and an `lcc` job build no gather layout and hold none; the
+    first `pr` or `wcc` job of the snapshot builds it, and the next job
+    of either kind reuses it."""
+    src, dst = ref.kronecker(8, 5)
+    node = _load(src, dst)
+    csr = node.snapshot().preds["follows"].csr
+    node.query(f"{{ q(func: uid(0x{int(src[0]):x})) {{ follows {{ uid }} }} }}")
+    assert node.analytics("lcc", "follows")["device"] is True
+    assert getattr(csr, "_gx_gather", None) is None
+    assert node.analytics(kind, "follows")["device"] is True
+    built = csr._gx_gather
+    assert built[0] is not None and built[1] > 0
+    other = "wcc" if kind == "pr" else "pr"
+    assert node.analytics(other, "follows")["device"] is True
+    assert csr._gx_gather is built
+
+
 def test_http_kinds_stages_span_and_counters():
     """POST /analytics `pr` / `wcc` through a served node: the answer, the
     request's stages on /metrics, the device_kernel span in its trace
@@ -248,6 +290,7 @@ def test_http_kinds_stages_span_and_counters():
 
     src, dst = ref.kronecker(9, 8)
     node = _load(src, dst, span_sample=1.0)
+    csr = node.snapshot().preds["follows"].csr
     srv = serve_forever(node, port=0)
     port = srv.server_address[1]
 
@@ -285,8 +328,15 @@ def test_http_kinds_stages_span_and_counters():
     # a step's reduction ran in the row_reduce kernel compiled for the chip,
     # or (off the chip) in Pallas' interpreter, which the counter leaves out
     reduce = "interpret" if pb.interpret_mode() else "pallas"
+    gather = "interpret" if pb.interpret_mode() else "vmem"
+    assert csr._gx_gather[0] is not None
     for kind, steps in (("pr", 10), ("wcc", wcc["rounds"])):
         assert val("dgraph_analytics_device_runs_total", kind=kind) == 1
+        # every step's gather by source rank, by where it ran
+        assert val("dgraph_analytics_gather_steps_total", kind=kind,
+                   path=gather) == steps
+        assert val("dgraph_analytics_gather_steps_total", kind=kind,
+                   path="xla") == 0
         assert val("dgraph_analytics_steps_total", kind=kind) == steps
         assert val("dgraph_analytics_kernel_steps_total", kind=kind) == \
             (steps if reduce == "pallas" else 0)
@@ -310,3 +360,5 @@ def test_http_kinds_stages_span_and_counters():
     assert kernels["pb.analytics_wcc"]["rounds"] == wcc["rounds"]
     assert kernels["pb.analytics_wcc"]["edges"] == len(src)
     assert {k["reduce"] for k in kernels.values()} == {reduce}
+    assert {k["gather"] for k in kernels.values()} == {gather}
+    assert {k["windows"] for k in kernels.values()} == {csr._gx_gather[1]}

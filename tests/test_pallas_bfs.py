@@ -787,3 +787,36 @@ def test_analytics_programs_reduce_rows_in_the_kernel(one_v5e, monkeypatch,
     assert "tpu_custom_call" in text
     scatters = [ln for ln in text.splitlines() if " scatter(" in ln]
     assert scatters and not any(f"[{nd + 1}]" in ln for ln in scatters)
+
+
+@pytest.mark.parametrize("program", ["analytics_pr", "analytics_wcc"])
+def test_analytics_programs_gather_by_source_in_the_kernel(one_v5e,
+                                                           monkeypatch,
+                                                           program):
+    """Handed a GatherLayout, a whole-graph step gathers by source rank
+    in gather_sorted compiled for the chip, with the largest table the
+    VMEM budget admits (GATHER_TABLE_MAX, 1M values): the program holds
+    no XLA gather with an element an edge, only vertex-sized ones
+    (FastSV's parents, the probes)."""
+    monkeypatch.setattr(pb, "interpret_mode", lambda: False)
+    e_pad, n_items = 16 * pb.EDGE_BLOCK, 32
+    nd = pb.GATHER_TABLE_MAX // 4 - 1
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e)
+
+    graph = (arg((e_pad,)), arg((nd + 1,)),
+             pb.RowEnds(arg((n_items,)), arg((n_items,))))
+    lay = pb.GatherLayout(arg((e_pad,)), arg((e_pad,)),
+                          arg((e_pad // pb.EDGE_BLOCK, 1, 128)))
+    if program == "analytics_pr":
+        lowered = pb.analytics_pr.lower(
+            *graph, arg((nd,)), arg((64,)), arg(()), arg((), jnp.float32),
+            lay, top=20)
+    else:
+        lowered = pb.analytics_wcc.lower(*graph, arg((64,)), lay,
+                                         push=False)
+    text = lowered.compile().as_text()
+    assert "gather_sorted" in text
+    gathers = [ln for ln in text.splitlines() if " gather(" in ln]
+    assert not any(f"[{e_pad}]" in ln for ln in gathers)
