@@ -347,7 +347,8 @@ def _gx_device(kind: str, g, symmetric: bool, gate, probes: np.ndarray, *,
     family = f"pb.analytics_{kind}"
     if kind == "lcc":
         attrs = {"oriented_edges": lay.oriented_edges,
-                 "max_out": lay.max_out}
+                 "max_out": lay.max_out, "core": lay.core,
+                 "core_edges": lay.core_edges}
     else:
         gather, windows = lay
         attrs = {"reduce": _reduce_path(), "gather": _gather_path(gather),
@@ -372,8 +373,9 @@ def _gx_device(kind: str, g, symmetric: bool, gate, probes: np.ndarray, *,
             else:
                 out = lcc.analytics_lcc(
                     lay.tables, lay.members, lay.tails, lay.heads,
-                    lay.head_ids, lay.order, lay.degree, pad,
-                    buckets=lay.buckets)
+                    lay.head_ids, lay.order, lay.degree, lay.adjacency,
+                    pad, buckets=lay.buckets, core=lay.core,
+                    spread=lay.spread)
             with costs.stage("dev.wait"):
                 out = jax.device_get(out)
             ck.set(h2d=int(pad.nbytes),
@@ -507,6 +509,12 @@ def _run_gx(kind: str, csr, gate, metrics, *, damping: float,
                     lay.compares)
                 metrics.counter("dgraph_analytics_lcc_merge_total").inc(
                     lay.merge)
+                metrics.counter(
+                    "dgraph_analytics_lcc_core_edges_total").inc(
+                    lay.core_edges)
+                metrics.counter(
+                    "dgraph_analytics_lcc_oriented_edges_total").inc(
+                    lay.oriented_edges)
             else:
                 if _reduce_path() == "pallas":
                     metrics.keyed("dgraph_analytics_kernel_steps_total",

@@ -436,9 +436,14 @@ class Registry:
                      # Graphalytics LCC on the device (ops/lcc.py): the
                      # element pairs its intersections compared, padding
                      # included, and Σ |R(u)| + |R(v)| over the edges they
-                     # intersected — the least a merge would read
+                     # intersected — the least a merge would read; the
+                     # oriented edges, and those of them inside the dense
+                     # core that the MXU product counts in place of
+                     # compares
                      "dgraph_analytics_lcc_compares_total",
                      "dgraph_analytics_lcc_merge_total",
+                     "dgraph_analytics_lcc_core_edges_total",
+                     "dgraph_analytics_lcc_oriented_edges_total",
                      # delta-journal retention (storage/store.py; ISSUE 18):
                      # keys/pinned_floor are gauges refreshed on scrape
                      "dgraph_delta_journal_keys",

@@ -53,6 +53,24 @@ def test_lcc_matches_the_reference_at_every_vertex(scale, seed):
     _check(out, nodes, tri, want)
 
 
+@pytest.mark.parametrize("scale,seed", [(10, 5), (11, 77)])
+def test_a_partial_core_matches_the_reference_at_every_vertex(
+        monkeypatch, scale, seed):
+    """The served program with the core held to 512 ranks, fewer than the
+    graph has: the product and the compares each find a part of the
+    triangles, and every vertex's t and lcc still hold."""
+    monkeypatch.setattr(lcc_ops, "core_size", lambda pairs: 512)
+    src, dst = ref.kronecker(scale, seed)
+    node = _load(src, dst)
+    nodes, tri, want = ref.lcc(src, dst)
+    out = node.analytics("lcc", "follows", uids=_hexes(nodes))
+    assert out["device"] is True and out["kind"] == "lcc"
+    lay = node.snapshot().preds["follows"].csr._lcc_layout
+    assert len(nodes) > lay.core == 512 and lay.core_edges > 0
+    assert lay.compares > lay.merge > 0
+    _check(out, nodes, tri, want)
+
+
 def _both_ways(pairs):
     e = np.asarray(pairs, dtype=np.int64)
     return (np.concatenate([e[:, 0], e[:, 1]]),
@@ -134,6 +152,116 @@ def test_a_hub_beyond_every_row_class_keeps_a_short_row():
     assert lay.max_out == 3 and lay.oriented_edges == len(src) // 2
 
 
+def _run_ops(lay, n):
+    return lcc_ops.analytics_lcc(
+        lay.tables, lay.members, lay.tails, lay.heads, lay.head_ids,
+        lay.order, lay.degree, lay.adjacency, np.arange(n, dtype=np.int32),
+        buckets=lay.buckets, core=lay.core, spread=lay.spread)
+
+
+def _rank_rows(src, dst):
+    """(iptr, nbrs, n) of the stored edges src -> dst in rank space: the
+    in-row of each rank sorted, as the PullGraph hands them to build."""
+    nodes = np.unique(np.concatenate([src, dst]))
+    s, d = np.searchsorted(nodes, src), np.searchsorted(nodes, dst)
+    o = np.lexsort((s, d))
+    iptr = np.zeros(len(nodes) + 1, dtype=np.int64)
+    np.cumsum(np.bincount(d, minlength=len(nodes)), out=iptr[1:])
+    return iptr, s[o], len(nodes)
+
+
+def _split_holds(lay, iptr, nbrs, n):
+    """Every vertex's t and lcc of the layout's program against lcc_host,
+    and the split of the oriented edges: those inside the core, and the
+    live ones outside it that the compare path holds."""
+    v = np.repeat(np.arange(n), np.diff(iptr))
+    tri, want = an.lcc_host(nbrs, v, n)
+    got = _run_ops(lay, n)
+    assert np.asarray(got[0]).tolist() == tri.tolist()
+    lcc = np.asarray(got[1], dtype=np.float64)
+    nz = want > 0
+    assert np.all(lcc[~nz] == 0)
+    if nz.any():
+        assert ref.rel_error(lcc[nz], want[nz]) <= TOL
+    assert int(got[2]) == tri.sum() // 3
+    order = np.asarray(lay.order)
+    a, b = order[nbrs[nbrs != v]], order[v[nbrs != v]]
+    a, b = a[a < b], b[a < b]
+    a, b = np.unique(np.stack([a, b]), axis=1)
+    od = np.bincount(a, minlength=n)
+    live = (od[a] > 1) & (od[b] > 0)
+    inner = a >= n - lay.core
+    compared = sum(int((np.asarray(h) < n).sum()) for h in lay.head_ids)
+    assert lay.oriented_edges == len(a)
+    assert lay.core_edges == inner.sum()
+    assert compared == (live & ~inner).sum()
+    assert lay.core_edges + compared == (live | inner).sum()
+    assert (lay.compares > lay.merge > 0) == (compared > 0)
+    assert lay.adjacency.shape == (-(-lay.core // lcc_ops.TILE)
+                                   * lcc_ops.TILE,) * 2
+    assert int(np.asarray(lay.adjacency, dtype=np.int64).sum()) == \
+        2 * lay.core_edges
+
+
+@pytest.mark.parametrize("core", [0, 512, "all", "rule"])
+def test_the_core_split_at_every_vertex(core):
+    """An R-MAT scale-11 graph with the core forced to nothing, to 512 of
+    its ranks, to all of them (the product alone), and as the rule picks:
+    every vertex's t exact and the edges split between the two paths."""
+    src, dst = ref.kronecker(11, 77)
+    iptr, nbrs, n = _rank_rows(src, dst)
+    k = {"all": n, "rule": None}.get(core, core)
+    lay = lcc_ops.build(iptr, nbrs, core=k)
+    assert lay.core == k if k is not None else 0 < lay.core <= n
+    assert n > 512
+    _split_holds(lay, iptr, nbrs, n)
+
+
+CORE_SHAPES = {
+    # name: (undirected pairs, the rule's K: None where it is the
+    # product's price against the compares' that decides)
+    "clique_of_40": (_clique(40), None),
+    "star_of_600": ([(1, 1 + k) for k in range(1, 601)], 0),
+    "ring_of_3000": ([(i, i % 3000 + 1) for i in range(1, 3001)], 0),
+    "two_cliques_under_512": (_clique(6) + _clique(7, first=40)
+                              + [(6, 40)], None),
+}
+
+
+@pytest.mark.parametrize("core", [0, "all", "rule"])
+@pytest.mark.parametrize("shape", sorted(CORE_SHAPES))
+def test_the_core_split_on_small_shapes(shape, core):
+    """A clique, a star, a ring and a graph of fewer than TILE vertices,
+    with no core, all of it in the core and as the rule picks; a graph
+    with no dense part (a star, a ring) gets no core from the rule."""
+    pairs, rule = CORE_SHAPES[shape]
+    src, dst = _both_ways(pairs)
+    iptr, nbrs, n = _rank_rows(src, dst)
+    lay = lcc_ops.build(iptr, nbrs, core={"all": n, "rule": None}.get(
+        core, core))
+    if core == "rule" and rule is not None:
+        assert lay.core == rule
+    _split_holds(lay, iptr, nbrs, n)
+
+
+def test_core_size_prices_the_compares_against_the_product():
+    """K = 0 where the compares cost less than the smallest product, the
+    whole graph where they all cost more than its product, and the TILE
+    multiple in between that the two prices balance at."""
+    n = 4096
+    size = lcc_ops.core_size
+    tile3 = lcc_ops.C_MAC * lcc_ops.TILE ** 3
+    assert size(np.zeros(n)) == 0
+    few = np.zeros(n)
+    few[-1] = 0.5 * tile3 / lcc_ops.C_PAIR
+    assert size(few) == 0
+    small = np.full(300, 2 * tile3 / lcc_ops.C_PAIR)
+    assert size(small) == 300
+    dense = np.zeros(n)
+    dense[-1024:] = 64 * tile3 / lcc_ops.C_PAIR / 1024
+    assert size(dense) == 1024
+
+
 def test_every_row_class_and_class_pair_of_a_clique():
     """A clique of 130 orients its k-th vertex to a row of 129 - k ids:
     every class from 8 to 256 holds rows, every pair of them with the
@@ -143,13 +271,10 @@ def test_every_row_class_and_class_pair_of_a_clique():
     n = 130
     nbrs = np.concatenate([np.delete(np.arange(n), v) for v in range(n)])
     iptr = np.arange(n + 1) * (n - 1)
-    lay = lcc_ops.build(iptr, nbrs)
+    lay = lcc_ops.build(iptr, nbrs, core=0)
     assert [int(t.shape[1]) for t in lay.tables] == [8, 16, 32, 64, 128, 256]
     assert lay.max_out == n - 1 and len(lay.buckets) == 20
-    got = lcc_ops.analytics_lcc(
-        lay.tables, lay.members, lay.tails, lay.heads, lay.head_ids,
-        lay.order, lay.degree, np.arange(n, dtype=np.int32),
-        buckets=lay.buckets)
+    got = _run_ops(lay, n)
     assert np.all(np.asarray(got[0]) == (n - 1) * (n - 2) // 2)
     assert np.allclose(np.asarray(got[1]), 1.0, rtol=TOL)
     assert int(got[2]) == n * (n - 1) * (n - 2) // 6
@@ -231,13 +356,15 @@ def test_bfloat16_counts_fail_the_check():
         assert (ref.rel_error(t / w, want[nz]) > TOL) is fails
 
 
-def test_http_kind_counters_and_span():
+def test_http_kind_counters_and_span(monkeypatch):
     """POST /analytics `lcc` through a served node: the answer, the
     request's stages, the device_kernel span's attributes and the
-    counters."""
+    counters. The core holds half the ranks, so both the product and the
+    compares run and are counted."""
     from dgraph_tpu.api.http import serve_forever
     from dgraph_tpu.obs import prom
 
+    monkeypatch.setattr(lcc_ops, "core_size", lambda pairs: len(pairs) // 2)
     src, dst = ref.kronecker(9, 8)
     node = _load(src, dst, span_sample=1.0)
     srv = serve_forever(node, port=0)
@@ -275,6 +402,11 @@ def test_http_kind_counters_and_span():
     assert val("dgraph_analytics_lcc_compares_total") == lay.compares > 0
     assert val("dgraph_analytics_lcc_merge_total") == lay.merge > 0
     assert lay.merge < lay.compares
+    assert 0 < lay.core == len(nodes) // 2
+    assert val("dgraph_analytics_lcc_core_edges_total") == \
+        lay.core_edges > 0
+    assert val("dgraph_analytics_lcc_oriented_edges_total") == \
+        lay.oriented_edges
     assert val("dgraph_analytics_kernel_steps_total", kind="pr") == 0
     assert "dgraph_analytics_host_runs_total" not in parsed
     for stage in ("http.read", "plan", "exec.prep", "dev.dispatch",
@@ -292,5 +424,7 @@ def test_http_kind_counters_and_span():
     assert (attrs["nodes"], attrs["edges"]) == (len(nodes), len(src))
     assert attrs["oriented_edges"] == lay.oriented_edges == len(src) // 2
     assert attrs["max_out"] == lay.max_out
+    assert (attrs["core"], attrs["core_edges"]) == (lay.core,
+                                                    lay.core_edges)
     assert attrs["total"] == tri.sum() // 3
     assert pb.JIT_PROGRAMS and "pb.analytics_lcc" in lcc_ops.JIT_PROGRAMS
