@@ -10,9 +10,10 @@
 #   * every terminal shape — traversal chain AND aggregation — is ONE
 #     mesh dispatch (dgraph_mesh_dispatches_total delta == 1) with a
 #     terminal op recorded (dgraph_agg_terminal_ops_total delta == 1),
-#   * whole-graph analytics agree with the NetworkX oracles: PageRank to
-#     1e-6, CC labels and triangle counts EXACT, host fallback (no-mesh
-#     node) matching the device path,
+#   * whole-graph analytics (`pagerank`, `cc`, `triangles`: the one-chip
+#     PR / WCC / LCC programs, no mesh) agree with the NetworkX oracles:
+#     PageRank to 1e-6, CC components and triangle counts EXACT, on the
+#     device path and on the host references (a delta overlay),
 #   * /metrics exposes the dgraph_agg_* / dgraph_analytics_* series and
 #     parses clean.
 # Runs entirely on the XLA host platform — no TPU required.
@@ -105,29 +106,41 @@ for name, q, terminal in BATTERY:
     print(f"  {name}: identical"
           + ("; ONE dispatch + terminal op" if terminal else ""))
 
-# -- analytics vs NetworkX oracles ----------------------------------------
-g = nx.DiGraph()
-sub, indptr, idx = \
-    mesh._read_view(None)[1].pred("follows").csr.host_arrays()
-for j, u in enumerate(sub):
-    for t in idx[indptr[j]: indptr[j + 1]]:
-        g.add_edge(int(u), int(t))
-pr_d = mesh.analytics("pagerank", "follows", tol=1e-10, max_iters=300)
-pr_h = plain.analytics("pagerank", "follows", tol=1e-10, max_iters=300)
-assert pr_d["device"] and not pr_h["device"]
-oracle = nx.pagerank(g, alpha=0.85, tol=1e-13, max_iter=1000)
-want = {hex(u): s for u, s in oracle.items()}
-for row in pr_d["top"]:
-    assert abs(row["score"] - want[row["uid"]]) < 1e-6, row
-cc_d, cc_h = mesh.analytics("cc", "follows"), plain.analytics("cc", "follows")
-assert cc_d["components"] == cc_h["components"] == \
-    nx.number_connected_components(g.to_undirected())
-tr_d = mesh.analytics("triangles", "follows")
-tr_h = plain.analytics("triangles", "follows")
-want_tri = sum(nx.triangles(g.to_undirected()).values()) // 3
-assert tr_d["triangles"] == tr_h["triangles"] == want_tri
-print(f"  analytics: pagerank<=1e-6, cc={cc_d['components']} exact, "
-      f"triangles={want_tri} exact (device + host fallback)")
+# -- analytics vs NetworkX oracles (one chip's programs: no mesh) --------
+def follows_graph():
+    g = nx.DiGraph()
+    sub, indptr, idx = \
+        plain._read_view(None)[1].pred("follows").csr.host_arrays()
+    for j, u in enumerate(sub):
+        for t in idx[indptr[j]: indptr[j + 1]]:
+            g.add_edge(int(u), int(t))
+    return g
+
+
+def check_analytics(g, device):
+    """pagerank to 1e-6 and cc exact on the path `device` names; triangles
+    exact (stored one way, `follows` always takes the host reference)."""
+    pr = plain.analytics("pagerank", "follows", tol=1e-10, max_iters=300)
+    oracle = nx.pagerank(g, alpha=0.85, tol=1e-13, max_iter=1000)
+    want = {hex(u): s for u, s in oracle.items()}
+    for row in pr["top"]:
+        assert abs(row["score"] - want[row["uid"]]) < 1e-6, row
+    cc = plain.analytics("cc", "follows")
+    assert cc["components"] == nx.number_connected_components(
+        g.to_undirected())
+    assert pr["device"] is cc["device"] is device
+    tr = plain.analytics("triangles", "follows")
+    want_tri = sum(nx.triangles(g.to_undirected()).values()) // 3
+    assert tr["triangles"] == want_tri and not tr["device"]
+    return cc["components"], want_tri
+
+
+comps, tri = check_analytics(follows_graph(), device=True)
+# a commit makes the tablet a delta overlay: the host references answer
+plain.mutate(set_nquads="<0x1> <follows> <0x3> .", commit_now=True)
+check_analytics(follows_graph(), device=False)
+print(f"  analytics: pagerank<=1e-6, cc={comps} exact, "
+      f"triangles={tri} exact (device + host references)")
 
 # -- /metrics exposes the new series and parses clean ---------------------
 series = prom.parse(prom.render(mesh.metrics))

@@ -820,11 +820,9 @@ class _Handler(BaseHTTPRequestHandler):
 
     def _analytics(self):
         """POST /analytics — whole-graph OLAP over one predicate's tablet
-        (docs/ops.md "Analytics"). Body: {"kind": "pagerank"|"cc"|
-        "triangles"|"pr"|"wcc"|"lcc", "pred": "<predicate>", ...knobs};
-        `pr`, `wcc` and `lcc` (LDBC Graphalytics) take "uids", the probe
-        vertices they report, `pr` also "iterations"; ?timeoutMs= rides
-        the query string like every other endpoint."""
+        (docs/ops.md "Analytics"). Body: {"kind": <query/analytics.KINDS>,
+        "pred": "<predicate>", "uids": [probe vertices], ...knobs};
+        ?timeoutMs= rides the query string like every other endpoint."""
         j = json.loads(self._read_body() or "{}")
         kind = str(j.get("kind", ""))
         pred = str(j.get("pred", ""))

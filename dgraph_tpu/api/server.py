@@ -1111,14 +1111,10 @@ class Node:
                   iterations: int = 10, uids=(),
                   timeout_ms: float | None = None,
                   start_ts: int | None = None) -> dict:
-        """Whole-graph analytics over one uid predicate's tablet
-        (query/analytics.py): PageRank / connected components / triangle
-        count as device-resident while_loop programs on the mesh, host
-        oracle fallback when the tablet is overlay/residency-deferred or
-        the node runs without a mesh; Graphalytics' `pr` (`iterations`
-        steps), `wcc` and `lcc` on one chip over the resident PullGraph,
-        for the probe vertices `uids`. Same request discipline as query(): stage
-        clock + span + deadline scope + cost ledger + DispatchGate."""
+        """Whole-graph analytics of `kind` (query/analytics.KINDS) over one
+        uid predicate's tablet, for the probe vertices `uids`. Same request
+        discipline as query(): stage clock + span + deadline scope + cost
+        ledger + DispatchGate."""
         # the stage clock, as query() joins or owns it: what is in no
         # narrower stage is `plan` (read view, snapshot, layout lookup)
         with self.clocked("analytics", "plan") as clk, costs.stage("plan"):
@@ -1168,8 +1164,7 @@ class Node:
                 lga = costs.current()
                 if lga is not None:
                     lga.add_task(pred[1:] if rev else pred, 0)
-                out = an.run(kind, csr, mesh=self.mesh_exec,
-                             gate=self.dispatch_gate, metrics=m,
+                out = an.run(kind, csr, gate=self.dispatch_gate, metrics=m,
                              damping=damping, tol=tol,
                              max_iters=max_iters, top=top,
                              iterations=iterations, uids=uids)

@@ -1047,7 +1047,7 @@ def recurse_fused_multi(in_src_pad, in_src_pad_d, in_iptr_rank, row_ends,
 # query/analytics.py): every step reads every in-edge of the dst-sorted
 # stream once, a segmented reduction by destination rank. The vertex set
 # is the DST-RANK space, which holds every vertex with an edge when every
-# source is also a destination (the caller checks: analytics.pull_layout).
+# source is also a destination (the caller checks: analytics.layout).
 # A step is a gather of a value by source rank (gather_sorted, a block
 # stream over a VMEM-resident table; XLA's element gather for a table too
 # large for VMEM) and row_reduce, a block stream over the row-end
@@ -1339,15 +1339,16 @@ def _by_source(table: jax.Array, src: jax.Array, gather) -> jax.Array:
 
 @partial(jax.jit, static_argnames=("top",))
 def analytics_pr(in_src_pad_d, in_iptr_rank, row_ends, out_degree_d, probes,
-                 iterations, damping, gather=None, *, top: int):
-    """Graphalytics PageRank: `iterations` steps from 1/N, no tolerance
-    stop; a step gives (1 - d) / N + d * (the in-neighbours' rank over
-    their out-degree + the dangling vertices' rank / N). Ranks are held in
-    `damping`'s dtype (float32 served; a row is summed in float32 by
-    row_reduce). `gather` is the stream's GatherLayout over Nd + 1 slots
-    (None: XLA's gather). Returns (ranks at the probe ranks, the `top`
-    highest ranks and their dst ranks, the sum of all ranks): nothing
-    vertex-sized leaves the device."""
+                 iterations, damping, tol, gather=None, *, top: int):
+    """PageRank from 1/N: `iterations` steps, or fewer where a step's L1
+    delta is <= `tol` (-1: none is, and every step runs, as Graphalytics
+    requires); a step gives (1 - d) / N + d * (the in-neighbours' rank
+    over their out-degree + the dangling vertices' rank / N). Ranks and
+    `tol` are in `damping`'s dtype (float32 served; a row is summed in
+    float32 by row_reduce). `gather` is the stream's GatherLayout over
+    Nd + 1 slots (None: XLA's gather). Returns (ranks at the probe ranks,
+    the `top` highest ranks and their dst ranks, the sum of all ranks, the
+    steps run): nothing vertex-sized leaves the device."""
     nd = out_degree_d.shape[0]
     dt = damping.dtype
     seg = _dst_segments(in_iptr_rank, in_src_pad_d.shape[0])
@@ -1357,7 +1358,7 @@ def analytics_pr(in_src_pad_d, in_iptr_rank, row_ends, out_degree_d, probes,
     inv = jnp.where(dangling, 0, 1 / jnp.maximum(out_degree_d, 1)).astype(dt)
     n = jnp.asarray(nd, dt)
 
-    def step(_, r):
+    def step(r):
         w = _by_source(jnp.concatenate([r * inv, jnp.zeros(1, dt)])
                        .astype(jnp.float32), src, gather)
         pulled = row_reduce(w, seg, row_ends, last,
@@ -1365,9 +1366,16 @@ def analytics_pr(in_src_pad_d, in_iptr_rank, row_ends, out_degree_d, probes,
         lost = jnp.sum(jnp.where(dangling, r, 0))
         return (1 - damping) / n + damping * (pulled + lost / n)
 
-    r = lax.fori_loop(0, iterations, step, jnp.full(nd, 1, dt) / n)
+    def body(carry):
+        k, r, _ = carry
+        new = step(r)
+        return k + 1, new, jnp.sum(jnp.abs(new - r))
+
+    steps, r, _ = lax.while_loop(
+        lambda c: (c[0] < iterations) & ~(c[2] <= tol), body,
+        (jnp.int32(0), jnp.full(nd, 1, dt) / n, jnp.asarray(jnp.inf, dt)))
     top_v, top_i = lax.top_k(r, top)
-    return r[probes], top_v, top_i, jnp.sum(r)
+    return r[probes], top_v, top_i, jnp.sum(r), steps
 
 
 @partial(jax.jit, static_argnames=("push",))

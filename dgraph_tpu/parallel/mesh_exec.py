@@ -66,12 +66,6 @@ def _target_table(csr: DistPredCSR) -> np.ndarray:
     return t
 
 
-def _distinct_targets(csr: DistPredCSR) -> int:
-    """Distinct destination uids of one sharded tablet — the tight upper
-    bound on any frontier a traversal through it can produce."""
-    return len(_target_table(csr))
-
-
 def _fcap_for(n: int) -> int:
     return 1 << max(int(np.ceil(np.log2(max(n, 1) + 1))), 4)
 
@@ -215,9 +209,6 @@ class MeshExecutor:
         """One labeled fused-coverage miss (the engine also folds these
         into the per-query fused/unfused ratio)."""
         self._k_fallback.inc(reason)
-
-    def fallback_total(self) -> int:
-        return sum(self._k_fallback.snapshot().values())
 
     def note_query(self, fused: bool) -> None:
         """Per-query coverage accounting: a query that touched mesh-owned
@@ -1066,224 +1057,3 @@ class MeshExecutor:
             if sp:
                 sp.set(cands=int((scores_h > -np.inf).sum()))
         return rows_h[scores_h > -np.inf]
-
-    # -- whole-graph analytics: device-resident while_loop programs ----------
-    #
-    # PageRank / connected components iterate entirely on device (the
-    # run_bfs idiom: lax.while_loop over edge-sharded scatter + ONE
-    # collective per iteration); only the converged vector crosses the
-    # host boundary. Edges arrive as rank pairs into a node table built
-    # by query/analytics._graph_arrays; padding edges scatter into a
-    # dropped slot (edst = ncap, mode="drop").
-
-    def _shard_edges(self, esrc: np.ndarray, edst: np.ndarray, ncap: int):
-        from jax.sharding import NamedSharding
-
-        S = self.n_devices
-        E = len(esrc)
-        epc = _fcap_for(-(-E // S) if E else 1)
-        es = np.zeros((S, epc), dtype=np.int32)
-        ed = np.full((S, epc), ncap, dtype=np.int32)
-        es.reshape(-1)[:E] = esrc
-        ed.reshape(-1)[:E] = edst
-        sh = NamedSharding(self.mesh, P("shard"))
-        return jax.device_put(es, sh), jax.device_put(ed, sh), epc
-
-    def _pagerank_program(self, epc: int, ncap: int):
-        key = ("pagerank", epc, ncap)
-        pr_prog = self._progs.get(key)
-        if pr_prog is not None:
-            return pr_prog
-        self._c_compiles.inc()
-        if self._prof is not None:
-            self._prof.on_build("mesh.pagerank", key)
-        mesh = self.mesh
-
-        def run(esrc, edst, outdeg, dangling, live, rank0, n, damping,
-                tol, maxit):
-            def cond(c):
-                _r, it, delta = c
-                return (it < maxit) & (delta > tol)
-
-            def body(c):
-                r, it, _ = c
-                w = jnp.take(r, esrc[0]) / jnp.take(outdeg, esrc[0])
-                contrib = lax.psum(
-                    jnp.zeros((ncap + 1,), jnp.float32).at[edst[0]].add(
-                        w, mode="drop"), "shard")[:ncap]
-                dang = jnp.sum(r * dangling)
-                new = jnp.where(
-                    live > 0,
-                    (1.0 - damping) / n + damping * (contrib + dang / n),
-                    0.0)
-                delta = jnp.sum(jnp.abs(new - r))
-                return new, it + 1, delta
-
-            r, it, _ = lax.while_loop(
-                cond, body, (rank0, jnp.int32(0), jnp.float32(jnp.inf)))
-            return r, it
-
-        pr_prog = jax.jit(shard_map(
-            run, mesh=mesh,
-            in_specs=(P("shard"), P("shard")) + (P(),) * 8,
-            out_specs=(P(), P()), check_vma=False),
-            donate_argnums=(5,))
-        self._progs[key] = pr_prog
-        return pr_prog
-
-    def run_pagerank(self, esrc: np.ndarray, edst: np.ndarray, n: int, *,
-                     damping: float = 0.85, tol: float = 1e-6,
-                     max_iters: int = 100):
-        """Power iteration over rank-space edges, edge-sharded across the
-        mesh. esrc/edst: int32[E] node ranks (0..n). Returns (float32[n]
-        ranks, iterations). Host finalization (sort/top-k) stays with the
-        caller; the f32 iterate is checked against a NetworkX-tolerance
-        oracle, not bitwise."""
-        ncap = _fcap_for(max(n, 1))
-        es, ed, epc = self._shard_edges(esrc, edst, ncap)
-        outdeg = np.zeros(ncap, dtype=np.float32)
-        deg = np.bincount(esrc, minlength=n).astype(np.float32) \
-            if len(esrc) else np.zeros(n, np.float32)
-        outdeg[:n] = deg[:n]
-        dangling = np.zeros(ncap, dtype=np.float32)
-        dangling[:n] = (outdeg[:n] == 0).astype(np.float32)
-        outdeg = np.maximum(outdeg, 1.0)
-        live = np.zeros(ncap, dtype=np.float32)
-        live[:n] = 1.0
-        rank0 = np.zeros(ncap, dtype=np.float32)
-        rank0[:n] = 1.0 / max(n, 1)
-        pr_prog = self._pagerank_program(epc, ncap)
-        with otrace.span("device_kernel", kernel="mesh.pagerank",
-                         nodes=n, edges=len(esrc),
-                         devices=self.n_devices) as sp:
-            with self.mesh:
-                r, it = pr_prog(es, ed, jnp.asarray(outdeg),
-                             jnp.asarray(dangling), jnp.asarray(live),
-                             jnp.asarray(rank0), jnp.float32(max(n, 1)),
-                             jnp.float32(damping), jnp.float32(tol),
-                             jnp.int32(max_iters))
-            r_h, it_h = jax.device_get((r, it))
-            # own the bytes: device_get can hand back a zero-copy view of
-            # the program output, which aliases the donated carry buffer —
-            # its memory is reclaimed once `r` drops, so a view would decay
-            # to garbage under later allocation churn
-            r_h = np.array(r_h[:n], copy=True)
-            self._c_dispatch.inc()
-            self._c_edges.inc(len(esrc) * int(it_h))
-            if sp:
-                sp.set(iterations=int(it_h))
-        return r_h, int(it_h)
-
-    def _cc_program(self, epc: int, ncap: int):
-        key = ("cc", epc, ncap)
-        cc_prog = self._progs.get(key)
-        if cc_prog is not None:
-            return cc_prog
-        self._c_compiles.inc()
-        if self._prof is not None:
-            self._prof.on_build("mesh.cc", key)
-        mesh = self.mesh
-
-        def run(esrc, edst, lab0, maxit):
-            def cond(c):
-                _l, it, ch = c
-                return ch & (it < maxit)
-
-            def body(c):
-                l, it, _ = c
-                le = jnp.take(l, esrc[0], mode="clip")
-                te = jnp.take(l, edst[0], mode="clip")
-                cand = jnp.full((ncap + 1,), jnp.int32(ncap))
-                cand = cand.at[edst[0]].min(le, mode="drop")
-                cand = cand.at[esrc[0]].min(te, mode="drop")
-                cand = lax.pmin(cand, "shard")[:ncap]
-                new = jnp.minimum(l, cand)
-                return new, it + 1, jnp.any(new != l)
-
-            l, it, _ = lax.while_loop(
-                cond, body, (lab0, jnp.int32(0), jnp.bool_(True)))
-            return l, it
-
-        cc_prog = jax.jit(shard_map(
-            run, mesh=mesh,
-            in_specs=(P("shard"), P("shard"), P(), P()),
-            out_specs=(P(), P()), check_vma=False),
-            donate_argnums=(2,))
-        self._progs[key] = cc_prog
-        return cc_prog
-
-    def run_cc(self, esrc: np.ndarray, edst: np.ndarray, n: int, *,
-               max_iters: int = 0):
-        """Min-label propagation (undirected: both edge directions each
-        iteration) until fixpoint. Returns (int32[n] labels — the minimum
-        node rank of each component, so EXACT vs any host oracle,
-        iterations)."""
-        ncap = _fcap_for(max(n, 1))
-        es, ed, epc = self._shard_edges(esrc, edst, ncap)
-        lab0 = np.arange(ncap, dtype=np.int32)
-        maxit = max_iters or (n + 2)
-        cc_prog = self._cc_program(epc, ncap)
-        with otrace.span("device_kernel", kernel="mesh.cc",
-                         nodes=n, edges=len(esrc),
-                         devices=self.n_devices) as sp:
-            with self.mesh:
-                l, it = cc_prog(es, ed, jnp.asarray(lab0), jnp.int32(maxit))
-            l_h, it_h = jax.device_get((l, it))
-            # see run_pagerank: the labels view aliases the donated lab0
-            l_h = np.array(l_h[:n], copy=True)
-            self._c_dispatch.inc()
-            self._c_edges.inc(2 * len(esrc) * int(it_h))
-            if sp:
-                sp.set(iterations=int(it_h))
-        return l_h, int(it_h)
-
-    def _tri_program(self, rows_per: int, ncap: int):
-        key = ("tri", rows_per, ncap)
-        tri_prog = self._progs.get(key)
-        if tri_prog is not None:
-            return tri_prog
-        self._c_compiles.inc()
-        if self._prof is not None:
-            self._prof.on_build("mesh.triangles", key)
-        mesh = self.mesh
-
-        def run(arow, afull):
-            # trace(A^3) row-sharded: each shard contracts its row block
-            # against the replicated adjacency; /6 happens on the host
-            b = arow[0] @ afull
-            return lax.psum(jnp.sum(arow[0] * b), "shard")
-
-        tri_prog = jax.jit(shard_map(
-            run, mesh=mesh, in_specs=(P("shard"), P()),
-            out_specs=P(), check_vma=False))
-        self._progs[key] = tri_prog
-        return tri_prog
-
-    def run_triangles(self, esrc: np.ndarray, edst: np.ndarray, n: int):
-        """Dense trace(A^3)/6 on the mesh — row-sharded matmul over the
-        symmetrized 0/1 adjacency. Exact (counts are small ints in f32
-        range); the caller gates on n (dense A is O(n^2) replicated)."""
-        from jax.sharding import NamedSharding
-
-        S = self.n_devices
-        ncap = max(_fcap_for(max(n, 1)), S)
-        a = np.zeros((ncap, ncap), dtype=np.float32)
-        a[esrc, edst] = 1.0
-        a[edst, esrc] = 1.0
-        np.fill_diagonal(a, 0.0)
-        rows_per = ncap // S
-        sh = NamedSharding(self.mesh, P("shard"))
-        arow = jax.device_put(a.reshape(S, rows_per, ncap), sh)
-        tri_prog = self._tri_program(rows_per, ncap)
-        with otrace.span("device_kernel", kernel="mesh.triangles",
-                         nodes=n, edges=len(esrc),
-                         devices=self.n_devices) as sp:
-            with self.mesh:
-                t = tri_prog(arow, jnp.asarray(a))
-            t_h = float(jax.device_get(t))
-            self._c_dispatch.inc()
-            self._c_edges.inc(len(esrc))
-            tri = int(round(t_h / 6.0))
-            if sp:
-                sp.set(triangles=tri)
-        return tri

@@ -1,52 +1,35 @@
-"""Whole-graph analytics: PageRank / connected components / triangles, and
-LDBC Graphalytics' PR, WCC and LCC.
+"""Whole-graph analytics over one uid predicate's tablet: LDBC
+Graphalytics' PR, WCC and LCC (specification v1.0), each under two names.
 
-The OLAP workload class beyond the reference (ROADMAP item 3): iterative
-SpMSpV programs that the per-query traversal engine cannot express run as
-device-resident ``lax.while_loop`` kernels over the mesh-sharded
-rank-space edge list (parallel/mesh_exec.run_pagerank / run_cc /
-run_triangles — the run_bfs idiom: one collective per iteration, only
-the converged vector crosses the host boundary).
+One implementation of each algorithm, behind one door (`run`): a jitted
+program over the PullGraph that ``pb.pull_graph_for`` keeps resident per
+snapshot (ops/pallas_bfs.analytics_pr / analytics_wcc, ops/lcc.analytics_lcc
+over degree-ordered rows built from it; `layout` builds what a program reads
+beside it on the snapshot's first request that reads it), and a host
+reference over ``graph_arrays`` for delta overlays, residency-deferred
+tablets, a source that is no destination (the device's vertex set would be
+partial) and, for LCC, a tablet not stored both ways — counted by reason.
 
-Surfaced as Node.analytics(...) + HTTP /analytics; deadline/shed-aware at
-the DispatchGate, cost-ledger-attributed, residency-aware: overlay or
-residency-deferred tablets (and nodes without a mesh) serve via the host
-fallbacks below. CC labels and triangle counts are EXACT either way (CC
-converges to the minimum member rank per component on both paths);
-PageRank device f32 vs host f64 agree to oracle tolerance, not bitwise —
-the result carries a ``device`` flag so callers know which path ran.
-
-Graphalytics' kinds (``pr``, ``wcc``, ``lcc``; specification v1.0) have
-the specification's semantics — PR runs exactly ``iterations`` steps, WCC
-labels every vertex with its component's least member, LCC gives each
-vertex the share of its neighbour pairs that are edges — and answer for the
-probe vertices a request names. Their device path needs no mesh: one
-jitted program each (ops/pallas_bfs.analytics_pr / analytics_wcc over the
-PullGraph that ``pb.pull_graph_for`` keeps resident per snapshot for the
-traversal programs, so a request builds no edge list; ops/lcc.analytics_lcc
-over the degree-ordered rows ``lcc_layout`` builds from that PullGraph on
-the first ``lcc`` request of a snapshot). Overlay or residency-deferred
-tablets, a predicate whose sources are not all destinations, and for
-``lcc`` one not stored in both directions, run the host oracles under the
-same kinds, counted by reason.
+KINDS maps a request's kind to its algorithm and its answer: ``pr`` runs
+exactly ``iterations`` steps and answers the probes' ranks, ``pagerank``
+stops after ``max_iters`` steps or a step whose L1 delta is <= ``tol`` and
+answers the top ranks; ``wcc`` labels the probes with their components'
+least members, ``cc`` counts the components; ``lcc`` gives the probes the
+share of their neighbour pairs that are edges, ``triangles`` the graph's
+triangle count. Labels and counts are exact on both paths; ranks are
+float32 on the device and float64 on the host, and the answer's ``device``
+flag says which ran. A probe (``uids``) that is no vertex answers None.
 """
 
 from __future__ import annotations
 
-import contextlib
+from typing import Callable, NamedTuple
 
 import numpy as np
-
-KINDS = ("pagerank", "cc", "triangles", "pr", "wcc", "lcc")
-GX_KINDS = ("pr", "wcc", "lcc")
 
 # a request's probe ranks go to the device padded to a multiple of this:
 # one program for every probe count up to it
 PROBE_CLASS = 64
-
-# dense trace(A^3) replicates an ncap x ncap f32 adjacency per device —
-# past this node count the exact host intersection counter wins
-TRI_DENSE_MAX = 2048
 
 
 def graph_arrays(csr):
@@ -66,8 +49,8 @@ def graph_arrays(csr):
 
 
 # ---------------------------------------------------------------------------
-# host fallbacks (cold tablets / no mesh) — the oracles the device
-# programs are tested against
+# host references (overlay, deferred or partial tablets) — the oracles
+# the device programs are tested against
 # ---------------------------------------------------------------------------
 
 def pagerank_host(esrc, edst, n: int, *, damping: float = 0.85,
@@ -96,8 +79,8 @@ def pagerank_host(esrc, edst, n: int, *, damping: float = 0.85,
 
 def cc_host(esrc, edst, n: int):
     """Union-find with union-by-minimum: every component's representative
-    is its minimum node rank — bit-identical to the device label
-    propagation's fixpoint."""
+    is its minimum node rank — the labels pb.analytics_wcc's fixpoint
+    gives."""
     parent = np.arange(n, dtype=np.int64)
 
     def find(x: int) -> int:
@@ -150,157 +133,87 @@ def lcc_host(esrc, edst, n: int) -> tuple[np.ndarray, np.ndarray]:
 
 
 # ---------------------------------------------------------------------------
-# execution
+# the layouts the device programs read: one cache a snapshot, on the tablet
 # ---------------------------------------------------------------------------
 
-def _device_eligible(mesh, csr) -> bool:
-    """Residency gate: the device path re-shards the edge list fresh, but
-    overlay tablets (uncompacted deltas) and residency-deferred shards
-    stay host-side by policy — cold data must not force HBM pressure."""
-    if mesh is None or csr is None:
-        return False
-    from dgraph_tpu.storage.delta import OverlayCSR
+def _pull(csr):
+    """(PullGraph, why its DST-RANK space is not the vertex set or None,
+    whether every edge is stored in both directions). The dst ranks hold
+    every vertex with an in-edge; they are the vertex set when every source
+    is also a destination. Stored both ways, a WCC round needs only the
+    pull, and an in-row is the vertex's whole neighbourhood."""
+    from dgraph_tpu.ops import pallas_bfs as pb
 
-    if isinstance(csr, OverlayCSR):
-        return False
-    return not getattr(csr, "_mesh_deferred", False)
+    g = pb.pull_graph_for(csr)
+    nd = len(g.host_in_subjects)
+    reason = None
+    if nd == 0:
+        reason = "empty"
+    elif len(g.host_map_s2d) and int(g.host_map_s2d.max()) >= nd:
+        reason = "rank_spaces"           # a source that is no destination
+    # an in-row holds its sources in rank order, a forward row its targets
+    # in uid order: equal rows, equal graphs
+    symmetric = reason is None and len(g.host_subjects) == nd \
+        and np.array_equal(g.host_fwd_indptr, g.host_in_iptr) \
+        and np.array_equal(g.host_subjects[g.host_in_src],
+                           csr.host_arrays()[2])
+    return g, reason, bool(symmetric)
 
 
-def run(kind: str, csr, mesh=None, gate=None, metrics=None, *,
-        damping: float = 0.85, tol: float = 1e-6, max_iters: int = 100,
-        top: int = 20, iterations: int = 10, uids=()) -> dict:
-    """One analytics computation over one tablet's whole graph. mesh is a
-    parallel/mesh_exec.MeshExecutor (or None → host oracles); gate the
-    DispatchGate (deadline/shed enforcement around the device program).
-    `iterations` and `uids` (the probe vertices) are Graphalytics'."""
+def _gather(csr):
+    """(GatherLayout, window steps a pass) of the gather by source rank
+    that every PR / WCC step makes (pb.gather_sorted over Nd + 1 slots,
+    slot Nd the pad edges'), or (None, 0) where the table is too large for
+    VMEM and XLA's gather runs."""
+    from dgraph_tpu.ops import pallas_bfs as pb
+
+    g = layout(csr, "pull")[0]
+    nd = len(g.host_in_subjects)
+    src = np.full(g.in_src_pad_d.shape[0], nd, dtype=np.int32)
+    src[:g.num_edges] = g.host_map_s2d[g.host_in_src]
+    return pb.gather_layout(src, nd + 1)
+
+
+def _lcc_rows(csr):
+    """The degree-ordered rows LCC's program reads (ops/lcc.Layout), built
+    from the in-rows of a tablet stored both ways: each is then the
+    vertex's neighbours in rank space."""
+    from dgraph_tpu.ops import lcc
+
+    g = layout(csr, "pull")[0]
+    return lcc.build(g.host_in_iptr, g.host_in_src)
+
+
+LAYOUTS = {"pull": _pull, "gather": _gather, "lcc": _lcc_rows}
+
+
+def layouts(csr) -> dict:
+    """The analytics layouts built so far for the tablet's snapshot, by
+    name (LAYOUTS), attached to the tablet beside its PullGraph."""
+    got = getattr(csr, "_analytics", None)
+    if got is None:
+        got = csr._analytics = {}
+    return got
+
+
+def layout(csr, name: str):
+    """The tablet's layout `name`, built inside the exec.prep stage on the
+    snapshot's first request that reads it: only the algorithms that read
+    a layout build it (LCC never the gather layout, PR and WCC never LCC's
+    rows)."""
     from dgraph_tpu.obs import costs
 
-    if kind not in KINDS:
-        raise ValueError(f"unknown analytics kind {kind!r}; "
-                         f"one of {', '.join(KINDS)}")
-    if kind in GX_KINDS:
-        return _run_gx(kind, csr, gate, metrics, damping=damping,
-                       iterations=iterations, top=top, uids=uids)
-    nodes, esrc, edst = graph_arrays(csr)
-    n = len(nodes)
-    device = _device_eligible(mesh, csr)
-    if kind == "triangles" and n > TRI_DENSE_MAX:
-        device = False
-    if metrics is not None:
-        metrics.counter("dgraph_analytics_runs_total").inc()
-        metrics.counter("dgraph_analytics_edges_total").inc(len(esrc))
-        if not device:
-            metrics.counter("dgraph_analytics_host_fallbacks_total").inc()
-
-    def gated(fn):
-        return gate.run(fn, klass="mesh") if gate is not None else fn()
-
-    out = {"kind": kind, "nodes": int(n), "edges": int(len(esrc)),
-           "device": bool(device)}
-    if kind == "pagerank":
-        with costs.kernel("analytics.pagerank"):
-            if device:
-                r, it = gated(lambda: mesh.run_pagerank(
-                    esrc, edst, n, damping=damping, tol=tol,
-                    max_iters=max_iters))
-            else:
-                r, it = pagerank_host(esrc, edst, n, damping=damping,
-                                      tol=tol, max_iters=max_iters)
-        order = np.argsort(-np.asarray(r, dtype=np.float64),
-                           kind="stable")[: max(int(top), 0)]
-        out["iterations"] = int(it)
-        out["top"] = [{"uid": hex(int(nodes[i])), "score": float(r[i])}
-                      for i in order.tolist()]
-    elif kind == "cc":
-        with costs.kernel("analytics.cc"):
-            if device:
-                lab, it = gated(lambda: mesh.run_cc(esrc, edst, n))
-            else:
-                lab, it = cc_host(esrc, edst, n), 0
-        comps, sizes = np.unique(lab, return_counts=True) \
-            if n else (np.zeros(0), np.zeros(0, np.int64))
-        out["iterations"] = int(it)
-        out["components"] = int(len(comps))
-        out["largest"] = int(sizes.max()) if len(sizes) else 0
-    else:
-        with costs.kernel("analytics.triangles"):
-            if device:
-                tri = gated(lambda: mesh.run_triangles(esrc, edst, n))
-            else:
-                tri = lcc_host(esrc, edst, n)[0].sum() // 3
-        out["triangles"] = int(tri)
-    if metrics is not None and "iterations" in out:
-        metrics.counter("dgraph_analytics_iterations_total").inc(
-            out["iterations"])
-    return out
+    cache = layouts(csr)
+    if name not in cache:
+        with costs.stage("exec.prep"):
+            cache[name] = LAYOUTS[name](csr)
+    return cache[name]
 
 
-# ---------------------------------------------------------------------------
-# LDBC Graphalytics PR, WCC and LCC
-# ---------------------------------------------------------------------------
-
-def pull_layout(csr):
-    """(PullGraph, why its DST-RANK space is not the vertex set or None,
-    whether every edge is stored in both directions), cached on the tablet
-    beside the PullGraph: once a snapshot. The dst ranks hold every vertex
-    with an in-edge; they are the vertex set when every source is also a
-    destination. Stored both ways, a WCC round needs only the pull."""
-    got = getattr(csr, "_gx_layout", None)
-    if got is None:
-        from dgraph_tpu.ops import pallas_bfs as pb
-
-        g = pb.pull_graph_for(csr)
-        nd = len(g.host_in_subjects)
-        reason = None
-        if nd == 0:
-            reason = "empty"
-        elif len(g.host_map_s2d) and int(g.host_map_s2d.max()) >= nd:
-            reason = "rank_spaces"       # a source that is no destination
-        # an in-row holds its sources in rank order, a forward row its
-        # targets in uid order: equal rows, equal graphs
-        symmetric = reason is None and len(g.host_subjects) == nd \
-            and np.array_equal(g.host_fwd_indptr, g.host_in_iptr) \
-            and np.array_equal(g.host_subjects[g.host_in_src],
-                               csr.host_arrays()[2])
-        got = csr._gx_layout = (g, reason, bool(symmetric))
-    return got
-
-
-def lcc_layout(csr, g):
-    """The degree-ordered rows LCC's program reads (ops/lcc.Layout), built
-    from the PullGraph `g` of a tablet stored both ways — each in-row is
-    then the vertex's neighbours in rank space — and cached on the tablet
-    beside _gx_layout: once a snapshot, on its first `lcc` request, so
-    that no other kind pays for it."""
-    got = getattr(csr, "_lcc_layout", None)
-    if got is None:
-        from dgraph_tpu.ops import lcc
-
-        got = csr._lcc_layout = lcc.build(g.host_in_iptr, g.host_in_src)
-    return got
-
-
-def gather_layout(csr, g):
-    """(GatherLayout, window steps a pass) of the gather by source rank
-    that every `pr` / `wcc` step makes (pb.gather_sorted over Nd + 1
-    slots, slot Nd the pad edges'), or (None, 0) where the table is too
-    large for VMEM and XLA's gather runs. Cached on the tablet beside
-    _gx_layout: once a snapshot, on its first `pr` or `wcc` request, so
-    that no other kind pays for it."""
-    got = getattr(csr, "_gx_gather", None)
-    if got is None:
-        from dgraph_tpu.ops import pallas_bfs as pb
-
-        nd = len(g.host_in_subjects)
-        src = np.full(g.in_src_pad_d.shape[0], nd, dtype=np.int32)
-        src[:g.num_edges] = g.host_map_s2d[g.host_in_src]
-        got = csr._gx_gather = pb.gather_layout(src, nd + 1)
-    return got
-
-
-def _gx_reason(csr) -> str | None:
+def _host_reason(csr) -> str | None:
     """Why a tablet cannot take the device path before its layout is
-    looked at: the residency gate of _device_eligible, without the mesh."""
+    looked at: delta overlays (uncompacted commits) and residency-deferred
+    tablets stay on the host — cold data must not force HBM pressure."""
     from dgraph_tpu.storage.delta import OverlayCSR
 
     if isinstance(csr, OverlayCSR):
@@ -310,224 +223,282 @@ def _gx_reason(csr) -> str | None:
     return None
 
 
-def _reduce_path() -> str:
-    """Where a device step's per-destination reduction runs: "pallas", the
-    row_reduce kernel compiled for the chip, or "interpret", the same
-    kernel in Pallas' interpreter off the chip (pb.interpret_mode)."""
+# ---------------------------------------------------------------------------
+# the algorithms: each one's layout, device program, host reference and
+# counters
+# ---------------------------------------------------------------------------
+
+class Request(NamedTuple):
+    """A request's knobs once its kind is looked up: PR stops after
+    `iterations` steps or a step whose L1 delta is <= `tol` (-1: never)."""
+
+    damping: float
+    iterations: int
+    tol: float
+    top: int
+
+
+def _stream_attrs(lay) -> dict:
+    """Where a PR / WCC step runs — its per-destination reduction:
+    "pallas", pb.row_reduce compiled for the chip, or "interpret", Pallas'
+    interpreter off the chip; its gather by source rank: "vmem",
+    pb.gather_sorted compiled for the chip, "interpret", or "xla", XLA's
+    element gather (no layout: a table too large for VMEM) — and the gather
+    layout's window steps a pass."""
     from dgraph_tpu.ops import pallas_bfs as pb
 
-    return "interpret" if pb.interpret_mode() else "pallas"
+    gather, windows = lay
+    reduce = "interpret" if pb.interpret_mode() else "pallas"
+    return {"reduce": reduce, "windows": windows,
+            "gather": "xla" if gather is None
+            else "vmem" if reduce == "pallas" else "interpret"}
 
 
-def _gather_path(gather) -> str:
-    """Where a device step's gather by source rank runs: "vmem", the
-    gather_sorted kernel compiled for the chip, "interpret", the same
-    kernel in Pallas' interpreter, or "xla", XLA's element gather (no
-    layout: a table too large for VMEM)."""
-    if gather is None:
-        return "xla"
-    return "vmem" if _reduce_path() == "pallas" else "interpret"
+def _stream_count(metrics, lay, name: str, steps: int) -> None:
+    """Of a PR / WCC run's device steps: those whose reduction ran in the
+    kernel compiled for the chip, and all of them by gather path."""
+    where = _stream_attrs(lay)
+    if where["reduce"] == "pallas":
+        metrics.keyed("dgraph_analytics_kernel_steps_total",
+                      labels=("kind",)).inc(name, steps)
+    metrics.keyed("dgraph_analytics_gather_steps_total",
+                  labels=("kind", "path")).inc(f"{name}|{where['gather']}",
+                                               steps)
 
 
-def _gx_device(kind: str, g, symmetric: bool, gate, probes: np.ndarray, *,
-               damping: float, iterations: int, top: int, lay=None):
-    """One launch of the kind's program inside a gate slot; its fetched
-    host arrays. The probe ranks cross padded to PROBE_CLASS; `lay` is
-    lcc_layout's for `lcc`, gather_layout's for `pr` / `wcc`."""
-    import jax
+def _pr_device(g, lay, symmetric, pad, req, fetch):
+    from dgraph_tpu.ops import pallas_bfs as pb
 
-    from dgraph_tpu.obs import costs, otrace
+    out = fetch(pb.analytics_pr(
+        g.in_src_pad_d, g.in_iptr_rank, g.row_ends, g.out_degree_d, pad,
+        np.int32(req.iterations), np.float32(req.damping),
+        np.float32(req.tol), lay[0],
+        top=max(1, min(req.top, len(g.host_in_subjects)))))
+    return out[:4], int(out[4]), {**_stream_attrs(lay),
+                                  "iterations": int(out[4])}
+
+
+def _pr_host(esrc, edst, n, at, req):
+    r, steps = pagerank_host(esrc, edst, n, damping=req.damping,
+                             tol=req.tol, max_iters=req.iterations)
+    order = np.argsort(-r, kind="stable")
+    return (r[at] if n else at, r[order], order, r.sum()), steps
+
+
+def _wcc_device(g, lay, symmetric, pad, req, fetch):
+    from dgraph_tpu.ops import pallas_bfs as pb
+
+    out = fetch(pb.analytics_wcc(g.in_src_pad_d, g.in_iptr_rank,
+                                 g.row_ends, pad, lay[0],
+                                 push=not symmetric))
+    return out[:3], int(out[3]), {**_stream_attrs(lay),
+                                  "rounds": int(out[3])}
+
+
+def _wcc_host(esrc, edst, n, at, req):
+    lab = cc_host(esrc, edst, n)
+    sizes = np.unique(lab, return_counts=True)[1]
+    # union-find: one pass of the edges
+    return (lab[at] if n else at, len(sizes), sizes.max(initial=0)), 1
+
+
+def _lcc_device(g, lay, symmetric, pad, req, fetch):
     from dgraph_tpu.ops import lcc
-    from dgraph_tpu.ops import pallas_bfs as pb
 
-    nd = len(g.host_in_subjects)
-    pad = np.zeros(-(-max(len(probes), 1) // PROBE_CLASS) * PROBE_CLASS,
-                   dtype=np.int32)
-    pad[:len(probes)] = probes
-    family = f"pb.analytics_{kind}"
-    if kind == "lcc":
-        attrs = {"oriented_edges": lay.oriented_edges,
-                 "max_out": lay.max_out, "core": lay.core,
-                 "core_edges": lay.core_edges}
-    else:
-        gather, windows = lay
-        attrs = {"reduce": _reduce_path(), "gather": _gather_path(gather),
-                 "windows": windows}
-    if kind == "pr":
-        attrs["iterations"] = iterations
-
-    def launch():
-        with otrace.span("device_kernel", kernel=family, nodes=nd,
-                         edges=g.num_edges, **attrs) as sp, \
-                costs.kernel(family, stage="dev.dispatch") as ck:
-            if kind == "pr":
-                out = pb.analytics_pr(
-                    g.in_src_pad_d, g.in_iptr_rank, g.row_ends,
-                    g.out_degree_d, pad, np.int32(iterations),
-                    np.float32(damping), gather,
-                    top=max(1, min(int(top), nd)))
-            elif kind == "wcc":
-                out = pb.analytics_wcc(g.in_src_pad_d, g.in_iptr_rank,
-                                       g.row_ends, pad, gather,
-                                       push=not symmetric)
-            else:
-                out = lcc.analytics_lcc(
-                    lay.tables, lay.members, lay.tails, lay.heads,
-                    lay.head_ids, lay.order, lay.degree, lay.adjacency,
-                    pad, buckets=lay.buckets, core=lay.core,
-                    spread=lay.spread)
-            with costs.stage("dev.wait"):
-                out = jax.device_get(out)
-            ck.set(h2d=int(pad.nbytes),
-                   d2h=int(sum(np.asarray(a).nbytes for a in out)))
-            if kind == "wcc":
-                sp.set(rounds=int(out[3]))
-            elif kind == "lcc":
-                sp.set(total=int(out[2]))
-        return out
-
-    return gate.run(launch, klass="analytics") if gate is not None \
-        else launch()
+    out = fetch(lcc.analytics_lcc(
+        lay.tables, lay.members, lay.tails, lay.heads, lay.head_ids,
+        lay.order, lay.degree, lay.adjacency, pad, buckets=lay.buckets,
+        core=lay.core, spread=lay.spread))
+    return out, 1, {"oriented_edges": lay.oriented_edges,
+                    "max_out": lay.max_out, "core": lay.core,
+                    "core_edges": lay.core_edges, "total": int(out[2])}
 
 
-def _pr_answer(nodes, want, at, ranks, top_v, top_i, total,
-               top: int) -> dict:
-    """`nodes` the vertex set's sorted uids, `at` each probe's rank in it
-    (-1: no vertex), `ranks` the probes' own ranks in `want`'s order."""
-    top = max(int(top), 0)
-    return {"values": {hex(int(u)): (float(v) if i >= 0 else None)
-                       for u, v, i in zip(want, ranks, at)},
+def _lcc_host(esrc, edst, n, at, req):
+    tri, lcc = lcc_host(esrc, edst, n)
+    return (tri[at] if n else at, lcc[at] if n else at, tri.sum() // 3,
+            lcc.sum()), 1
+
+
+def _lcc_count(metrics, lay, name: str, steps: int) -> None:
+    metrics.counter("dgraph_analytics_lcc_compares_total").inc(lay.compares)
+    metrics.counter("dgraph_analytics_lcc_merge_total").inc(lay.merge)
+    metrics.counter("dgraph_analytics_lcc_core_edges_total").inc(
+        lay.core_edges)
+    metrics.counter("dgraph_analytics_lcc_oriented_edges_total").inc(
+        lay.oriented_edges)
+
+
+def _at_probes(want, at, vals, cast) -> dict:
+    """{probe uid: cast(its value)}, None for a probe that is no vertex."""
+    return {hex(int(u)): (cast(v) if i >= 0 else None)
+            for u, v, i in zip(want, vals, at)}
+
+
+def _pr_answer(nodes, want, at, res, top) -> dict:
+    ranks, top_v, top_i, total = res
+    return {"values": _at_probes(want, at, ranks, float),
             "top": [{"uid": hex(int(nodes[i])), "score": float(v)}
                     for v, i in zip(top_v[:top], top_i[:top])],
             "sum": float(total)}
 
 
-def _wcc_answer(nodes, want, at, labels, components, largest) -> dict:
-    """As _pr_answer; `labels` the probes' components as vertex ranks."""
-    return {"labels": {hex(int(u)): (hex(int(nodes[lab])) if i >= 0
-                                     else None)
-                       for u, lab, i in zip(want, labels, at)},
-            "components": int(components), "largest": int(largest)}
+def _wcc_answer(nodes, want, at, res, top) -> dict:
+    return {"labels": _at_probes(want, at, res[0],
+                                 lambda lab: hex(int(nodes[lab]))),
+            "components": int(res[1]), "largest": int(res[2])}
 
 
-def _lcc_answer(want, at, tri, values, total, sums) -> dict:
-    """As _pr_answer; `tri` and `values` the probes' t and lcc."""
-    return {"values": {hex(int(u)): (float(v) if i >= 0 else None)
-                       for u, v, i in zip(want, values, at)},
-            "triangles": {hex(int(u)): (int(c) if i >= 0 else None)
-                          for u, c, i in zip(want, tri, at)},
-            "total": int(total), "sum": float(sums)}
+def _lcc_answer(nodes, want, at, res, top) -> dict:
+    return {"values": _at_probes(want, at, res[1], float),
+            "triangles": _at_probes(want, at, res[0], int),
+            "total": int(res[2]), "sum": float(res[3])}
 
 
-def _run_gx(kind: str, csr, gate, metrics, *, damping: float,
-            iterations: int, top: int, uids) -> dict:
-    """Graphalytics' PR / WCC / LCC: on the device over the resident
-    PullGraph (LCC over the rows lcc_layout builds from it), or by the
-    host oracles over graph_arrays where the device path cannot answer
-    over the whole vertex set, or for LCC over the whole undirected
-    graph (the reason is counted)."""
+class Algorithm(NamedTuple):
+    """One whole-graph algorithm. `name` labels its device program
+    (pb.analytics_<name>) and its counters. Its device program and its
+    host reference give the same result, which its answer reads — PR: the
+    probes' ranks, the top scores and their vertex ranks, the sum; WCC:
+    the probes' labels as vertex ranks, the components, the largest's
+    size; LCC: the probes' t and lcc, the triangles, the sum of lcc."""
+
+    name: str
+    layout: str        # the LAYOUTS entry its program reads
+    both_ways: bool    # its program needs every edge stored both ways
+    device: Callable   # (g, layout, symmetric, probes, Request, fetch) ->
+    #                    (result, steps, span attributes)
+    host: Callable     # (esrc, edst, n, probe ranks, Request) ->
+    #                    (result, steps)
+    count: Callable    # (metrics, layout, name, steps): its own counters
+    answer: Callable   # (nodes, probe uids, their ranks, result, top) ->
+    #                    the answer's keys
+
+
+PR = Algorithm("pr", "gather", False, _pr_device, _pr_host, _stream_count,
+               _pr_answer)
+WCC = Algorithm("wcc", "gather", False, _wcc_device, _wcc_host,
+                _stream_count, _wcc_answer)
+LCC = Algorithm("lcc", "lcc", True, _lcc_device, _lcc_host, _lcc_count,
+                _lcc_answer)
+
+# kind: (its algorithm; whether PR stops after `max_iters` steps or a step
+# whose L1 delta is <= `tol`, else it runs exactly `iterations`; each key
+# of its answer from a key of the algorithm's, "steps" the steps it ran)
+KINDS = {
+    "pr": (PR, False, dict(values="values", top="top", sum="sum",
+                           iterations="steps")),
+    "pagerank": (PR, True, dict(iterations="steps", top="top")),
+    "wcc": (WCC, False, dict(labels="labels", components="components",
+                             largest="largest", rounds="steps")),
+    "cc": (WCC, False, dict(iterations="steps", components="components",
+                            largest="largest")),
+    "lcc": (LCC, False, dict(values="values", triangles="triangles",
+                             total="total", sum="sum")),
+    "triangles": (LCC, False, dict(triangles="total")),
+}
+
+
+# ---------------------------------------------------------------------------
+# execution
+# ---------------------------------------------------------------------------
+
+def _device(alg: Algorithm, g, lay, symmetric: bool, gate,
+            probes: np.ndarray, req: Request):
+    """One launch of the algorithm's program inside a gate slot: (its
+    result on the host, its steps). The probe ranks cross padded to
+    PROBE_CLASS."""
+    import jax
+
+    from dgraph_tpu.obs import costs, otrace
+
+    pad = np.zeros(-(-max(len(probes), 1) // PROBE_CLASS) * PROBE_CLASS,
+                   dtype=np.int32)
+    pad[:len(probes)] = probes
+    family = f"pb.analytics_{alg.name}"
+
+    def launch():
+        with otrace.span("device_kernel", kernel=family,
+                         nodes=len(g.host_in_subjects),
+                         edges=g.num_edges) as sp, \
+                costs.kernel(family, stage="dev.dispatch") as ck:
+            def fetch(out):
+                with costs.stage("dev.wait"):
+                    out = jax.device_get(out)
+                ck.set(h2d=int(pad.nbytes),
+                       d2h=int(sum(np.asarray(a).nbytes for a in out)))
+                return out
+
+            res, steps, attrs = alg.device(g, lay, symmetric, pad, req,
+                                           fetch)
+            sp.set(**attrs)
+        return res, steps
+
+    return gate.run(launch, klass="analytics") if gate is not None \
+        else launch()
+
+
+def run(kind: str, csr, gate=None, metrics=None, *, damping: float = 0.85,
+        tol: float = 1e-6, max_iters: int = 100, top: int = 20,
+        iterations: int = 10, uids=()) -> dict:
+    """One analytics job of `kind` (KINDS) over one tablet's whole graph:
+    on the device over the resident PullGraph, or by the host reference
+    over graph_arrays where the device cannot answer (the reason is
+    counted). `gate` is the DispatchGate the device program runs inside
+    (deadline and shed enforcement); `uids` are the probe vertices."""
     from dgraph_tpu.obs import costs
     from dgraph_tpu.ops.uidset import host_rank_of
 
-    iterations = int(iterations)
-    if kind == "pr" and iterations < 0:
+    if kind not in KINDS:
+        raise ValueError(f"unknown analytics kind {kind!r}; "
+                         f"one of {', '.join(KINDS)}")
+    alg, stops, keys = KINDS[kind]
+    req = Request(float(damping), int(max_iters if stops else iterations),
+                  float(tol) if stops else -1.0, max(int(top), 0))
+    if req.iterations < 0:
         raise ValueError("analytics: iterations must be >= 0")
     want = np.asarray([int(u, 0) if isinstance(u, str) else int(u)
                        for u in uids], dtype=np.int64)
-    reason, lay = _gx_reason(csr), None
+    reason = _host_reason(csr)
     if reason is None:
-        fresh = getattr(csr, "_gx_layout", None) is None or getattr(
-            csr, "_lcc_layout" if kind == "lcc" else "_gx_gather",
-            None) is None
-        with costs.stage("exec.prep") if fresh else contextlib.nullcontext():
-            g, reason, symmetric = pull_layout(csr)
-            if reason is None and kind == "lcc":
-                # an in-row is the whole neighbourhood only when every
-                # edge is stored both ways
-                if symmetric:
-                    lay = lcc_layout(csr, g)
-                else:
-                    reason = "one_way"
-            elif reason is None:
-                lay = gather_layout(csr, g)
+        g, reason, symmetric = layout(csr, "pull")
+    if reason is None and alg.both_ways and not symmetric:
+        reason = "one_way"
     if reason is None:
+        lay = layout(csr, alg.layout)
         nodes, edges = g.host_in_subjects, g.num_edges
         at = host_rank_of(nodes, want, -1)
-        res = _gx_device(kind, g, symmetric, gate, np.maximum(at, 0),
-                         damping=damping, iterations=iterations, top=top,
-                         lay=lay)
-        with costs.stage("dev.post"):
-            head = res[0][:len(want)]
-            if kind == "pr":
-                steps = iterations
-                out = _pr_answer(nodes, want, at, head, res[1], res[2],
-                                 res[3], top)
-            elif kind == "wcc":
-                steps = int(res[3])
-                out = _wcc_answer(nodes, want, at, head, res[1], res[2])
-            else:
-                steps = 1
-                out = _lcc_answer(want, at, head, res[1][:len(want)],
-                                  res[2], res[3])
+        res, steps = _device(alg, g, lay, symmetric, gate,
+                             np.maximum(at, 0), req)
+        post = "dev.post"
     else:
         with costs.stage("exec"):
             nodes, esrc, edst = graph_arrays(csr)
-            n, edges = len(nodes), len(esrc)
+            edges = len(esrc)
             at = host_rank_of(nodes, want, -1)
-            if kind == "pr":
-                # tol -1: no delta stops it, every step runs
-                r, steps = pagerank_host(esrc, edst, n, damping=damping,
-                                         tol=-1.0, max_iters=iterations)
-                order = np.argsort(-r, kind="stable")
-                out = _pr_answer(nodes, want, at, r[at] if n else at,
-                                 r[order], order, r.sum(), top)
-            elif kind == "wcc":
-                lab = cc_host(esrc, edst, n)
-                sizes = np.unique(lab, return_counts=True)[1]
-                steps = 1                # union-find: one pass of the edges
-                out = _wcc_answer(nodes, want, at, lab[at] if n else at,
-                                  len(sizes), sizes.max(initial=0))
-            else:
-                tri, lcc = lcc_host(esrc, edst, n)
-                steps = 1
-                out = _lcc_answer(want, at, tri[at] if n else at,
-                                  lcc[at] if n else at, tri.sum() // 3,
-                                  lcc.sum())
-    out = {"kind": kind, "nodes": int(len(nodes)), "edges": int(edges),
-           "device": reason is None, **out}
-    if kind != "lcc":
-        out["iterations" if kind == "pr" else "rounds"] = int(steps)
+            res, steps = alg.host(esrc, edst, len(nodes), at, req)
+        post = "exec"
+    with costs.stage(post):
+        got = {**alg.answer(nodes, want, at, res, req.top),
+               "steps": int(steps)}
+        out = {"kind": kind, "nodes": int(len(nodes)), "edges": int(edges),
+               "device": reason is None,
+               **{key: got[of] for key, of in keys.items()}}
     if metrics is not None:
+        name, steps = alg.name, int(steps)
         metrics.counter("dgraph_analytics_runs_total").inc()
         metrics.counter("dgraph_analytics_edges_total").inc(int(edges))
-        metrics.counter("dgraph_analytics_iterations_total").inc(int(steps))
+        metrics.counter("dgraph_analytics_iterations_total").inc(steps)
         if reason is None:
             metrics.keyed("dgraph_analytics_device_runs_total",
-                          labels=("kind",)).inc(kind)
-            if kind == "lcc":
-                metrics.counter("dgraph_analytics_lcc_compares_total").inc(
-                    lay.compares)
-                metrics.counter("dgraph_analytics_lcc_merge_total").inc(
-                    lay.merge)
-                metrics.counter(
-                    "dgraph_analytics_lcc_core_edges_total").inc(
-                    lay.core_edges)
-                metrics.counter(
-                    "dgraph_analytics_lcc_oriented_edges_total").inc(
-                    lay.oriented_edges)
-            else:
-                if _reduce_path() == "pallas":
-                    metrics.keyed("dgraph_analytics_kernel_steps_total",
-                                  labels=("kind",)).inc(kind, int(steps))
-                metrics.keyed("dgraph_analytics_gather_steps_total",
-                              labels=("kind", "path")).inc(
-                    f"{kind}|{_gather_path(lay[0])}", int(steps))
+                          labels=("kind",)).inc(name)
+            alg.count(metrics, lay, name, steps)
         else:
             metrics.counter("dgraph_analytics_host_fallbacks_total").inc()
             metrics.keyed("dgraph_analytics_host_runs_total",
-                          labels=("kind", "reason")).inc(f"{kind}|{reason}")
+                          labels=("kind", "reason")).inc(f"{name}|{reason}")
         metrics.keyed("dgraph_analytics_steps_total",
-                      labels=("kind",)).inc(kind, int(steps))
+                      labels=("kind",)).inc(name, steps)
         metrics.keyed("dgraph_analytics_edges_read_total",
-                      labels=("kind",)).inc(kind, int(steps) * int(edges))
+                      labels=("kind",)).inc(name, steps * int(edges))
     return out

@@ -52,3 +52,33 @@ def _restore_query_edge_limit():
     old = engine.MAX_QUERY_EDGES
     yield
     engine.MAX_QUERY_EDGES = old
+
+
+@pytest.fixture(scope="module")
+def one_v5e():
+    """A described (not attached) v5e chip to compile for: the TPU's own
+    compiler and its memory analysis, with no chip. Skips where this
+    installation cannot describe one."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")   # or the compiler logs to /tmp
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        # a compile for a described chip can be written to the persistent
+        # cache and never read back (the next one would warn); the tests
+        # that take it are their files' last, so no other compile goes
+        # uncached
+        cached = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            jax.config.update("jax_enable_compilation_cache", cached)
+            compilation_cache.reset_cache()

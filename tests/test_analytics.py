@@ -1,123 +1,168 @@
-"""Whole-graph analytics (ISSUE 17): PageRank / connected components /
-triangle counting as device-resident while_loop programs on the mesh,
-checked against NetworkX oracles; host fallbacks byte-identical where the
-math is exact (CC labels, triangle counts); Node.analytics + /analytics
-surfaces with metrics and the LDBC SF10 scale gate.
-
-Needs the conftest-provided 8-virtual-device CPU mesh."""
+"""Whole-graph analytics under their older names: `pagerank`, `cc` and
+`triangles` are PR, WCC and LCC (query/analytics.KINDS) with answers of
+their own. Checked against NetworkX through Node.analytics on a tablet
+stored both ways (the one-chip device programs) and on a one-way tablet
+with source-only vertices (the host references); the stop rule that only
+`pagerank` has; dangling mass; empty and one-vertex tablets; and the
+Node / HTTP surface with its metrics."""
 
 import json
-import time
 
 import numpy as np
 import pytest
-import jax
 
 nx = pytest.importorskip("networkx")
 
 from dgraph_tpu.api.server import Node
-from dgraph_tpu.parallel.mesh_exec import MeshExecutor
 from dgraph_tpu.query import analytics as an
+from dgraph_tpu.storage.csr_build import PredCSR
+from test_graphalytics import _dangling_digraph, _load
 
-pytestmark = pytest.mark.skipif(
-    len(jax.devices()) < 8,
-    reason="needs the conftest-provided 8-virtual-device CPU mesh")
-
-
-@pytest.fixture(scope="module")
-def mesh():
-    return MeshExecutor()
+OLD = ("pagerank", "cc", "triangles")
 
 
 def _random_digraph(n, m, seed):
+    """Distinct edges over uids 1..n, no self-loops: some vertices are
+    sources only, some sinks."""
     rng = np.random.default_rng(seed)
-    e = rng.integers(0, n, size=(m, 2))
+    e = rng.integers(1, n + 1, size=(m, 2))
     e = np.unique(e[e[:, 0] != e[:, 1]], axis=0)
-    return e[:, 0].astype(np.int32), e[:, 1].astype(np.int32)
+    return e[:, 0], e[:, 1]
 
 
-# ---------------------------------------------------------------------------
-# device kernels vs NetworkX oracles
-# ---------------------------------------------------------------------------
-
-def test_pagerank_device_matches_networkx(mesh):
-    n = 500
-    esrc, edst = _random_digraph(n, 3000, 7)
-    r, it = mesh.run_pagerank(esrc, edst, n, tol=1e-9, max_iters=200)
-    assert 0 < it < 200
+def _digraph(src, dst):
     g = nx.DiGraph()
-    g.add_nodes_from(range(n))
-    g.add_edges_from(zip(esrc.tolist(), edst.tolist()))
+    g.add_edges_from(zip(src.tolist(), dst.tolist()))
+    return g
+
+
+@pytest.fixture(scope="module", params=["both_ways", "one_way"])
+def tablet(request):
+    """(stored both ways, src, dst, node): both ways, every source is a
+    destination and the device programs answer; one way, some source is
+    not and the host references answer."""
+    src, dst = _random_digraph(300, 1200, 7)
+    if request.param == "both_ways":
+        src, dst = np.concatenate([src, dst]), np.concatenate([dst, src])
+        e = np.unique(np.stack([src, dst], 1), axis=0)
+        src, dst = e[:, 0], e[:, 1]
+    else:
+        assert not np.isin(src, dst).all()
+    return request.param == "both_ways", src, dst, _load(src, dst)
+
+
+@pytest.mark.parametrize("kind", OLD)
+def test_old_names_match_networkx(tablet, kind):
+    """pagerank within 1e-6 of NetworkX at every vertex, cc's components
+    and triangles exact, each with its documented answer keys, on the
+    device (both ways) and on the host (one way)."""
+    device, src, dst, node = tablet
+    g = _digraph(src, dst)
+    n = g.number_of_nodes()
+    out = node.analytics(kind, "follows", tol=1e-9, max_iters=200, top=n)
+    assert out["device"] is device
+    assert (out["nodes"], out["edges"]) == (n, len(src))
+    own = {"pagerank": {"iterations", "top"},
+           "cc": {"iterations", "components", "largest"},
+           "triangles": {"triangles"}}[kind]
+    assert set(out) == {"kind", "pred", "nodes", "edges", "device"} | own
+    if kind == "pagerank":
+        oracle = nx.pagerank(g, alpha=0.85, tol=1e-12, max_iter=500)
+        assert len(out["top"]) == n and 0 < out["iterations"] <= 200
+        for row in out["top"]:
+            assert abs(row["score"] - oracle[int(row["uid"], 16)]) < 1e-6
+        scores = [row["score"] for row in out["top"]]
+        assert scores == sorted(scores, reverse=True)
+    elif kind == "cc":
+        sizes = [len(c) for c in nx.connected_components(g.to_undirected())]
+        assert (out["components"], out["largest"]) == (len(sizes),
+                                                      max(sizes))
+        assert out["iterations"] >= 1
+    else:
+        want = sum(nx.triangles(g.to_undirected()).values()) // 3
+        assert want > 0 and out["triangles"] == want
+
+
+def test_pagerank_stops_once_a_step_moves_less_than_tol(tablet):
+    """pagerank stops before max_iters, at the first step whose L1 delta
+    is <= tol: the float64 reference's delta at that step is within tol
+    and the step before's is not (a tol far above float32's noise)."""
+    device, _, _, node = tablet
+    tol = 1e-4
+    out = node.analytics("pagerank", "follows", tol=tol, max_iters=100)
+    assert out["device"] is device
+    it = out["iterations"]
+    assert 2 < it < 100
+    csr = node.snapshot().preds["follows"].csr
+    _, esrc, edst = an.graph_arrays(csr)
+    n = out["nodes"]
+    r = [an.pagerank_host(esrc, edst, n, tol=-1.0, max_iters=k)[0]
+         for k in (it - 2, it - 1, it)]
+    assert np.abs(r[2] - r[1]).sum() <= tol * (1 + 1e-3)
+    assert np.abs(r[1] - r[0]).sum() > tol * (1 - 1e-3)
+
+
+def test_pr_runs_every_step_whatever_tol(tablet):
+    """pr sent tol = 0.5 (which stops pagerank after its first step) still
+    runs exactly `iterations` steps, with the reference's ranks."""
+    device, _, _, node = tablet
+    stopped = node.analytics("pagerank", "follows", tol=0.5, max_iters=7)
+    assert stopped["iterations"] == 1
+    csr = node.snapshot().preds["follows"].csr
+    nodes, esrc, edst = an.graph_arrays(csr)
+    want, _ = an.pagerank_host(esrc, edst, len(nodes), tol=-1.0,
+                               max_iters=7)
+    probes = [hex(int(u)) for u in nodes]
+    out = node.analytics("pr", "follows", iterations=7, tol=0.5,
+                         uids=probes)
+    assert out["device"] is device and out["iterations"] == 7
+    got = np.asarray([out["values"][h] for h in probes])
+    assert np.abs(got - want).max() < 1e-6
+
+
+def test_pagerank_conserves_dangling_mass_on_the_device():
+    """Sinks hand their rank to every vertex: on the device path the ranks
+    sum to 1 and match NetworkX's (which treats sinks the same way)."""
+    src, dst = _dangling_digraph(3)
+    node = _load(src, dst)
+    g = _digraph(src, dst)
+    n = g.number_of_nodes()
+    assert any(d == 0 for _, d in g.out_degree())
+    out = node.analytics("pagerank", "follows", tol=1e-9, max_iters=200,
+                         top=n)
+    assert out["device"] is True and len(out["top"]) == n
+    assert abs(sum(row["score"] for row in out["top"]) - 1.0) < 1e-5
     oracle = nx.pagerank(g, alpha=0.85, tol=1e-12, max_iter=500)
-    want = np.asarray([oracle[i] for i in range(n)])
-    assert np.abs(np.asarray(r, np.float64) - want).max() < 1e-6
-    assert abs(float(np.sum(r)) - 1.0) < 1e-4
+    for row in out["top"]:
+        assert abs(row["score"] - oracle[int(row["uid"], 16)]) < 1e-6
 
 
-def test_pagerank_dangling_mass_conserved(mesh):
-    # a sink chain: dangling mass must redistribute, not vanish
-    esrc = np.asarray([0, 1, 2], np.int32)
-    edst = np.asarray([1, 2, 3], np.int32)
-    r, _ = mesh.run_pagerank(esrc, edst, 4, tol=1e-12, max_iters=300)
-    g = nx.DiGraph()
-    g.add_nodes_from(range(4))
-    g.add_edges_from(zip(esrc.tolist(), edst.tolist()))
-    oracle = nx.pagerank(g, alpha=0.85, tol=1e-14, max_iter=1000)
-    want = np.asarray([oracle[i] for i in range(4)])
-    assert np.abs(np.asarray(r, np.float64) - want).max() < 1e-6
-
-
-def test_cc_device_exact_vs_networkx(mesh):
-    n = 400
-    esrc, edst = _random_digraph(n, 260, 11)   # sparse → many components
-    lab, it = mesh.run_cc(esrc, edst, n)
-    assert it >= 1
-    g = nx.Graph()
-    g.add_nodes_from(range(n))
-    g.add_edges_from(zip(esrc.tolist(), edst.tolist()))
-    want = np.arange(n, dtype=np.int64)
-    for comp in nx.connected_components(g):
-        mn = min(comp)
-        for v in comp:
-            want[v] = mn
-    assert np.array_equal(np.asarray(lab, np.int64), want)
-
-
-def test_triangles_device_exact_vs_networkx(mesh):
-    n = 300
-    esrc, edst = _random_digraph(n, 4000, 13)
-    tri = mesh.run_triangles(esrc, edst, n)
-    g = nx.Graph()
-    g.add_nodes_from(range(n))
-    g.add_edges_from(zip(esrc.tolist(), edst.tolist()))
-    want = sum(nx.triangles(g).values()) // 3
-    assert tri == want
-
-
-def test_host_fallbacks_match_device(mesh):
-    n = 350
-    esrc, edst = _random_digraph(n, 2200, 17)
-    lab_d, _ = mesh.run_cc(esrc, edst, n)
-    lab_h = an.cc_host(esrc, edst, n)
-    assert np.array_equal(np.asarray(lab_d, np.int64),
-                          np.asarray(lab_h, np.int64))
-    assert mesh.run_triangles(esrc, edst, n) == \
-        an.lcc_host(esrc, edst, n)[0].sum() // 3
-    r_d, _ = mesh.run_pagerank(esrc, edst, n, tol=1e-9, max_iters=200)
-    r_h, _ = an.pagerank_host(esrc, edst, n, tol=1e-9, max_iters=200)
-    assert np.abs(np.asarray(r_d, np.float64) - r_h).max() < 1e-6
-
-
-def test_empty_and_single_node_graphs(mesh):
-    r, it = mesh.run_pagerank(np.zeros(0, np.int32), np.zeros(0, np.int32),
-                              1, tol=1e-9, max_iters=50)
-    assert len(r) == 1 and abs(float(r[0]) - 1.0) < 1e-6
-    lab, _ = mesh.run_cc(np.zeros(0, np.int32), np.zeros(0, np.int32), 3)
-    assert np.array_equal(np.asarray(lab), [0, 1, 2])
-    assert an.pagerank_host(np.zeros(0, np.int32),
-                            np.zeros(0, np.int32), 0)[0].shape == (0,)
-    assert an.lcc_host(np.zeros(0, np.int32),
-                       np.zeros(0, np.int32), 0)[0].sum() == 0
+@pytest.mark.parametrize("shape", ["empty", "one_vertex"])
+@pytest.mark.parametrize("kind", OLD)
+def test_empty_and_one_vertex_tablets(kind, shape):
+    """A tablet with no edge left answers 400 through the node and zeros
+    from the host path; one vertex with a self-loop holds the whole rank,
+    is one component and closes no triangle."""
+    if shape == "empty":
+        node = _load([1], [2])
+        node.mutate(del_nquads="<0x1> <follows> <0x2> .", commit_now=True)
+        with pytest.raises(ValueError):
+            node.analytics(kind, "follows")
+        nothing = np.zeros(0, np.int32)
+        out = an.run(kind, PredCSR(nothing, np.zeros(1, np.int32), nothing))
+        assert (out["nodes"], out["edges"], out["device"]) == (0, 0, False)
+        want = {"pagerank": {"iterations": 0, "top": []},
+                "cc": {"iterations": 1, "components": 0, "largest": 0},
+                "triangles": {"triangles": 0}}[kind]
+    else:
+        out = _load([1], [1]).analytics(kind, "follows")
+        assert (out["nodes"], out["edges"], out["device"]) == (1, 1, True)
+        want = {"pagerank": {"iterations": 1,
+                             "top": [{"uid": "0x1",
+                                      "score": pytest.approx(1.0, abs=1e-6)}]},
+                "cc": {"components": 1, "largest": 1},
+                "triangles": {"triangles": 0}}[kind]
+    assert {k: out[k] for k in want} == want
 
 
 # ---------------------------------------------------------------------------
@@ -131,22 +176,35 @@ follows: [uid] @reverse .
 
 
 def _social_quads(n=60, seed=3):
+    """Random follows over a ring, each stored both ways: every vertex is
+    a source and a destination, and the device path answers all three
+    kinds over `follows` and `~follows` alike."""
     rng = np.random.default_rng(seed)
     quads = [f'<0x{i:x}> <name> "u{i}" .' for i in range(1, n + 1)]
+    pairs = set()
     for i in range(1, n + 1):
-        for j in sorted(set(int(x) for x in rng.integers(1, n + 1, 4))):
+        for j in set(int(x) for x in rng.integers(1, n + 1, 4)) | {i % n + 1}:
             if j != i:
-                quads.append(f"<0x{i:x}> <follows> <0x{j:x}> .")
-    return "\n".join(quads)
+                pairs |= {(i, j), (j, i)}
+    quads += [f"<0x{i:x}> <follows> <0x{j:x}> ." for i, j in sorted(pairs)]
+    return quads
 
 
 @pytest.fixture(scope="module")
 def social_pair():
+    """(host, dev): the same graph, on `host` as a delta overlay — its
+    last edge committed after the tablet's first read — which the host
+    references answer, on `dev` as a base tablet the device answers."""
+    quads = _social_quads()
     nodes = []
-    for dev in (0, 8):
-        node = Node(mesh_devices=dev, mesh_min_edges=1)
+    for last in (True, False):
+        node = Node()
         node.alter(schema_text=SCHEMA)
-        node.mutate(set_nquads=_social_quads(), commit_now=True)
+        node.mutate(set_nquads="\n".join(quads[:-1] if last else quads),
+                    commit_now=True)
+        if last:
+            node.analytics("cc", "follows")
+            node.mutate(set_nquads=quads[-1], commit_now=True)
         nodes.append(node)
     return nodes
 
@@ -174,7 +232,6 @@ def test_node_analytics_reverse_pred_and_oracle(social_pair):
     assert out["pred"] == "~follows" and out["device"] is True
     # oracle over the reversed edge set
     g = nx.DiGraph()
-    uids, _, _ = dev._read_view(None)[1].pred("follows").csr.host_arrays()
     q, _ = dev.query('{ q(func: has(name)) { uid follows { uid } } }')
     for row in q["q"]:
         for t in row.get("follows", []):
@@ -238,39 +295,3 @@ def test_http_analytics_endpoint(social_pair):
         assert ei.value.code == 400
     finally:
         srv.shutdown()
-
-
-# ---------------------------------------------------------------------------
-# scale gate: LDBC SF10 person_knows_person PageRank in seconds
-# ---------------------------------------------------------------------------
-
-def test_pagerank_ldbc_sf10_scale(tmp_path, mesh):
-    """The acceptance claim: PageRank over the LDBC SF10 knows graph
-    (~70k persons, ~1.5M edges) converges on the mesh in seconds and
-    matches the NetworkX oracle."""
-    from dgraph_tpu.models.ldbc import generate_ldbc
-
-    d = tmp_path / "ldbc"
-    st = generate_ldbc(str(d), sf=10)
-    assert st.persons > 50_000 and st.knows > 1_000_000
-    raw = np.loadtxt(d / "person_knows_person_0_0.csv", delimiter="|",
-                     skiprows=1, usecols=(0, 1), dtype=np.int64)
-    ids = np.unique(raw)
-    esrc = np.searchsorted(ids, raw[:, 0]).astype(np.int32)
-    edst = np.searchsorted(ids, raw[:, 1]).astype(np.int32)
-    n = len(ids)
-    t0 = time.perf_counter()
-    r, it = mesh.run_pagerank(esrc, edst, n, tol=1e-8, max_iters=200)
-    dt = time.perf_counter() - t0
-    assert 0 < it < 200
-    assert dt < 120.0, f"SF10 PageRank took {dt:.1f}s"
-    g = nx.DiGraph()
-    g.add_nodes_from(range(n))
-    g.add_edges_from(zip(esrc.tolist(), edst.tolist()))
-    oracle = nx.pagerank(g, alpha=0.85, tol=1e-11, max_iter=500)
-    want = np.asarray([oracle[i] for i in range(n)])
-    got = np.asarray(r, np.float64)
-    assert np.abs(got - want).max() < 1e-5
-    # the top of the ranking is stable across device/oracle
-    assert set(np.argsort(-got)[:10].tolist()) == \
-        set(np.argsort(-want)[:10].tolist())

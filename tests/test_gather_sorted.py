@@ -145,7 +145,8 @@ def test_served_programs_keep_their_names_with_the_kernel():
              pb.RowEnds(arg((n_items,)), arg((n_items,))))
     lay = pb.GatherLayout(arg((e_pad,)), arg((e_pad,)), arg((1, 1, 128)))
     pr = pb.analytics_pr.lower(*graph, arg((nd,)), arg((64,)), arg(()),
-                               arg((), jnp.float32), lay, top=20).as_text()
+                               arg((), jnp.float32), arg((), jnp.float32),
+                               lay, top=20).as_text()
     wcc = pb.analytics_wcc.lower(*graph, arg((64,)), lay,
                                  push=False).as_text()
     assert "module @jit_analytics_pr " in pr and "gather_sorted" in pr

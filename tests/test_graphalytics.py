@@ -16,6 +16,7 @@ import json
 import time
 import urllib.request
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -85,14 +86,15 @@ def test_vmem_gather_leaves_every_rank_and_label_bit_identical(kron):
     sums each row in the same order."""
     _, _, _, node = kron
     csr = node.snapshot().preds["follows"].csr
-    g = an.pull_layout(csr)[0]
-    lay, windows = an.gather_layout(csr, g)
+    g = an.layout(csr, "pull")[0]
+    lay, windows = an.layout(csr, "gather")
     assert lay is not None and windows > 0
     probes = np.arange(len(g.host_in_subjects), dtype=np.int32)
     for gather in (None, lay):
         pr = pb.analytics_pr(g.in_src_pad_d, g.in_iptr_rank, g.row_ends,
                              g.out_degree_d, probes, np.int32(10),
-                             np.float32(0.85), gather, top=20)
+                             np.float32(0.85), np.float32(-1), gather,
+                             top=20)
         wcc = pb.analytics_wcc(g.in_src_pad_d, g.in_iptr_rank, g.row_ends,
                                probes, gather, push=False)
         got = [np.asarray(a).tobytes() for a in (*pr, *wcc)]
@@ -112,7 +114,7 @@ def test_lower_precision_and_lost_dangling_mass_fail_the_check(kron):
     probes = np.arange(len(nodes), dtype=np.int32)
     vals = pb.analytics_pr(g.in_src_pad_d, g.in_iptr_rank, g.row_ends,
                            g.out_degree_d, probes, np.int32(10),
-                           jnp.bfloat16(0.85), top=1)[0]
+                           jnp.bfloat16(0.85), jnp.bfloat16(-1), top=1)[0]
     assert ref.rel_error(np.asarray(vals, np.float64), want) > TOL
     s, t = _dangling_digraph(7)
     _, full = ref.pagerank(s, t)
@@ -145,7 +147,7 @@ def test_directed_graph_with_dangling_vertices(directed):
     ways, a WCC round also pushes along out-edges."""
     s, t, node = directed
     csr = node.snapshot().preds["follows"].csr
-    assert an.pull_layout(csr)[1:] == (None, False)
+    assert an.layout(csr, "pull")[1:] == (None, False)
     nodes, want = ref.pagerank(s, t)
     out = node.analytics("pr", "follows", uids=_hexes(nodes))
     assert out["device"] is True
@@ -183,7 +185,7 @@ def test_wcc_on_sparse_random_graphs_with_many_components(seed):
     s, t = e[:, 0], e[:, 1]
     node = _load(s, t)
     csr = node.snapshot().preds["follows"].csr
-    assert an.pull_layout(csr)[1] is None
+    assert an.layout(csr, "pull")[1] is None
     nodes, labels = ref.wcc(s, t)
     out = node.analytics("wcc", "follows", uids=_hexes(nodes))
     assert out["device"] is True
@@ -272,13 +274,13 @@ def test_gather_layout_is_built_by_the_first_pr_or_wcc_only(kind):
     csr = node.snapshot().preds["follows"].csr
     node.query(f"{{ q(func: uid(0x{int(src[0]):x})) {{ follows {{ uid }} }} }}")
     assert node.analytics("lcc", "follows")["device"] is True
-    assert getattr(csr, "_gx_gather", None) is None
+    assert "gather" not in an.layouts(csr)
     assert node.analytics(kind, "follows")["device"] is True
-    built = csr._gx_gather
+    built = an.layouts(csr)["gather"]
     assert built[0] is not None and built[1] > 0
     other = "wcc" if kind == "pr" else "pr"
     assert node.analytics(other, "follows")["device"] is True
-    assert csr._gx_gather is built
+    assert an.layouts(csr)["gather"] is built
 
 
 def test_http_kinds_stages_span_and_counters():
@@ -329,7 +331,7 @@ def test_http_kinds_stages_span_and_counters():
     # or (off the chip) in Pallas' interpreter, which the counter leaves out
     reduce = "interpret" if pb.interpret_mode() else "pallas"
     gather = "interpret" if pb.interpret_mode() else "vmem"
-    assert csr._gx_gather[0] is not None
+    assert an.layouts(csr)["gather"][0] is not None
     for kind, steps in (("pr", 10), ("wcc", wcc["rounds"])):
         assert val("dgraph_analytics_device_runs_total", kind=kind) == 1
         # every step's gather by source rank, by where it ran
@@ -361,4 +363,67 @@ def test_http_kinds_stages_span_and_counters():
     assert kernels["pb.analytics_wcc"]["edges"] == len(src)
     assert {k["reduce"] for k in kernels.values()} == {reduce}
     assert {k["gather"] for k in kernels.values()} == {gather}
-    assert {k["windows"] for k in kernels.values()} == {csr._gx_gather[1]}
+    assert {k["windows"] for k in kernels.values()} == {
+        an.layouts(csr)["gather"][1]}
+
+
+@pytest.mark.parametrize("program", ["analytics_pr", "analytics_wcc"])
+def test_analytics_programs_reduce_rows_in_the_kernel(one_v5e, monkeypatch,
+                                                      program):
+    """A whole-graph step's per-destination sum / min runs in row_reduce
+    compiled for the chip: the program holds a Pallas custom call and no
+    scatter into Nd + 1 slots (the segment_sum / segment_min by
+    destination rank it replaced). What scatters are left build the
+    destination ids once a call (into E_pad + 1) or are FastSV's
+    vertex-sized hooking and the component sizes (into Nd)."""
+    monkeypatch.setattr(pb, "interpret_mode", lambda: False)
+    e_pad, n_items, nd = 16 * pb.EDGE_BLOCK, 32, 3_001
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e)
+
+    graph = (arg((e_pad,)), arg((nd + 1,)),
+             pb.RowEnds(arg((n_items,)), arg((n_items,))))
+    if program == "analytics_pr":
+        lowered = pb.analytics_pr.lower(
+            *graph, arg((nd,)), arg((64,)), arg(()), arg((), jnp.float32),
+            arg((), jnp.float32), top=20)
+    else:
+        lowered = pb.analytics_wcc.lower(*graph, arg((64,)), push=False)
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text
+    scatters = [ln for ln in text.splitlines() if " scatter(" in ln]
+    assert scatters and not any(f"[{nd + 1}]" in ln for ln in scatters)
+
+
+@pytest.mark.parametrize("program", ["analytics_pr", "analytics_wcc"])
+def test_analytics_programs_gather_by_source_in_the_kernel(one_v5e,
+                                                           monkeypatch,
+                                                           program):
+    """Handed a GatherLayout, a whole-graph step gathers by source rank
+    in gather_sorted compiled for the chip, with the largest table the
+    VMEM budget admits (GATHER_TABLE_MAX, 1M values): the program holds
+    no XLA gather with an element an edge, only vertex-sized ones
+    (FastSV's parents, the probes)."""
+    monkeypatch.setattr(pb, "interpret_mode", lambda: False)
+    e_pad, n_items = 16 * pb.EDGE_BLOCK, 32
+    nd = pb.GATHER_TABLE_MAX // 4 - 1
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e)
+
+    graph = (arg((e_pad,)), arg((nd + 1,)),
+             pb.RowEnds(arg((n_items,)), arg((n_items,))))
+    lay = pb.GatherLayout(arg((e_pad,)), arg((e_pad,)),
+                          arg((e_pad // pb.EDGE_BLOCK, 1, 128)))
+    if program == "analytics_pr":
+        lowered = pb.analytics_pr.lower(
+            *graph, arg((nd,)), arg((64,)), arg(()), arg((), jnp.float32),
+            arg((), jnp.float32), lay, top=20)
+    else:
+        lowered = pb.analytics_wcc.lower(*graph, arg((64,)), lay,
+                                         push=False)
+    text = lowered.compile().as_text()
+    assert "gather_sorted" in text
+    gathers = [ln for ln in text.splitlines() if " gather(" in ln]
+    assert not any(f"[{e_pad}]" in ln for ln in gathers)

@@ -65,7 +65,7 @@ def test_a_partial_core_matches_the_reference_at_every_vertex(
     nodes, tri, want = ref.lcc(src, dst)
     out = node.analytics("lcc", "follows", uids=_hexes(nodes))
     assert out["device"] is True and out["kind"] == "lcc"
-    lay = node.snapshot().preds["follows"].csr._lcc_layout
+    lay = an.layouts(node.snapshot().preds["follows"].csr)["lcc"]
     assert len(nodes) > lay.core == 512 and lay.core_edges > 0
     assert lay.compares > lay.merge > 0
     _check(out, nodes, tri, want)
@@ -121,7 +121,7 @@ def test_duplicates_and_self_loops_in_the_stored_tablet():
     dst = np.concatenate([dst, [2, 3, 8, 9, 50], dst[:6]])
     node = _load(src, dst)
     csr = node.snapshot().preds["follows"].csr
-    assert an.pull_layout(csr)[1:] == (None, True)
+    assert an.layout(csr, "pull")[1:] == (None, True)
     nodes, tri, want = ref.lcc(src, dst)
     assert 50 in nodes.tolist()
     out = node.analytics("lcc", "follows", uids=_hexes(nodes))
@@ -148,7 +148,7 @@ def test_a_hub_beyond_every_row_class_keeps_a_short_row():
     assert out["triangles"]["0x1"] == 1500
     assert out["values"]["0x1"] == pytest.approx(1500 / (1500 * 1499 / 2),
                                                  rel=TOL)
-    lay = node.snapshot().preds["follows"].csr._lcc_layout
+    lay = an.layouts(node.snapshot().preds["follows"].csr)["lcc"]
     assert lay.max_out == 3 and lay.oriented_edges == len(src) // 2
 
 
@@ -298,7 +298,7 @@ def test_a_one_way_tablet_declines_to_the_host_and_is_counted():
     t = np.asarray([2, 3, 1, 4, 5, 3, 1])        # every source a dst
     node = _load(s, t)
     csr = node.snapshot().preds["follows"].csr
-    assert an.pull_layout(csr)[1:] == (None, False)
+    assert an.layout(csr, "pull")[1:] == (None, False)
     nodes, tri, want = ref.lcc(s, t)
     out = node.analytics("lcc", "follows", uids=_hexes(nodes))
     assert out["device"] is False
@@ -307,7 +307,7 @@ def test_a_one_way_tablet_declines_to_the_host_and_is_counted():
         "lcc|one_way") == 1
     assert node.metrics.keyed("dgraph_analytics_device_runs_total").get(
         "lcc") == 0
-    assert getattr(csr, "_lcc_layout", None) is None
+    assert "lcc" not in an.layouts(csr)
 
 
 def test_a_write_answers_on_the_host_then_on_the_device():
@@ -317,10 +317,9 @@ def test_a_write_answers_on_the_host_then_on_the_device():
     node = _load(src, dst)
     first = node.analytics("pr", "follows")
     csr = node.snapshot().preds["follows"].csr
-    assert first["device"] is True and getattr(csr, "_lcc_layout",
-                                               None) is None
+    assert first["device"] is True and "lcc" not in an.layouts(csr)
     assert node.analytics("lcc", "follows")["device"] is True
-    assert csr._lcc_layout is not None
+    assert an.layouts(csr).get("lcc") is not None
     new = [(0x7001, 0x7002), (0x7002, 0x7003), (0x7003, 0x7001)]
     s2, t2 = _both_ways(new)
     node.mutate(set_nquads="\n".join(f"<0x{a:x}> <follows> <0x{b:x}> ."
@@ -395,7 +394,7 @@ def test_http_kind_counters_and_span(monkeypatch):
         return next(v for lab, v in parsed[name]
                     if all(lab.get(k) == x for k, x in labels.items()))
 
-    lay = node.snapshot().preds["follows"].csr._lcc_layout
+    lay = an.layouts(node.snapshot().preds["follows"].csr)["lcc"]
     assert val("dgraph_analytics_device_runs_total", kind="lcc") == 1
     assert val("dgraph_analytics_steps_total", kind="lcc") == 1
     assert val("dgraph_analytics_edges_read_total", kind="lcc") == len(src)

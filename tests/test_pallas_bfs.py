@@ -691,35 +691,6 @@ def test_recurse_dedups_edges(rng, graph, levels_of, allow_loop):
         assert any(sum(len(r) for r in m) < t for _f, t, m in want)
 
 
-@pytest.fixture(scope="module")
-def one_v5e():
-    """A described (not attached) v5e chip to compile for: the TPU's own
-    compiler and its memory analysis, with no chip. Skips where this
-    installation cannot describe one."""
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
-
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setenv("TPU_LOG_DIR", "disabled")   # or the compiler logs to /tmp
-        try:
-            topo = topologies.get_topology_desc(platform="tpu",
-                                                topology_name="v5e:2x2")
-        except Exception as e:
-            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-        # a compile for a described chip can be written to the persistent
-        # cache and never read back (the next one would warn); these are
-        # the file's last tests, so no other compile goes uncached
-        cached = jax.config.jax_enable_compilation_cache
-        jax.config.update("jax_enable_compilation_cache", False)
-        compilation_cache.reset_cache()
-        try:
-            yield SingleDeviceSharding(topo.devices[0])
-        finally:
-            jax.config.update("jax_enable_compilation_cache", cached)
-            compilation_cache.reset_cache()
-
-
 @pytest.mark.parametrize("program", ["recurse_fused", "recurse_fused_multi",
                                      "recurse_step"])
 def test_recurse_programs_hold_nothing_edge_sized(one_v5e, monkeypatch,
@@ -758,65 +729,3 @@ def test_recurse_programs_hold_nothing_edge_sized(one_v5e, monkeypatch,
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.output_size_in_bytes < e_pad
-
-
-@pytest.mark.parametrize("program", ["analytics_pr", "analytics_wcc"])
-def test_analytics_programs_reduce_rows_in_the_kernel(one_v5e, monkeypatch,
-                                                      program):
-    """A whole-graph step's per-destination sum / min runs in row_reduce
-    compiled for the chip: the program holds a Pallas custom call and no
-    scatter into Nd + 1 slots (the segment_sum / segment_min by
-    destination rank it replaced). What scatters are left build the
-    destination ids once a call (into E_pad + 1) or are FastSV's
-    vertex-sized hooking and the component sizes (into Nd)."""
-    monkeypatch.setattr(pb, "interpret_mode", lambda: False)
-    e_pad, n_items, nd = 16 * pb.EDGE_BLOCK, 32, 3_001
-
-    def arg(shape, dtype=jnp.int32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e)
-
-    graph = (arg((e_pad,)), arg((nd + 1,)),
-             pb.RowEnds(arg((n_items,)), arg((n_items,))))
-    if program == "analytics_pr":
-        lowered = pb.analytics_pr.lower(
-            *graph, arg((nd,)), arg((64,)), arg(()), arg((), jnp.float32),
-            top=20)
-    else:
-        lowered = pb.analytics_wcc.lower(*graph, arg((64,)), push=False)
-    text = lowered.compile().as_text()
-    assert "tpu_custom_call" in text
-    scatters = [ln for ln in text.splitlines() if " scatter(" in ln]
-    assert scatters and not any(f"[{nd + 1}]" in ln for ln in scatters)
-
-
-@pytest.mark.parametrize("program", ["analytics_pr", "analytics_wcc"])
-def test_analytics_programs_gather_by_source_in_the_kernel(one_v5e,
-                                                           monkeypatch,
-                                                           program):
-    """Handed a GatherLayout, a whole-graph step gathers by source rank
-    in gather_sorted compiled for the chip, with the largest table the
-    VMEM budget admits (GATHER_TABLE_MAX, 1M values): the program holds
-    no XLA gather with an element an edge, only vertex-sized ones
-    (FastSV's parents, the probes)."""
-    monkeypatch.setattr(pb, "interpret_mode", lambda: False)
-    e_pad, n_items = 16 * pb.EDGE_BLOCK, 32
-    nd = pb.GATHER_TABLE_MAX // 4 - 1
-
-    def arg(shape, dtype=jnp.int32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e)
-
-    graph = (arg((e_pad,)), arg((nd + 1,)),
-             pb.RowEnds(arg((n_items,)), arg((n_items,))))
-    lay = pb.GatherLayout(arg((e_pad,)), arg((e_pad,)),
-                          arg((e_pad // pb.EDGE_BLOCK, 1, 128)))
-    if program == "analytics_pr":
-        lowered = pb.analytics_pr.lower(
-            *graph, arg((nd,)), arg((64,)), arg(()), arg((), jnp.float32),
-            lay, top=20)
-    else:
-        lowered = pb.analytics_wcc.lower(*graph, arg((64,)), lay,
-                                         push=False)
-    text = lowered.compile().as_text()
-    assert "gather_sorted" in text
-    gathers = [ln for ln in text.splitlines() if " gather(" in ln]
-    assert not any(f"[{e_pad}]" in ln for ln in gathers)

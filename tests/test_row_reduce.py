@@ -123,7 +123,7 @@ def test_programs_keep_the_names_the_benchmark_reads(op):
     if op == "gx_pr":
         lowered = pb.analytics_pr.lower(
             *graph, arg((nd,)), arg((64,)), arg(()), arg((), jnp.float32),
-            top=20)
+            arg((), jnp.float32), top=20)
     else:
         lowered = pb.analytics_wcc.lower(*graph, arg((64,)), push=False)
     name = _harness_programs()[op]
